@@ -32,7 +32,7 @@ tier1: hash-stream-smoke chaos-smoke wal-torture-smoke statesync-smoke statetree
 # (bench_devd_stream asserts the streamed-vs-single-shot win;
 # bench_partset asserts the hash-stream + flat-builder wins).
 bench-smoke:
-	JAX_PLATFORMS=cpu TENDERMINT_TPU_PLATFORM=cpu $(PY) benches/run_all.py
+	JAX_PLATFORMS=cpu TENDERMINT_TPU_DISABLE=1 $(PY) benches/run_all.py
 
 # Hash-plane smoke, chip-free and fast (~30 s): only bench_partset's two
 # asserted rows — sim-transport hash_stream and the flat host builder —

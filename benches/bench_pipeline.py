@@ -41,7 +41,7 @@ netchaos tier exercises — and it is the structural prerequisite for the
 big-committee and sharded-device-plane items (ROADMAP).
 
 Chip-free: consensus + kvstore host planes; verify/hash ride the
-gateway's CPU/AVX floor. A live-daemon row joins the standard tunnel
+gateway's CPU/AVX floor. A live-daemon row joins the standard live-chip
 queue (the batched deliver verify routes through the same verify plane
 BENCH_r06 records).
 

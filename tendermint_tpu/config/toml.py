@@ -55,10 +55,7 @@ def config_to_toml(cfg: Config) -> str:
 
 def load_config(root: str) -> Config:
     """Read config.toml under root (tomllib), overlaying defaults."""
-    try:
-        import tomllib
-    except ImportError:  # Python < 3.11: same API under the PyPI name
-        import tomli as tomllib
+    import tomllib
 
     cfg = default_config()
     path = os.path.join(root, _CONFIG_FILE)

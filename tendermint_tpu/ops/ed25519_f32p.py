@@ -341,10 +341,19 @@ def _get_verify(tile: int, interpret: bool):
     return _verify_calls[key]
 
 
-def _on_tpu() -> bool:
-    from tendermint_tpu.ops.gateway import on_tpu
+def _interpret() -> bool:
+    """Decided from the backend of the process that runs the kernel
+    (ops/gateway.pallas_interpret): never from a platform guess."""
+    from tendermint_tpu.ops.gateway import pallas_interpret
 
-    return on_tpu()
+    return pallas_interpret()
+
+
+def built_interpret_modes() -> list[bool]:
+    """interpret= of every pallas_call this process has built — what the
+    device daemon reports for the kernel it serves (must be [False] on
+    a chip)."""
+    return sorted({key[1] for key in _verify_calls})
 
 
 @jax.jit
@@ -391,7 +400,7 @@ def verify_batch_async(items: list[tuple[bytes, bytes, bytes]]):
     if len(items) == 0:
         return lambda: np.zeros(0, dtype=bool)
     args, valid, n = marshal_device_args(items)
-    fn = _get_verify(S_TILE, not _on_tpu())
+    fn = _get_verify(S_TILE, _interpret())
     ok = fn(*args)
     return lambda: materialize_verdicts(ok, valid, n)
 
@@ -443,10 +452,7 @@ def make_sharded_verify(mesh, on_tpu: bool):
     if key in _sharded_calls:
         return _sharded_calls[key]
     import jax
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as PS
 
     spec = PS(None, "batch", None)

@@ -280,10 +280,18 @@ def _get_verify(tb: int, interpret: bool):
     return _verify_calls[key]
 
 
-def _on_tpu() -> bool:
-    from tendermint_tpu.ops.gateway import on_tpu
+def built_interpret_modes() -> list[bool]:
+    """interpret= of every pallas_call this process has built (what the
+    device daemon reports for a Pallas kernel it serves)."""
+    return sorted({key[1] for key in _verify_calls})
 
-    return on_tpu()
+
+def _interpret() -> bool:
+    """Decided from the backend of the process that runs the kernel
+    (ops/gateway.pallas_interpret): never from a platform guess."""
+    from tendermint_tpu.ops.gateway import pallas_interpret
+
+    return pallas_interpret()
 
 
 S_TILE = 8  # (8, 128) = one full int32 vreg per limb row
@@ -295,7 +303,7 @@ def verify_batch(items: list[tuple[bytes, bytes, bytes]]) -> np.ndarray:
     n = len(items)
     if n == 0:
         return np.zeros(0, dtype=bool)
-    interpret = not _on_tpu()
+    interpret = _interpret()
     tile_lanes = S_TILE * 128
     bucket = ((n + tile_lanes - 1) // tile_lanes) * tile_lanes
     s_total = bucket // 128

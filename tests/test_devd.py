@@ -352,11 +352,14 @@ def test_available_requires_held_device(daemon, monkeypatch, tmp_path):
     assert devd.available() is None
 
 
-def test_resolve_platform_waits_out_claiming_daemon(monkeypatch, tmp_path):
+@pytest.mark.parametrize("final,want", [("serving", "tpu"), ("failed", None)])
+def test_resolve_platform_waits_out_claiming_daemon(
+        monkeypatch, tmp_path, final, want):
     """A devd socket whose daemon is mid-claim/warm means the chip is
     (about to be) owned: resolve_platform must WAIT for it to serve —
-    never launch a contending probe, never latch the CPU path minutes
-    before the daemon comes up (VERDICT r4 #2's anti-goal)."""
+    never touch the device itself, never settle on the host path minutes
+    before the daemon comes up. A daemon that reports `failed` (final:
+    it is about to exit) ends the wait at once with no accelerator."""
     import pickle
     import socket as socketlib
     import struct
@@ -376,6 +379,9 @@ def test_resolve_platform_waits_out_claiming_daemon(monkeypatch, tmp_path):
                 if state["pings"] < 3:
                     rep = {"ok": True, "held": False, "status": "warming",
                            "platform": None}
+                elif final == "failed":
+                    rep = {"ok": True, "held": False, "status": "failed",
+                           "platform": None, "error": "ClaimError: boom"}
                 else:
                     rep = {"ok": True, "held": True, "status": "serving",
                            "platform": "tpu"}
@@ -400,6 +406,245 @@ def test_resolve_platform_waits_out_claiming_daemon(monkeypatch, tmp_path):
     monkeypatch.setitem(gateway._platform_cache, "v", None)
     gateway._platform_cache.pop("v")
     devd.bust_avail_cache()
-    assert gateway.resolve_platform() == "tpu"
+    t0 = time.time()
+    assert gateway.resolve_platform() == want
     assert state["pings"] >= 3  # it actually polled through "warming"
+    assert time.time() - t0 < 25  # and "failed" did not wait out the bound
     gateway._platform_cache.pop("v", None)
+
+
+# -- the rules of the device plane (PR 22) ------------------------------------
+#
+# libtpu gives a chip to ONE process. Its owner — the daemon — never
+# serves the host verifier under the device's name, a claim that fails is
+# final, and everybody else is TOLD the platform: nobody dials to find out.
+
+
+def test_ping_carries_device_kind_and_count(daemon):
+    """`kind` and `count` of chip_smoke.py's last line come from the
+    daemon: the one process that may ask JAX."""
+    _, client = daemon
+    rep = client.ping()
+    assert rep["device_kind"] == "cpu"
+    assert rep["device_count"] >= 1
+    assert len(rep["device_ids"]) == rep["device_count"]
+    assert rep["error"] is None
+    claim = client.status()["claim"]
+    assert claim["served"] == "f32"
+    assert claim["cache_dir"]
+    assert claim["kernels"]["f32"]["warm_s"].keys() == {"16"}
+
+
+# A daemon whose f32 kernel raises — on marked batches ("marked": the
+# warm-up passes, a later client batch hits it) or on every batch
+# ("always": the warm-up itself hits it). The steering lives HERE, in the
+# test's launcher; the program grows no option for it.
+_BOOM_DAEMON = """
+import sys
+from tendermint_tpu.ops import ed25519_f32 as k
+mode = sys.argv[1]
+real = k.verify_batch_async
+def boom(items):
+    if mode == "always" or any(m.startswith(b"BOOM") for _, m, _ in items):
+        raise RuntimeError("kernel refused (test)")
+    return real(items)
+k.verify_batch_async = boom
+k.verify_batch = lambda items: boom(items)()
+from tendermint_tpu import devd
+devd.main()
+"""
+
+
+def _start_boom_daemon(tmp_path, mode: str):
+    sock = str(tmp_path / "boom.sock")
+    env = {
+        **os.environ,
+        "JAX_PLATFORMS": "cpu",
+        "TENDERMINT_DEVD_SOCK": sock,
+        "TENDERMINT_DEVD_ACCEPT_CPU": "1",
+        "TENDERMINT_DEVD_WARM": "16",
+        "TENDERMINT_DEVD_EXIT_ON_TERM": "1",
+        "PYTHONPATH": REPO,
+    }
+    proc = subprocess.Popen(
+        [sys.executable, "-c", _BOOM_DAEMON, mode], env=env, cwd=REPO,
+        stdout=subprocess.DEVNULL, stderr=subprocess.PIPE,
+    )
+    return sock, proc
+
+
+def _wait_status(sock, proc, wanted: set, timeout: float = 240.0) -> dict:
+    client = devd.DevdClient(sock)
+    deadline = time.time() + timeout
+    try:
+        while time.time() < deadline:
+            try:
+                rep = client.ping(timeout=2.0)
+                if rep.get("status") in wanted:
+                    return rep
+            except Exception:  # noqa: BLE001 — not listening yet
+                pass
+            if proc.poll() is not None:
+                break
+            time.sleep(0.2)
+    finally:
+        client.close()
+    err = proc.stderr.read() if proc.poll() is not None else b""
+    proc.kill()
+    pytest.fail(f"daemon never reached {wanted}: {err[-2000:]!r}")
+
+
+def test_kernel_failure_in_daemon_is_an_error_not_a_host_answer(
+        tmp_path, monkeypatch):
+    """The owner of the chip never answers an Ed25519 lane from the
+    host: a kernel that raises inside the daemon is an error to the
+    client (whose breaker then opens and whose OWN host verifier
+    answers), the daemon's cpu_sigs stays 0, and the daemon keeps
+    serving the batches its kernel accepts."""
+    sock, proc = _start_boom_daemon(tmp_path, "marked")
+    try:
+        _wait_status(sock, proc, {"serving"})
+        client = devd.DevdClient(sock)
+        bad = _items(4, tag=b"BOOM")
+        with pytest.raises(devd.DevdError, match="kernel refused"):
+            client.verify_batch(bad)
+        with pytest.raises(Exception, match="kernel refused"):
+            client.verify_stream(bad, chunk=2)
+        good = _items(4, tag=b"fine")
+        assert client.verify_batch(good) == [True] * 4
+        assert client.stats()["cpu_sigs"] == 0
+
+        # through the gateway: the client's breaker does its job
+        monkeypatch.setenv("TENDERMINT_DEVD_SOCK", sock)
+        monkeypatch.delenv("TENDERMINT_TPU_KERNEL", raising=False)
+        monkeypatch.setenv("TENDERMINT_TPU_BREAKER_BACKOFF_S", "30")
+        devd.bust_avail_cache()
+        import tendermint_tpu.ops.devd_backend as backend
+        from tendermint_tpu.ops import gateway
+
+        monkeypatch.setattr(backend, "_client", None)
+        gateway.reset_devd_breaker()
+        try:
+            v = gateway.Verifier(min_tpu_batch=1)
+            assert v._kernel == "devd"
+            assert v.verify_batch(bad) == [True] * 4  # the CLIENT's host path
+            br = gateway.devd_breaker()
+            assert br.state == br.OPEN
+            assert v.stats()["cpu_sigs"] == 4
+        finally:
+            gateway.reset_devd_breaker()
+        stats = client.stats()
+        assert stats["cpu_sigs"] == 0, stats
+        client.shutdown()
+        client.close()
+        assert proc.wait(timeout=30) == 0
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+
+
+def test_failed_claim_is_final_status_failed_exit_nonzero(tmp_path):
+    """A warm-up that hits a refused kernel: status `failed` with the
+    error text in ping, and the process exits non-zero — it does not
+    retry for ever and does not serve the host under the device's name."""
+    sock, proc = _start_boom_daemon(tmp_path, "always")
+    try:
+        rep = _wait_status(sock, proc, {"failed"})
+        assert not rep["held"]
+        assert "kernel refused" in rep["error"]
+        assert proc.wait(timeout=30) != 0
+        assert b"claim failed" in proc.stderr.read()
+        assert not os.path.exists(sock)
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+
+
+def test_production_daemon_without_accelerator_fails_its_claim(tmp_path):
+    """No ACCEPT_CPU and no chip: initialising lands on the cpu backend,
+    which a production daemon refuses to serve. Bounded, no retry loop."""
+    sock = str(tmp_path / "prod.sock")
+    env = {
+        **{k: v for k, v in os.environ.items()
+           if k != "TENDERMINT_DEVD_ACCEPT_CPU"},
+        "JAX_PLATFORMS": "cpu",
+        "TENDERMINT_DEVD_SOCK": sock,
+        "TENDERMINT_DEVD_EXIT_ON_TERM": "1",
+    }
+    t0 = time.time()
+    proc = subprocess.run(
+        [sys.executable, "-m", "tendermint_tpu.devd"],
+        env=env, cwd=REPO, capture_output=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert b"no accelerator" in proc.stderr
+    assert time.time() - t0 < 60
+
+
+@pytest.mark.parametrize("placed", [True, False])
+def test_jitcache_enable_honours_an_outside_cache_dir(tmp_path, placed):
+    """With JAX_COMPILATION_CACHE_DIR set the program sets no cache
+    directory in code (JAX reads the variable itself); without it the
+    cache is at a fixed path under the checkout."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cc")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax\n"
+         "from tendermint_tpu.jitcache import enable\n"
+         "before = jax.config.jax_compilation_cache_dir\n"
+         "print(before); print(enable())"],
+        env=env, cwd=REPO, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    before, after = out.stdout.split()[-2:]
+    if placed:
+        assert before == after == str(tmp_path / "cc")
+    else:
+        assert before == "None"
+        assert os.path.dirname(after) == os.path.join(REPO, ".jax_cache")
+
+
+@pytest.mark.parametrize("told,want", [
+    ("nothing", None), ("daemon", "tpu"), ("disable", "cpu"),
+    ("platform", "tpu"),
+])
+def test_non_daemon_resolves_platform_without_dialing(
+        monkeypatch, tmp_path, told, want):
+    """A process that is not the daemon is TOLD its platform (env, or
+    the daemon's ping): it starts no child and never asks jax.devices()."""
+    import jax
+
+    from tendermint_tpu.ops import gateway
+
+    def forbidden(*a, **k):
+        raise AssertionError("platform resolution dialed the device")
+
+    monkeypatch.setattr(subprocess, "Popen", forbidden)
+    monkeypatch.setattr(jax, "devices", forbidden)
+    monkeypatch.setattr(jax, "default_backend", forbidden)
+    monkeypatch.delenv("TENDERMINT_TPU_PLATFORM", raising=False)
+    monkeypatch.delenv("TENDERMINT_TPU_DISABLE", raising=False)
+    monkeypatch.setenv("TENDERMINT_DEVD_SOCK", str(tmp_path / "absent.sock"))
+    if told == "daemon":
+        monkeypatch.setattr(
+            devd, "available",
+            lambda *a, **k: {"held": True, "platform": "tpu"},
+        )
+    elif told == "disable":
+        monkeypatch.setenv("TENDERMINT_TPU_DISABLE", "1")
+    elif told == "platform":
+        monkeypatch.setenv("TENDERMINT_TPU_PLATFORM", "tpu")
+    saved = dict(gateway._platform_cache)
+    gateway._platform_cache.clear()
+    devd.bust_avail_cache()
+    try:
+        assert gateway.resolve_platform() == want
+        assert gateway.on_tpu() == (want == "tpu")
+    finally:
+        gateway._platform_cache.clear()
+        gateway._platform_cache.update(saved)
+        devd.bust_avail_cache()
