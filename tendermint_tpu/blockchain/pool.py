@@ -71,6 +71,9 @@ class BlockPool(BaseService):
         self.peers: dict[str, BpPeer] = {}
         self.requesters: dict[int, BpRequester] = {}
         self.max_peer_height = 0
+        # blocks that arrived from a peer their request no longer names
+        # (or twice): fastsync_blocks_dropped_unsolicited
+        self.dropped_unsolicited = 0
         self.request_fn = request_fn
         self.timeout_fn = timeout_fn
 
@@ -178,7 +181,11 @@ class BlockPool(BaseService):
         with self._mtx:
             req = self.requesters.get(block.header.height)
             if req is None or req.peer_id != peer_id or req.block is not None:
-                return  # unsolicited or duplicate
+                # unsolicited or duplicate: downloaded, decoded and thrown
+                # away (a request re-assigned before its answer came
+                # makes the first peer's answer one of these)
+                self.dropped_unsolicited += 1
+                return
             req.block = block
             peer = self.peers.get(peer_id)
             if peer:

@@ -123,11 +123,14 @@ class BlockchainReactor(Reactor, BaseService):
         self.flightrec = None
         # cumulative per-stage seconds on the consume thread; exposed via
         # /metrics (fastsync_*_s) so the residual bottleneck is measured
-        # in production, not guessed (VERDICT r3 weak #6)
+        # in production, not guessed (VERDICT r3 weak #6). `decode` is the
+        # one stage off that thread: json.loads + Block.from_json of every
+        # block_response, on the peers' recv routines (hence its lock)
         self.stage_s = {
             "dispatch": 0.0, "part_hash": 0.0, "verify_wait": 0.0,
-            "store_save": 0.0, "apply": 0.0,
+            "store_save": 0.0, "apply": 0.0, "decode": 0.0,
         }
+        self._decode_mtx = threading.Lock()
         # horizon-aware catchup (round 19): when every serving peer has
         # PRUNED the next height we need, fast sync can never converge —
         # the node wires this to its statesync arm (node._on_below_horizon)
@@ -182,6 +185,7 @@ class BlockchainReactor(Reactor, BaseService):
         from tendermint_tpu.codec import jsonval as jv
 
         try:
+            t0 = time.perf_counter()
             msg = json.loads(msg_bytes.decode())
             mtype = msg["type"]
             if mtype == "block_request":
@@ -190,6 +194,8 @@ class BlockchainReactor(Reactor, BaseService):
                 )
             elif mtype == "block_response":
                 block = Block.from_json(jv.dict_field(msg, "block"))
+                with self._decode_mtx:
+                    self.stage_s["decode"] += time.perf_counter() - t0
                 self.pool.add_block(peer.id(), block, len(msg_bytes))
             elif mtype == "status_request":
                 peer.try_send(BLOCKCHAIN_CHANNEL, self._status_response())

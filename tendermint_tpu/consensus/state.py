@@ -50,6 +50,7 @@ import threading
 import time
 from dataclasses import dataclass
 
+from tendermint_tpu import devd
 from tendermint_tpu.consensus import messages as msgs
 from tendermint_tpu.consensus import pipeline as cpipeline
 from tendermint_tpu.consensus import trace as ctrace
@@ -1031,7 +1032,7 @@ class ConsensusState(BaseService):
         try:
             sm.validate_block(
                 self.state, rs.proposal_block,
-                batch_verifier=self.verifier.commit_batch_verifier(),
+                batch_verifier=self._commit_batch_verifier(),
             )
         except sm.InvalidBlockError as e:
             self.logger.error("prevote: proposal block invalid: %s", e)
@@ -1117,7 +1118,7 @@ class ConsensusState(BaseService):
             try:
                 sm.validate_block(
                     self.state, rs.proposal_block,
-                    batch_verifier=self.verifier.commit_batch_verifier(),
+                    batch_verifier=self._commit_batch_verifier(),
                 )
             except sm.InvalidBlockError as e:
                 raise RuntimeError(f"enter_precommit: +2/3 prevoted an invalid block: {e}")
@@ -1299,7 +1300,7 @@ class ConsensusState(BaseService):
         if block_id is None or not block.hashes_to(block_id.hash):
             raise RuntimeError("cannot finalize: proposal block does not hash to commit hash")
         sm.validate_block(
-            self.state, block, batch_verifier=self.verifier.commit_batch_verifier()
+            self.state, block, batch_verifier=self._commit_batch_verifier()
         )
         self.logger.info(
             "finalizing commit of block %d: hash=%s txs=%d",
@@ -1373,7 +1374,7 @@ class ConsensusState(BaseService):
                 block,
                 block_parts.header(),
                 self.mempool,
-                batch_verifier=self.verifier.commit_batch_verifier(),
+                batch_verifier=self._commit_batch_verifier(),
             )
 
             fail_point()
@@ -1589,6 +1590,32 @@ class ConsensusState(BaseService):
                 pending.height, rs.height, reason,
             )
 
+    # -- waits for signature verdicts --------------------------------------
+
+    def _verdict_wait(self, verify, *args):
+        """One wait of the receive routine for signature verdicts, noted
+        on the height's trace: `verify_wait_s` (the whole wait),
+        `verify_ipc_s` (what single-shot devd calls made on this thread
+        spent outside the daemon: round trip less the reply's svc_ns)
+        and `verify_calls`. Aux notes: they overlap segments and never
+        enter the partition. Receive routine only (note() has one
+        writer)."""
+        ipc0 = devd.thread_ipc_ns()
+        t0 = time.perf_counter()
+        try:
+            return verify(*args)
+        finally:
+            self.trace.note("verify_wait_s", time.perf_counter() - t0)
+            self.trace.note("verify_ipc_s",
+                            (devd.thread_ipc_ns() - ipc0) / 1e9)
+            self.trace.note("verify_calls", 1)
+
+    def _commit_batch_verifier(self):
+        """`commit_batch_verifier` for block validation ON the receive
+        routine: its wait is noted on the height's trace."""
+        verify = self.verifier.commit_batch_verifier()
+        return lambda items: self._verdict_wait(verify, items)
+
     # -- proposals ---------------------------------------------------------
 
     def default_set_proposal(self, proposal: Proposal) -> None:
@@ -1606,7 +1633,8 @@ class ConsensusState(BaseService):
         self._join_apply("set_proposal")
         proposer = rs.validators.get_proposer()
         sign_bytes = proposal.sign_bytes(self.state.chain_id)
-        if proposal.signature is None or not self.verifier.verify_one(
+        if proposal.signature is None or not self._verdict_wait(
+            self.verifier.verify_one,
             proposer.pub_key.raw, sign_bytes, proposal.signature.raw
         ):
             raise ValueError("invalid proposal signature")
@@ -1789,7 +1817,9 @@ class ConsensusState(BaseService):
             if peer_id:
                 self._note_vote_duplicate(peer_id)
             return False  # exact duplicate (add_vote's False)
-        added = pending.commit(self.vote_batcher.verdict(pending.item()))
+        added = pending.commit(
+            self._verdict_wait(self.vote_batcher.verdict, pending.item())
+        )
         if added and peer_id:
             self.vote_accepted += 1
             self._stamp_vote_recv(vote)

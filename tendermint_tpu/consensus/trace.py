@@ -324,6 +324,10 @@ class TraceRecorder:
         except Exception:  # noqa: BLE001
             pass
 
+    @property
+    def ring_size(self) -> int:
+        return self._ring.maxlen
+
     def last(self, n: int = 10) -> list[HeightTrace]:
         """Newest-first slice of the completed-trace ring."""
         n = max(1, int(n))

@@ -34,8 +34,13 @@ TPU-native replacement: one chip, one owner, many client processes.
 
 Wire protocol (trusted local IPC, socket mode 0600, root-only box):
 4-byte big-endian length + pickled dict. Requests: {"op": "ping" |
-"verify" | "verify_stream" | "hash" | "hash_stream" | "stats" |
-"status" | "bench" | "shutdown", ...}. Replies: {"ok": bool, ...}.
+"verify" | "verify_stream" | "agg" | "hash" | "hash_stream" | "stats" |
+"status" | "spans" | "profile" | "shutdown", ...}. Replies: {"ok": bool, ...}.
+
+Every verifier call leaves one record of five phases in a ring, taken
+from inside (tendermint_tpu/devd_spans.py): the `spans` op serves it,
+`serve()` writes it out when it returns, and the `profile` op starts and
+stops a `jax.profiler` trace that holds the same phases as annotations.
 
 Streaming transport (round 6 — docs/streaming-devd.md): the single-shot
 "verify" op serializes the WHOLE batch into one pickle frame and blocks
@@ -73,6 +78,7 @@ crypto.hashing.ripemd160 / merkle.simple (parity-tested).
 
 from __future__ import annotations
 
+import itertools
 import logging
 import os
 import pickle
@@ -87,6 +93,7 @@ import time
 # env-tunable deadline budgets parse via the shared defensive knob helper:
 # a typo'd value must not kill the verify hot path (libs.envknob is
 # stdlib-only, so the daemon's light import footprint is preserved)
+from tendermint_tpu import devd_spans
 from tendermint_tpu.libs.envknob import env_number as _env_timeout
 
 logger = logging.getLogger("devd")
@@ -137,13 +144,20 @@ def _recv_exact(conn: socket.socket, n: int) -> bytes:
     return buf
 
 
-def _recv_raw_frame(conn: socket.socket) -> bytes:
-    """Length-prefixed frame WITHOUT unpickling — stream chunk/result
-    frames are binary, not pickle."""
+def _recv_frame_len(conn: socket.socket) -> int:
+    """A frame's length header. On a pooled connection this is where a
+    handler waits idle between requests: a record's t_recv0 is taken
+    AFTER it returns."""
     (n,) = struct.unpack(">I", _recv_exact(conn, 4))
     if n > (1 << 30):
         raise ValueError(f"devd frame too large: {n}")
-    return _recv_exact(conn, n)
+    return n
+
+
+def _recv_raw_frame(conn: socket.socket) -> bytes:
+    """Length-prefixed frame WITHOUT unpickling — stream chunk/result
+    frames are binary, not pickle."""
+    return _recv_exact(conn, _recv_frame_len(conn))
 
 
 def _recv_frame(conn: socket.socket):
@@ -341,6 +355,12 @@ class _DaemonState:
         self.claim: dict = {}
         self.lock = threading.Lock()
         self.stop = threading.Event()
+        # one record per verifier call, taken from inside (devd_spans):
+        # the `spans` op and the dump at stop read the ring, the
+        # `profile` op drives the profiler
+        self.spans = devd_spans.SpanRing()
+        self.profile = devd_spans.Profile(self.spans)
+        self.conn_ids = itertools.count(1)
         # claim-time-tuned streamed chunk width, advertised in ping/status
         # so clients frame at the width the held device actually likes
         self.stream_chunk = int(
@@ -418,13 +438,16 @@ class _SimVerifier:
         items = list(items)
         oks = [len(it[0]) == 32 and len(it[2]) == 64 for it in items]
         done = threading.Event()
+        devd_spans.mark("marshal")
         self._q.put((len(items), done))
         with self._mtx:
             self._stats["tpu_batches"] += 1
             self._stats["tpu_sigs"] += len(items)
+        devd_spans.mark("dispatch")
 
         def resolve():
             done.wait()
+            devd_spans.mark("device_wait")
             return oks
 
         return resolve
@@ -767,10 +790,6 @@ def _comb_pool_stats() -> dict | None:
             **pool.stats}
 
 
-# one bench at a time daemon-wide (see the bench op)
-_bench_gate = threading.Lock()
-
-
 def _stream_depth() -> int:
     try:
         return max(2, int(os.environ.get("TENDERMINT_DEVD_STREAM_DEPTH", "4")))
@@ -779,7 +798,7 @@ def _stream_depth() -> int:
 
 
 def _handle_verify_stream(conn: socket.socket, st: _DaemonState,
-                          req: dict) -> bool:
+                          req: dict, conn_id: int = 0) -> bool:
     """Serve one verify_stream request: read chunk frames off the socket,
     dispatch each to the kernel as it decodes (verify_batch_async), and
     stream verdict frames back in order from a sender thread — so chunk
@@ -800,6 +819,7 @@ def _handle_verify_stream(conn: socket.socket, st: _DaemonState,
     return _serve_stream(
         conn, st, st.stream, n_chunks,
         _unpack_chunk, v.verify_batch_async, _send_result_frame,
+        record=(st.spans, conn_id),
     )
 
 
@@ -854,7 +874,7 @@ def _handle_hash_stream(conn: socket.socket, st: _DaemonState,
 
 def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
                   n_chunks: int, unpack, dispatch, send_result,
-                  on_result=None) -> bool:
+                  on_result=None, record=None) -> bool:
     """The chunked-stream serving core shared by verify_stream and
     hash_stream: bounded in-flight dispatch, in-order result frames from
     a sender thread, error-frame-then-close on any malformed frame.
@@ -862,7 +882,11 @@ def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
     same keys); `dispatch(items)` returns a zero-arg resolver;
     `send_result(conn, idx, result)` frames one chunk's result;
     `on_result(result)` (optional) observes results in chunk order from
-    the sender thread. Returns True when the connection stays usable."""
+    the sender thread; `record` = (ring, connection id) makes every chunk
+    one record of the ring (the verify plane): opened on this thread,
+    handed to the sender thread with the chunk. Returns True when the
+    connection stays usable."""
+    ring, conn_id = record or (None, 0)
     depth = threading.Semaphore(_stream_depth())
     results: queuelib.Queue = queuelib.Queue()
     send_ok = threading.Event()
@@ -873,7 +897,8 @@ def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
             entry = results.get()
             if entry is None:
                 return
-            idx, resolver_or_err, n, t_disp = entry
+            idx, resolver_or_err, n, t_disp, rec = entry
+            devd_spans.attach(rec)
             try:
                 if isinstance(resolver_or_err, str):
                     _send_error_frame(conn, idx, resolver_or_err)
@@ -883,6 +908,8 @@ def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
                     return
                 counted = False
                 res = resolver_or_err()
+                if rec is not None:
+                    rec.mark("device_wait")  # a kernel without marks
                 dt_ms = (time.time() - t_disp) * 1000.0
                 with st.lock:
                     s = gauges
@@ -897,6 +924,9 @@ def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
                 if on_result is not None:
                     on_result(res)
                 send_result(conn, idx, res)
+                if rec is not None:
+                    ring.finish(rec)
+                    rec = None
             except Exception as exc:  # noqa: BLE001 — resolve/send died
                 logger.exception("stream chunk %d failed", idx)
                 try:
@@ -913,6 +943,8 @@ def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
                 send_ok.clear()
                 return
             finally:
+                if ring is not None:
+                    ring.drop(rec)  # a chunk that failed leaves no record
                 depth.release()
 
     send_thread = threading.Thread(target=sender, daemon=True,
@@ -930,8 +962,12 @@ def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
     aborted = False
     try:
         for idx in range(n_chunks):
+            rec = None
             try:
-                payload = _recv_raw_frame(conn)
+                size = _recv_frame_len(conn)
+                if ring is not None:
+                    rec = ring.begin(conn_id)
+                payload = _recv_exact(conn, size)
                 items = unpack(payload)
             except (ConnectionError, EOFError):
                 aborted = True
@@ -939,25 +975,35 @@ def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
             except Exception as exc:  # noqa: BLE001 — malformed frame:
                 # answer with an error frame, never hang the client
                 if acquire_slot():
-                    results.put((idx, f"malformed chunk: {exc}", 0, 0.0))
+                    results.put((idx, f"malformed chunk: {exc}", 0, 0.0, None))
                 aborted = True
                 break
+            if rec is not None:
+                ring.decoded(rec, "verify_stream", len(items))
             if not acquire_slot():
                 aborted = True
                 break
             try:
                 resolver = dispatch(items)
             except Exception as exc:  # noqa: BLE001 — dispatch failed
-                results.put((idx, f"{type(exc).__name__}: {exc}", 0, 0.0))
+                results.put((idx, f"{type(exc).__name__}: {exc}", 0, 0.0, None))
                 aborted = True
                 break
+            if rec is not None:
+                rec.mark("dispatch")
             with st.lock:
                 s = gauges
                 s["bytes_framed"] += len(payload)
                 s["inflight"] += 1
                 s["inflight_max"] = max(s["inflight_max"], s["inflight"])
-            results.put((idx, resolver, len(items), time.time()))
+            # the record goes on with the chunk: the sender thread ends
+            # its last two phases
+            devd_spans.attach(None)
+            results.put((idx, resolver, len(items), time.time(), rec))
+            rec = None
     finally:
+        if ring is not None:
+            ring.drop(rec)
         results.put(None)
         send_thread.join()
         # stats hygiene on abort: entries the dead sender never resolved
@@ -970,6 +1016,8 @@ def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
                 break
             if entry is not None and not isinstance(entry[1], str):
                 leaked += 1
+                if entry[4] is not None:
+                    ring.drop(entry[4])
         if leaked:
             with st.lock:
                 gauges["inflight"] -= leaked
@@ -977,10 +1025,16 @@ def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
 
 
 def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
+    ring = st.spans
+    conn_id = next(st.conn_ids)
+    rec = None
     try:
         while True:
             try:
-                req = _recv_frame(conn)
+                size = _recv_frame_len(conn)
+                # t_recv0: the header is in hand, the idle wait is over
+                rec = ring.begin(conn_id)
+                req = pickle.loads(_recv_exact(conn, size))
             except (ConnectionError, EOFError):
                 return
             op = req.get("op")
@@ -1024,11 +1078,17 @@ def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
                         rep["stream"] = st.stream_stats()
                         rep["hash_stream"] = st.hash_stream_stats()
                         rep["stream_depth"] = _stream_depth()
+                        # the ring of per-call records: its size and how
+                        # many it has ever held (past the size it wrapped)
+                        rep["spans"] = ring.stats()
+                        rep["profiling"] = st.profile.active()
                     _send_frame(conn, rep)
                     if st.status == "failed":
                         st.failed_seen.set()
                 elif op == "verify_stream":
-                    if not _handle_verify_stream(conn, st, req):
+                    ring.drop(rec)  # the header; each chunk is a record
+                    rec = None
+                    if not _handle_verify_stream(conn, st, req, conn_id):
                         return  # stream aborted; framing is untrustworthy
                 elif op == "hash_stream":
                     if not _handle_hash_stream(conn, st, req):
@@ -1070,8 +1130,15 @@ def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
                             "error": f"device not held (status: {st.status})",
                         })
                     else:
-                        oks = v.verify_batch(req["items"])
-                        _send_frame(conn, {"ok": True, "results": [bool(b) for b in oks]})
+                        items = req["items"]
+                        ring.decoded(rec, op, len(items), req.get("rid", ""))
+                        oks = v.verify_batch(items)
+                        rec.mark("device_wait")  # a kernel without marks
+                        _send_frame(conn, {
+                            "ok": True, "results": [bool(b) for b in oks],
+                            "svc_ns": rec.service_ns(),
+                        })
+                        ring.finish(rec)
                 elif op == "agg":
                     # aggregate-commit dual-scalar-mul lanes
                     # (ops/ed25519.dsm_batch; docs/upgrade.md): terms are
@@ -1087,10 +1154,16 @@ def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
                     else:
                         from tendermint_tpu.ops import ed25519 as _ops_ed
 
-                        points = _ops_ed.dsm_batch(
-                            [tuple(t) for t in req.get("items", [])]
-                        )
-                        _send_frame(conn, {"ok": True, "points": points})
+                        terms = [tuple(t) for t in req.get("items", [])]
+                        ring.decoded(rec, op, len(terms), req.get("rid", ""))
+                        # dsm_batch marshals, dispatches and reads back in
+                        # one call: its whole time stands as device_wait
+                        rec.mark("dispatch")
+                        points = _ops_ed.dsm_batch(terms)
+                        rec.mark("device_wait")
+                        _send_frame(conn, {"ok": True, "points": points,
+                                           "svc_ns": rec.service_ns()})
+                        ring.finish(rec)
                 elif op == "stats":
                     _send_frame(conn, {
                         "ok": True,
@@ -1098,78 +1171,33 @@ def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
                         "stream": st.stream_stats(),
                         "hash_stream": st.hash_stream_stats(),
                     })
-                elif op == "bench":
-                    # In-daemon pipelined throughput measurement: the one
-                    # number free of ALL client-side confounds (IPC
-                    # marshal, socket hops, client thread scheduling) —
-                    # how fast the held device verifies when its queue is
-                    # kept full. Items are synthesized daemon-side with
-                    # the warm-set key-reuse shape (64 keys cycled, a
-                    # real commit's profile). MAINTENANCE op: it queues
-                    # ~n_batches*batch lanes on the shared serving
-                    # verifier, so concurrent verify traffic both stalls
-                    # and skews it — benches are serialized against each
-                    # other here, and callers should run it on an
-                    # otherwise idle daemon.
-                    v = st.verifier
-                    if v is None:
-                        _send_frame(conn, {
-                            "ok": False,
-                            "error": f"device not held (status: {st.status})",
-                        })
-                    elif not _bench_gate.acquire(blocking=False):
-                        _send_frame(conn, {
-                            "ok": False,
-                            "error": "bench already running (serialized)",
-                        })
+                elif op == "spans":
+                    # the ring of per-call records (devd_spans.FIELDS),
+                    # oldest first: `since_ns` keeps those received at or
+                    # after it, `last` the newest that many
+                    last = req.get("last")
+                    _send_frame(conn, {
+                        "ok": True, "fields": list(devd_spans.FIELDS),
+                        "records": ring.rows(
+                            int(req.get("since_ns", 0) or 0),
+                            None if last is None else int(last)),
+                        **ring.stats(),
+                    })
+                elif op == "profile":
+                    # jax.profiler from inside: `start` (dir, max_calls;
+                    # stops by itself after that many verifier calls, on
+                    # a thread of its own) and `stop`. Errors are replies.
+                    action = req.get("action")
+                    if action == "start" and req.get("dir"):
+                        rep = st.profile.start(
+                            str(req["dir"]), int(req.get("max_calls", 0) or 0))
+                    elif action == "stop":
+                        rep = st.profile.stop()
                     else:
-                        try:
-                            batch = int(req.get("batch", 8192))
-                            n_batches = int(req.get("n_batches", 8))
-                            from tendermint_tpu.crypto import ed25519 as _ed
-
-                            seeds = [
-                                bytes([5, k]) + b"\x05" * 30 for k in range(64)
-                            ]
-                            base_items = [
-                                (
-                                    _ed.public_key(seeds[i % 64]),
-                                    b"dbench-%d" % i,
-                                    _ed.sign(seeds[i % 64], b"dbench-%d" % i),
-                                )
-                                for i in range(min(batch, 256))
-                            ]
-                            items = [
-                                base_items[i % len(base_items)]
-                                for i in range(batch)
-                            ]
-                            for _ in range(2):  # tables/compile off-clock
-                                v.verify_batch(items)
-                            t0 = time.time()
-                            resolvers = [
-                                v.verify_batch_async(items)
-                                for _ in range(n_batches)
-                            ]
-                            # resolve EVERY batch before stopping the
-                            # clock — short-circuiting on a failed batch
-                            # would leave device work in flight and
-                            # inflate the rate
-                            results = [r() for r in resolvers]
-                            dt = time.time() - t0
-                            all_ok = all(all(res) for res in results)
-                        finally:
-                            _bench_gate.release()
-                        _send_frame(conn, {
-                            "ok": True,
-                            "sigs_per_sec": (
-                                batch * n_batches / dt if dt > 0 else 0.0
-                            ),
-                            "elapsed_s": dt,
-                            "batch": batch,
-                            "n_batches": n_batches,
-                            "all_ok": all_ok,
-                            "kernel": os.environ.get("TENDERMINT_TPU_KERNEL", ""),
-                        })
+                        rep = {"ok": False, "error":
+                               f"profile: bad request {action!r} "
+                               "(start needs dir; or stop)"}
+                    _send_frame(conn, rep)
                 elif op == "shutdown":
                     _send_frame(conn, {"ok": True})
                     st.stop.set()
@@ -1182,7 +1210,12 @@ def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
                     _send_frame(conn, {"ok": False, "error": f"{type(exc).__name__}: {exc}"})
                 except Exception:
                     return
+            finally:
+                # ping, status, hash and whatever failed leave no record
+                ring.drop(rec)
+                rec = None
     finally:
+        ring.drop(rec)
         try:
             conn.close()
         except Exception:
@@ -1197,7 +1230,9 @@ def serve(path: str | None = None) -> None:
     TENDERMINT_DEVD_KERNEL        pin the served kernel (skips the claim-time
                                   comb-vs-f32p bake-off; any gateway.KERNELS
                                   name except "devd")
-    TENDERMINT_DEVD_EXIT_ON_TERM=1  honor SIGTERM (default: ignore — device discipline)
+    TENDERMINT_DEVD_EXIT_ON_TERM=1  honor SIGTERM (default: ignore — device
+                                  discipline); it then stops as the shutdown
+                                  op does, records written out
     TENDERMINT_DEVD_CHUNK         pin the streamed chunk width (skips the
                                   claim-time width bake-off; clients pin
                                   their framing with the same var)
@@ -1242,7 +1277,8 @@ def serve(path: str | None = None) -> None:
         ).split(",") if x
     )
 
-    if os.environ.get("TENDERMINT_DEVD_EXIT_ON_TERM", "") != "1":
+    exit_on_term = os.environ.get("TENDERMINT_DEVD_EXIT_ON_TERM", "") == "1"
+    if not exit_on_term:
         def _ignore(signum, frame):
             logger.warning(
                 "ignoring signal %d: the device owner outlives its clients; "
@@ -1270,6 +1306,16 @@ def serve(path: str | None = None) -> None:
     srv.settimeout(1.0)
 
     st = _DaemonState()
+    if exit_on_term:
+        # leave through the same door as the shutdown op, so that the
+        # records are written out; closing the listener wakes accept()
+        def _term(signum, frame):
+            st.stop.set()
+            srv.close()
+        try:
+            signal.signal(signal.SIGTERM, _term)
+        except ValueError:  # serve() off the main thread: default action
+            pass
     threading.Thread(
         target=_device_loop, args=(st,),
         kwargs=dict(accept_cpu=accept_cpu, warm_shapes=warm),
@@ -1283,6 +1329,10 @@ def serve(path: str | None = None) -> None:
                 conn, _ = srv.accept()
             except socket.timeout:
                 continue
+            except OSError:
+                if st.stop.is_set():
+                    break
+                raise
             threading.Thread(
                 target=_handle_conn, args=(conn, st), daemon=True
             ).start()
@@ -1292,7 +1342,16 @@ def serve(path: str | None = None) -> None:
             os.unlink(path)
         except OSError:
             pass
-        logger.info("devd stopped")
+        # what an operator reads after a restart: the ring of per-call
+        # records, beside the socket (a running profile is written first)
+        if st.profile.active():
+            st.profile.stop()
+        spans_path = st.spans.dump(
+            devd_spans.dump_path(path), pid=os.getpid(),
+            device_kind=st.device_kind, platform=st.platform,
+        )
+        logger.info("devd stopped; %d call records in %s",
+                    min(st.spans.count, st.spans.size), spans_path)
     if st.error is not None:
         raise SystemExit(f"devd: claim failed: {st.error}")
 
@@ -1348,8 +1407,49 @@ def _latency_hists():
             "single-shot devd pickle round trip (whole batch)",
             labelnames=("op",),
         )
+        _hist_cache["ipc"] = reg.histogram(
+            "devd_single_shot_ipc_seconds",
+            "single-shot devd round trip LESS the daemon's own service "
+            "time (the reply's svc_ns): the socket both ways, the "
+            "request's pickle, the reply's encoding and decoding, the "
+            "scheduler",
+            labelnames=("op",),
+        )
         _hist_cache["reg"] = reg
-    return _hist_cache["chunk"], _hist_cache["single"]
+    return _hist_cache["chunk"], _hist_cache["single"], _hist_cache["ipc"]
+
+
+# what single-shot calls made ON THIS THREAD have spent in IPC so far:
+# the consensus receive routine reads it before and after a wait for
+# verdicts (consensus/state.py: the height trace's verify_ipc_s)
+_ipc_tls = threading.local()
+
+
+def thread_ipc_ns() -> int:
+    return getattr(_ipc_tls, "ns", 0)
+
+
+def _observe_single(op: str, t0: float, rep: dict) -> None:
+    """One single-shot round trip: its whole time, and (where the daemon
+    says how long it held the request: an older one does not) the rest,
+    which is the IPC of that call."""
+    rtt = time.perf_counter() - t0
+    _chunk, single, ipc_hist = _latency_hists()
+    single.labels(op=op).observe(rtt)
+    svc_ns = rep.get("svc_ns") if isinstance(rep, dict) else None
+    if svc_ns is not None:
+        ipc = max(0.0, rtt - svc_ns / 1e9)
+        ipc_hist.labels(op=op).observe(ipc)
+        _ipc_tls.ns = thread_ipc_ns() + int(ipc * 1e9)
+
+
+_rid_counter = itertools.count(1)
+
+
+def _next_rid() -> str:
+    """The client's name for one request (pid and a counter): the
+    daemon's record of the call carries it."""
+    return f"{os.getpid()}-{next(_rid_counter)}"
 
 
 class DevdClient:
@@ -1500,11 +1600,10 @@ class DevdClient:
 
     def verify_batch(self, items) -> list[bool]:
         t0 = time.perf_counter()
-        rep = self.request({"op": "verify", "items": list(items)},
-                           timeout=self.io_timeout)
-        _latency_hists()[1].labels(op="verify").observe(
-            time.perf_counter() - t0
-        )
+        rep = self.request(
+            {"op": "verify", "items": list(items), "rid": _next_rid()},
+            timeout=self.io_timeout)
+        _observe_single("verify", t0, rep)
         if not rep.get("ok"):
             raise DevdError(rep.get("error", "verify failed"))
         return rep["results"]
@@ -1515,20 +1614,20 @@ class DevdClient:
         pre-agg daemon replies 'unknown op' -> DevdError, which
         ops/devd_backend latches into its CPU-floor fallback."""
         t0 = time.perf_counter()
-        rep = self.request({"op": "agg", "items": [tuple(t) for t in terms]},
-                           timeout=self.io_timeout)
-        _latency_hists()[1].labels(op="agg").observe(
-            time.perf_counter() - t0
-        )
+        rep = self.request(
+            {"op": "agg", "items": [tuple(t) for t in terms],
+             "rid": _next_rid()}, timeout=self.io_timeout)
+        _observe_single("agg", t0, rep)
         if not rep.get("ok"):
             raise DevdError(rep.get("error", "agg failed"))
         return [tuple(p) for p in rep["points"]]
 
     def verify_batch_async(self, items):
         items = list(items)
+        req = {"op": "verify", "items": items, "rid": _next_rid()}
         conn, pooled = self._acquire()
         try:
-            _send_frame(conn, {"op": "verify", "items": items})
+            _send_frame(conn, req)
         except Exception as exc:
             self._discard(conn)
             if not (pooled and isinstance(exc, (ConnectionError, EOFError))):
@@ -1536,7 +1635,7 @@ class DevdClient:
             self._note_reconnect(self._stream_stats, "connect")
             conn, pooled = self._fresh(), False
             try:
-                _send_frame(conn, {"op": "verify", "items": items})
+                _send_frame(conn, req)
             except Exception:
                 self._discard(conn)
                 raise
@@ -1787,9 +1886,7 @@ class DevdClient:
             "op": "hash", "mode": mode,
             "items": [bytes(b) for b in items], "tree": bool(tree),
         }, timeout=self.io_timeout)
-        _latency_hists()[1].labels(op="hash").observe(
-            time.perf_counter() - t0
-        )
+        _observe_single("hash", t0, rep)
         if not rep.get("ok"):
             raise DevdError(rep.get("error", "hash failed"))
         with self._mtx:
@@ -1909,15 +2006,35 @@ class DevdClient:
             raise DevdError(rep.get("error", "stats failed"))
         return rep["stats"]
 
-    def bench(self, batch: int = 8192, n_batches: int = 8,
-              timeout: float = 600.0) -> dict:
-        """In-daemon pipelined device rate (see the bench op)."""
-        rep = self.request(
-            {"op": "bench", "batch": batch, "n_batches": n_batches},
-            timeout=timeout,
-        )
+    def spans(self, since_ns: int = 0, last: int | None = None,
+              timeout: float = 30.0) -> dict:
+        """The daemon's ring of per-call records (devd_spans.FIELDS),
+        oldest first, with the ring's `size` and total `count`."""
+        rep = self.request({"op": "spans", "since_ns": int(since_ns),
+                            "last": last}, timeout=timeout)
         if not rep.get("ok"):
-            raise DevdError(rep.get("error", "bench failed"))
+            raise DevdError(rep.get("error", "spans failed"))
+        return rep
+
+    def profile_start(self, tdir: str, max_calls: int = 0,
+                      timeout: float = 60.0) -> dict:
+        """Start a jax.profiler trace inside the daemon; it stops by
+        itself after `max_calls` verifier calls (0: only `profile_stop`
+        stops it). A second start is refused with DevdError."""
+        rep = self.request({"op": "profile", "action": "start", "dir": tdir,
+                            "max_calls": int(max_calls)}, timeout=timeout)
+        if not rep.get("ok"):
+            raise DevdError(rep.get("error", "profile start failed"))
+        return rep
+
+    def profile_stop(self, timeout: float = 600.0) -> dict:
+        """Stop the trace and wait until it is written out (about 3 s a
+        traced verifier call on the chip); after it stopped by itself
+        this returns that stop's answer."""
+        rep = self.request({"op": "profile", "action": "stop"},
+                           timeout=timeout)
+        if not rep.get("ok"):
+            raise DevdError(rep.get("error", "profile stop failed"))
         return rep
 
     def shutdown(self) -> None:

@@ -50,11 +50,15 @@ when the condition clears):
   TENDERMINT_FLIGHTREC_WEDGE_S (default 60; waived during fast sync)
 - an unhandled exception escaping the consensus receive routine
 
+- the node stops (``Node.on_stop``, reason ``stop``): what an operator
+  reads after a restart
+
 Dumps are JSON files under ``<node home>/flightrec/`` named
-``dump-<utc>-<reason>.json``: the event ring, the trigger, and a
-counter snapshot (p2p gossip totals + consensus position via
-``counters_fn``, wired by node/node.py) so picks-vs-sends is readable
-without a second artifact.
+``dump-<utc>-<reason>.json``: the event ring, the trigger, a counter
+snapshot (p2p gossip totals + consensus position via ``counters_fn``,
+wired by node/node.py) so picks-vs-sends is readable without a second
+artifact, and ``consensus_traces``: the per-height traces' ring as the
+``consensus_trace`` RPC serves it (``traces_fn``, wired the same way).
 
 ``record()`` is one enabled-check + one deque.append (GIL-atomic) — the
 TENDERMINT_FLIGHTREC_DISABLE kill switch makes it a single attribute
@@ -99,6 +103,9 @@ class FlightRecorder:
         # optional counter-snapshot provider for dumps (node/node.py
         # wires p2p gossip totals + consensus position)
         self.counters_fn = None
+        # optional provider of the per-height consensus traces (newest
+        # first, as the consensus_trace RPC serves them) for dumps
+        self.traces_fn = None
         self._watch_stop: threading.Event | None = None
 
     @property
@@ -207,15 +214,16 @@ class FlightRecorder:
             items = items[-max(1, int(last)):]
         return [{"t": t, "kind": kind, **fields} for t, kind, fields in items]
 
-    def _snapshot_counters(self) -> dict:
-        if self.counters_fn is None:
-            return {}
+    def _snapshot(self, fn, empty):
+        """What a provider wired by node/node.py says now; a provider
+        bug must never cost the dump itself."""
+        if fn is None:
+            return empty
         try:
-            return dict(self.counters_fn())
-        except Exception:  # noqa: BLE001 — a counter provider bug must
-            # never cost the dump itself
-            logger.exception("flightrec counter snapshot failed")
-            return {}
+            return type(empty)(fn())
+        except Exception:  # noqa: BLE001
+            logger.exception("flightrec snapshot provider failed")
+            return empty
 
     def dump(self, reason: str) -> str | None:
         """Write the ring + counter snapshot to the node home. Returns
@@ -226,8 +234,9 @@ class FlightRecorder:
             "dumped_at": time.time(),
             "recorded_total": self.recorded,
             "ring_size": self._ring.maxlen,
-            "counters": self._snapshot_counters(),
+            "counters": self._snapshot(self.counters_fn, {}),
             "events": self.events(),
+            "consensus_traces": self._snapshot(self.traces_fn, []),
         }
         self.dumps += 1
         if self.dump_dir is None:

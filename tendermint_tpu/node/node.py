@@ -473,6 +473,11 @@ class Node(BaseService):
             return out
 
         self.flightrec.counters_fn = _flight_counters
+        # ... and the per-height traces' ring, as the consensus_trace RPC
+        # serves it (newest first)
+        trace = self.consensus_state.trace
+        self.flightrec.traces_fn = lambda: [
+            t.to_json() for t in trace.last(trace.ring_size)]
 
     # -- retention wiring --------------------------------------------------
 
@@ -680,6 +685,10 @@ class Node(BaseService):
 
     def on_stop(self) -> None:
         self.flightrec.stop_watchdog()
+        # what an operator reads after a restart: the recent events and
+        # the per-height traces, while the consensus state still stands
+        if self.flightrec.enabled:  # the kill switch writes nothing
+            self.flightrec.dump("stop")
         if self.grpc_server is not None:
             self.grpc_server.stop()
         if self.rpc_server is not None:

@@ -371,6 +371,9 @@ def build_registry(node) -> telemetry.Registry:
             # round 19: times the catchup path detected the network's
             # retained horizon above its target and armed statesync
             "below_horizon_fallbacks": bc.below_horizon_fallbacks,
+            # blocks downloaded and thrown away: they came from a peer
+            # their request no longer named, or twice (blockchain/pool.py)
+            "blocks_dropped_unsolicited": bc.pool.dropped_unsolicited,
         }
         for stage, secs in bc.stage_s.items():
             out[f"{stage}_s"] = round(secs, 3)

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tendermint_tpu.crypto import ed25519 as ed_ref
+from tendermint_tpu.devd_spans import mark
 from tendermint_tpu.ops import ed25519_f32 as base
 
 logger = logging.getLogger("ops.ed25519_comb")
@@ -514,6 +515,10 @@ def _dispatch_comb(items, kidx, keys, pool_mgr):
         slots[np.asarray(vidx)] = leased
     else:
         pool_arr = pool_mgr.ensure([], np.zeros((0, 32)), np.zeros((0, 32)))[1]
+    # the daemon's per-call record (devd_spans): arrays ready / the jit
+    # call returned / verdicts on the host. One attribute test each where
+    # no record is open, which is everywhere but inside devd.
+    mark("marshal", bucket)
     ok_dev = _verify_jit(
         pool_arr,
         pool_mgr.table_b(),
@@ -523,7 +528,14 @@ def _dispatch_comb(items, kidx, keys, pool_mgr):
         jnp.asarray(s8),
         jnp.asarray(h8),
     )
-    return lambda: np.asarray(ok_dev)[:n] & valid[:n]
+    mark("dispatch")
+
+    def resolve():
+        ok = np.asarray(ok_dev)
+        mark("device_wait")
+        return ok[:n] & valid[:n]
+
+    return resolve
 
 
 def verify_batch_async(items: list[tuple[bytes, bytes, bytes]]):

@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tendermint_tpu.crypto import ed25519 as ed_ref
+from tendermint_tpu.devd_spans import mark
 
 P = ed_ref.P
 L = ed_ref.L
@@ -583,6 +584,9 @@ def verify_batch_async(items: list[tuple[bytes, bytes, bytes]]):
         return lambda: np.zeros(0, dtype=bool)
     bucket = _next_pow2(n)
     ax, ay, ry, rs, s8, h8, valid = prepare_batch8(items, bucket)
+    # the daemon's per-call record (devd_spans): one attribute test each
+    # where no record is open, which is everywhere but inside devd
+    mark("marshal", bucket)
     ok_dev = _verify_jit(
         jnp.asarray(ax),
         jnp.asarray(ay),
@@ -591,7 +595,14 @@ def verify_batch_async(items: list[tuple[bytes, bytes, bytes]]):
         jnp.asarray(s8),
         jnp.asarray(h8),
     )
-    return lambda: np.asarray(ok_dev)[:n] & valid[:n]
+    mark("dispatch")
+
+    def resolve():
+        ok = np.asarray(ok_dev)
+        mark("device_wait")
+        return ok[:n] & valid[:n]
+
+    return resolve
 
 
 def verify_batch(items: list[tuple[bytes, bytes, bytes]]) -> np.ndarray:

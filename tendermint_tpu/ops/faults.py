@@ -799,8 +799,11 @@ class DaemonFleet:
         base = sock_dir or tempfile.gettempdir()
         self.supervisors = [
             DaemonSupervisor(
+                # short: a unix socket's path may have 107 bytes, and a
+                # test's tmp_path under xdist leaves some 35 of them
                 os.path.join(
-                    base, f"devd-fleet-{os.getpid()}-{id(self):x}-{i}.sock"
+                    base,
+                    f"fleet-{os.getpid()}-{id(self) >> 4 & 0xffffff:x}-{i}.sock",
                 ),
                 extra_env=dict(extra_env or {}),
             )

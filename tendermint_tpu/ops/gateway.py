@@ -36,6 +36,7 @@ import numpy as np
 
 from tendermint_tpu.crypto import ed25519 as ed_cpu
 from tendermint_tpu.crypto.keys import verify_any
+from tendermint_tpu.devd_spans import mark as _span_mark
 from tendermint_tpu.libs.envknob import env_number as _env_number
 
 logger = logging.getLogger("ops.gateway")
@@ -875,6 +876,11 @@ class Verifier:
                     return lambda: res_now
 
                 kernel_resolve = ops_ed.verify_batch_async(items)
+                # the daemon's per-call record: a kernel that marks its own
+                # phases has ended these two already (the first mark wins);
+                # for one that does not, the pipelined path still shows
+                # where the enqueue ends and the wait for verdicts does
+                _span_mark("dispatch")
                 with self._mtx:
                     self._stats["tpu_batches"] += 1
                     self._stats["tpu_sigs"] += n
@@ -884,7 +890,9 @@ class Verifier:
                     # materialization: keep the sync path's fallback
                     # guarantee here too.
                     try:
-                        res = [bool(b) for b in kernel_resolve()]
+                        out = kernel_resolve()
+                        _span_mark("device_wait")
+                        res = [bool(b) for b in out]
                         self._note_device_success()
                         return res
                     except Exception:

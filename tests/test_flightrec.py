@@ -130,6 +130,33 @@ class TestAutoDump:
         with open(path) as f:
             assert json.load(f)["counters"] == {}
 
+    def test_every_dump_carries_the_consensus_traces(self, tmp_path):
+        rec = FlightRecorder(home=str(tmp_path), ring=8)
+        path = rec.dump("bare")   # no provider wired: an empty list
+        with open(path) as f:
+            assert json.load(f)["consensus_traces"] == []
+        rec.traces_fn = lambda: [{"height": 7, "aux": {"verify_wait_s": 0.01}},
+                                 {"height": 6, "aux": {}}]
+        with open(rec.dump("stop")) as f:
+            payload = json.load(f)
+        assert payload["reason"] == "stop"
+        assert [t["height"] for t in payload["consensus_traces"]] == [7, 6]
+
+    def test_trace_provider_failure_costs_the_section_not_the_dump(
+        self, tmp_path
+    ):
+        rec = FlightRecorder(home=str(tmp_path), ring=8)
+        rec.record("step", height=3)
+
+        def boom():
+            raise RuntimeError("mid-teardown")
+
+        rec.traces_fn = boom
+        with open(rec.dump("provider_down")) as f:
+            payload = json.load(f)
+        assert payload["consensus_traces"] == []
+        assert len(payload["events"]) == 1
+
     def test_exception_note_records_and_dumps(self, tmp_path):
         rec = FlightRecorder(home=str(tmp_path), ring=8)
         rec.note_exception("consensus", RuntimeError("boom"))

@@ -556,6 +556,85 @@ def test_speculative_group_spans_never_overshoot():
     assert group_spans([], 4096) == []
 
 
+def test_pool_counts_blocks_dropped_as_unsolicited():
+    """A block from a peer its request no longer names, a block nobody
+    asked for and a block that comes twice are downloaded, decoded and
+    thrown away: fastsync_blocks_dropped_unsolicited counts them (fault 1
+    of PERF.md section 7 was computed from bytes until now)."""
+    from types import SimpleNamespace
+
+    from tendermint_tpu.blockchain.pool import BlockPool, BpRequester
+
+    pool = BlockPool(5, lambda h, p: None, lambda p, r: None)
+    pool.set_peer_height("a", 10)
+    pool.set_peer_height("b", 10)
+    req = pool.requesters[5] = BpRequester(5)
+    req.peer_id = "a"
+    block = SimpleNamespace(header=SimpleNamespace(height=5))
+    assert pool.dropped_unsolicited == 0
+    pool.add_block("b", block, 100)          # the request names peer a
+    assert pool.dropped_unsolicited == 1 and req.block is None
+    pool.add_block("a", SimpleNamespace(header=SimpleNamespace(height=9)), 100)
+    assert pool.dropped_unsolicited == 2     # no request at that height
+    pool.add_block("a", block, 100)          # the one that was asked for
+    assert pool.dropped_unsolicited == 2 and req.block is block
+    pool.add_block("a", block, 100)          # and again
+    assert pool.dropped_unsolicited == 3
+
+
+def test_reactor_times_block_decode_as_a_stage():
+    """json.loads + Block.from_json of a block_response lay outside the
+    five fastsync_*_s stages; `decode` is the sixth, and only a
+    block_response adds to it."""
+    import json as _json
+
+    from tendermint_tpu.blockchain.reactor import BlockchainReactor
+
+    doc, pvs = make_genesis(1)
+    node = make_node(doc, pvs[0])
+    bc = BlockchainReactor(
+        node.state.copy(), node.cs.proxy_app_conn, node.store, fast_sync=True,
+    )
+    assert bc.stage_s["decode"] == 0.0 and len(bc.stage_s) == 6
+    got = []
+
+    class _Pool:
+        dropped_unsolicited = 0
+
+        def add_block(self, peer_id, block, size):
+            got.append((peer_id, block.header.height, size))
+
+        def peer_has_no_block(self, peer_id, height):
+            pass
+
+    class _Peer:
+        def id(self):
+            return "peer-1"
+
+    errors = []
+    bc.pool = _Pool()
+    bc.switch = type("S", (), {"stop_peer_for_error":
+                               lambda self, peer, exc: errors.append(exc)})()
+    from tendermint_tpu.types.block import Block, empty_commit
+    from tendermint_tpu.types.block_id import BlockID
+
+    block, _parts = Block.make_block(
+        1, doc.chain_id, [b"k=v"], empty_commit(), BlockID(),
+        node.state.validators.hash(), b"", 65536)
+    raw = _json.dumps({"type": "block_response",
+                       "block": block.to_json()}).encode()
+    bc.receive(0x40, _Peer(), raw)
+    assert not errors, errors
+    assert got == [("peer-1", 1, len(raw))]
+    first = bc.stage_s["decode"]
+    assert first > 0.0
+    bc.receive(0x40, _Peer(), _json.dumps(
+        {"type": "no_block_response", "height": 3}).encode())
+    assert bc.stage_s["decode"] == first
+    bc.receive(0x40, _Peer(), raw)
+    assert bc.stage_s["decode"] > first
+
+
 def test_fastsync_flag_clears_on_switchover():
     """/metrics fastsync_active must go 0 once the node switches to
     consensus (code-review r3: the constructor flag was never cleared)."""
