@@ -36,7 +36,11 @@ DATA_CHANNEL = 0x21
 VOTE_CHANNEL = 0x22
 VOTE_SET_BITS_CHANNEL = 0x23
 
-PEER_GOSSIP_SLEEP = 0.1  # reactor.go peerGossipSleepDuration
+# reactor.go peerGossipSleepDuration. Since round 26 the idle back-stop
+# of the gossip routines' wait (_gossip_wait), not their pace: a routine
+# with nothing to send blocks on its wake signal and is woken by the
+# event that makes something sendable (wake_gossip, PeerState.gossip).
+PEER_GOSSIP_SLEEP = 0.1
 PEER_QUERY_MAJ23_SLEEP = 2.0
 # lazy-relay hold (round 20, gossip_dedup): a vote we RECEIVED moments
 # ago is being fanned out by its origin right now, and every recipient
@@ -59,6 +63,13 @@ VOTE_RELAY_DELAY_MIN = 0.5 * VOTE_RELAY_DELAY
 VOTE_RELAY_DELAY_MAX = 4.0 * VOTE_RELAY_DELAY
 
 PEER_STATE_KEY = "ConsensusReactor.peerState"
+
+# the reactor's flat counters of its gossip routines (round 26), as the
+# `consensus` producer and the flight recorder's dumps list them
+GOSSIP_COUNTERS = (
+    "gossip_sends", "gossip_wakes_event", "gossip_wakes_hold",
+    "gossip_wakes_backstop", "gossip_backstop_sends",
+)
 
 
 def adaptive_relay_delay(rtt_s: float | None) -> float:
@@ -99,6 +110,34 @@ class PeerRoundState:
         self.catchup_commit: BitArray | None = None
 
 
+class _PeerGossip:
+    """One peer's stop flag and the wake signal of each of its two
+    gossip routines. A routine clears its signal BEFORE it reads the
+    round state and waits on it after a pass that sent nothing, so an
+    event landing between the look and the wait ends the wait at once.
+    A signal that is set is left alone (one attribute read, no lock), so
+    a burst of events is one wake and costs the thread that fires them
+    next to nothing: whoever wakes has changed the state first, and the
+    routine that clears the signal looks at the state after that."""
+
+    __slots__ = ("stop", "data", "votes")
+
+    def __init__(self):
+        self.stop = threading.Event()
+        self.data = threading.Event()
+        self.votes = threading.Event()
+
+    def wake(self) -> None:
+        if not self.data.is_set():
+            self.data.set()
+        if not self.votes.is_set():
+            self.votes.set()
+
+    def end(self) -> None:
+        self.stop.set()
+        self.wake()  # the stop must also end a routine's wait
+
+
 def _peer_label(peer) -> str:
     """Best-effort peer id for metric labels ("?" for harness stubs)."""
     try:
@@ -115,6 +154,10 @@ class PeerState:
         self.peer = peer
         self.prs = PeerRoundState()
         self._mtx = threading.RLock()
+        # stop flag + wake signals of this peer's gossip routines;
+        # receive() wakes them when the mirror changes in a way that can
+        # make MORE sendable to this peer
+        self.gossip = _PeerGossip()
         # per-peer gossip instrumentation (round 15): child series
         # resolved once — picks vs successful sends is the signal that
         # would have caught the PR-13 pick-marks-before-send wedge
@@ -249,8 +292,12 @@ class PeerState:
                 else BitArray(num_validators)
             )
 
-    def pick_vote_to_send(self, vote_set) -> object | None:
+    def pick_vote_to_send(self, vote_set, hold_of=None) -> object | None:
         """A random vote the peer needs from `vote_set` (reactor.go:899-933).
+        With `hold_of` (the reactor's lazy-relay screen: seconds a vote
+        is still held, 0.0 for none) a held pick is passed over for any
+        other needed vote, in random order — so our own fresh vote is
+        never kept back by a relay that has to wait.
 
         Does NOT mark the peer as having it — the caller marks via
         set_has_vote only AFTER peer.send succeeds (reactor.go's
@@ -273,7 +320,16 @@ class PeerState:
             index, ok = needed.pick_random()
             if not ok:
                 return None
-            return vote_set.get_by_index(index)
+            vote = vote_set.get_by_index(index)
+            if hold_of is None or hold_of(vote) <= 0.0:
+                return vote
+            rest = [i for i in needed.indices() if i != index]
+            random.shuffle(rest)
+            for i in rest:
+                vote = vote_set.get_by_index(i)
+                if hold_of(vote) <= 0.0:
+                    return vote
+            return None
 
     def agg_commit_due(self, height: int, hold: float = 1.0) -> bool:
         """Whether the aggregate catchup commit for `height` should be
@@ -380,7 +436,11 @@ class ConsensusReactor(Reactor, BaseService):
         self.fast_sync = fast_sync
         self.evsw = None
         self._peer_threads: dict[str, list] = {}
-        self._peer_stops: dict[str, threading.Event] = {}
+        self._peer_gossip: dict[str, _PeerGossip] = {}
+        # what wake_gossip walks: replaced whole under _mtx when a peer
+        # comes or goes and read without it, so the consensus receive
+        # routine (which fires the events) never waits for a lock
+        self._gossips: tuple[_PeerGossip, ...] = ()
         self._mtx = threading.Lock()
         # has-vote-aware gossip dedup (round 20): when on, STATE-channel
         # HasVotes ensure the tracking arrays before applying (a fresh
@@ -388,7 +448,7 @@ class ConsensusReactor(Reactor, BaseService):
         # before), last-commit-height HasVotes land, local part adds
         # broadcast HasBlockPart screens, and the vote pick loops hold
         # re-pushes of just-received votes for one gossip tick so the
-        # announcements can set the mirror bits first (_relay_ready).
+        # announcements can set the mirror bits first (_relay_hold).
         # Off restores the pre-round-20 gossip for the before/after
         # bench.
         self.gossip_dedup = bool(
@@ -401,22 +461,43 @@ class ConsensusReactor(Reactor, BaseService):
         # aggregate-format catchup accounting (round 22, docs/upgrade.md)
         self.agg_commits_sent = 0      # whole-commit catchup sends
         self.agg_commits_rejected = 0  # forged/sub-quorum screened out
+        # GOSSIP_COUNTERS: passes that found an item to send; how the
+        # routines' waits ended — by a signal, by a lazy-relay hold
+        # running out, by the idle back-stop; and how often a back-stop
+        # wake then found something to send, which is an event nobody
+        # signalled (must stay near 0 beside gossip_sends)
+        self.gossip_sends = 0
+        self.gossip_wakes_event = 0
+        self.gossip_wakes_hold = 0
+        self.gossip_wakes_backstop = 0
+        self.gossip_backstop_sends = 0
+        # default_set_proposal fires no event: it calls this
+        consensus_state.gossip_wake = self.wake_gossip
 
     # -- wiring ------------------------------------------------------------
 
     def set_event_switch(self, evsw) -> None:
-        """Subscribe broadcast triggers (reactor.go:321-337)."""
+        """Subscribe broadcast triggers (reactor.go:321-337). The three
+        events that change what OUR round state holds also wake every
+        peer's gossip routines."""
         self.evsw = evsw
+
+        def on_step(_d):
+            self.wake_gossip()
+            self._broadcast_step()
+
+        def on_vote(d):
+            self.wake_gossip()
+            self._broadcast_has_vote(d.vote)
+
+        def on_part(d):
+            self.wake_gossip()
+            self._broadcast_has_part(d)
+
+        evsw.add_listener_for_event("conR", tev.EVENT_NEW_ROUND_STEP, on_step)
+        evsw.add_listener_for_event("conR", tev.EVENT_VOTE, on_vote)
         evsw.add_listener_for_event(
-            "conR", tev.EVENT_NEW_ROUND_STEP, lambda _d: self._broadcast_step()
-        )
-        evsw.add_listener_for_event(
-            "conR", tev.EVENT_VOTE, lambda d: self._broadcast_has_vote(d.vote)
-        )
-        evsw.add_listener_for_event(
-            "conR",
-            tev.EVENT_PROPOSAL_BLOCK_PART,
-            lambda d: self._broadcast_has_part(d),
+            "conR", tev.EVENT_PROPOSAL_BLOCK_PART, on_part
         )
         evsw.add_listener_for_event(
             "conR",
@@ -463,20 +544,21 @@ class ConsensusReactor(Reactor, BaseService):
     def add_peer(self, peer) -> None:
         ps = PeerState(peer)
         peer.set(PEER_STATE_KEY, ps)
-        stop = threading.Event()
+        gw = ps.gossip
         threads = []
-        for fn, nm in (
-            (self._gossip_data_routine, "gossipData"),
-            (self._gossip_votes_routine, "gossipVotes"),
-            (self._query_maj23_routine, "queryMaj23"),
+        for fn, arg, nm in (
+            (self._gossip_data_routine, gw, "gossipData"),
+            (self._gossip_votes_routine, gw, "gossipVotes"),
+            (self._query_maj23_routine, gw.stop, "queryMaj23"),
         ):
             t = threading.Thread(
-                target=fn, args=(peer, ps, stop), daemon=True,
+                target=fn, args=(peer, ps, arg), daemon=True,
                 name=f"conR.{nm}:{peer.id()[:8]}",
             )
             threads.append(t)
         with self._mtx:
-            self._peer_stops[peer.id()] = stop
+            self._peer_gossip[peer.id()] = gw
+            self._gossips = tuple(self._peer_gossip.values())
             self._peer_threads[peer.id()] = threads
         for t in threads:
             t.start()
@@ -487,10 +569,19 @@ class ConsensusReactor(Reactor, BaseService):
 
     def remove_peer(self, peer, reason) -> None:
         with self._mtx:
-            stop = self._peer_stops.pop(peer.id(), None)
+            gw = self._peer_gossip.pop(peer.id(), None)
+            self._gossips = tuple(self._peer_gossip.values())
             self._peer_threads.pop(peer.id(), None)
-        if stop:
-            stop.set()
+        if gw:
+            gw.end()
+
+    def wake_gossip(self) -> None:
+        """Our own round state changed (a step, a vote, a part, the
+        proposal): every peer's routines look again now. O(peers) flag
+        sets, no lock, never blocks — this runs on the consensus receive
+        routine."""
+        for gw in self._gossips:
+            gw.wake()
 
     def receive(self, ch_id: int, peer, msg_bytes: bytes) -> None:
         """reactor.go:159-302."""
@@ -506,10 +597,15 @@ class ConsensusReactor(Reactor, BaseService):
             return
 
         if ch_id == STATE_CHANNEL:
+            # a wake follows what can make MORE sendable to this peer
+            # (it entered our height or round, it asks for votes);
+            # HasVote / HasBlockPart only ever take away: no wake
             if isinstance(msg, msgs.NewRoundStepMessage):
                 ps.apply_new_round_step(msg)
+                ps.gossip.wake()
             elif isinstance(msg, msgs.CommitStepMessage):
                 ps.apply_commit_step(msg)
+                ps.gossip.wake()
             elif isinstance(msg, msgs.HasVoteMessage):
                 if self.gossip_dedup:
                     # ensure the tracking arrays BEFORE applying — at a
@@ -539,6 +635,7 @@ class ConsensusReactor(Reactor, BaseService):
                 )
             elif isinstance(msg, msgs.VoteSetMaj23Message):
                 self._handle_vote_set_maj23(peer, ps, msg)
+                ps.gossip.wake()
             else:
                 self.switch.stop_peer_for_error(peer, f"bad state msg {type(msg)}")
         elif ch_id == DATA_CHANNEL:
@@ -549,6 +646,7 @@ class ConsensusReactor(Reactor, BaseService):
                 self.con_s.add_peer_message(msg, peer.id())
             elif isinstance(msg, msgs.ProposalPOLMessage):
                 ps.apply_proposal_pol(msg)
+                ps.gossip.wake()
             elif isinstance(msg, msgs.BlockPartMessage):
                 ps.set_has_proposal_block_part(msg.height, msg.round_, msg.part.index)
                 self.con_s.add_peer_message(msg, peer.id())
@@ -592,6 +690,7 @@ class ConsensusReactor(Reactor, BaseService):
                 else:
                     ours = None
                 ps.apply_vote_set_bits(msg, ours)
+                ps.gossip.wake()
             else:
                 self.switch.stop_peer_for_error(peer, f"bad bits msg {type(msg)}")
 
@@ -660,10 +759,8 @@ class ConsensusReactor(Reactor, BaseService):
 
     def on_stop(self) -> None:
         self.con_s.stop()
-        with self._mtx:
-            stops = list(self._peer_stops.values())
-        for s in stops:
-            s.set()
+        for gw in self._gossips:
+            gw.end()
 
     def switch_to_consensus(self, state) -> None:
         """Fast sync complete (reactor.go:78-90). Note: update BEFORE
@@ -740,60 +837,127 @@ class ConsensusReactor(Reactor, BaseService):
             STATE_CHANNEL, _enc(msgs.ProposalHeartbeatMessage(heartbeat))
         )
 
+    # -- the loop both gossip routines run ----------------------------------
+
+    def _gossip_routine(self, gw: _PeerGossip, wake: threading.Event,
+                        gossip_pass) -> None:
+        """`gossip_pass()` looks at the round state and the peer's mirror
+        and sends at most ONE item; it returns (sent, hold_s), hold_s
+        being the seconds until the earliest lazy-relay hold ends when
+        held votes were all it found. The signal is cleared BEFORE the
+        look, so whatever lands after it ends the wait at once; a pass
+        that sent keeps going without waiting (a burst of events is one
+        wake); a pass that found nothing goes back to a full wait."""
+        ran_out = False  # the last wait ended on the idle back-stop
+        while self.is_running():
+            if self.fast_sync:
+                if gw.stop.wait(PEER_GOSSIP_SLEEP):
+                    return
+                continue
+            wake.clear()
+            # the stop is read AFTER the clear, like the round state: a
+            # stop that lands from here on still finds the signal to set
+            if gw.stop.is_set():
+                return
+            sent, hold_s = gossip_pass()
+            if sent:
+                self.gossip_sends += 1
+                if ran_out:
+                    # nothing told us: an event this reactor fails to
+                    # signal, or a hold that outlived the wait
+                    self.gossip_backstop_sends += 1
+                    ran_out = False
+                continue
+            ran_out = self._gossip_wait(wake, hold_s)
+
+    def _gossip_wait(self, wake: threading.Event,
+                     hold_s: float | None = None) -> bool:
+        """Block until signalled, until the earliest relay hold ends, or
+        for PEER_GOSSIP_SLEEP, whichever is first. True when the
+        back-stop ran out."""
+        held = hold_s is not None and hold_s < PEER_GOSSIP_SLEEP
+        if wake.wait(hold_s if held else PEER_GOSSIP_SLEEP):
+            self.gossip_wakes_event += 1
+            return False
+        if held:
+            self.gossip_wakes_hold += 1
+            return False
+        self.gossip_wakes_backstop += 1
+        return True
+
+    def _note_own_send(self, height: int, key: tuple) -> None:
+        """The first send of an item of OUR OWN origin (our proposal, its
+        parts, our votes): the time since it entered our round state
+        (state.own_entered_mono) goes onto that height's trace as the
+        aux note gossip_send_lag_s, summed over the height's items."""
+        entered = getattr(self.con_s, "own_entered_mono", None)
+        if not entered:
+            return
+        t = entered.pop(key, None)
+        if t is not None:
+            self.con_s.trace.note_overlap(
+                height, "gossip_send_lag_s", time.monotonic() - t
+            )
+
     # -- gossip_data (reactor.go:413-535) ----------------------------------
 
-    def _gossip_data_routine(self, peer, ps: PeerState, stop: threading.Event) -> None:
-        while self.is_running() and not stop.is_set():
-            if self.fast_sync:
-                stop.wait(PEER_GOSSIP_SLEEP)
-                continue
-            rs = self.con_s.get_round_state()
-            prs = ps.get_round_state()
-            # 1. send a block part the peer lacks
-            if (
-                rs.proposal_block_parts is not None
-                and prs.proposal_block_parts is not None
-                and rs.height == prs.height
-                and rs.round_ == prs.round_
-            ):
-                have = rs.proposal_block_parts.bit_array()
-                needed = have.sub(prs.proposal_block_parts)
-                if not needed.is_empty():
-                    index, ok = needed.pick_random()
-                    if ok:
-                        part = rs.proposal_block_parts.get_part(index)
-                        msg = msgs.BlockPartMessage(rs.height, rs.round_, part)
-                        if peer.send(DATA_CHANNEL, _enc(msg)):
-                            ps.set_has_proposal_block_part(prs.height, prs.round_, index)
-                        continue
-            # 2. peer is on an older height: catch them up from the store
-            if prs.height != 0 and rs.height > prs.height:
-                if self._gossip_data_catchup(peer, ps, prs):
-                    continue
-                stop.wait(PEER_GOSSIP_SLEEP)
-                continue
-            # 3. send the proposal (+POL) if the peer doesn't have it
-            if (
-                rs.height == prs.height
-                and rs.round_ == prs.round_
-                and rs.proposal is not None
-                and not prs.proposal
-            ):
-                if peer.send(DATA_CHANNEL, _enc(msgs.ProposalMessage(rs.proposal))):
-                    ps.set_has_proposal(rs.proposal)
-                if 0 <= rs.proposal.pol_round < rs.round_ and rs.votes is not None:
-                    pol = rs.votes.prevotes(rs.proposal.pol_round)
-                    if pol is not None:
-                        peer.send(
-                            DATA_CHANNEL,
-                            _enc(
-                                msgs.ProposalPOLMessage(
-                                    rs.height, rs.proposal.pol_round, pol.bit_array()
-                                )
-                            ),
+    def _gossip_data_routine(self, peer, ps: PeerState, gw: _PeerGossip) -> None:
+        self._gossip_routine(
+            gw, gw.data, lambda: (self._gossip_data_pass(peer, ps), None)
+        )
+
+    def _gossip_data_pass(self, peer, ps: PeerState) -> bool:
+        """One look of gossip_data; True when it found something to send."""
+        rs = self.con_s.get_round_state()
+        prs = ps.get_round_state()
+        # 1. send a block part the peer lacks
+        if (
+            rs.proposal_block_parts is not None
+            and prs.proposal_block_parts is not None
+            and rs.height == prs.height
+            and rs.round_ == prs.round_
+        ):
+            have = rs.proposal_block_parts.bit_array()
+            needed = have.sub(prs.proposal_block_parts)
+            if not needed.is_empty():
+                index, ok = needed.pick_random()
+                if ok:
+                    part = rs.proposal_block_parts.get_part(index)
+                    msg = msgs.BlockPartMessage(rs.height, rs.round_, part)
+                    if peer.send(DATA_CHANNEL, _enc(msg)):
+                        ps.set_has_proposal_block_part(prs.height, prs.round_, index)
+                        self._note_own_send(
+                            rs.height, ("part", rs.height, rs.round_, index)
                         )
-                continue
-            stop.wait(PEER_GOSSIP_SLEEP)
+                    return True
+        # 2. peer is on an older height: catch them up from the store
+        if prs.height != 0 and rs.height > prs.height:
+            return self._gossip_data_catchup(peer, ps, prs)
+        # 3. send the proposal (+POL) if the peer doesn't have it
+        if (
+            rs.height == prs.height
+            and rs.round_ == prs.round_
+            and rs.proposal is not None
+            and not prs.proposal
+        ):
+            if peer.send(DATA_CHANNEL, _enc(msgs.ProposalMessage(rs.proposal))):
+                ps.set_has_proposal(rs.proposal)
+                self._note_own_send(
+                    rs.height, ("proposal", rs.height, rs.round_)
+                )
+            if 0 <= rs.proposal.pol_round < rs.round_ and rs.votes is not None:
+                pol = rs.votes.prevotes(rs.proposal.pol_round)
+                if pol is not None:
+                    peer.send(
+                        DATA_CHANNEL,
+                        _enc(
+                            msgs.ProposalPOLMessage(
+                                rs.height, rs.proposal.pol_round, pol.bit_array()
+                            )
+                        ),
+                    )
+            return True
+        return False
 
     def _gossip_data_catchup(self, peer, ps: PeerState, prs: PeerRoundState) -> bool:
         """Send a part of a committed block (reactor.go:494-535)."""
@@ -832,24 +996,23 @@ class ConsensusReactor(Reactor, BaseService):
 
     # -- gossip_votes (reactor.go:537-645) ---------------------------------
 
-    def _gossip_votes_routine(self, peer, ps: PeerState, stop: threading.Event) -> None:
-        while self.is_running() and not stop.is_set():
-            if self.fast_sync:
-                stop.wait(PEER_GOSSIP_SLEEP)
-                continue
-            rs = self.con_s.get_round_state()
-            prs = ps.get_round_state()
-            if rs.validators is not None:
-                ps.ensure_vote_bit_arrays(rs.height, rs.validators.size())
-                # a peer lagging one height needs last-commit bit arrays
-                # before pick_vote_to_send can track what it has
-                if rs.last_validators is not None:
-                    ps.ensure_vote_bit_arrays(
-                        rs.height - 1, rs.last_validators.size()
-                    )
-            if self._pick_and_send_vote(peer, ps, rs, prs):
-                continue
-            stop.wait(PEER_GOSSIP_SLEEP)
+    def _gossip_votes_routine(self, peer, ps: PeerState, gw: _PeerGossip) -> None:
+        self._gossip_routine(
+            gw, gw.votes, lambda: self._gossip_votes_pass(peer, ps)
+        )
+
+    def _gossip_votes_pass(self, peer, ps: PeerState) -> tuple[bool, float | None]:
+        rs = self.con_s.get_round_state()
+        prs = ps.get_round_state()
+        if rs.validators is not None:
+            ps.ensure_vote_bit_arrays(rs.height, rs.validators.size())
+            # a peer lagging one height needs last-commit bit arrays
+            # before pick_vote_to_send can track what it has
+            if rs.last_validators is not None:
+                ps.ensure_vote_bit_arrays(
+                    rs.height - 1, rs.last_validators.size()
+                )
+        return self._pick_and_send_vote(peer, ps, rs, prs)
 
     def _send_vote(self, peer, ps: PeerState, vote) -> bool:
         """Send one vote and, ONLY on success, mark the peer as having
@@ -885,47 +1048,81 @@ class ConsensusReactor(Reactor, BaseService):
 
         return adaptive_relay_delay(peer_metrics(reg)["ping_rtt_ewma"].value())
 
-    def _relay_ready(self, vote) -> bool:
-        """The lazy-relay screen: hold re-pushes of a vote we received
-        less than _relay_delay() ago (VOTE_RELAY_DELAY, RTT-adapted when
-        samples exist). Unstamped votes — our own, and store-backed
-        catchup commits — relay immediately; a held vote stays pickable
-        and goes out on a later tick if the peer's mirror bit is still
-        clear then."""
+    def _relay_hold(self, vote, delay: float | None = None) -> float:
+        """The lazy-relay screen: seconds a re-push of `vote` is still
+        held (0.0: relay now). A vote we received less than
+        _relay_delay() ago (VOTE_RELAY_DELAY, RTT-adapted when samples
+        exist) is held; unstamped votes — our own, and store-backed
+        catchup commits — relay immediately. A held vote stays pickable
+        and goes out when its hold ends if the peer's mirror bit is
+        still clear then."""
         if not self.gossip_dedup:
-            return True
+            return 0.0
         t = self.con_s.vote_recv_mono.get(
             (vote.height, vote.round_, vote.type_, vote.validator_index)
         )
-        return t is None or time.monotonic() - t >= self._relay_delay()
+        if t is None:
+            return 0.0
+        if delay is None:
+            delay = self._relay_delay()
+        return max(0.0, t + delay - time.monotonic())
 
-    def _pick_and_send_vote(self, peer, ps: PeerState, rs, prs: PeerRoundState) -> bool:
+    def _pick_and_send_vote(self, peer, ps: PeerState, rs,
+                            prs: PeerRoundState) -> tuple[bool, float | None]:
         """One needed vote, if any (reactor.go:609-645 gossipVotesForHeight
-        + same-height/lastCommit/catchup cases)."""
+        + same-height/lastCommit/catchup cases). Returns (sent, hold_s):
+        when nothing went out and votes the peer needs sit behind the
+        lazy-relay screen, hold_s is the time until the earliest of
+        them may go — what the routine waits, instead of a whole
+        back-stop on top of the hold."""
+        hold_s: float | None = None
+        delay = self._relay_delay() if self.gossip_dedup else 0.0
+
+        def hold_of(vote) -> float:
+            nonlocal hold_s
+            left = self._relay_hold(vote, delay)
+            if left > 0.0 and (hold_s is None or left < hold_s):
+                hold_s = left
+            return left
+
+        def send(vote) -> tuple[bool, None]:
+            ok = self._send_vote(peer, ps, vote)
+            if ok:
+                self._note_own_send(
+                    vote.height,
+                    (vote.height, vote.round_, vote.type_,
+                     vote.validator_index),
+                )
+            return ok, None
+
         # same height
         if rs.height == prs.height and rs.votes is not None:
             # peer is lagging in rounds: their POL prevotes
             if prs.step <= RoundStep.PROPOSE and prs.round_ != -1 and \
                prs.round_ <= rs.round_ and prs.proposal_pol_round != -1:
                 pol = rs.votes.prevotes(prs.proposal_pol_round)
-                vote = ps.pick_vote_to_send(pol) if pol else None
-                if vote is not None and self._relay_ready(vote):
-                    return self._send_vote(peer, ps, vote)
+                vote = ps.pick_vote_to_send(pol, hold_of) if pol else None
+                if vote is not None:
+                    return send(vote)
             if prs.step <= RoundStep.PREVOTE_WAIT and prs.round_ != -1 and \
                prs.round_ <= rs.round_:
-                vote = ps.pick_vote_to_send(rs.votes.prevotes(prs.round_))
-                if vote is not None and self._relay_ready(vote):
-                    return self._send_vote(peer, ps, vote)
+                vote = ps.pick_vote_to_send(
+                    rs.votes.prevotes(prs.round_), hold_of
+                )
+                if vote is not None:
+                    return send(vote)
             if prs.step <= RoundStep.PRECOMMIT_WAIT and prs.round_ != -1 and \
                prs.round_ <= rs.round_:
-                vote = ps.pick_vote_to_send(rs.votes.precommits(prs.round_))
-                if vote is not None and self._relay_ready(vote):
-                    return self._send_vote(peer, ps, vote)
+                vote = ps.pick_vote_to_send(
+                    rs.votes.precommits(prs.round_), hold_of
+                )
+                if vote is not None:
+                    return send(vote)
             if prs.proposal_pol_round != -1:
                 pol = rs.votes.prevotes(prs.proposal_pol_round)
-                vote = ps.pick_vote_to_send(pol) if pol else None
-                if vote is not None and self._relay_ready(vote):
-                    return self._send_vote(peer, ps, vote)
+                vote = ps.pick_vote_to_send(pol, hold_of) if pol else None
+                if vote is not None:
+                    return send(vote)
         # peer is at our last height: send from our last commit. The
         # peer's CURRENT round usually raced past the commit round (it
         # entered a timeout round precisely because the commit votes
@@ -943,16 +1140,16 @@ class ConsensusReactor(Reactor, BaseService):
                 # possible — ship the whole commit
                 return self._send_agg_commit(
                     peer, ps, prs.height, rs.last_commit.agg
-                )
+                ), None
             if rs.last_validators is not None:
                 ps.ensure_catchup_commit_round(
                     prs.height, rs.last_commit.round_,
                     rs.last_validators.size(),
                 )
                 prs = ps.get_round_state()
-            vote = ps.pick_vote_to_send(rs.last_commit)
-            if vote is not None and self._relay_ready(vote):
-                return self._send_vote(peer, ps, vote)
+            vote = ps.pick_vote_to_send(rs.last_commit, hold_of)
+            if vote is not None:
+                return send(vote)
         # peer is far behind: catch up with the stored seen-commit
         if rs.height >= prs.height + 2 and prs.height > 0:
             store = getattr(self.con_s, "block_store", None)
@@ -966,14 +1163,14 @@ class ConsensusReactor(Reactor, BaseService):
                         # the whole quorum
                         return self._send_agg_commit(
                             peer, ps, prs.height, commit
-                        )
+                        ), None
                     ps.ensure_catchup_commit_round(
                         prs.height, commit.round_(), len(commit.precommits)
                     )
                     vote = self._pick_commit_vote_to_send(ps, prs, commit)
                     if vote is not None:
-                        return self._send_vote(peer, ps, vote)
-        return False
+                        return self._send_vote(peer, ps, vote), None
+        return False, hold_s
 
     def _send_agg_commit(self, peer, ps: PeerState, height: int, agg) -> bool:
         """One whole-commit catchup send, per-peer deduplicated: the

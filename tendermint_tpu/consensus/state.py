@@ -166,9 +166,18 @@ class ConsensusState(BaseService):
         # 20): the reactor's lazy-relay screen holds re-pushes of a
         # just-received vote for one gossip tick so the origin's own
         # fan-out + the recipients' HasVote announcements win the race
-        # (reactor._relay_ready). Own votes are never stamped — they
+        # (reactor._relay_hold). Own votes are never stamped — they
         # relay immediately.
         self.vote_recv_mono: dict[tuple, float] = {}
+        # when each item of OUR OWN origin (our proposal, its parts, our
+        # votes) entered the round state (round 26): the reactor pops
+        # the stamp at the item's first send and notes the difference on
+        # the height's trace (aux gossip_send_lag_s)
+        self.own_entered_mono: dict[tuple, float] = {}
+        # the consensus reactor's wake_gossip, for the one change of the
+        # round state that fires no event (default_set_proposal); None
+        # in harnesses without a reactor
+        self.gossip_wake = None
         # aggregate commit-proof plane (round 22, docs/upgrade.md):
         # catchup under the aggregate format ships whole commits, and a
         # lagging node finalizes from the proof instead of a VoteSet —
@@ -1645,6 +1654,15 @@ class ConsensusState(BaseService):
             rs.proposal_block_parts = PartSet.from_header(proposal.block_parts_header)
         self.trace.mark_arrival("proposal")
         self.logger.info("received proposal %r", proposal)
+        if self.is_proposer():
+            self._stamp_own_entered(
+                ("proposal", proposal.height, proposal.round_)
+            )
+        # no event carries this change of the round state, so the
+        # reactor's gossip routines are told directly: the proposer's
+        # sends begin now, and a relayer's as soon as parts follow
+        if self.gossip_wake is not None:
+            self.gossip_wake()
 
     def add_proposal_block_part(self, height: int, part, verify: bool) -> bool:
         """consensus/state.go:1394-1457. Returns True if added."""
@@ -1659,6 +1677,10 @@ class ConsensusState(BaseService):
             # cross-node spread of this instant IS the proposer->peer
             # propagation lag (mark_arrival keeps the first only)
             self.trace.mark_arrival("first_block_part")
+            if not verify:  # built here, not gossiped to us
+                self._stamp_own_entered(
+                    ("part", height, rs.round_, part.index)
+                )
             # round 20: announce the part so peers stop re-sending it —
             # the reactor broadcasts a HasBlockPart off this event (the
             # part-set analogue of the EVENT_VOTE -> HasVote broadcast)
@@ -1823,6 +1845,10 @@ class ConsensusState(BaseService):
         if added and peer_id:
             self.vote_accepted += 1
             self._stamp_vote_recv(vote)
+        elif added and not self.replay_mode:
+            self._stamp_own_entered(
+                (vote.height, vote.round_, vote.type_, vote.validator_index)
+            )
         return added
 
     def _stamp_vote_recv(self, vote: Vote) -> None:
@@ -1830,15 +1856,17 @@ class ConsensusState(BaseService):
         screen reads it). Bounded: entries only matter for one gossip
         tick, so on overflow everything older than a couple seconds is
         dropped in one sweep."""
-        now = time.monotonic()
-        self.vote_recv_mono[
-            (vote.height, vote.round_, vote.type_, vote.validator_index)
-        ] = now
-        if len(self.vote_recv_mono) > 4096:
-            cutoff = now - 2.0
-            self.vote_recv_mono = {
-                k: t for k, t in self.vote_recv_mono.items() if t >= cutoff
-            }
+        self.vote_recv_mono = _stamp_bounded(
+            self.vote_recv_mono,
+            (vote.height, vote.round_, vote.type_, vote.validator_index),
+        )
+
+    def _stamp_own_entered(self, key: tuple) -> None:
+        """Record when an item of our own origin entered the round
+        state; the reactor pops it at the first send. Bounded like
+        vote_recv_mono: stamps nobody popped (no peer to send to) are
+        swept once they are seconds old."""
+        self.own_entered_mono = _stamp_bounded(self.own_entered_mono, key)
 
     def _note_vote_duplicate(self, peer_id: str) -> None:
         """Count one already-seen gossiped vote: the flat gauge, the
@@ -1951,6 +1979,20 @@ class ConsensusState(BaseService):
         self.send_internal_message(MsgInfo(msgs.VoteMessage(vote)))
         self.logger.info("signed and pushed vote %r", vote)
         return vote
+
+
+def _stamp_bounded(stamps: dict, key: tuple) -> dict:
+    """Stamp `key` with the monotonic clock in a table whose entries
+    matter for a moment (the relay hold, a first send). On overflow
+    everything older than a couple of seconds goes in one sweep; the
+    table to keep is returned. Readers on other threads may pop from it
+    meanwhile, hence the copy before the sweep."""
+    now = time.monotonic()
+    stamps[key] = now
+    if len(stamps) > 4096:
+        cutoff = now - 2.0
+        return {k: t for k, t in list(stamps.items()) if t >= cutoff}
+    return stamps
 
 
 class _NullCache:

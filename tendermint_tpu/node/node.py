@@ -467,8 +467,15 @@ class Node(BaseService):
                 "round": rs.round_,
                 "step": int(rs.step),
                 "vote_duplicates": self.consensus_state.vote_duplicates,
+                "vote_accepted": self.consensus_state.vote_accepted,
                 "peer_msg_drops": self.consensus_state.peer_msg_drops,
             }
+            # how the gossip routines' waits ended: sends that only the
+            # back-stop found are a missing wake-up (consensus/reactor.py)
+            from tendermint_tpu.consensus.reactor import GOSSIP_COUNTERS
+
+            for k in GOSSIP_COUNTERS:
+                out[k] = getattr(self.consensus_reactor, k)
             out.update(p2p_telemetry.family_totals(self.telemetry))
             return out
 

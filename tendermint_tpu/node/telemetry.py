@@ -108,6 +108,8 @@ def build_registry(node) -> telemetry.Registry:
     _txtrace.txtrace_hists(reg)
     node.txtrace.metrics_registry = reg
 
+    from tendermint_tpu.consensus.reactor import GOSSIP_COUNTERS
+
     def consensus() -> dict:
         rs = cs.get_round_state()
         return {
@@ -152,6 +154,11 @@ def build_registry(node) -> telemetry.Registry:
                 node.consensus_reactor.part_announces_sent,
             "gossip_part_announces_applied":
                 node.consensus_reactor.part_announces_applied,
+            # round 26: the gossip routines wake on events. Passes that
+            # sent, how their waits ended, and the sends that only the
+            # idle back-stop found (a missing signal: near 0)
+            **{k: getattr(node.consensus_reactor, k)
+               for k in GOSSIP_COUNTERS},
         }
 
     reg.register_producer("consensus", consensus)

@@ -1,0 +1,510 @@
+"""The gossip routines wake on the event they used to poll for (round 26).
+
+Each of a peer's two gossip routines blocks on a wake signal with
+PEER_GOSSIP_SLEEP as the time-out of that wait. Nothing here is timed
+against a 100 ms tick: the back-stop is patched to several seconds, so
+a send that shows up within a second can only have been woken by a
+signal (or by a relay hold's end, where the test says so).
+"""
+
+from __future__ import annotations
+
+import threading
+import time
+from types import SimpleNamespace
+
+import pytest
+
+from tendermint_tpu.consensus import messages as msgs
+from tendermint_tpu.consensus import reactor as reactor_mod
+from tendermint_tpu.consensus.reactor import (
+    DATA_CHANNEL,
+    PEER_STATE_KEY,
+    STATE_CHANNEL,
+    VOTE_CHANNEL,
+    VOTE_SET_BITS_CHANNEL,
+    ConsensusReactor,
+    _dec,
+    _enc,
+)
+from tendermint_tpu.consensus.round_state import RoundStep
+from tendermint_tpu.consensus.trace import TraceRecorder
+from tendermint_tpu.libs.bitarray import BitArray
+from tendermint_tpu.libs.events import EventSwitch
+from tendermint_tpu.types import PartSet, Proposal, Vote
+from tendermint_tpu.types import events as tev
+from tendermint_tpu.types.block_id import BlockID
+from tendermint_tpu.types.vote import VOTE_TYPE_PRECOMMIT, VOTE_TYPE_PREVOTE
+
+BACKSTOP = 5.0  # what PEER_GOSSIP_SLEEP is patched to
+SOON = 1.0      # far below BACKSTOP, far above a scheduler hiccup
+HEIGHT = 5
+
+
+class _VoteSet:
+    def __init__(self, type_, size=4):
+        self.height, self.round_, self.type_ = HEIGHT, 0, type_
+        self._size = size
+        self.votes: dict[int, Vote] = {}
+
+    def add(self, index: int) -> Vote:
+        vote = Vote(
+            validator_address=bytes([index + 1]) * 20, validator_index=index,
+            height=self.height, round_=self.round_, type_=self.type_,
+            block_id=BlockID(),
+        )
+        self.votes[index] = vote
+        return vote
+
+    def size(self):
+        return self._size
+
+    def bit_array(self):
+        return BitArray.from_indices(self._size, list(self.votes))
+
+    def get_by_index(self, index):
+        return self.votes[index]
+
+    def bit_array_by_block_id(self, block_id):
+        return self.bit_array()
+
+
+class _Votes:
+    def __init__(self):
+        self.pre = _VoteSet(VOTE_TYPE_PREVOTE)
+        self.pc = _VoteSet(VOTE_TYPE_PRECOMMIT)
+
+    def prevotes(self, round_):
+        return self.pre if round_ == 0 else None
+
+    def precommits(self, round_):
+        return self.pc if round_ == 0 else None
+
+
+class _ConState:
+    """As much of ConsensusState as the reactor reads."""
+
+    def __init__(self):
+        self.config = SimpleNamespace(gossip_dedup=True)
+        self.rs = SimpleNamespace(
+            height=HEIGHT, round_=0, step=RoundStep.PREVOTE,
+            start_time=time.time(),
+            validators=SimpleNamespace(size=lambda: 4),
+            last_validators=None, last_commit=None, votes=_Votes(),
+            proposal=None, proposal_block_parts=None, proposal_block=None,
+        )
+        self.vote_recv_mono: dict = {}
+        self.own_entered_mono: dict = {}
+        self.trace = TraceRecorder()
+        self.trace.begin(HEIGHT)
+        self.gossip_wake = None
+
+    def get_round_state(self):
+        return self.rs
+
+
+class _Peer:
+    def __init__(self, name: str):
+        self._id = name
+        self._kv: dict = {}
+        self.sent: list = []  # (monotonic, channel, decoded message)
+        self._cond = threading.Condition()
+
+    def id(self):
+        return self._id
+
+    def get(self, k):
+        return self._kv.get(k)
+
+    def set(self, k, v):
+        self._kv[k] = v
+
+    def send(self, ch, raw):
+        with self._cond:
+            self.sent.append((time.monotonic(), ch, _dec(raw)))
+            self._cond.notify_all()
+        return True
+
+    try_send = send
+
+    def of(self, cls) -> list:
+        with self._cond:
+            return [(t, m) for t, _ch, m in self.sent if isinstance(m, cls)]
+
+    def wait_for(self, cls, n: int = 1, timeout: float = SOON) -> bool:
+        deadline = time.monotonic() + timeout
+        with self._cond:
+            while sum(isinstance(m, cls) for _t, _c, m in self.sent) < n:
+                left = deadline - time.monotonic()
+                if left <= 0:
+                    return False
+                self._cond.wait(left)
+        return True
+
+
+class _Net:
+    """One reactor over a stub consensus state, with stub peers whose
+    mirrors sit at our height and round."""
+
+    def __init__(self, n_peers: int = 1, at_our_height: bool = True):
+        self.cs = _ConState()
+        self.r = ConsensusReactor(self.cs)
+        self.r._started = True  # the routines guard on is_running()
+        self.evsw = EventSwitch()
+        self.evsw.start()
+        self.r.set_event_switch(self.evsw)
+        self.peers = [_Peer(f"peer-{i:04d}") for i in range(n_peers)]
+        for p in self.peers:
+            self.r.add_peer(p)
+            if at_our_height:
+                self.step_to(p, HEIGHT)
+
+    def ps(self, peer):
+        return peer.get(PEER_STATE_KEY)
+
+    def step_to(self, peer, height: int, step=RoundStep.PREVOTE) -> None:
+        self.r.receive(STATE_CHANNEL, peer, _enc(msgs.NewRoundStepMessage(
+            height=height, round_=0, step=step,
+            seconds_since_start_time=0, last_commit_round=-1,
+        )))
+
+    def settle(self) -> None:
+        """Let every routine finish the passes its start and the
+        set-up's wakes caused, so that it sits in its wait."""
+        deadline = time.monotonic() + SOON
+        while time.monotonic() < deadline:
+            before = self.waits()
+            time.sleep(0.05)
+            if self.waits() == before:
+                return
+        raise AssertionError("routines never came to rest")
+
+    def waits(self) -> int:
+        r = self.r
+        return (r.gossip_wakes_event + r.gossip_wakes_hold
+                + r.gossip_wakes_backstop + r.gossip_sends)
+
+    def threads(self) -> list:
+        with self.r._mtx:
+            return [t for ts in self.r._peer_threads.values() for t in ts]
+
+    def close(self) -> None:
+        for p in self.peers:
+            self.r.remove_peer(p, "test over")
+        self.evsw.stop()
+
+
+@pytest.fixture
+def net_factory(monkeypatch):
+    monkeypatch.setattr(reactor_mod, "PEER_GOSSIP_SLEEP", BACKSTOP)
+    nets: list[_Net] = []
+
+    def make(*a, **kw) -> _Net:
+        net = _Net(*a, **kw)
+        nets.append(net)
+        return net
+
+    yield make
+    for net in nets:
+        net.close()
+
+
+def test_own_vote_goes_out_on_its_event(net_factory):
+    """Our own prevote enters the vote set and EVENT_VOTE fires: the
+    peer's vote routine sends it at once, with its lag on the trace."""
+    net = net_factory()
+    peer = net.peers[0]
+    net.settle()
+    assert not peer.of(msgs.VoteMessage)
+
+    vote = net.cs.rs.votes.pre.add(2)
+    key = (HEIGHT, 0, VOTE_TYPE_PREVOTE, 2)
+    net.cs.own_entered_mono[key] = t0 = time.monotonic()
+    net.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(vote))
+
+    assert peer.wait_for(msgs.VoteMessage), "no wake: the vote sat out a back-stop"
+    t_sent, sent = peer.of(msgs.VoteMessage)[0]
+    assert sent.vote.validator_index == 2
+    assert t_sent - t0 < SOON
+    assert net.r.gossip_backstop_sends == 0
+    # first send of an item of our own origin: stamp popped, lag noted
+    assert key not in net.cs.own_entered_mono
+    lag = net.cs.trace.finish(HEIGHT, 1.0).aux["gossip_send_lag_s"]
+    assert 0.0 <= lag < SOON
+
+
+def test_proposal_then_part_go_out_on_their_signals(net_factory):
+    """set_proposal fires no event: the state calls the reactor's wake
+    directly (the reactor hangs it on the state). The part follows on
+    EVENT_PROPOSAL_BLOCK_PART. The data routine sends proposal, then
+    part, each once."""
+    net = net_factory()
+    peer = net.peers[0]
+    net.settle()
+    assert net.cs.gossip_wake == net.r.wake_gossip
+
+    parts = PartSet.from_data(b"block" * 8, 64)
+    assert parts.total == 1
+    rs = net.cs.rs
+    rs.proposal = Proposal(
+        height=HEIGHT, round_=0, block_parts_header=parts.header(),
+        pol_round=-1, pol_block_id=BlockID(),
+    )
+    net.cs.gossip_wake()
+    assert peer.wait_for(msgs.ProposalMessage)
+    assert not peer.of(msgs.BlockPartMessage)  # we hold no part yet
+
+    rs.proposal_block_parts = parts
+    net.evsw.fire_event(
+        tev.EVENT_PROPOSAL_BLOCK_PART, tev.EventDataBlockPart(HEIGHT, 0, 0)
+    )
+    assert peer.wait_for(msgs.BlockPartMessage)
+    net.settle()
+    assert len(peer.of(msgs.ProposalMessage)) == 1
+    assert len(peer.of(msgs.BlockPartMessage)) == 1
+    assert peer.of(msgs.ProposalMessage)[0][0] < peer.of(msgs.BlockPartMessage)[0][0]
+    assert net.r.gossip_backstop_sends == 0
+
+
+def test_peer_step_into_our_height_wakes_that_peer_only(net_factory):
+    """The case that cost the proposer a second sleep: the proposal is
+    there, the peer's NewRoundStep for our height is not. When it
+    comes, that peer's routines look again; the other peer's sleep on."""
+    net = net_factory(n_peers=2, at_our_height=False)
+    late, other = net.peers
+    rs = net.cs.rs
+    parts = PartSet.from_data(b"block" * 8, 64)
+    rs.proposal = Proposal(
+        height=HEIGHT, round_=0, block_parts_header=parts.header(),
+        pol_round=-1, pol_block_id=BlockID(),
+    )
+    rs.votes.pre.add(1)
+    net.r.wake_gossip()
+    net.settle()
+    assert not late.of(msgs.ProposalMessage) and not late.of(msgs.VoteMessage)
+
+    before = net.r.gossip_wakes_event
+    net.step_to(late, HEIGHT)
+    assert late.wait_for(msgs.ProposalMessage)
+    assert late.wait_for(msgs.VoteMessage)
+    net.settle()
+    # two waits ended on a signal, late's: other's two routines slept on
+    assert net.r.gossip_wakes_event - before == 2
+    assert not other.of(msgs.ProposalMessage) and not other.of(msgs.VoteMessage)
+
+
+def _receive_case(what: str):
+    bits = BitArray(4)
+    vote = _VoteSet(VOTE_TYPE_PREVOTE).add(3)
+    parts = PartSet.from_data(b"block" * 8, 64)
+    return {
+        "has_vote": (STATE_CHANNEL, msgs.HasVoteMessage(HEIGHT, 0, VOTE_TYPE_PREVOTE, 3)),
+        "has_block_part": (STATE_CHANNEL, msgs.HasBlockPartMessage(HEIGHT, 0, 0)),
+        "vote": (VOTE_CHANNEL, msgs.VoteMessage(vote)),
+        "block_part": (DATA_CHANNEL, msgs.BlockPartMessage(HEIGHT, 0, parts.get_part(0))),
+        "commit_step": (STATE_CHANNEL, msgs.CommitStepMessage(
+            HEIGHT, parts.header(), BitArray(1))),
+        "proposal_pol": (DATA_CHANNEL, msgs.ProposalPOLMessage(HEIGHT, 0, bits)),
+        "vote_set_bits": (VOTE_SET_BITS_CHANNEL, msgs.VoteSetBitsMessage(
+            HEIGHT, 0, VOTE_TYPE_PREVOTE, BlockID(), bits)),
+        "vote_set_maj23": (STATE_CHANNEL, msgs.VoteSetMaj23Message(
+            HEIGHT, 0, VOTE_TYPE_PREVOTE, BlockID())),
+    }[what]
+
+
+@pytest.mark.parametrize(
+    "what,woken",
+    [
+        # only ever take away from what is sendable to the peer
+        ("has_vote", 0), ("has_block_part", 0), ("vote", 0), ("block_part", 0),
+        # can add to it: that peer's two routines, nobody else's
+        ("commit_step", 2), ("proposal_pol", 2), ("vote_set_bits", 2),
+        ("vote_set_maj23", 2),
+    ],
+)
+def test_a_peers_message_wakes_its_routines_only_if_it_can_add(net_factory, what, woken):
+    """HasVote, HasBlockPart and a received vote's or part's own mirror
+    bit only REDUCE what is sendable: no wake. What the peer asks for or
+    steps into wakes that peer's routines, and the other peers' sleep on
+    (three peers here: six waits would end if every routine woke)."""
+    net = net_factory(n_peers=3)
+    net.cs.add_peer_message = lambda msg, peer_id: None
+    net.cs.rs.votes.set_peer_maj23 = lambda *a: None
+    net.settle()
+    before = net.waits()
+    ch, msg = _receive_case(what)
+    net.r.receive(ch, net.peers[0], _enc(msg))
+    net.settle()
+    assert net.waits() - before == woken
+    assert net.r.gossip_sends == 0
+
+
+def test_held_vote_goes_out_when_its_hold_ends(net_factory):
+    """A vote we received moments ago is held by the lazy-relay screen.
+    The woken routine waits out the rest of the hold — not a back-stop
+    on top of it — and does not send before."""
+    net = net_factory()
+    peer = net.peers[0]
+    net.settle()
+    hold = net.r._relay_delay()
+    assert 0.0 < hold < SOON < BACKSTOP
+
+    vote = net.cs.rs.votes.pre.add(1)
+    net.cs.vote_recv_mono[(HEIGHT, 0, VOTE_TYPE_PREVOTE, 1)] = t0 = time.monotonic()
+    net.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(vote))
+
+    assert peer.wait_for(msgs.VoteMessage, timeout=hold + SOON)
+    t_sent = peer.of(msgs.VoteMessage)[0][0]
+    assert t_sent - t0 >= hold, "sent inside its hold"
+    assert t_sent - t0 < hold + SOON, "waited a back-stop on top of the hold"
+    assert net.r.gossip_wakes_hold >= 1
+    assert net.r.gossip_backstop_sends == 0
+
+
+def test_own_vote_is_not_kept_back_by_a_held_one(net_factory):
+    """Two votes the peer lacks, one of them held: the pick passes over
+    the held one, whichever the random pick lands on first."""
+    net = net_factory()
+    peer = net.peers[0]
+    net.settle()
+    pre = net.cs.rs.votes.pre
+    for i in (0, 1, 3):
+        pre.add(i)
+        net.cs.vote_recv_mono[(HEIGHT, 0, VOTE_TYPE_PREVOTE, i)] = time.monotonic() + 60
+    own = pre.add(2)
+    net.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(own))
+    assert peer.wait_for(msgs.VoteMessage)
+    net.settle()
+    assert [m.vote.validator_index for _t, m in peer.of(msgs.VoteMessage)] == [2]
+
+
+def test_event_between_the_look_and_the_wait_is_not_lost(net_factory):
+    """The signal is cleared BEFORE the round state is read. Drive the
+    worst interleaving with a hook: the vote appears and its event
+    fires after the pass has looked (and found nothing) and before the
+    routine waits. The wait must end at once."""
+    net = net_factory()
+    peer = net.peers[0]
+    net.settle()
+    real_wait = net.r._gossip_wait
+    votes_wake = net.ps(peer).gossip.votes
+    fired = threading.Event()
+
+    def wait_after_a_late_event(wake, hold_s=None):
+        if wake is votes_wake and not fired.is_set():
+            fired.set()
+            vote = net.cs.rs.votes.pre.add(0)
+            net.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(vote))
+        return real_wait(wake, hold_s)
+
+    net.r._gossip_wait = wait_after_a_late_event
+    # one more (empty) pass of the vote routine, ending in the hooked wait
+    votes_wake.set()
+    assert fired.wait(SOON)
+    assert peer.wait_for(msgs.VoteMessage), "the wake-up was lost"
+
+
+def test_idle_routine_makes_about_ten_passes_a_second():
+    """No spin, at the real back-stop: a routine with nothing to send,
+    woken by nothing, looks ten times a second, as it always did."""
+    net = _Net()
+    try:
+        time.sleep(0.3)  # the passes of its start
+        before = net.r.gossip_wakes_backstop, net.r.gossip_wakes_event
+        t0 = time.monotonic()
+        time.sleep(1.0)
+        elapsed = time.monotonic() - t0
+        backstops = net.r.gossip_wakes_backstop - before[0]
+        events = net.r.gossip_wakes_event - before[1]
+    finally:
+        net.close()
+    assert events == 0
+    # two routines of one peer, a pass every PEER_GOSSIP_SLEEP each (a
+    # busy box makes fewer, never more)
+    assert 6 <= backstops <= 2 * (elapsed / reactor_mod.PEER_GOSSIP_SLEEP + 2), backstops
+    assert net.r.gossip_sends == 0 and net.r.gossip_backstop_sends == 0
+
+
+def test_a_wake_that_finds_nothing_goes_back_to_a_full_wait(net_factory):
+    net = net_factory()
+    net.settle()
+    passes = net.waits()
+    for _ in range(3):
+        net.r.wake_gossip()
+        net.settle()
+    # three wakes, two routines: six waits ended, and no more than that
+    assert net.waits() - passes == 6
+    assert net.r.gossip_wakes_event >= 6 and net.r.gossip_sends == 0
+
+
+def test_remove_peer_ends_both_routines_promptly(net_factory):
+    """The stop must end the wait too: with a back-stop of seconds the
+    routines are gone in far less."""
+    net = net_factory()
+    net.settle()
+    gossip = [t for t in net.threads() if "gossip" in t.name]
+    assert len(gossip) == 2 and all(t.is_alive() for t in gossip)
+    t0 = time.monotonic()
+    net.r.remove_peer(net.peers[0], "gone")
+    for t in gossip:
+        t.join(SOON)
+    assert not any(t.is_alive() for t in gossip)
+    assert time.monotonic() - t0 < SOON
+    assert net.r._gossips == ()
+
+
+def test_on_stop_ends_every_peers_routines(net_factory):
+    net = net_factory(n_peers=3)
+    net.settle()
+    gossip = [t for t in net.threads() if "gossip" in t.name]
+    assert len(gossip) == 6
+    net.cs.stop = lambda: None
+    net.r.on_stop()
+    for t in gossip:
+        t.join(SOON)
+    assert not any(t.is_alive() for t in gossip)
+
+
+def test_a_burst_of_vote_events_is_a_handful_of_wakes(net_factory):
+    """A committee's votes arrive in a burst: 200 EVENT_VOTEs must cost
+    a routine a few wakes, not 200. The signal coalesces, and firing it
+    never waits for a routine."""
+    net = net_factory()
+    peer = net.peers[0]
+    net.settle()
+    vote = _VoteSet(VOTE_TYPE_PREVOTE).add(0)  # in nobody's vote set
+    before = net.r.gossip_wakes_event
+    t0 = time.monotonic()
+    for _ in range(200):
+        net.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(vote))
+    fire_s = time.monotonic() - t0
+    net.settle()
+    wakes = net.r.gossip_wakes_event - before
+    assert 2 <= wakes <= 2 * 20, wakes  # two routines; nowhere near 400
+    assert fire_s < SOON
+    assert not peer.of(msgs.VoteMessage)
+
+
+def test_state_signals_the_proposal_and_stamps_its_own_items():
+    """The state's side, on a real one-validator ConsensusState: setting
+    the proposal calls gossip_wake (there is no event for it), and our
+    proposal, its part and our two votes are stamped as they enter the
+    round state, for gossip_send_lag_s."""
+    from tests.consensus_common import make_cs_and_stubs, wait_for_height
+
+    cs, _stubs, _ = make_cs_and_stubs(1)
+    had_proposal = []
+    cs.gossip_wake = lambda: had_proposal.append(cs.rs.proposal is not None)
+    cs.start()
+    try:
+        assert wait_for_height(cs, 3, timeout=15)
+    finally:
+        cs.stop()
+    assert had_proposal and all(had_proposal)
+    stamped = set(cs.own_entered_mono)  # no reactor here to pop them
+    idx = 0
+    for h in (1, 2):
+        assert ("proposal", h, 0) in stamped
+        assert ("part", h, 0, 0) in stamped
+        assert (h, 0, VOTE_TYPE_PREVOTE, idx) in stamped
+        assert (h, 0, VOTE_TYPE_PRECOMMIT, idx) in stamped

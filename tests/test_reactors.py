@@ -68,9 +68,11 @@ def make_genesis(n: int):
     return doc, pvs
 
 
-def make_node(doc: GenesisDoc, pv, app=None) -> Node:
+def make_node(doc: GenesisDoc, pv, app=None, consensus_tweak=None) -> Node:
     config = _test_config().consensus
     config.root_dir = tempfile.mkdtemp(prefix="reactor-test-")
+    if consensus_tweak is not None:
+        consensus_tweak(config)
     app = app if app is not None else CounterApp()
     mtx = threading.RLock()
     mempool = Mempool(_test_config().mempool, AppConnMempool(LocalClient(app, mtx)))
@@ -88,12 +90,14 @@ def make_node(doc: GenesisDoc, pv, app=None) -> Node:
 
 
 def start_consensus_net(n: int, app_factory=None, switch_factory=None,
-                        genesis=None):
+                        genesis=None, consensus_tweak=None):
     """genesis=(doc, pvs) overrides make_genesis(n) — e.g. a doc whose
     validator set covers only some of the n nodes (the rest run as full
-    nodes until a val-tx adds them)."""
+    nodes until a val-tx adds them). consensus_tweak(config) edits each
+    node's consensus config (the preset skips timeout_commit)."""
     doc, pvs = genesis if genesis is not None else make_genesis(n)
-    nodes = [make_node(doc, pvs[i], app_factory() if app_factory else None)
+    nodes = [make_node(doc, pvs[i], app_factory() if app_factory else None,
+                       consensus_tweak)
              for i in range(n)]
     for node in nodes:
         node.subscribe_blocks()
@@ -145,6 +149,65 @@ def test_reactor_net_makes_blocks():
         assert len(set(h1)) == 1
     finally:
         stop_net(nodes, switches)
+
+
+def test_height_interval_is_commit_timeout_plus_work_not_plus_ticks():
+    """A height lasts timeout_commit plus the work of one proposal and
+    two rounds of votes. While the gossip routines polled every
+    PEER_GOSSIP_SLEEP, that work was three sleeps in series: the median
+    interval of this net read timeout_commit + 200-300 ms, in whole
+    ticks, and an item of our own waited 30-70 ms for its first send.
+    Woken by the event they wait for (round 26) the interval reads
+    timeout_commit + 40-50 ms and the items of a height wait 1-3 ms
+    together. The 60 ms on the interval are a soft bound, for a box
+    busy with other tests (eight busy loops beside this test read 120).
+    What fails hard is what the polling routines read: two ticks on the
+    interval, or a height's own items waiting half a tick to be sent."""
+    import statistics
+
+    from tendermint_tpu.consensus.reactor import PEER_GOSSIP_SLEEP
+
+    timeout_commit = 0.2
+
+    def paced(c):
+        c.timeout_commit = timeout_commit
+        c.skip_timeout_commit = False
+        # a round that times out would be a second cause of long heights
+        c.timeout_propose, c.timeout_prevote, c.timeout_precommit = 3.0, 1.0, 1.0
+
+    medians, lags = [], []
+    for _attempt in range(3):
+        nodes, switches = start_consensus_net(4, consensus_tweak=paced)
+        commits: list[float] = []
+        nodes[0].evsw.add_listener_for_event(
+            "pace", tev.EVENT_NEW_BLOCK, lambda _d: commits.append(time.monotonic())
+        )
+        try:
+            assert wait_until(lambda: len(commits) >= 12, timeout=60), len(commits)
+            traces = [t for t in nodes[0].cs.trace.last(12) if 2 <= t.height <= 11]
+        finally:
+            stop_net(nodes, switches)
+        # ten heights, after the one the net connected in
+        gaps = [b - a for a, b in zip(commits[1:11], commits[2:12])]
+        medians.append(statistics.median(gaps))
+        lags.append(statistics.median(
+            t.aux.get("gossip_send_lag_s", 0.0) for t in traces
+        ))
+        assert len(traces) == 10 and any(
+            "gossip_send_lag_s" in t.aux for t in traces
+        ), "no first send of an own item was noted on ten heights"
+        if medians[-1] < timeout_commit + 0.060:
+            break
+    over = [round((m - timeout_commit) * 1000, 1) for m in medians]
+    assert min(lags) < PEER_GOSSIP_SLEEP / 2, (
+        f"a height's own items waited {lags} s for their first send"
+    )
+    assert min(medians) < timeout_commit + 2 * PEER_GOSSIP_SLEEP, (
+        f"median height interval {over} ms over timeout_commit in three "
+        "nets: the gossip routines are sleeping through what they wait for"
+    )
+    if min(medians) >= timeout_commit + 0.060:
+        pytest.skip(f"slow box: median interval {over} ms over timeout_commit")
 
 
 @pytest.mark.slow
