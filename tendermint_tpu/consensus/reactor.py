@@ -42,6 +42,15 @@ VOTE_SET_BITS_CHANNEL = 0x23
 # event that makes something sendable (wake_gossip, PeerState.gossip).
 PEER_GOSSIP_SLEEP = 0.1
 PEER_QUERY_MAJ23_SLEEP = 2.0
+# a routine whose back-stop ran out and found nothing doubles its next
+# back-stop, up to this many PEER_GOSSIP_SLEEPs; any wake by an event, a
+# hold or a send puts it back to one. A committee's node has 62 routines,
+# and a timed wait that ends is a thread switch whatever the look then
+# costs (21 us): at ten a second each they were 620 wake-ups a second a
+# node that found nothing, on a host with fewer cores than validators
+# (PERF.md, PR 27: `gossip_backstop_sends` 0 of 37,209 sends in
+# `net4.steady`).
+GOSSIP_BACKSTOP_MAX_SLEEPS = 8
 # lazy-relay hold (round 20, gossip_dedup): a vote we RECEIVED moments
 # ago is being fanned out by its origin right now, and every recipient
 # announces it via HasVote within the same window — re-pushing it
@@ -128,14 +137,69 @@ class _PeerGossip:
         self.votes = threading.Event()
 
     def wake(self) -> None:
+        self.wake_data()
+        self.wake_votes()
+
+    def wake_data(self) -> None:
         if not self.data.is_set():
             self.data.set()
+
+    def wake_votes(self) -> None:
         if not self.votes.is_set():
             self.votes.set()
 
     def end(self) -> None:
         self.stop.set()
         self.wake()  # the stop must also end a routine's wait
+
+
+class _DeferredWake:
+    """One timer for the whole reactor: `at(deadline)` asks for ONE call
+    of `fire` at that instant (time.monotonic). A call asked for while
+    one is pending rides it (the earlier instant stands), so a burst of
+    31 votes is one wake of the votes routines when the first hold ends,
+    not 31. Its thread starts with the first call and ends with
+    `stop()`."""
+
+    def __init__(self, fire):
+        self._fire = fire
+        self._cond = threading.Condition()
+        self._due: float | None = None
+        self._stopped = False
+        self._thread: threading.Thread | None = None
+
+    def at(self, deadline: float) -> None:
+        with self._cond:
+            if self._stopped or self._due is not None:
+                return
+            self._due = deadline
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, daemon=True, name="conR.relayWake")
+                self._thread.start()
+            self._cond.notify()
+
+    def stop(self) -> None:
+        with self._cond:
+            self._stopped = True
+            self._cond.notify()
+
+    def _run(self) -> None:
+        with self._cond:
+            while not self._stopped:
+                if self._due is None:
+                    self._cond.wait()
+                    continue
+                left = self._due - time.monotonic()
+                if left > 0:
+                    self._cond.wait(left)
+                    continue
+                self._due = None
+                self._cond.release()
+                try:
+                    self._fire()
+                finally:
+                    self._cond.acquire()
 
 
 def _peer_label(peer) -> str:
@@ -473,6 +537,10 @@ class ConsensusReactor(Reactor, BaseService):
         self.gossip_backstop_sends = 0
         # default_set_proposal fires no event: it calls this
         consensus_state.gossip_wake = self.wake_gossip
+        self._relay_wake = _DeferredWake(self.wake_votes_gossip)
+        # smoothed seconds from our receipt of a vote to a peer's HasVote
+        # for it (None until one was seen)
+        self._has_vote_lag: float | None = None
 
     # -- wiring ------------------------------------------------------------
 
@@ -487,11 +555,21 @@ class ConsensusReactor(Reactor, BaseService):
             self._broadcast_step()
 
         def on_vote(d):
-            self.wake_gossip()
+            # a vote is the votes routines' business alone. Our own goes
+            # out now. One we RECEIVED is held back from relay for
+            # _relay_hold (its origin is fanning it out, and every peer
+            # says so with HasVote): waking 31 routines to find it held,
+            # and again when its hold ends, is 62 thread switches a
+            # vote; one deferred wake at the hold's end serves the burst
+            hold = self._relay_hold(d.vote)
+            if hold > 0:
+                self._relay_wake.at(time.monotonic() + hold)
+            else:
+                self.wake_votes_gossip()
             self._broadcast_has_vote(d.vote)
 
         def on_part(d):
-            self.wake_gossip()
+            self.wake_data_gossip()
             self._broadcast_has_part(d)
 
         evsw.add_listener_for_event("conR", tev.EVENT_NEW_ROUND_STEP, on_step)
@@ -576,12 +654,21 @@ class ConsensusReactor(Reactor, BaseService):
             gw.end()
 
     def wake_gossip(self) -> None:
-        """Our own round state changed (a step, a vote, a part, the
-        proposal): every peer's routines look again now. O(peers) flag
-        sets, no lock, never blocks — this runs on the consensus receive
-        routine."""
+        """Our own round state changed (a step, the proposal): every
+        peer's routines look again now. O(peers) flag sets, no lock,
+        never blocks — this runs on the consensus receive routine."""
         for gw in self._gossips:
             gw.wake()
+
+    def wake_votes_gossip(self) -> None:
+        """A vote entered our round state: the votes routines alone."""
+        for gw in self._gossips:
+            gw.wake_votes()
+
+    def wake_data_gossip(self) -> None:
+        """A part entered our round state: the data routines alone."""
+        for gw in self._gossips:
+            gw.wake_data()
 
     def receive(self, ch_id: int, peer, msg_bytes: bytes) -> None:
         """reactor.go:159-302."""
@@ -621,6 +708,15 @@ class ConsensusReactor(Reactor, BaseService):
                     ps.ensure_vote_bit_arrays(rs.height - 1, last_size)
                 if ps.apply_has_vote(msg, allow_last_commit=self.gossip_dedup):
                     self.has_votes_applied += 1
+                # how long after WE received a vote a peer says it has it:
+                # what a relay's hold has to outlast (_relay_delay)
+                got = self.con_s.vote_recv_mono.get(
+                    (msg.height, msg.round_, msg.type_, msg.index))
+                if got is not None:
+                    lag = time.monotonic() - got
+                    old = self._has_vote_lag
+                    self._has_vote_lag = lag if old is None else \
+                        0.9 * old + 0.1 * lag
             elif isinstance(msg, msgs.HasBlockPartMessage):
                 # round 20 part dedup screen: the peer announced a part
                 # it holds — mark the mirror so gossip_data skips it
@@ -759,6 +855,7 @@ class ConsensusReactor(Reactor, BaseService):
 
     def on_stop(self) -> None:
         self.con_s.stop()
+        self._relay_wake.stop()
         for gw in self._gossips:
             gw.end()
 
@@ -849,6 +946,7 @@ class ConsensusReactor(Reactor, BaseService):
         that sent keeps going without waiting (a burst of events is one
         wake); a pass that found nothing goes back to a full wait."""
         ran_out = False  # the last wait ended on the idle back-stop
+        idle = 0         # back-stops in a row that found nothing to send
         while self.is_running():
             if self.fast_sync:
                 if gw.stop.wait(PEER_GOSSIP_SLEEP):
@@ -862,21 +960,27 @@ class ConsensusReactor(Reactor, BaseService):
             sent, hold_s = gossip_pass()
             if sent:
                 self.gossip_sends += 1
+                idle = 0
                 if ran_out:
                     # nothing told us: an event this reactor fails to
                     # signal, or a hold that outlived the wait
                     self.gossip_backstop_sends += 1
                     ran_out = False
                 continue
-            ran_out = self._gossip_wait(wake, hold_s)
+            ran_out = self._gossip_wait(wake, hold_s, idle)
+            idle = min(idle + 1, GOSSIP_BACKSTOP_MAX_SLEEPS) if ran_out else 0
 
     def _gossip_wait(self, wake: threading.Event,
-                     hold_s: float | None = None) -> bool:
+                     hold_s: float | None = None, idle: int = 0) -> bool:
         """Block until signalled, until the earliest relay hold ends, or
-        for PEER_GOSSIP_SLEEP, whichever is first. True when the
-        back-stop ran out."""
-        held = hold_s is not None and hold_s < PEER_GOSSIP_SLEEP
-        if wake.wait(hold_s if held else PEER_GOSSIP_SLEEP):
+        for the back-stop, whichever is first: PEER_GOSSIP_SLEEP, doubled
+        for every back-stop in a row (`idle`) that found nothing, up to
+        GOSSIP_BACKSTOP_MAX_SLEEPS of them. True when the back-stop ran
+        out."""
+        backstop = PEER_GOSSIP_SLEEP * min(2 ** idle,
+                                           GOSSIP_BACKSTOP_MAX_SLEEPS)
+        held = hold_s is not None and hold_s < backstop
+        if wake.wait(hold_s if held else backstop):
             self.gossip_wakes_event += 1
             return False
         if held:
@@ -1043,10 +1147,22 @@ class ConsensusReactor(Reactor, BaseService):
         reg = getattr(getattr(self, "switch", None), "metrics_registry",
                       None)
         if reg is None:
-            return VOTE_RELAY_DELAY
-        from tendermint_tpu.p2p.telemetry import peer_metrics
+            hold = VOTE_RELAY_DELAY
+        else:
+            from tendermint_tpu.p2p.telemetry import peer_metrics
 
-        return adaptive_relay_delay(peer_metrics(reg)["ping_rtt_ewma"].value())
+            hold = adaptive_relay_delay(
+                peer_metrics(reg)["ping_rtt_ewma"].value())
+        # a ping every 40 s says what the link costs, not how far behind
+        # a peer's process runs: on a host with fewer cores than
+        # validators the HasVotes came 0.1-0.3 s after the vote, every
+        # relay fired inside that, and a node received 3.5 duplicates for
+        # each vote it accepted (PERF.md, PR 27). So the hold also
+        # outlasts twice the lag the HasVotes are seen to have.
+        lag = self._has_vote_lag
+        if lag is not None:
+            hold = min(VOTE_RELAY_DELAY_MAX, max(hold, 2.0 * lag))
+        return hold
 
     def _relay_hold(self, vote, delay: float | None = None) -> float:
         """The lazy-relay screen: seconds a re-push of `vote` is still

@@ -270,6 +270,7 @@ class ConsensusState(BaseService):
         return {
             "verify_tpu_sigs": v.get("tpu_sigs", 0),
             "verify_cpu_sigs": v.get("cpu_sigs", 0),
+            "verify_single_sigs": v.get("single_sigs", 0),
             "hash_tpu_leaves": h.get("tpu_leaves", 0),
             "hash_cpu_leaves": h.get("cpu_leaves", 0),
             "breaker_opens": v.get("breaker_opens",
@@ -665,7 +666,9 @@ class ConsensusState(BaseService):
                         break
                 try:
                     if self.vote_batching and not self.replay_mode:
-                        self.vote_batcher.prepare(
+                        vb = self.vote_batcher
+                        b0, s0 = vb.batches, vb.batched_sigs
+                        vb.prepare(
                             [
                                 i.msg.vote
                                 for t, i in batch
@@ -674,6 +677,11 @@ class ConsensusState(BaseService):
                             self.rs,
                             self.state.chain_id,
                         )
+                        if vb.batches != b0:
+                            # the height's share of the batched plane
+                            self.trace.note("vote_batches", vb.batches - b0)
+                            self.trace.note("votes_batched",
+                                            vb.batched_sigs - s0)
                 except Exception:
                     # batching is purely an accelerator over adversarial
                     # input — it must never kill the receive routine
@@ -706,6 +714,8 @@ class ConsensusState(BaseService):
         elif isinstance(msg, msgs.BlockPartMessage):
             self.add_proposal_block_part(msg.height, msg.part, verify=bool(peer_id))
         elif isinstance(msg, msgs.VoteMessage):
+            if peer_id:
+                self.trace.note("votes_received", 1)
             self.try_add_vote(msg.vote, peer_id)
         elif isinstance(msg, msgs.AggregateCommitMessage):
             self.apply_commit_proof(msg.commit, peer_id)
@@ -1605,11 +1615,13 @@ class ConsensusState(BaseService):
         """One wait of the receive routine for signature verdicts, noted
         on the height's trace: `verify_wait_s` (the whole wait),
         `verify_ipc_s` (what single-shot devd calls made on this thread
-        spent outside the daemon: round trip less the reply's svc_ns)
-        and `verify_calls`. Aux notes: they overlap segments and never
-        enter the partition. Receive routine only (note() has one
-        writer)."""
+        spent outside the daemon: round trip less the reply's svc_ns),
+        `verify_calls`, and `verify_batch_ipc_s` (the same of the vote
+        micro-batches whose verdicts this wait was first to take). Aux
+        notes: they overlap segments and never enter the partition.
+        Receive routine only (note() has one writer)."""
         ipc0 = devd.thread_ipc_ns()
+        bipc0 = devd.thread_batch_ipc_ns()
         t0 = time.perf_counter()
         try:
             return verify(*args)
@@ -1618,12 +1630,31 @@ class ConsensusState(BaseService):
             self.trace.note("verify_ipc_s",
                             (devd.thread_ipc_ns() - ipc0) / 1e9)
             self.trace.note("verify_calls", 1)
+            bipc = devd.thread_batch_ipc_ns() - bipc0
+            if bipc:
+                # a vote micro-batch whose first lane this wait took: its
+                # round trip less the daemon's service time. It overlapped
+                # the routine's work from the dispatch on, so it stands
+                # beside verify_ipc_s and not in it
+                self.trace.note("verify_batch_ipc_s", bipc / 1e9)
 
     def _commit_batch_verifier(self):
         """`commit_batch_verifier` for block validation ON the receive
-        routine: its wait is noted on the height's trace."""
+        routine: its wait is noted on the height's trace, and beside it
+        what the commits alone took (`commit_verify_s`,
+        `commit_verify_lanes`: a committee's LastCommit is N lanes a
+        call)."""
         verify = self.verifier.commit_batch_verifier()
-        return lambda items: self._verdict_wait(verify, items)
+
+        def timed(items):
+            t0 = time.perf_counter()
+            try:
+                return self._verdict_wait(verify, items)
+            finally:
+                self.trace.note("commit_verify_s", time.perf_counter() - t0)
+                self.trace.note("commit_verify_lanes", len(items))
+
+        return timed
 
     # -- proposals ---------------------------------------------------------
 

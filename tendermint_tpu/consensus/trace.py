@@ -70,7 +70,7 @@ _STEP_SEGMENTS = {
 # device-probe keys differenced per height; anything else in the probe
 # dict records as <key>_start / <key>_end (state, not a counter)
 _DELTA_KEYS = (
-    "verify_tpu_sigs", "verify_cpu_sigs",
+    "verify_tpu_sigs", "verify_cpu_sigs", "verify_single_sigs",
     "hash_tpu_leaves", "hash_cpu_leaves",
     "breaker_opens",
 )
@@ -184,6 +184,10 @@ class TraceRecorder:
         # marks feed at finish (node/telemetry.py sets the node registry)
         self._arrivals: dict[str, float] = {}
         self._started_wall = time.time()
+        # the process's CPU clock at the height's start: aux cpu_s is what
+        # ALL its threads burnt over the height (a host with fewer cores
+        # than validators is read off the fleet's sum)
+        self._cpu0 = time.process_time()
         self.metrics_registry = None
         # finish()'s end snapshot doubles as the next begin()'s start —
         # one probe per height boundary, not two back-to-back on the
@@ -217,6 +221,7 @@ class TraceRecorder:
         self._last_t = now if now is not None else time.monotonic()
         self._arrivals = {}
         self._started_wall = time.time()
+        self._cpu0 = time.process_time()
         with self._ov_mtx:
             # _height moves under the overlay lock so a concurrent
             # note_overlap either parks in _ov_pending (and is adopted
@@ -282,6 +287,7 @@ class TraceRecorder:
             overlay, self._overlay = self._overlay, {}
         for k, v in overlay.items():
             self._aux[k] = self._aux.get(k, 0.0) + v
+        self._aux["cpu_s"] = time.process_time() - self._cpu0
         end = self._probe()
         self._dev_carry = end  # the next begin() starts from this reading
         start = self._dev_start

@@ -42,6 +42,13 @@ from inside (tendermint_tpu/devd_spans.py): the `spans` op serves it,
 `serve()` writes it out when it returns, and the `profile` op starts and
 stops a `jax.profiler` trace that holds the same phases as annotations.
 
+Single-shot `verify` requests that wait at the same instant ride ONE
+program (`_VerifyMerger`, PR 27): a committee's validators ask for the
+same few verdicts within milliseconds of each other, and a program costs
+the same host crossing whether it carries one lane or 256. Each caller
+gets the verdicts of its own lanes; each request's record says which
+program it rode and with how many others.
+
 Streaming transport (round 6 — docs/streaming-devd.md): the single-shot
 "verify" op serializes the WHOLE batch into one pickle frame and blocks
 for one monolithic round trip, which caps the serving path well below
@@ -361,6 +368,13 @@ class _DaemonState:
         self.spans = devd_spans.SpanRing()
         self.profile = devd_spans.Profile(self.spans)
         self.conn_ids = itertools.count(1)
+        # client connections open now (a gauge: ping, status and the
+        # records' header carry it) and the most there ever were
+        self.conns_open = 0
+        self.conns_open_max = 0
+        # single-shot `verify` requests that wait at the same instant
+        # ride one program (_VerifyMerger)
+        self.merger = _VerifyMerger(self)
         # claim-time-tuned streamed chunk width, advertised in ping/status
         # so clients frame at the width the held device actually likes
         self.stream_chunk = int(
@@ -404,6 +418,159 @@ class _DaemonState:
     def hash_stream_stats(self) -> dict:
         with self.lock:
             return dict(self.hash_stream)
+
+
+# The merge of waiting single-shot `verify` requests (_VerifyMerger; why:
+# the module's docstring). What chooses is what is waiting: a request that
+# meets nobody runs alone, as it always did. Constants, not knobs: a
+# merged program never passes MERGE_MAX_LANES (a single request wider
+# than that runs alone), and MERGE_TURNS programs are in flight at once,
+# so that the next one marshals while the device runs the last. And a
+# merge never makes a SHAPE the daemon has not run yet: requests join
+# only while the padded width of the whole (_width) is one a program has
+# already had here, or the width the first request has alone. A new width
+# is a new program to trace and compile, seconds inside somebody's wait;
+# whoever warms the daemon decides which widths exist.
+MERGE_MAX_LANES = 256
+MERGE_TURNS = 2
+
+
+def _width(lanes: int) -> int:
+    """The bucket the verify kernels pad a batch to (a power of two, 8 at
+    the least: ops/ed25519_f32._next_pow2)."""
+    return max(8, 1 << max(0, lanes - 1).bit_length())
+
+
+class _Waiting:
+    __slots__ = ("items", "rec", "conn", "done", "oks", "err")
+
+    def __init__(self, items, rec, conn: int):
+        self.items = items
+        self.rec = rec
+        self.conn = conn
+        self.done = threading.Event()
+        self.oks = None
+        self.err: BaseException | None = None
+
+
+class _VerifyMerger:
+    """The daemon's queue of single-shot verify requests and the
+    MERGE_TURNS threads that serve it. A turn takes the oldest waiting
+    request and every other one that fits beside it, verifies their lanes
+    as one batch on the daemon's verifier, and hands each caller the
+    verdicts of its own lanes: a forged lane of one caller is False in
+    that caller's reply and nowhere else. A batch that raises is run
+    again one request at a time, so that only the request at fault gets
+    the error. Each request's record notes the program it rode
+    (devd_spans.CallRecord.ride)."""
+
+    def __init__(self, st: "_DaemonState"):
+        self._st = st
+        self._cond = threading.Condition()
+        self._queue: list[_Waiting] = []
+        self._threads: list[threading.Thread] = []
+        self._widths_run: set[int] = set()   # padded widths programs had
+        # flat counters (status op): programs run, the requests and lanes
+        # they carried, programs that carried more than one request, and
+        # the most requests one program ever carried
+        self.stats = {"programs": 0, "requests": 0, "lanes": 0,
+                      "merged_programs": 0, "requests_max": 0}
+
+    def verify(self, items, rec, conn: int):
+        """Called on a connection's thread: blocks for this request's
+        verdicts; raises what the verifier raised for it."""
+        w = _Waiting(items, rec, conn)
+        with self._cond:
+            if not self._threads:
+                for k in range(MERGE_TURNS):
+                    t = threading.Thread(target=self._turns, daemon=True,
+                                         name=f"devd-verify-{k}")
+                    t.start()
+                    self._threads.append(t)
+            self._queue.append(w)
+            self._cond.notify()
+        w.done.wait()
+        if w.err is not None:
+            raise w.err
+        return w.oks
+
+    def _take(self) -> list[_Waiting]:
+        """The oldest request and, in arrival order, every other that
+        fits beside it under MERGE_MAX_LANES without making a width no
+        program has had yet."""
+        q = self._queue
+        group = [q.pop(0)]
+        lanes = len(group[0].items)
+        i = 0
+        while i < len(q) and lanes < MERGE_MAX_LANES:
+            total = lanes + len(q[i].items)
+            if total <= MERGE_MAX_LANES and (
+                _width(total) == _width(lanes)
+                or _width(total) in self._widths_run
+            ):
+                lanes = total
+                group.append(q.pop(i))
+            else:
+                i += 1
+        return group
+
+    def _turns(self) -> None:
+        while True:
+            with self._cond:
+                while not self._queue:
+                    self._cond.wait()
+                group = self._take()
+                if self._queue:
+                    self._cond.notify()
+            try:
+                self._run(group)
+            except BaseException as exc:  # noqa: BLE001 — a turn never dies
+                for w in group:
+                    if not w.done.is_set():
+                        w.err = exc
+                        w.done.set()
+
+    def _run(self, group: list[_Waiting]) -> None:
+        v = self._st.verifier
+        lead = group[0]
+        items = lead.items if len(group) == 1 else \
+            [it for w in group for it in w.items]
+        # the kernel's marks (marshal, dispatch, device_wait) land on the
+        # leading request's record; the others take them from it
+        devd_spans.attach(lead.rec)
+        try:
+            oks = v.verify_batch(items)
+            if lead.rec is not None:
+                lead.rec.mark("device_wait")  # a kernel without marks
+        except Exception as exc:  # noqa: BLE001
+            if len(group) == 1:
+                lead.err = exc
+                lead.done.set()
+                return
+            logger.exception("merged verify of %d requests failed; "
+                             "running them one by one", len(group))
+            for w in group:
+                self._run([w])
+            return
+        finally:
+            devd_spans.attach(None)
+        conns = len({w.conn for w in group})
+        with self._st.lock:
+            self._widths_run.add(_width(len(items)))
+            s = self.stats
+            s["programs"] += 1
+            s["requests"] += len(group)
+            s["lanes"] += len(items)
+            s["merged_programs"] += len(group) > 1
+            s["requests_max"] = max(s["requests_max"], len(group))
+        at = 0
+        for w in group:
+            n = len(w.items)
+            w.oks = [bool(b) for b in oks[at:at + n]]
+            at += n
+            if w.rec is not None:
+                w.rec.ride(lead.rec, len(group), conns, len(items))
+            w.done.set()
 
 
 class _SimVerifier:
@@ -1028,6 +1195,9 @@ def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
     ring = st.spans
     conn_id = next(st.conn_ids)
     rec = None
+    with st.lock:
+        st.conns_open += 1
+        st.conns_open_max = max(st.conns_open_max, st.conns_open)
     try:
         while True:
             try:
@@ -1064,6 +1234,7 @@ def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
                         # (libtpu's own variable; None = all it finds)
                         "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
                         "error": st.error,
+                        "conns_open": st.conns_open,
                     }
                     if op == "status":
                         # the claim's own account: cache dir in use,
@@ -1082,6 +1253,11 @@ def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
                         # many it has ever held (past the size it wrapped)
                         rep["spans"] = ring.stats()
                         rep["profiling"] = st.profile.active()
+                        # how the single-shot verify requests rode the
+                        # device: programs, requests, lanes, merged ones
+                        with st.lock:
+                            rep["merge"] = dict(st.merger.stats)
+                            rep["conns_open_max"] = st.conns_open_max
                     _send_frame(conn, rep)
                     if st.status == "failed":
                         st.failed_seen.set()
@@ -1132,10 +1308,10 @@ def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
                     else:
                         items = req["items"]
                         ring.decoded(rec, op, len(items), req.get("rid", ""))
-                        oks = v.verify_batch(items)
-                        rec.mark("device_wait")  # a kernel without marks
+                        # with whatever else is waiting, as one program
+                        oks = st.merger.verify(items, rec, conn_id)
                         _send_frame(conn, {
-                            "ok": True, "results": [bool(b) for b in oks],
+                            "ok": True, "results": oks,
                             "svc_ns": rec.service_ns(),
                         })
                         ring.finish(rec)
@@ -1216,6 +1392,8 @@ def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
                 rec = None
     finally:
         ring.drop(rec)
+        with st.lock:
+            st.conns_open -= 1
         try:
             conn.close()
         except Exception:
@@ -1349,6 +1527,7 @@ def serve(path: str | None = None) -> None:
         spans_path = st.spans.dump(
             devd_spans.dump_path(path), pid=os.getpid(),
             device_kind=st.device_kind, platform=st.platform,
+            merge=dict(st.merger.stats), conns_open_max=st.conns_open_max,
         )
         logger.info("devd stopped; %d call records in %s",
                     min(st.spans.count, st.spans.size), spans_path)
@@ -1427,6 +1606,18 @@ _ipc_tls = threading.local()
 
 def thread_ipc_ns() -> int:
     return getattr(_ipc_tls, "ns", 0)
+
+
+def thread_batch_ipc_ns() -> int:
+    """The IPC of pipelined batches whose verdicts this thread was the
+    first to take (ops/gateway: a batch resolves on a thread of its own,
+    and the thread that pops its first lane books its IPC here): the
+    height trace's verify_batch_ipc_s."""
+    return getattr(_ipc_tls, "batch_ns", 0)
+
+
+def note_batch_ipc_ns(ns: int) -> None:
+    _ipc_tls.batch_ns = thread_batch_ipc_ns() + int(ns)
 
 
 def _observe_single(op: str, t0: float, rep: dict) -> None:
@@ -1640,9 +1831,14 @@ class DevdClient:
                 self._discard(conn)
                 raise
 
+        t0 = time.perf_counter()
+
         def resolve() -> list[bool]:
             try:
                 rep = _recv_frame(conn)
+                # the whole round trip from the send, less what the daemon
+                # held the request: this batch's IPC, on this thread
+                _observe_single("verify_async", t0, rep)
             except Exception as exc:
                 self._discard(conn)
                 if pooled and isinstance(exc, (ConnectionError, EOFError)):
@@ -2080,13 +2276,20 @@ def available(timeout: float = 1.0, path: str | None = None) -> dict | None:
             return hit[1]
     rep = None
     if os.path.exists(path):
+        c = DevdClient(path, connect_timeout=timeout, io_timeout=timeout)
         try:
-            c = DevdClient(path, connect_timeout=timeout, io_timeout=timeout)
             r = c.ping(timeout=timeout)
-            c.close()
             rep = r if r.get("held") else None
-        except Exception:
+        except TimeoutError:
+            # somebody listens and did not answer in time: a daemon that
+            # is compiling, or a host with more processes than cores. That
+            # is no verdict on it, so it is not cached as one: the next
+            # caller asks again (gateway.resolve_platform waits it out)
+            return None
+        except Exception:  # noqa: BLE001 — nobody there
             rep = None
+        finally:
+            c.close()
     with _avail_mtx:
         _avail_cache[path] = (now, rep)
     return rep

@@ -59,6 +59,11 @@ FIELDS = (
     "seq", "conn", "op", "lanes", "width",
     "t_recv0", "t_decoded", "t_marshalled", "t_dispatched", "t_verdicts",
     "t_replied", "in_flight_at_recv", "rid",
+    # the program the request rode (devd's merge of waiting `verify`
+    # requests): the leading record's seq, how many requests and how many
+    # connections it carried, and its lanes. A call that ran alone reads
+    # its own seq, 1, 1 and its own lanes.
+    "program", "merged", "merged_conns", "program_lanes",
 )
 RING_SIZE = 65536
 CLOCK_MARK = "devd.clock:"
@@ -96,7 +101,8 @@ class CallRecord:
     PHASES[i]; `_cur` is the phase now running."""
 
     __slots__ = ("seq", "conn", "op", "lanes", "width", "rid", "in_flight",
-                 "t", "_cur", "_ann", "_counted", "_closed")
+                 "t", "_cur", "_ann", "_counted", "_closed",
+                 "program", "merged", "merged_conns", "program_lanes")
 
     def __init__(self, seq: int, conn: int, in_flight: int):
         self.seq = seq
@@ -110,6 +116,10 @@ class CallRecord:
         self._cur = 0
         self._counted = False
         self._closed = False
+        self.program = seq
+        self.merged = 1
+        self.merged_conns = 1
+        self.program_lanes = 0
         self._ann = _annotation(_NAMES[0], seq=seq)
 
     def mark(self, phase: str, width: int = 0) -> None:
@@ -133,13 +143,38 @@ class CallRecord:
                                 lanes=self.lanes) \
             if ann is not None and k + 1 < len(PHASES) else None
 
+    def ride(self, lead: "CallRecord", requests: int, conns: int,
+             lanes: int) -> None:
+        """This request rode `lead`'s program with `requests` - 1 others:
+        note the program on the record and, where it is not the leading
+        one, take the program's three phases from the leader (its wait in
+        the daemon's queue then lies in `marshal`). Only the leader's
+        phases are annotations: one program, one set of spans."""
+        self.program, self.merged = lead.seq, requests
+        self.merged_conns, self.program_lanes = conns, lanes
+        if lead is self:
+            return
+        ann = self._ann
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        # a request decoded while the leader marshalled joined it late:
+        # its phases cannot end before they began
+        for j in range(max(self._cur, 1), 4):
+            self.t[j + 1] = max(lead.t[j + 1], self.t[j])
+        self.width = lead.width
+        self._cur = 4
+        self._ann = _annotation(_NAMES[4], seq=self.seq, lanes=self.lanes) \
+            if ann is not None else None
+
     def service_ns(self) -> int:
         """t_recv0 until now: what the reply carries as `svc_ns`."""
         return time.time_ns() - self.t[0]
 
     def row(self) -> tuple:
         return (self.seq, self.conn, self.op, self.lanes, self.width,
-                *self.t, self.in_flight, self.rid)
+                *self.t, self.in_flight, self.rid, self.program,
+                self.merged, self.merged_conns,
+                self.program_lanes or self.lanes)
 
 
 def attach(rec: CallRecord | None) -> None:

@@ -142,6 +142,7 @@ def _resolve_platform_locked() -> str | None:
 
     rep = devd.available()
     if rep is not None:
+        _platform_cache["daemon_said"] = True
         return rep.get("platform")
     wait_s = float(os.environ.get("TENDERMINT_DEVD_RESOLVE_WAIT_S", "600"))
     if wait_s <= 0 or not os.path.exists(devd.sock_path()):
@@ -151,9 +152,16 @@ def _resolve_platform_locked() -> str | None:
         client = devd.DevdClient(devd.sock_path())
         try:
             while time.monotonic() < deadline:
-                ping = client.ping(timeout=3.0)
+                try:
+                    ping = client.ping(timeout=3.0)
+                except TimeoutError:
+                    # it listens and is too busy to answer (compiling, or
+                    # more processes than cores): still the chip's owner
+                    logger.info("device daemon slow to answer; asking again")
+                    continue
                 if ping.get("held"):
                     devd.bust_avail_cache()
+                    _platform_cache["daemon_said"] = True
                     return ping.get("platform")
                 if ping.get("status") not in _DAEMON_PENDING:
                     logger.warning(
@@ -256,7 +264,14 @@ def kernel_name() -> str:
 
         if devd.available() is not None:
             return "devd"
-        return "comb" if on_tpu() else "f32"
+        # a daemon too busy to answer that one ping within its second is
+        # still the owner of the chip: resolve_platform waits it out, and
+        # a platform that a daemon told us means the daemon serves (a
+        # process that owns its chip directly says so in the environment)
+        platform = resolve_platform()
+        if _platform_cache.get("daemon_said"):
+            return "devd"
+        return "comb" if platform == "tpu" else "f32"
     if name not in KERNELS:
         raise ValueError(
             f"TENDERMINT_TPU_KERNEL={name!r}: expected one of {sorted(KERNELS)}"
@@ -632,17 +647,25 @@ class _PendingBatch:
     dispatch→verdicts wall time (the round-16 vote plane's batch
     histogram rides it)."""
 
-    __slots__ = ("_done", "_event")
+    __slots__ = ("_done", "_event", "_ipc_ns")
 
     def __init__(self, items: list[Item], resolve, on_done=None):
         self._done: dict[Item, bool] = {}
         self._event = threading.Event()
+        self._ipc_ns = 0
         t0 = time.monotonic()
 
         def materialize() -> None:
+            from tendermint_tpu import devd
+
+            ipc0 = devd.thread_ipc_ns()
             try:
+                verdicts = resolve()
+                # what the batch's round trip spent outside the daemon
+                # (the devd client measures it on the resolving thread)
+                self._ipc_ns = devd.thread_ipc_ns() - ipc0
                 self._done.update(
-                    (it, bool(ok)) for it, ok in zip(items, resolve())
+                    (it, bool(ok)) for it, ok in zip(items, verdicts)
                 )
                 if on_done is not None:
                     on_done(time.monotonic() - t0)
@@ -667,6 +690,12 @@ class _PendingBatch:
         (caller re-verifies on CPU — never reject on transport loss)."""
         self._event.wait()
         return self._done.get(item)
+
+    def take_ipc_ns(self) -> int:
+        """The batch's IPC, once: the first caller to pop a lane of it
+        books it (a batch is one round trip, however many lanes)."""
+        ns, self._ipc_ns = self._ipc_ns, 0
+        return ns
 
 
 class Verifier:
@@ -721,6 +750,9 @@ class Verifier:
         self._mtx = threading.Lock()
         self._stats = {
             "tpu_batches": 0, "tpu_sigs": 0, "cpu_sigs": 0,
+            # of cpu_sigs, the one-signature verifies (verify_one: a lone
+            # vote, a proposal): the host by design, never a fallback
+            "single_sigs": 0,
             # aggregate-commit verify lanes (docs/upgrade.md): device-
             # batched dual-scalar-muls vs the pure-python CPU floor
             "agg_batches": 0, "agg_lanes_device": 0, "agg_lanes_cpu": 0,
@@ -985,19 +1017,30 @@ class Verifier:
             primed = self._primed.pop(item, None)
         if isinstance(primed, _PendingBatch):
             # wait OUTSIDE the mutex: this blocks on the device
-            primed = primed.result_for(item)
+            batch = primed
+            primed = batch.result_for(item)
+            ns = batch.take_ipc_ns()
+            if ns:
+                from tendermint_tpu import devd
+
+                devd.note_batch_ipc_ns(ns)
         return primed
 
     def verify_one(self, pubkey: bytes, msg: bytes, sig: bytes) -> bool:
         """Single-signature path (vote-by-vote arrival). A result primed
         by prime_cache is consumed here without re-verifying; otherwise
-        CPU — latency over throughput. Exists so VoteSet can take one
-        pluggable callable."""
+        CPU — latency over throughput: the receive routine waits for each
+        of these in turn, and a device round trip a vote made a
+        16-validator height last 9-30 s on the chip (PERF.md, PR 27).
+        Exists so VoteSet can take one pluggable callable."""
         primed = self.pop_primed((pubkey, msg, sig))
         if primed is not None:
             return primed
         with self._mtx:
+            # by design, not a fallback: single_sigs tells the two apart
+            # (cpu_sigs less single_sigs = batch lanes the host verified)
             self._stats["cpu_sigs"] += 1
+            self._stats["single_sigs"] += 1
         return verify_any(pubkey, msg, sig)
 
     def prime_cache(self, items: list[Item]) -> None:

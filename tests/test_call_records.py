@@ -554,8 +554,11 @@ def test_handlers_call_verify_batch_and_verify_batch_async_on_the_attribute():
     """The launcher wraps exactly these two entry points."""
     import inspect
 
-    src = inspect.getsource(devd._handle_conn)
-    assert "v.verify_batch(items)" in src
+    # a single-shot `verify` goes through the merger, which reads the
+    # state's `verifier` attribute anew for every program it runs
+    assert "st.merger.verify(items" in inspect.getsource(devd._handle_conn)
+    src = inspect.getsource(devd._VerifyMerger._run)
+    assert "self._st.verifier" in src and "v.verify_batch(items)" in src
     assert "v.verify_batch_async" in inspect.getsource(devd._handle_verify_stream)
 
 

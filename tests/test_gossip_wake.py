@@ -341,13 +341,16 @@ def test_a_peers_message_wakes_its_routines_only_if_it_can_add(net_factory, what
 
 def test_held_vote_goes_out_when_its_hold_ends(net_factory):
     """A vote we received moments ago is held by the lazy-relay screen.
-    The woken routine waits out the rest of the hold — not a back-stop
-    on top of it — and does not send before."""
+    Its event wakes no routine while it is held (PR 27: that was a
+    thread switch to find it held and one more at the hold's end, per
+    peer and vote); ONE deferred wake when the hold ends does — not a
+    back-stop on top of it — and nothing is sent before."""
     net = net_factory()
     peer = net.peers[0]
     net.settle()
     hold = net.r._relay_delay()
     assert 0.0 < hold < SOON < BACKSTOP
+    before = net.waits()
 
     vote = net.cs.rs.votes.pre.add(1)
     net.cs.vote_recv_mono[(HEIGHT, 0, VOTE_TYPE_PREVOTE, 1)] = t0 = time.monotonic()
@@ -357,7 +360,9 @@ def test_held_vote_goes_out_when_its_hold_ends(net_factory):
     t_sent = peer.of(msgs.VoteMessage)[0][0]
     assert t_sent - t0 >= hold, "sent inside its hold"
     assert t_sent - t0 < hold + SOON, "waited a back-stop on top of the hold"
-    assert net.r.gossip_wakes_hold >= 1
+    net.settle()
+    # the votes routine alone: one wake at the hold's end, one send
+    assert net.waits() - before == 2 and net.r.gossip_sends == 1
     assert net.r.gossip_backstop_sends == 0
 
 
@@ -390,12 +395,12 @@ def test_event_between_the_look_and_the_wait_is_not_lost(net_factory):
     votes_wake = net.ps(peer).gossip.votes
     fired = threading.Event()
 
-    def wait_after_a_late_event(wake, hold_s=None):
+    def wait_after_a_late_event(wake, hold_s=None, idle=0):
         if wake is votes_wake and not fired.is_set():
             fired.set()
             vote = net.cs.rs.votes.pre.add(0)
             net.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(vote))
-        return real_wait(wake, hold_s)
+        return real_wait(wake, hold_s, idle)
 
     net.r._gossip_wait = wait_after_a_late_event
     # one more (empty) pass of the vote routine, ending in the hooked wait
@@ -404,24 +409,33 @@ def test_event_between_the_look_and_the_wait_is_not_lost(net_factory):
     assert peer.wait_for(msgs.VoteMessage), "the wake-up was lost"
 
 
-def test_idle_routine_makes_about_ten_passes_a_second():
+def test_idle_routine_backs_its_back_stop_off_and_an_event_resets_it():
     """No spin, at the real back-stop: a routine with nothing to send,
-    woken by nothing, looks ten times a second, as it always did."""
+    woken by nothing, looks after 0.1, 0.2, 0.4 and then every 0.8 s
+    (PR 27: at ten looks a second each, a committee node's 62 routines
+    were 620 thread switches a second that found nothing). An event puts
+    it back to 0.1 s."""
+    sleep = reactor_mod.PEER_GOSSIP_SLEEP
+    top = reactor_mod.GOSSIP_BACKSTOP_MAX_SLEEPS
+    assert top == 8 and sleep == 0.1
     net = _Net()
     try:
-        time.sleep(0.3)  # the passes of its start
+        time.sleep(2.0)  # 0.1 + 0.2 + 0.4 + 0.8: at the longest wait now
         before = net.r.gossip_wakes_backstop, net.r.gossip_wakes_event
-        t0 = time.monotonic()
-        time.sleep(1.0)
-        elapsed = time.monotonic() - t0
+        time.sleep(2.0)
         backstops = net.r.gossip_wakes_backstop - before[0]
         events = net.r.gossip_wakes_event - before[1]
+        assert events == 0
+        # two routines of one peer, a look every 0.8 s each
+        assert 2 <= backstops <= 2 * 3 + 1, backstops
+        net.r.wake_gossip()          # found nothing either: back to 0.1 s
+        time.sleep(0.05)
+        before = net.r.gossip_wakes_backstop
+        time.sleep(0.65)             # 0.1 + 0.2 end inside, 0.4 may
+        fast = net.r.gossip_wakes_backstop - before
+        assert 4 <= fast <= 6, fast
     finally:
         net.close()
-    assert events == 0
-    # two routines of one peer, a pass every PEER_GOSSIP_SLEEP each (a
-    # busy box makes fewer, never more)
-    assert 6 <= backstops <= 2 * (elapsed / reactor_mod.PEER_GOSSIP_SLEEP + 2), backstops
     assert net.r.gossip_sends == 0 and net.r.gossip_backstop_sends == 0
 
 
@@ -480,7 +494,7 @@ def test_a_burst_of_vote_events_is_a_handful_of_wakes(net_factory):
     fire_s = time.monotonic() - t0
     net.settle()
     wakes = net.r.gossip_wakes_event - before
-    assert 2 <= wakes <= 2 * 20, wakes  # two routines; nowhere near 400
+    assert 1 <= wakes <= 20, wakes  # the votes routine; nowhere near 200
     assert fire_s < SOON
     assert not peer.of(msgs.VoteMessage)
 

@@ -408,6 +408,8 @@ class TestTraceRecorder:
         }
         assert tr.total_s == pytest.approx(1.5)
         assert tr.wall_s == 1.5
+        # beside the notes, the process's CPU seconds over the height
+        assert tr.aux.pop("cpu_s") >= 0.0
         assert tr.aux == {"part_hash_s": 0.2}
         assert rec.last(1)[0] is tr
 
