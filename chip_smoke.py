@@ -42,7 +42,7 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 OUT = os.path.join(ROOT, "chiprun_out", "chip_smoke")
-CHAIN_ID = "chip-smoke"
+CHAIN_ID = "chipsmoke"
 
 # bounds, in seconds; the whole run must fit 1200 with compilation
 WATCHDOG_S = 1150
@@ -109,7 +109,7 @@ def sock_dir() -> str:
     back to the temporary directory the caller gave this process."""
     if len(os.path.join(OUT, "devd-0.sock")) < 100:
         return OUT
-    return tempfile.mkdtemp(prefix="chip-smoke-")
+    return tempfile.mkdtemp(prefix="chipsmoke-")
 
 
 def daemon_env(sock: str, chip: int | None) -> dict:
@@ -285,7 +285,7 @@ def make_committee(n: int, seed: int):
 
     privs = [
         PrivValidatorFS(
-            gen_priv_key_ed25519(b"chip-smoke-%d-val-%d" % (seed, i)), None
+            gen_priv_key_ed25519(b"chipsmoke-%d-val-%d" % (seed, i)), None
         )
         for i in range(n)
     ]

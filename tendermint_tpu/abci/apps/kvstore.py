@@ -69,7 +69,7 @@ def tx_priority_hint(tx: bytes) -> int:
 # deterministic merge (sorted key order) mutates state + tree — the
 # canonical-treap shape is a pure function of the final key set, so the
 # commit root is byte-identical to the serial per-tx apply (asserted in
-# tests/test_pipeline.py and benches/bench_pipeline.py). Default 0 =
+# tests/test_pipeline.py). Default 0 =
 # the serial loop.
 SHARDS_DEFAULT = int(env_number("TENDERMINT_KVSTORE_SHARDS", 0, cast=int))
 SHARD_MIN_TXS = max(2, int(env_number("TENDERMINT_KVSTORE_SHARD_MIN", 32,
@@ -87,7 +87,7 @@ class KVStoreApp(Application):
         # batches onto the device plane.
         self.tree = VersionedTree()
         # round 14: sharded parallel apply shape (see module docstring);
-        # assignable per instance for benches/tests
+        # assignable per instance for tests
         self.shards = SHARDS_DEFAULT
         self.shard_min_txs = SHARD_MIN_TXS
         self.sharded_batches = 0  # deliver_txs batches that took the

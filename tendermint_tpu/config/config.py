@@ -139,8 +139,8 @@ class ConsensusConfig:
     # non-ENDHEIGHT records are fsynced at most this many seconds after
     # they buffer; #ENDHEIGHT markers always fsync synchronously
     wal_flush_interval_s: float = 0.1
-    # True restores the pre-round-9 fsync-per-record bound (10-40x slower
-    # commit hot path; benches/bench_wal.py measures the gap)
+    # True restores the pre-round-9 fsync-per-record bound (one fsync a
+    # record on the commit hot path instead of one a group)
     wal_sync_every_write: bool = False
 
     timeout_propose: float = 3.0
@@ -161,7 +161,7 @@ class ConsensusConfig:
     # pipelined execution plane (round 14, docs/execution-pipeline.md):
     # defer apply(H) + snapshot hook + events to the ordered executor
     # while consensus advances to H+1; False restores the fully serial
-    # finalize_commit (benches/bench_pipeline.py measures the gap)
+    # finalize_commit (tests/test_pipeline.py holds the two byte-identical)
     pipeline_apply: bool = True
 
     peer_gossip_sleep_duration: float = 0.100
@@ -174,8 +174,8 @@ class ConsensusConfig:
     # parts we already hold, and hold RE-pushes of a just-received vote
     # for one gossip tick so those announcements win the relay race
     # (reactor.VOTE_RELAY_DELAY). False restores the pre-round-20
-    # gossip (benches/bench_localnet.py measures the duplicate-ratio
-    # gap — ~30% fewer duplicate votes at n=10 real processes).
+    # gossip (tests/test_gossip_dedup.py holds that announcements cut
+    # the redundant sends).
     gossip_dedup: bool = True
 
     def wal_file(self) -> str:

@@ -158,8 +158,8 @@ class ConsensusState(BaseService):
         # (per-peer attribution rides p2p_peer_vote_duplicates_total)
         self.vote_duplicates = 0
         # gossiped votes genuinely ADDED (round 20): the denominator of
-        # the duplicate-vote ratio duplicates/accepted that BENCH_r20
-        # reads off scrapes — own re-delivered votes stay uncounted like
+        # the duplicate-vote ratio duplicates/accepted, readable off
+        # scrapes — own re-delivered votes stay uncounted like
         # the duplicate side
         self.vote_accepted = 0
         # when each gossiped vote was ACCEPTED, by coordinates (round

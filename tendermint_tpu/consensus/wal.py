@@ -26,7 +26,7 @@ Durability contract (group commit):
   durable before the block applies, so recovery can never lose a height
   past its last synced ``#ENDHEIGHT``.
 - `sync_every_write=True` restores fsync-per-record (the legacy-strength
-  bound; ~10-40x slower on real disks, benches/bench_wal.py).
+  bound: one fsync a record instead of one a group).
 
 Repair on open: scan every chunk forward; at the first record whose
 magic/length/CRC fails, back the damaged tail (and any later chunks) up

@@ -6,8 +6,8 @@ Why both:
   against (BASELINE.md north star: >=10x VerifyCommit throughput vs a
   sequential CPU verify loop, the reference's types/validator_set.go:247-250).
 - The pure-Python path provides the exact group/field math used to derive
-  test vectors and the precomputed tables for the JAX kernel
-  (tendermint_tpu/ops/ed25519.py), and serves as the fallback when neither
+  test vectors and the precomputed tables for the JAX kernels
+  (tendermint_tpu/ops/ed25519_*.py), and serves as the fallback when neither
   OpenSSL nor a TPU is present.
 
 All integers little-endian per RFC 8032.

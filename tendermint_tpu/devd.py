@@ -791,7 +791,7 @@ def _claim(st: _DaemonState, *, accept_cpu: bool,
     )
     # kernel choice: explicit TENDERMINT_DEVD_KERNEL wins; on TPU
     # hardware, bake off the comb kernel against the f32p ladder at claim
-    # time and serve the measured winner (pinning the direct kernel also
+    # time and serve the measured winner (naming the direct kernel also
     # keeps the gateway default from routing the daemon's own verifier
     # back through devd)
     env_k = os.environ.get("TENDERMINT_DEVD_KERNEL", "")
@@ -809,9 +809,9 @@ def _claim(st: _DaemonState, *, accept_cpu: bool,
     verifier = None
     best: tuple[float, str] | None = None
     for kname in candidates:
-        os.environ["TENDERMINT_TPU_KERNEL"] = kname
         # the owner of the chip never answers from the host
-        v = gateway.Verifier(min_tpu_batch=1, use_tpu=True, host_fallback=False)
+        v = gateway.Verifier(min_tpu_batch=1, use_tpu=True,
+                             host_fallback=False, kernel=kname)
         if not warm_shapes:
             # warming disabled (TENDERMINT_DEVD_WARM=""): serve the
             # first candidate unwarmed
@@ -875,7 +875,6 @@ def _claim(st: _DaemonState, *, accept_cpu: bool,
         if best is None or rate > best[0]:
             best = (rate, kname)
             verifier = v
-    os.environ["TENDERMINT_TPU_KERNEL"] = best[1]
     logger.info("serving kernel: %s", best[1])
     chunk_rates: dict = {}
     if not os.environ.get("TENDERMINT_DEVD_CHUNK") and warm_shapes:
@@ -928,7 +927,7 @@ def _device_loop(st: _DaemonState, *, accept_cpu: bool,
         # accept_cpu enforcement lives in serve() — a SystemExit raised
         # here, inside a daemon thread, would be swallowed silently
         # pure-python daemon: no jax, no device, instant startup — exists
-        # for transport benches/tests that need device time held constant
+        # for transport tests that need device time held constant
         with st.lock:
             st.platform = "cpu"
             st.verifier = _SimVerifier(sim_rate)
@@ -1320,8 +1319,8 @@ def _handle_conn(conn: socket.socket, st: _DaemonState) -> None:
                     # (ops/ed25519.dsm_batch; docs/upgrade.md): terms are
                     # (a, (px,py), b, (qx,qy)) python-int tuples, the
                     # reply the per-lane affine points. Rides the held
-                    # device via the int32 kernel module directly — the
-                    # only kernel with the dsm ladder.
+                    # device via the int32 field module directly — the
+                    # only one with the dsm ladder.
                     if st.verifier is None:
                         _send_frame(conn, {
                             "ok": False,

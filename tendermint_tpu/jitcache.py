@@ -1,7 +1,7 @@
 """Shared JAX persistent-compile-cache setup.
 
 Every entry point that compiles — the device daemon, the CLI's
-direct-kernel path, bench.py, __graft_entry__.py, tests/conftest.py —
+direct-kernel path, __graft_entry__.py, tests/conftest.py —
 calls enable() before its first jit. The ed25519 ladder takes ~45s to
 compile on the CPU backend and the Pallas ladder minutes for the chip;
 caching them is the difference between a 10-minute and a 10-second

@@ -32,9 +32,8 @@ error or an HTTP 500 scrape (which monitoring alerts on), never as a
 silently missing plane behind a 200 (the PR-4 loud-wiring convention).
 
 ``set_enabled(False)`` (or TENDERMINT_TELEMETRY_DISABLE=1) turns every
-hot-path ``inc``/``observe`` into a no-op — the lever the overhead guard
-in benches/bench_telemetry.py uses to prove instrumentation costs <2%
-on the mempool signed-burst gate.
+hot-path ``inc``/``observe`` into a no-op (tests/test_telemetry.py holds
+that the disabled path records nothing).
 """
 
 from __future__ import annotations

@@ -36,9 +36,8 @@ Sampling (env knobs, libs/envknob semantics):
     TENDERMINT_TXTRACE_RING        (256) completed-trace ring
     TENDERMINT_TXTRACE_DISABLE     (0)   kill switch
 
-Hot-path cost discipline (the <2% bound benches/bench_txtrace.py
-asserts on the signed-burst shape — the harshest denominator in the
-repo, ~16 us/tx through the batched gate): an untraced tx pays ONE
+Hot-path cost discipline (the signed-burst shape through the batched
+gate is the harshest denominator in the repo): an untraced tx pays ONE
 inline countdown at ingress (``rec._tick -= 1`` at the check_tx call
 site — no method call; both sampling arms are folded into the one
 counter, re-armed by the slow path), and the sig-gate/admit stamps run
@@ -55,8 +54,8 @@ plus the end-to-end ``tx_commit_latency_seconds`` (rpc_ingress ->
 block_commit) and ``tx_visible_latency_seconds`` (rpc_ingress ->
 event_delivery) histograms, observed once per sealed trace. The spans
 TELESCOPE: for any sealed trace the stamped spans through block_commit
-sum EXACTLY to its commit latency (the bench asserts within 10% to
-guard the stamping sites, not the arithmetic).
+sum EXACTLY to its commit latency (tests/test_txtrace.py and
+tests/test_node_rpc.py hold it, guarding the stamping sites).
 
 Served by the ``tx_trace`` RPC (completed ring + in-flight actives —
 a partition-parked tx is visible mid-flight, which is exactly what the

@@ -111,8 +111,7 @@ class SigBatcher:
         # round 11: per-batch gate latency distribution (dispatch ->
         # verdicts delivered) — scrape-only; the flat mempool_sig_gate_*
         # gauges stay the legacy metrics-RPC surface. One observe per
-        # BATCH, so the burst hot path pays nothing per tx (the <2%
-        # overhead floor benches/bench_telemetry.py asserts).
+        # BATCH, so the burst hot path pays nothing per tx.
         from tendermint_tpu.libs import telemetry
 
         self._batch_hist = telemetry.default_registry().histogram(

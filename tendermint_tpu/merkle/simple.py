@@ -12,11 +12,9 @@ shape-cached level-order schedule over a preallocated node array
 (`FlatTree`), with proofs as (tree, leaf-index) views into the shared
 node buffer (`SharedProof`) instead of per-leaf copied aunt lists. The
 pre-r7 recursive builder survives as `recursive_proofs_from_hashes`, the
-parity oracle the flat path is tested (and benched) against: measured at
-the 1 MB / 64 KB part-set shape (16 leaves) the flat build is ~6.7x the
-recursive one (15.8 vs 106.5 us — the recursion's list-slice copies,
-per-leaf aunt appends, and per-node encode_bytes churn were ~85% of the
-build; the 15 compressions are ~17 us either way).
+parity oracle the flat path is tested against (the recursion's
+list-slice copies, per-leaf aunt appends, and per-node encode_bytes churn
+are what the flat build avoids; the compressions are the same).
 
 The vectorized TPU variant (tendermint_tpu/ops/merkle.py) must reproduce
 these digests byte-for-byte; tests cross-check the two. Its node buffer
@@ -343,8 +341,7 @@ def recursive_proofs_from_hashes(
     hashes: list[bytes],
 ) -> tuple[bytes, list[SimpleProof]]:
     """The pre-r7 recursive builder, kept verbatim as the parity oracle
-    for the flat path (tests/test_merkle_flat.py) and the baseline of the
-    host-builder bench row (benches/bench_partset.py)."""
+    for the flat path (tests/test_merkle_flat.py)."""
     n = len(hashes)
     proofs = [SimpleProof() for _ in range(n)]
     root = _recursive_build(hashes, list(range(n)), proofs)

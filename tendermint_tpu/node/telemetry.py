@@ -143,8 +143,8 @@ def build_registry(node) -> telemetry.Registry:
             # (per-peer attribution: p2p_peer_vote_duplicates_total)
             "vote_duplicates": cs.vote_duplicates,
             # round 20: gossiped votes genuinely added — the ratio
-            # vote_duplicates/vote_accepted is the duplicate-vote ratio
-            # BENCH_r20 reads off scrapes — plus the dedup plane's own
+            # vote_duplicates/vote_accepted is the duplicate-vote ratio,
+            # readable off scrapes — plus the dedup plane's own
             # accounting: HasVotes that landed in a peer mirror, and
             # HasBlockPart announcements sent/applied
             "vote_accepted": cs.vote_accepted,

@@ -1,17 +1,13 @@
-"""Batched Ed25519 signature verification for TPU (pure jnp, int32 lanes).
+"""GF(2^255-19) field and Edwards-curve arithmetic on int32 lanes, and the
+batched dual scalar multiplication built on it (pure jnp).
 
-STATUS: tested math-reference implementation and selectable backend.
-The production default is ops/ed25519_f32.py (94.4k sigs/s vs this
-kernel's 50.0k at batch 8192 on a v5e — see ops/gateway.py KERNELS);
-select this one with TENDERMINT_TPU_KERNEL=int32. It stays in-tree as
-the independently-derived oracle the rigorous RFC 8032 / malformed-input
-tests cross-check (tests/test_ops.py), and its limb codecs
-(int_to_limbs_np, scalar_bits_np) are shared by the pallas kernel.
-
-This kernel replaces the reference's sequential per-vote/per-commit Ed25519
-verify loops (types/vote_set.go:175, types/validator_set.go:247-250) with a
-wide SIMD batch: every lane verifies one signature, all lanes share the
-instruction stream.
+What this module serves: `dsm_batch`, the per-lane [a]P + [b]Q over
+VARIABLE points that the aggregate-commit verify needs
+(crypto/ed25519_agg.py, docs/upgrade.md; called by ops/gateway.py
+Verifier.verify_aggregate in process and by the device daemon's `agg`
+op). Signature verification is not here: the verify kernels are
+ops/ed25519_comb.py, ops/ed25519_f32p.py and ops/ed25519_f32.py
+(ops/gateway.py KERNELS).
 
 Design notes (TPU-first, not a port of any CPU bignum library):
 
@@ -20,31 +16,18 @@ Design notes (TPU-first, not a port of any CPU bignum library):
 - LIMB-MAJOR layout: a batch of field elements is int32[17, B] — the batch
   axis is the TPU's 128-wide lane dimension, the limb axis is the
   instruction stream. Every limb operation is a full-width vector op; with
-  the batch axis minor there are no strided column accesses and no wasted
-  lanes. (The batch-minor variant of this kernel measured ~25x slower.)
+  the batch axis minor there are strided column accesses and wasted lanes.
 - 15-bit limbs keep every partial product under 2^30; products are split
   hi/lo at bit 15 BEFORE accumulation so row sums stay under 2^21 — the
   whole multiply needs no 64-bit type (TPU has no native wide int).
-  Anti-diagonal accumulation uses shift-and-add via jnp.pad, not scatter.
-- Verification checks the strict (cofactorless) RFC 8032 equation
-  [s]B == R + [h]A, rearranged as P := [s]B + [h](-A), then point-compresses
-  P and compares against the signature's R half. One field inversion
-  (addition chain), no on-TPU decompression of R; pubkey decompression is
-  cached per validator on host (validator sets are stable across blocks).
-- Double-scalar multiplication is interleaved Straus over 253 bit
-  positions under lax.scan: per bit one complete-Edwards doubling and one
-  select-add from {identity, B, -A, B-A}. Complete formulas (RFC 8032
-  section 5.1.4) mean no data-dependent branches.
-- The outer SHA-512 hash h = H(R || A || M) mod L stays on HOST: hashing is
-  C-speed and cheap; the TPU gets only fixed-shape scalar bit arrays.
-
-Batch semantics match crypto/ed25519.verify exactly (tests cross-check
-RFC 8032 vectors, random sign/verify, and malformed-input rejection).
+  Anti-diagonal accumulation uses shift-and-add, not scatter.
+- The scalar multiplication is interleaved Straus with 2-bit joint
+  windows under lax.scan: per step two complete-Edwards doublings and one
+  add from a 16-entry table. Complete formulas (RFC 8032 section 5.1.4)
+  mean no data-dependent branches.
 """
 
 from __future__ import annotations
-
-import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +36,6 @@ import numpy as np
 from tendermint_tpu.crypto import ed25519 as ed_ref
 
 P = ed_ref.P
-L = ed_ref.L
 M15 = 0x7FFF
 NLIMB = 17
 
@@ -78,15 +60,6 @@ def limbs_to_int(limbs: np.ndarray) -> int:
     return sum(int(limbs[k]) << (15 * k) for k in range(NLIMB))
 
 
-def scalar_bits_np(vals: list[int], nbits: int = 253) -> np.ndarray:
-    """ints -> int32[nbits, B] little-endian bit-major bits."""
-    b = np.zeros((len(vals), 32), dtype=np.uint8)
-    for i, v in enumerate(vals):
-        b[i] = np.frombuffer(v.to_bytes(32, "little"), dtype=np.uint8)
-    bits = np.unpackbits(b, axis=1, bitorder="little")
-    return np.ascontiguousarray(bits[:, :nbits].astype(np.int32).T)
-
-
 def _const_limbs(v: int) -> np.ndarray:
     return int_to_limbs_np([v])[:, 0]  # (17,)
 
@@ -94,25 +67,7 @@ def _const_limbs(v: int) -> np.ndarray:
 _D2 = _const_limbs((2 * ed_ref.D) % P)
 _P_LIMBS = np.array([32749] + [32767] * 16, dtype=np.int32)
 _PX2 = (2 * _P_LIMBS).astype(np.int32)
-_BX = _const_limbs(ed_ref.B[0])
-_BY = _const_limbs(ed_ref.B[1])
-_BT = _const_limbs((ed_ref.B[0] * ed_ref.B[1]) % P)
-_SQRT_M1 = _const_limbs(ed_ref.I_SQRT)
-_D_LIMBS = _const_limbs(ed_ref.D)
 
-
-def _affine(pt) -> tuple[int, int]:
-    zinv = pow(pt[2], P - 2, P)
-    return (pt[0] * zinv % P, pt[1] * zinv % P)
-
-
-# 2B and 3B affine constants for the 2-bit windowed ladder
-_B2_AFF = _affine(ed_ref.point_add(ed_ref.B, ed_ref.B))
-_B3_AFF = _affine(
-    ed_ref.point_add(ed_ref.point_add(ed_ref.B, ed_ref.B), ed_ref.B)
-)
-_B2X, _B2Y = _const_limbs(_B2_AFF[0]), _const_limbs(_B2_AFF[1])
-_B3X, _B3Y = _const_limbs(_B3_AFF[0]), _const_limbs(_B3_AFF[1])
 
 # ---------------------------------------------------------------------------
 # field arithmetic on (17, B) int32 arrays
@@ -217,11 +172,6 @@ def fcanon(x: jax.Array) -> jax.Array:
     return x
 
 
-def feq(a: jax.Array, b: jax.Array) -> jax.Array:
-    """Canonical equality -> bool[B]."""
-    return jnp.all(fcanon(a) == fcanon(b), axis=0)
-
-
 # ---------------------------------------------------------------------------
 # point arithmetic (extended coordinates X, Y, Z, T), complete formulas
 # ---------------------------------------------------------------------------
@@ -261,199 +211,20 @@ def _identity(batch: int):
     return (zeros, one, one, zeros)
 
 
-def _select4(sel: jax.Array, options):
-    """sel: int32[B] in 0..3; options: 4 points of (17,B) coords."""
-    out = []
-    for coord in range(4):
-        stacked = jnp.stack([opt[coord] for opt in options], axis=0)  # (4,17,B)
-        picked = jnp.take_along_axis(stacked, sel[None, None, :], axis=0)
-        out.append(picked[0])
-    return tuple(out)
-
-
 # ---------------------------------------------------------------------------
-# the verify kernel
+# scalar digits and batch padding
 # ---------------------------------------------------------------------------
 
 
 def _digits2_from_limbs(limbs: jax.Array) -> jax.Array:
     """(17,B) 15-bit limbs -> (127,B) 2-bit digits, MSB-first. Scalars are
     < L < 2^253, so bits 253/254 are zero. Unpacking on-device keeps the
-    host->device transfer at 17 words/scalar instead of 253 bit-ints —
-    transfer volume was the sustained-throughput bottleneck."""
+    host->device transfer at 17 words/scalar instead of 253 bit-ints."""
     shifts = jnp.arange(15, dtype=jnp.int32)
     bits = (limbs[:, None, :] >> shifts[None, :, None]) & 1  # (17,15,B)
     bits = bits.reshape(NLIMB * 15, limbs.shape[-1])[:254]  # little-endian
     d = bits[0::2] + 2 * bits[1::2]  # (127,B)
     return d[::-1]
-
-
-def _verify_impl(ax, ay, r_y, r_sign, s_limbs, h_limbs):
-    """ax/ay: affine pubkey limbs (17,B); r_y: R's y limbs (canonical,
-    host-validated < p); r_sign: (B,) x-parity of R; s_limbs/h_limbs:
-    (17,B) 15-bit limb encodings of the scalars. Returns bool[B].
-
-    Interleaved Straus with 2-bit joint windows: 127 iterations of
-    (2 doublings + 1 table add) instead of 253 x (1 doubling + 1 add) —
-    same 253 doublings, half the point additions. The 16-entry table
-    [i]B + [j](-A), i,j in 0..3, costs ~11 one-time point ops (B-side
-    multiples are host constants)."""
-    batch = ax.shape[-1]
-    zeros = jnp.zeros((NLIMB, batch), dtype=jnp.int32)
-    one = zeros.at[0].set(1)
-
-    def const_pt(xc, yc):
-        x = jnp.broadcast_to(jnp.asarray(xc)[:, None], (NLIMB, batch))
-        y = jnp.broadcast_to(jnp.asarray(yc)[:, None], (NLIMB, batch))
-        return (x, y, one, fmul(x, y))
-
-    # -A = (p - x, y) and its small multiples
-    nax = fsub(zeros, ax)
-    neg_a = (nax, ay, one, fmul(nax, ay))
-    na2 = point_double(neg_a)
-    na3 = point_add(na2, neg_a)
-    ident = _identity(batch)
-    b_row = [ident, const_pt(_BX, _BY), const_pt(_B2X, _B2Y), const_pt(_B3X, _B3Y)]
-    a_row = [ident, neg_a, na2, na3]
-    table = []
-    for j in range(4):  # h digit (multiples of -A)
-        for i in range(4):  # s digit (multiples of B)
-            if i == 0:
-                table.append(a_row[j])
-            elif j == 0:
-                table.append(b_row[i])
-            else:
-                table.append(point_add(b_row[i], a_row[j]))
-    tcoords = [
-        jnp.stack([t[c] for t in table], axis=0) for c in range(4)
-    ]  # 4 x (16,17,B)
-
-    xs = jnp.stack(
-        [_digits2_from_limbs(s_limbs), _digits2_from_limbs(h_limbs)], axis=1
-    )  # (127,2,B)
-    idx16 = jnp.arange(16, dtype=jnp.int32)
-
-    def step(acc, dig):
-        acc = point_double(point_double(acc))
-        sel = dig[0] + 4 * dig[1]  # (B,)
-        onehot = (sel[None, :] == idx16[:, None]).astype(jnp.int32)  # (16,B)
-        addend = tuple(
-            jnp.sum(onehot[:, None, :] * tc, axis=0) for tc in tcoords
-        )
-        return point_add(acc, addend), None
-
-    acc, _ = jax.lax.scan(step, ident, xs)
-
-    # compress P and compare with R
-    px, py, pz, _ = acc
-    zinv = finv(pz)
-    x_aff = fcanon(fmul(px, zinv))
-    y_aff = fcanon(fmul(py, zinv))
-    sign = x_aff[0] & 1
-    return jnp.all(y_aff == fcanon(r_y), axis=0) & (sign == r_sign)
-
-
-_verify_jit = jax.jit(_verify_impl)
-
-
-# ---------------------------------------------------------------------------
-# pubkey decompression kernel (for cache misses / arbitrary key batches)
-# ---------------------------------------------------------------------------
-
-
-def _pow_2_252_m3(z: jax.Array) -> jax.Array:
-    z2 = fsq(z)
-    z9 = fmul(_rep_sq(z2, 2), z)
-    z11 = fmul(z9, z2)
-    z_5_0 = fmul(fsq(z11), z9)
-    z_10_0 = fmul(_rep_sq(z_5_0, 5), z_5_0)
-    z_20_0 = fmul(_rep_sq(z_10_0, 10), z_10_0)
-    z_40_0 = fmul(_rep_sq(z_20_0, 20), z_20_0)
-    z_50_0 = fmul(_rep_sq(z_40_0, 10), z_10_0)
-    z_100_0 = fmul(_rep_sq(z_50_0, 50), z_50_0)
-    z_200_0 = fmul(_rep_sq(z_100_0, 100), z_100_0)
-    z_250_0 = fmul(_rep_sq(z_200_0, 50), z_50_0)
-    return fmul(_rep_sq(z_250_0, 2), z)  # 2^252 - 3
-
-
-def _decompress_impl(y_limbs, x_sign):
-    """RFC 8032 5.1.3 point decompression, batched.
-    Returns (x_limbs (17,B), valid bool[B])."""
-    batch = y_limbs.shape[-1]
-    zeros = jnp.zeros((NLIMB, batch), dtype=jnp.int32)
-    one = zeros.at[0].set(1)
-    # constants must be batch-width: fmul sizes its accumulator from its
-    # FIRST argument's batch axis
-    d_l = jnp.broadcast_to(jnp.asarray(_D_LIMBS)[:, None], (NLIMB, batch))
-    sqrt_m1 = jnp.broadcast_to(jnp.asarray(_SQRT_M1)[:, None], (NLIMB, batch))
-    y2 = fsq(y_limbs)
-    u = fsub(y2, one)
-    v = fadd(fmul(d_l, y2), one)
-    v3 = fmul(fsq(v), v)
-    v7 = fmul(fsq(v3), v)
-    x = fmul(fmul(u, v3), _pow_2_252_m3(fmul(u, v7)))
-    vx2 = fmul(v, fsq(x))
-    ok_direct = feq(vx2, u)
-    neg_u = fsub(zeros, u)
-    ok_flip = feq(vx2, neg_u)
-    x = jnp.where(ok_flip[None, :], fmul(x, sqrt_m1), x)
-    x = fcanon(x)
-    valid = ok_direct | ok_flip
-    x_is_zero = jnp.all(x == 0, axis=0)
-    want_flip = x_sign != (x[0] & 1)
-    valid = valid & ~(x_is_zero & (x_sign == 1))
-    x = jnp.where(want_flip[None, :], fsub(zeros, x), x)
-    return fcanon(x), valid
-
-
-_decompress_jit = jax.jit(_decompress_impl)
-
-
-def decompress_batch(compressed: list[bytes]) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """32-byte encodings -> (x_limbs int32[17,B], y_limbs int32[17,B],
-    valid bool[B]). Rejects non-canonical y >= p on host."""
-    n = len(compressed)
-    bucket = _next_pow2(max(n, 1))  # pad: one compiled program per bucket
-    ys, signs, valid_host = [], [], []
-    for c in compressed:
-        yi = int.from_bytes(c, "little")
-        signs.append((yi >> 255) & 1)
-        yi &= (1 << 255) - 1
-        if yi >= P:
-            valid_host.append(False)
-            ys.append(0)
-        else:
-            valid_host.append(True)
-            ys.append(yi)
-    ys += [1] * (bucket - n)
-    signs += [0] * (bucket - n)
-    valid_host += [False] * (bucket - n)
-    y_limbs = int_to_limbs_np(ys)
-    x_limbs, valid_dev = _decompress_jit(
-        jnp.asarray(y_limbs), jnp.asarray(np.array(signs, dtype=np.int32))
-    )
-    valid = np.asarray(valid_dev) & np.array(valid_host)
-    return np.asarray(x_limbs)[:, :n], y_limbs[:, :n], valid[:n]
-
-
-# ---------------------------------------------------------------------------
-# host orchestration
-# ---------------------------------------------------------------------------
-
-_pubkey_cache: dict[bytes, tuple[int, int] | None] = {}
-
-
-def _decompress_pubkey_cached(pub: bytes) -> tuple[int, int] | None:
-    """Affine (x, y) ints for a compressed pubkey; None if invalid.
-    Cached: validator pubkeys repeat for every vote/commit."""
-    hit = _pubkey_cache.get(pub, False)
-    if hit is not False:
-        return hit
-    pt = ed_ref.point_decompress(pub)
-    res = None if pt is None else (pt[0], pt[1])
-    if len(_pubkey_cache) < 1_000_000:
-        _pubkey_cache[pub] = res
-    return res
 
 
 def _next_pow2(n: int) -> int:
@@ -463,79 +234,12 @@ def _next_pow2(n: int) -> int:
     return b
 
 
-def _prepare_ints(items: list[tuple[bytes, bytes, bytes]], bucket: int):
-    """Shared host validation/marshaling: returns python-int columns
-    (ax, ay, ry, r_sign, s, h, valid)."""
-    ax_i, ay_i, ry_i = [0] * bucket, [1] * bucket, [1] * bucket
-    rs = np.zeros(bucket, dtype=np.int32)
-    s_i, h_i = [0] * bucket, [0] * bucket
-    valid = np.zeros(bucket, dtype=bool)
-
-    for i, (pub, msg, sig) in enumerate(items):
-        if len(sig) != 64 or len(pub) != 32:
-            continue
-        aff = _decompress_pubkey_cached(bytes(pub))
-        if aff is None:
-            continue
-        r_bytes, s_bytes = sig[:32], sig[32:]
-        s = int.from_bytes(s_bytes, "little")
-        if s >= L:
-            continue
-        ry = int.from_bytes(r_bytes, "little")
-        r_sign = (ry >> 255) & 1
-        ry &= (1 << 255) - 1
-        if ry >= P:
-            continue
-        h = (
-            int.from_bytes(
-                hashlib.sha512(bytes(r_bytes) + bytes(pub) + bytes(msg)).digest(),
-                "little",
-            )
-            % L
-        )
-        ax_i[i], ay_i[i], ry_i[i] = aff[0], aff[1], ry
-        rs[i] = r_sign
-        s_i[i], h_i[i] = s, h
-        valid[i] = True
-    return ax_i, ay_i, ry_i, rs, s_i, h_i, valid
-
-
-def prepare_batch(items: list[tuple[bytes, bytes, bytes]], bucket: int):
-    """Bit-array form (used by the pallas variant): returns
-    (ax, ay, ry, r_sign, s_bits(253,B), h_bits(253,B), valid)."""
-    ax_i, ay_i, ry_i, rs, s_i, h_i, valid = _prepare_ints(items, bucket)
-    return (
-        int_to_limbs_np(ax_i),
-        int_to_limbs_np(ay_i),
-        int_to_limbs_np(ry_i),
-        rs,
-        scalar_bits_np(s_i),
-        scalar_bits_np(h_i),
-        valid,
-    )
-
-
-def prepare_batch_limbs(items: list[tuple[bytes, bytes, bytes]], bucket: int):
-    """Limb form (the jnp verify kernel): scalars travel as (17,B) 15-bit
-    limbs; the kernel unpacks digits on-device."""
-    ax_i, ay_i, ry_i, rs, s_i, h_i, valid = _prepare_ints(items, bucket)
-    return (
-        int_to_limbs_np(ax_i),
-        int_to_limbs_np(ay_i),
-        int_to_limbs_np(ry_i),
-        rs,
-        int_to_limbs_np(s_i),
-        int_to_limbs_np(h_i),
-        valid,
-    )
-
-
 # ---------------------------------------------------------------------------
 # batched dual scalar multiplication: per-lane [a]P + [b]Q for VARIABLE
 # points (the aggregate-commit verify's per-lane term [z_i]R_i +
-# [z_i*h_i]A_i — see crypto/ed25519_agg.py and docs/upgrade.md). Same
-# 2-bit interleaved Straus scan as _verify_impl, but the whole 16-entry
-# table is built from per-lane points instead of host constants.
+# [z_i*h_i]A_i — see crypto/ed25519_agg.py and docs/upgrade.md). A 2-bit
+# interleaved Straus scan whose whole 16-entry table is built from
+# per-lane points.
 # ---------------------------------------------------------------------------
 
 
@@ -598,8 +302,7 @@ def dsm_batch(
     reduced mod L, points affine on-curve (caller-validated — the
     aggregate path decompresses via crypto/ed25519.point_decompress).
     Returns per-lane affine [a]P + [b]Q as python ints. Padded to the
-    next power of two like verify_batch (one compiled program per
-    bucket)."""
+    next power of two (one compiled program per bucket)."""
     n = len(terms)
     if n == 0:
         return []
@@ -625,22 +328,3 @@ def dsm_batch(
     ]
 
 
-def verify_batch(items: list[tuple[bytes, bytes, bytes]]) -> np.ndarray:
-    """Batched strict-RFC8032 verify of (pubkey32, message, signature64)
-    triples -> bool[B]. Semantics identical to crypto.ed25519.verify per
-    item. Batch is padded to the next power of two so jit re-compilation is
-    bounded (one program per bucket)."""
-    n = len(items)
-    if n == 0:
-        return np.zeros(0, dtype=bool)
-    bucket = _next_pow2(n)
-    ax, ay, ry, rs, s_l, h_l, valid = prepare_batch_limbs(items, bucket)
-    ok = _verify_jit(
-        jnp.asarray(ax),
-        jnp.asarray(ay),
-        jnp.asarray(ry),
-        jnp.asarray(rs),
-        jnp.asarray(s_l),
-        jnp.asarray(h_l),
-    )
-    return np.asarray(ok)[:n] & valid[:n]

@@ -3,12 +3,11 @@ TPU kernel.
 
 Replaces the reference's sequential per-signature verify loops
 (types/vote_set.go:175, types/validator_set.go:247-250) with a wide SIMD
-batch, like ops/ed25519.py — but the field arithmetic runs in float32,
-where the TPU VPU fuses multiply+accumulate into FMAs. Measured on a
-v5e chip this kernel's fmul is ~2x the int32 radix-2^15 variant's
-(22.8us vs 43.9us per (B=8192) field multiply), because the schoolbook
-row sums become FMA chains instead of separate int multiply + mask +
-shift + add sequences.
+batch. The field arithmetic runs in float32, where the TPU VPU fuses
+multiply+accumulate into FMAs: the schoolbook row sums become FMA chains
+instead of the separate int multiply + mask + shift + add sequences of
+the int32 radix-2^15 field (ops/ed25519.py, which the aggregate-commit
+lanes still use).
 
 EXACTNESS ARGUMENT (all fp32 values are integers; fp32 is exact for
 integers < 2^24; every intermediate below stays under 2^23.5):
@@ -42,8 +41,8 @@ integers < 2^24; every intermediate below stays under 2^23.5):
   limb0 <= 255 + 38*66 = 2763; pass3 carries <= 13 -> the loose bound
   above. All carry intermediates < 2^21.7: exact.
 
-Verification math is identical to ops/ed25519.py (strict cofactorless
-RFC 8032: compress([s]B + [h](-A)) == R), and the host marshaling is
+Verification is strict cofactorless RFC 8032
+(compress([s]B + [h](-A)) == R), and the host marshaling is
 byte-level (radix-2^8 IS the byte string), which makes prepare cheaper
 than the radix-2^15 bit repacking.
 

@@ -1,6 +1,7 @@
 """Pallas TPU kernel: fp32 radix-2^8 Ed25519 verify, VMEM-resident ladder.
 
-STATUS: bake-off candidate, selectable with TENDERMINT_TPU_KERNEL=f32p.
+STATUS: the daemon's claim bakes it off against comb on a TPU; also
+selectable with TENDERMINT_TPU_KERNEL=f32p.
 
 Same field representation, bounds, and verification math as the XLA-composed
 production kernel (ops/ed25519_f32.py — read its EXACTNESS ARGUMENT first;
@@ -16,9 +17,7 @@ conv formulation:
   accumulation, not a gather through memory.
 
 Field elements are Python lists of 32 (S, 128) float32 rows (limb-major,
-fully unrolled limb arithmetic, batch in the lane dimensions) — the same
-row discipline as the int32 pallas kernel (ops/ed25519_pallas.py), in the
-arithmetic that won the round-2 bake-off.
+fully unrolled limb arithmetic, batch in the lane dimensions).
 
 Host marshaling is shared with ed25519_f32 (prepare_batch8); the 2-bit
 digit expansion runs on-device outside the kernel (f32._digits2) so the

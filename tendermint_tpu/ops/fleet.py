@@ -23,7 +23,7 @@ netchaos partition scenario asserts on: a partition is a quorum-time
 spike + a degraded /health + frozen per-peer gossip counters, all read
 from scrapes — never by reaching into harness objects.
 
-Importable pieces (used by tests/test_fleet.py and benches/bench_fleet.py):
+Importable pieces (used by tests/test_fleet.py and ops/localnet.py):
 ``fetch_metrics`` / ``fetch_health`` / ``fetch_traces`` / ``collect`` /
 ``build_timeline`` / ``metric_value`` / ``render``.
 """

@@ -67,26 +67,20 @@ def _cpu_verify_batch(items: list[Item]) -> list[bool]:
 
 # Every batch kernel exposes verify_batch(items) -> np.ndarray[bool] with
 # identical accept/reject semantics (cross-checked lane-for-lane by
-# tests/test_ops*.py). All stay selectable so a bake-off is reproducible
-# and any backend regression has an immediate alternative. Their rates on
-# the attached chip are not measured as a benchmark yet (PERF.md keeps
-# the bring-up observation); every one compiles for a v5e:
+# tests/test_ops*.py). These are what a daemon can serve; every one
+# compiles for a v5e (tests/test_chip_compile.py):
+#   comb   doubling-free verify from per-validator device-resident comb
+#          tables + a fixed-base comb (ops/ed25519_comb.py); first-sight
+#          lanes ride the f32 ladder inside the same call
 #   f32p   pallas fp32 radix-2^8, VMEM-resident ladder
-#   f32    fp32 radix-2^8 depthwise-conv field mults
-#   int32  int32 radix-2^15 jnp limb vectors (VPU)
-#   pallas int32 radix-2^15 single-pallas_call ladder
-# Round 5 adds "comb" (ops/ed25519_comb.py): doubling-free verify from
-# per-validator device-resident comb tables + a fixed-base MXU comb —
-# ~3x fewer VPU ops/lane than f32p once a key's table is built (keys
-# repeat every block in consensus); first-sight lanes ride the f32
-# ladder inside the same call. The device daemon bakes comb off against
-# f32p at claim time and serves the measured winner.
+#   f32    fp32 radix-2^8 depthwise-conv field mults; the one that also
+#          compiles natively on the CPU backend
+# The device daemon bakes comb off against f32p at claim time and serves
+# the measured winner (PERF.md section 7: not settled at 1000 keys).
 KERNELS = {
     "comb": "tendermint_tpu.ops.ed25519_comb",
     "f32p": "tendermint_tpu.ops.ed25519_f32p",
     "f32": "tendermint_tpu.ops.ed25519_f32",
-    "int32": "tendermint_tpu.ops.ed25519",
-    "pallas": "tendermint_tpu.ops.ed25519_pallas",
     # not a kernel: socket IPC to the device daemon (devd.py), which runs
     # its claim-time bake-off winner (comb vs f32p on TPU; f32 on CPU) on
     # the device it holds. The automatic default whenever a daemon is
@@ -207,20 +201,6 @@ def on_tpu() -> bool:
     return resolve_platform() == "tpu"
 
 
-def platform_label() -> str:
-    """Platform name for bench output, as this process was TOLD it —
-    never a dial: an explicit TENDERMINT_TPU_DISABLE, a serving daemon's
-    ping, else resolve_platform()."""
-    if os.environ.get("TENDERMINT_TPU_DISABLE", "") == "1":
-        return "cpu (TENDERMINT_TPU_DISABLE)"
-    from tendermint_tpu import devd
-
-    rep = devd.available()
-    if rep is not None:
-        return f"{rep.get('platform')} (via devd)"
-    return resolve_platform() or "none (told of no accelerator; host path)"
-
-
 def pallas_interpret() -> bool:
     """interpret= for a Pallas kernel, decided from the backend of the
     process that is about to RUN it (it has initialised JAX by then, so
@@ -240,9 +220,10 @@ def pallas_interpret() -> bool:
     return interpret
 
 
-def kernel_name() -> str:
-    """Validated TENDERMINT_TPU_KERNEL. Raises on unknown names;
-    Verifier.__init__ calls this so a typo'd env var fails at startup
+def kernel_name(name: str | None = None) -> str:
+    """The validated kernel choice: `name` when the caller made one (the
+    daemon's claim does), else TENDERMINT_TPU_KERNEL. Raises on unknown
+    names; Verifier.__init__ calls this so a typo fails at startup
     rather than silently latching the CPU fallback.
 
     Default is environment-aware, in priority order:
@@ -258,7 +239,7 @@ def kernel_name() -> str:
        natively everywhere.
     Resolving the platform may ping a daemon, so the default branch is
     evaluated lazily here, not at import."""
-    name = os.environ.get("TENDERMINT_TPU_KERNEL", "")
+    name = name or os.environ.get("TENDERMINT_TPU_KERNEL", "")
     if not name:
         from tendermint_tpu import devd
 
@@ -274,7 +255,8 @@ def kernel_name() -> str:
         return "comb" if platform == "tpu" else "f32"
     if name not in KERNELS:
         raise ValueError(
-            f"TENDERMINT_TPU_KERNEL={name!r}: expected one of {sorted(KERNELS)}"
+            f"verify kernel {name!r} (TENDERMINT_TPU_KERNEL): "
+            f"expected one of {sorted(KERNELS)}"
         )
     return name
 
@@ -702,7 +684,11 @@ class Verifier:
     """Batch signature verifier with TPU acceleration and CPU fallback."""
 
     def __init__(self, min_tpu_batch: int | None = None,
-                 use_tpu: bool | None = None, host_fallback: bool = True):
+                 use_tpu: bool | None = None, host_fallback: bool = True,
+                 kernel: str | None = None):
+        # kernel: a name of KERNELS the caller chose (the daemon's claim
+        # passes each candidate); None reads TENDERMINT_TPU_KERNEL, then
+        # the default of kernel_name().
         # host_fallback=False is the device daemon's own verifier: the
         # owner of the chip never answers an Ed25519 lane from the host.
         # A kernel that raises there propagates (an error frame to the
@@ -716,6 +702,7 @@ class Verifier:
             min_tpu_batch = int(
                 _env_number("TENDERMINT_TPU_MIN_BATCH", 32, cast=int)
             )
+        chosen = kernel or os.environ.get("TENDERMINT_TPU_KERNEL", "")
         kernel = None
         if use_tpu is None:
             if os.environ.get("TENDERMINT_TPU_DISABLE", "") == "1":
@@ -727,14 +714,10 @@ class Verifier:
                 # SLOWER than the native C++ batch verifier the CPU path
                 # runs (measured: ~5k vs ~10k sigs/s), so "no accelerator"
                 # must mean the native path, not a de-optimizing kernel
-                kernel = kernel_name()
-                use_tpu = (
-                    kernel == "devd"
-                    or bool(os.environ.get("TENDERMINT_TPU_KERNEL"))
-                    or on_tpu()
-                )
+                kernel = kernel_name(chosen)
+                use_tpu = kernel == "devd" or bool(chosen) or on_tpu()
         if kernel is None and use_tpu:
-            kernel = kernel_name()
+            kernel = kernel_name(chosen)
         # kernel choice is resolved ONCE per verifier (a typo'd env var
         # fails at startup; a daemon appearing or dying mid-run cannot
         # flip the hot path under a live consensus node)
@@ -902,8 +885,8 @@ class Verifier:
             try:
                 ops_ed = self._kernel_module()
                 if not hasattr(ops_ed, "verify_batch_async"):
-                    # only the default kernel pipelines; the bake-off
-                    # kernels verify synchronously under the same contract
+                    # a kernel without a pipelined entry point verifies
+                    # synchronously under the same contract
                     res_now = self.verify_batch(items)
                     return lambda: res_now
 
@@ -1126,7 +1109,7 @@ class ShardedVerifier(Verifier):
     single-chip winner, now the TPU-mesh default; per-shard body is plain
     XLA on non-TPU meshes, same math — ed25519_f32p.make_sharded_verify)
     and "f32" (pjit over the conv formulation — the non-TPU default and
-    the fallback). Bake-off backends don't shard; requesting one
+    the fallback). The comb kernel does not shard; requesting it
     explicitly is an error rather than a silent misreport."""
 
     def __init__(self, mesh, min_tpu_batch: int | None = None):
@@ -1136,7 +1119,7 @@ class ShardedVerifier(Verifier):
             raise ValueError(
                 f"ShardedVerifier shards the f32/f32p kernels; "
                 f"TENDERMINT_TPU_KERNEL={explicit!r} — use the base "
-                f"Verifier to run a bake-off backend or the device daemon"
+                f"Verifier to run another kernel or the device daemon"
             )
         # base init may have resolved devd; this class does its own
         # in-process sharded dispatch
@@ -1311,10 +1294,7 @@ class Hasher:
     help, parallel only across parts) — to be measured, not assumed.
     The streamed route (hash_stream — ops/devd_backend.hash_batch)
     frames a batch in chunks over devd and its tree frame makes
-    part-set proofs free for the client; on a simulated transport with
-    device time held constant it ran ~2.2x the single-shot offload
-    (benches/bench_partset.py's sim row: a CPU-side transport figure,
-    not a device one).
+    part-set proofs free for the client. Not measured on the chip.
 
     Routing (resolved ONCE at construction, like Verifier's kernel):
     when offload is on and a device daemon is serving, every hash batch

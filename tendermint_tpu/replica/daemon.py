@@ -17,7 +17,7 @@ against the proven one, and only then caches + serves. Clients re-verify
 — ``LightClient.verified_query`` pointed at a replica runs the exact
 same checks, so a corrupt replica is DETECTED, never trusted
 (``TENDERMINT_REPLICA_TAMPER=value|proof`` exists to prove that in
-benches/tests: it corrupts responses at serve time, after verification).
+tests: it corrupts responses at serve time, after verification).
 
 The listener is the ordinary rpc/server.py stack with a replica route
 table, so the round-23 admission plane (connection/inflight caps, rate
@@ -427,7 +427,7 @@ class ReplicaDaemon(BaseService):
         """Serve a cached entry: the verified response + the header it
         verified against (a convenience — clients re-verify through their
         own light client regardless). The tamper knob corrupts AT SERVE
-        TIME, after verification: it exists so benches/tests can prove a
+        TIME, after verification: it exists so tests can prove a
         lying replica is detected client-side, never accepted."""
         tamper = env_str("TENDERMINT_REPLICA_TAMPER", "",
                          allowed=("", "value", "proof"))

@@ -12,7 +12,6 @@ verifier: a whole commit's signatures flush to the kernel in one batch.
 from __future__ import annotations
 
 import logging
-import os
 
 from tendermint_tpu.crypto.keys import PubKeyEd25519, pub_key_from_json
 from tendermint_tpu.state.fail import fail_point
@@ -124,17 +123,13 @@ def exec_block_on_proxy_app(event_cache, proxy_app_conn, block) -> ABCIResponses
     if proxy_app_conn.error():
         raise ProxyAppConnError(str(proxy_app_conn.error()))
 
-    # stream txs asynchronously; responses arrive in order. Round 14:
-    # the whole block dispatches in ONE grouped call when the connection
-    # offers it — a batch-capable app (kvstore sharded apply) sees the
-    # txs together, a local client pays one lock round trip, and the
-    # socket client's default keeps the per-tx pipelining.
-    # TENDERMINT_DELIVER_BATCH=0 restores the per-tx dispatch (the
-    # pre-round-14 execution plane; benches/bench_pipeline.py's serial
-    # baseline)
+    # stream txs asynchronously; responses arrive in order. The whole
+    # block dispatches in ONE grouped call when the connection offers it
+    # — a batch-capable app (kvstore sharded apply) sees the txs
+    # together, a local client pays one lock round trip, and the socket
+    # client's default keeps the per-tx pipelining. A connection without
+    # the method gets the per-tx dispatch.
     deliver_many = getattr(proxy_app_conn, "deliver_txs_async", None)
-    if os.environ.get("TENDERMINT_DELIVER_BATCH", "") == "0":
-        deliver_many = None
     if deliver_many is not None and len(block.data.txs) > 1:
         reqres = deliver_many(list(block.data.txs))
         if proxy_app_conn.error():

@@ -21,8 +21,7 @@ waves and each wave's preimages go through ONE `Hasher.part_leaf_hashes`
 call (the streamed devd `hash_stream` plane when a daemon serves, AVX
 batch / CPU behind the shared breaker otherwise — ops/gateway routing).
 A bulk load (snapshot restore) is a single O(n) Cartesian-tree build
-whose n node hashes ride the same waves, which is where the streamed
-plane wins big (benches/bench_statetree.py).
+whose n node hashes ride the same waves.
 
 Thread safety: one RLock around every public op — reads included, since
 the RPC query path proves against versions the consensus thread is

@@ -23,9 +23,8 @@ oversized-frame peer, slow-loris, eclipse identities, frame corruptor —
 every one speaking the real encrypted protocol.
 
 Shared by tests/test_netchaos.py (the scenario matrix) and
-benches/bench_netchaos.py + benches/bench_wan.py (BENCH_r12/r18),
-which is why it lives in a _common module like
-tests/consensus_common.py.
+tests/test_gossip_dedup.py, which is why it lives in a _common module
+like tests/consensus_common.py.
 """
 
 from __future__ import annotations
@@ -783,8 +782,7 @@ def hostile_offerer_matrix(target_host: str, target_port: int,
     (the picker takes max, so it is exercised first and its light walk
     succeeds while the binding check proves the lie), a CORRUPT-chunk
     offerer and a STALLING offerer both pinned at the honest height.
-    Shared by the netchaos scenario and benches/bench_retention.py —
-    callers also arm the TENDERMINT_STATESYNC_{WINDOW,CHUNK_TIMEOUT_S,
+    Callers also arm the TENDERMINT_STATESYNC_{WINDOW,CHUNK_TIMEOUT_S,
     STALL_BAN,DISCOVERY_S} knobs for their timing budget, and must
     close() every offerer."""
     return {

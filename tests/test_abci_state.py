@@ -277,6 +277,47 @@ class TestExecution:
         app_hash = exec_commit_block(conns.consensus(), block)
         assert len(app_hash) == 20
 
+    def test_per_tx_dispatch_matches_grouped_dispatch(self, tmp_path):
+        """A connection without `deliver_txs_async` gets one DeliverTx a
+        tx; the block's results and the app hash are those of the
+        grouped dispatch, a rejected tx included."""
+        from tendermint_tpu.state.execution import exec_block_on_proxy_app
+
+        class PerTxOnly:
+            """The consensus connection less its grouped call."""
+
+            def __init__(self, conn):
+                self._conn = conn
+                self.delivered = 0
+
+            def deliver_tx_async(self, tx):
+                self.delivered += 1
+                return self._conn.deliver_tx_async(tx)
+
+            def __getattr__(self, name):
+                if name == "deliver_txs_async":
+                    raise AttributeError(name)
+                return getattr(self._conn, name)
+
+        txs = [b"a=1", b"b=2", b"val:zz/1", b"a=3"]
+        outcomes = []
+        for per_tx in (False, True):
+            s, conns, privs = self._setup(
+                PersistentKVStoreApp(str(tmp_path / str(per_tx))))
+            conn = conns.consensus()
+            if per_tx:
+                conn = PerTxOnly(conn)
+            block, _ = make_next_block(s, txs, privs)
+            res = exec_block_on_proxy_app(None, conn, block)
+            if per_tx:
+                assert conn.delivered == len(txs)
+            outcomes.append((
+                [(r.code, r.data, r.log) for r in res.deliver_tx],
+                conns.consensus().commit_sync().data,
+            ))
+        assert outcomes[0] == outcomes[1]
+        assert [c == 0 for c, _, _ in outcomes[0][0]] == [True, True, False, True]
+
     def test_update_validators_errors(self):
         _, vs, _ = make_genesis()
         missing = gen_priv_key_ed25519(b"missing").pub_key()

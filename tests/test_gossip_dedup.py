@@ -10,9 +10,8 @@ the mirror). With `consensus.gossip_dedup` on (the default), the STATE
 channel feeds all of them into the mirror and the part-set gossip
 gains the same screen (HasBlockPartMessage).
 
-These are the unit halves; the process-scale A/B lives in
-benches/bench_localnet.py (dedup on-vs-off duplicate-vote ratio at
-n=10 real processes, asserted directional)."""
+These are the unit halves; the process-scale scenario is
+ops/localnet.py's (tests/test_localnet.py, slow-marked)."""
 
 from __future__ import annotations
 
@@ -419,8 +418,7 @@ def test_announcements_reduce_redundant_sends_across_peer_fan_out():
     the receiving side would count as duplicates once the vote has
     propagated); with HasVotes applied from two peers, only the silent
     one is picked for — redundant sends drop 3 -> 1. This is the causal
-    core of the duplicate-ratio drop the n=10 process A/B in
-    benches/bench_localnet.py asserts wall-clock."""
+    core of the duplicate-ratio drop."""
     vs = _VoteSet(5, 0, VOTE_TYPE_PREVOTE, [1])
 
     def fresh_peer():
@@ -447,9 +445,8 @@ def test_duplicate_ratio_counters_move_on_live_net(tmp_path):
     (never negative, never counted as accepts), the ratio is finite,
     and the dedup plumbing demonstrably engages (announcements applied,
     part screens sent AND applied). The wall-clock on-vs-off ratio drop
-    is asserted at n=10 REAL PROCESSES in benches/bench_localnet.py —
-    at 4 in-process nodes under one GIL the scheduler noise swamps the
-    few-percent gain."""
+    is not asserted here: at 4 in-process nodes under one GIL the
+    scheduler noise swamps the few-percent gain."""
     from tests.netchaos_common import ChaosNet
 
     net = ChaosNet(4, str(tmp_path / "dedup-on"), gossip_dedup=True)
@@ -518,8 +515,7 @@ def test_dedup_reduces_duplicate_ratio_on_live_net(tmp_path):
     (HasVote exploitation + lazy-relay hold) yields a strictly lower
     fleet duplicate-vote ratio than off, at real commit pacing (the
     hold needs a cadence where announcements can land; the unthrottled
-    test preset commits heights faster than a gossip tick). The
-    process-scale A/B at n=10 is asserted in benches/bench_localnet.py."""
+    test preset commits heights faster than a gossip tick)."""
     from tests.netchaos_common import ChaosNet
 
     def ratio(dedup: bool, sub: str) -> float:

@@ -8,10 +8,9 @@ The convergence assertion is the same byte-identity the existing soaks
 use: (block hash, part-set root, app hash, evidence hash) per height,
 identical across every node.
 
-The whole matrix is slow-marked (the ISSUE-8 tiering: tier-1's
-network-chaos gate is `make net-chaos-smoke`, the bench's reduced
-partition-heal pass — full nodes booting N-at-a-time are too
-scheduler-sensitive for the strict tier-1 budget on a 2-core box):
+The matrix is slow-marked but for partition-heal (full nodes booting
+N-at-a-time are too scheduler-sensitive for the strict tier-1 budget on
+a 2-core box):
 partition-heal and the byzantine double-signer are the two acceptance
 pillars, then asymmetric delay, peer churn, frame reorder
 (AEAD-detected), statesync join mid-chaos, and the 5-node
@@ -48,12 +47,11 @@ def net4(tmp_path):
 # -- the two acceptance pillars ----------------------------------------------
 
 
-@pytest.mark.slow
 def test_partition_heal_converges(net4):
     """{0,1} | {2,3}: neither side holds +2/3, so the chain HALTS (the
     safety half); healing re-peers via the persistent-dial loop and the
     chain resumes to byte-identical state everywhere (the liveness
-    half)."""
+    half). The one scenario of the matrix that tier-1 runs."""
     net4.partition({0, 1})
     h_stall = max(net4.heights())
     time.sleep(2.5)

@@ -63,9 +63,8 @@ class TestMerkleKernel:
 
 @pytest.mark.slow
 class TestFieldArithmetic:
-    """int32 radix-2^15 reference-kernel math (dormant in production —
-    the gateway runs ops/ed25519_f32; see tests/test_ops_f32.py). Marked
-    slow: compiles the big ladder graphs."""
+    """int32 radix-2^15 field math under ops/ed25519.dsm_batch (the
+    aggregate-commit lanes). Marked slow: compiles the multiply graphs."""
 
     def test_mul_inv_canon(self):
         import random
@@ -111,120 +110,6 @@ def _mk_items(n, corrupt=()):
             s = int.from_bytes(sig[32:], "little") + ref.L
             items[i] = (pub, msg, sig[:32] + s.to_bytes(32, "little"))
     return items
-
-
-@pytest.mark.slow
-class TestVerifyKernel:
-    """Compiles the full jnp verify program once (slow on CPU backend) and
-    reuses it; the pallas variant shares all math helpers. Slow: the
-    int32 kernel is the dormant math reference — the production f32
-    kernel has its own always-on suite in tests/test_ops_f32.py."""
-
-    def test_verify_and_reject(self):
-        items = _mk_items(
-            8, corrupt=[(1, "sig"), (2, "msg"), (3, "high_s"), (4, "pub")]
-        )
-        ok = ops_ed.verify_batch(items)
-        expected = [ref.verify(p, m, s) for p, m, s in items]
-        assert list(ok) == expected
-        assert expected == [True, False, False, False, False, True, True, True]
-
-    def test_rfc8032_vectors(self):
-        from tests.test_crypto import RFC8032_VECTORS
-
-        items = [
-            (bytes.fromhex(pk), bytes.fromhex(msg), bytes.fromhex(sig))
-            for _, pk, msg, sig in RFC8032_VECTORS
-        ]
-        assert ops_ed.verify_batch(items).all()
-
-    def test_decompress_batch(self):
-        pubs = [ref.public_key(hashlib.sha256(b"d%d" % i).digest()) for i in range(6)]
-        x, y, valid = ops_ed.decompress_batch(pubs + [b"\xff" * 32])
-        assert valid[:6].all() and not valid[6]
-        for i, p in enumerate(pubs):
-            pt = ref.point_decompress(p)
-            assert ops_ed.limbs_to_int(x[:, i]) == pt[0]
-            assert ops_ed.limbs_to_int(y[:, i]) == pt[1]
-
-
-@pytest.mark.slow
-class TestPallasKernelMath:
-    """The Pallas kernel's row-based limb arithmetic is plain jnp outside
-    the pallas_call plumbing — test it directly against the reference so
-    the production-TPU math has CPU coverage. The pallas_call plumbing
-    itself (block specs, lane reshape) runs under the real-TPU bench and
-    the TPU-gated test below."""
-
-    def _to_rows(self, vals):
-        import jax.numpy as jnp
-
-        from tendermint_tpu.ops import ed25519_pallas as pk
-
-        arr = ops_ed.int_to_limbs_np(vals)  # (17, B)
-        return [jnp.asarray(arr[k]) for k in range(pk.NLIMB)]
-
-    def _to_int(self, rows, i):
-        import numpy as np
-
-        stacked = np.stack([np.asarray(r) for r in rows])
-        return ops_ed.limbs_to_int(stacked[:, i])
-
-    def test_fmul_fsq_rows(self):
-        import random
-
-        from tendermint_tpu.ops import ed25519_pallas as pk
-
-        random.seed(11)
-        vals = [random.randrange(ref.P) for _ in range(8)]
-        bv = [random.randrange(ref.P) for _ in range(8)]
-        a = self._to_rows(vals)
-        b = self._to_rows(bv)
-        m = pk._fcanon_rows(pk._fmul_rows(a, b))
-        s = pk._fcanon_rows(pk._fsq_rows(a))
-        for i in range(8):
-            assert self._to_int(m, i) == (vals[i] * bv[i]) % ref.P
-            assert self._to_int(s, i) == (vals[i] * vals[i]) % ref.P
-
-    def test_point_ladder_rows(self):
-        """One double+add in row form matches the reference group law."""
-        import jax.numpy as jnp
-
-        from tendermint_tpu.ops import ed25519_pallas as pk
-
-        B_pt = ref.B
-        dbl = ref.point_double(B_pt)
-        tripled = ref.point_add(dbl, B_pt)
-
-        def const_rows(v):
-            arr = ops_ed.int_to_limbs_np([v] * 4)
-            return [jnp.asarray(arr[k]) for k in range(pk.NLIMB)]
-
-        zeros = const_rows(0)
-        one = const_rows(1)
-        bx, by = const_rows(B_pt[0]), const_rows(B_pt[1])
-        bt = const_rows((B_pt[0] * B_pt[1]) % ref.P)
-        d2 = const_rows((2 * ref.D) % ref.P)
-        p = (bx, by, one, bt)
-        d = pk._point_double_rows(p)
-        t = pk._point_add_rows(d, p, d2)
-        # compare affine
-        zinv = pk._finv_rows(t[2])
-        x = pk._fcanon_rows(pk._fmul_rows(t[0], zinv))
-        y = pk._fcanon_rows(pk._fmul_rows(t[1], zinv))
-        zexp = pow(tripled[2], ref.P - 2, ref.P)
-        assert self._to_int(x, 0) == tripled[0] * zexp % ref.P
-        assert self._to_int(y, 0) == tripled[1] * zexp % ref.P
-
-    @pytest.mark.skipif(
-        jax.devices()[0].platform != "tpu", reason="full pallas kernel needs TPU"
-    )
-    def test_pallas_verify_on_tpu(self):
-        from tendermint_tpu.ops import ed25519_pallas as pk
-
-        items = _mk_items(8, corrupt=[(2, "sig")])
-        ok = pk.verify_batch(items)
-        assert list(ok) == [True, True, False] + [True] * 5
 
 
 class TestGateway:
@@ -360,7 +245,7 @@ class TestShardedVerifier:
     def test_sharded_rejects_bakeoff_kernels(self, monkeypatch):
         from jax.sharding import Mesh
 
-        monkeypatch.setenv("TENDERMINT_TPU_KERNEL", "int32")
+        monkeypatch.setenv("TENDERMINT_TPU_KERNEL", "comb")
         mesh = Mesh(np.array(jax.devices()), ("batch",))
         with pytest.raises(ValueError, match="shards the f32/f32p"):
             gateway.ShardedVerifier(mesh)

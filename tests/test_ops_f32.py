@@ -306,8 +306,6 @@ class TestKernelRegistry:
         [
             ("f32p", "tendermint_tpu.ops.ed25519_f32p"),
             ("f32", "tendermint_tpu.ops.ed25519_f32"),
-            ("int32", "tendermint_tpu.ops.ed25519"),
-            ("pallas", "tendermint_tpu.ops.ed25519_pallas"),
         ],
     )
     def test_selects_each_backend(self, monkeypatch, name, module):
@@ -355,15 +353,53 @@ class TestKernelRegistry:
         # with the TPU disabled outright the env var is irrelevant
         gw.Verifier(use_tpu=False)
 
+    @pytest.mark.parametrize("name", ["int32", "pallas"])
+    def test_a_deleted_kernels_name_fails_with_the_names_left(
+            self, monkeypatch, name):
+        from tendermint_tpu.ops import gateway as gw
+
+        assert sorted(gw.KERNELS) == ["comb", "devd", "f32", "f32p"]
+        monkeypatch.setenv("TENDERMINT_TPU_KERNEL", name)
+        with pytest.raises(ValueError, match=name) as err:
+            gw.Verifier(use_tpu=True)
+        assert str(sorted(gw.KERNELS)) in str(err.value)
+
+    def test_kernel_by_argument_leaves_the_environment_alone(self, monkeypatch):
+        """The daemon's claim names each candidate by argument: the
+        verifier it gets dispatches to that kernel on the device only, and
+        nothing is written into the process environment on the way."""
+        import os
+
+        from tendermint_tpu.ops import gateway as gw
+
+        monkeypatch.delenv("TENDERMINT_TPU_KERNEL", raising=False)
+        before = dict(os.environ)
+        v = gw.Verifier(min_tpu_batch=1, use_tpu=True, host_fallback=False,
+                        kernel="f32")
+        assert v._kernel_module().__name__ == "tendermint_tpu.ops.ed25519_f32"
+        seed = b"\x54" * 32
+        items = [
+            (ed.public_key(seed), b"k%d" % i, ed.sign(seed, b"k%d" % i))
+            for i in range(4)
+        ]
+        assert v.verify_batch(items) == [True] * 4
+        assert v.stats()["tpu_sigs"] == 4 and v.stats()["cpu_sigs"] == 0
+        assert dict(os.environ) == before
+        # the argument wins over the environment, and is validated like it
+        monkeypatch.setenv("TENDERMINT_TPU_KERNEL", "f32p")
+        assert gw.Verifier(use_tpu=True, kernel="f32")._kernel == "f32"
+        with pytest.raises(ValueError, match="int32"):
+            gw.Verifier(use_tpu=True, kernel="int32")
+
     def test_sharded_rejects_non_f32(self, monkeypatch):
         import jax
         from jax.sharding import Mesh
 
         from tendermint_tpu.ops import gateway as gw
 
-        monkeypatch.setenv("TENDERMINT_TPU_KERNEL", "pallas")
+        monkeypatch.setenv("TENDERMINT_TPU_KERNEL", "comb")
         mesh = Mesh(np.array(jax.devices()[:1]), ("batch",))
-        with pytest.raises(ValueError, match="pallas"):
+        with pytest.raises(ValueError, match="comb"):
             gw.ShardedVerifier(mesh)
 
 

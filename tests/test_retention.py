@@ -12,8 +12,7 @@ below-horizon statesync fallback trigger.
 The live multi-node tiers — the retention soak (disk bounded by
 retention, wiped node re-joins via snapshot), the adversarial statesync
 offerer matrix, and the laggard-below-horizon auto-switch — live in
-tests/test_netchaos.py (slow-marked) and benches/bench_retention.py
-(`make retention-smoke`, tier 1).
+tests/test_netchaos.py (slow-marked).
 """
 
 from __future__ import annotations

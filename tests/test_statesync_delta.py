@@ -117,16 +117,31 @@ class TestDeltaProducer:
         assert producer._delta_base(16) is None
 
     def test_delta_meaningfully_smaller(self):
-        chain, store, _producer = build_delta_home(
-            n_heights=8, interval=4, full_every=2
+        """800 keys seeded over four heights, then four heights that
+        touch 20 of them: the delta at 8 is under half the full snapshot
+        at 4 (bytes follow the change, not the state)."""
+        def tx_fn(h):
+            if h <= 4:
+                return [b"seed-%04d=v%d" % (i, h)
+                        for i in range(200 * (h - 1), 200 * h)]
+            return ([b"seed-%04d=updated%d" % (i, h) for i in range(10)]
+                    + [b"fresh-%d-%d=x" % (h, i) for i in range(5)]
+                    + [b"rm:seed-%04d" % (799 - i) for i in range(5)])
+
+        chain = DevChain(KVStoreApp())
+        store = SnapshotStore(tempfile.mkdtemp(prefix="delta-size-"))
+        producer = SnapshotProducer(
+            store, chain.app, chain.block_store, interval=4,
+            keep_recent=8, chunk_size=65536, full_every=2,
         )
+        for h in range(1, 9):
+            chain.commit_block(tx_fn(h))
+            producer.maybe_snapshot(chain.state)
         full = store.load_manifest(4)
         delta = store.load_manifest(8)
         assert delta.kind == KIND_DELTA
-        # state grows every height, the per-interval change doesn't; at
-        # even this tiny scale the delta should undercut the full copy
-        assert delta.total_bytes < full.total_bytes * 3  # sanity ceiling
-        # the real assertion rides bench_statetree at larger sizes
+        assert delta.total_bytes <= 0.5 * full.total_bytes, (
+            delta.total_bytes, full.total_bytes)
 
     def test_payload_excludes_seen_commit_manifest_carries_it(self):
         _chain, store, _p = build_delta_home()

@@ -305,6 +305,41 @@ class TestCommitDispatch:
         assert full.to_bytes() == commit.to_bytes()
 
 
+# -- a live chain across the flip, in process ----------------------------------
+
+
+def test_live_chain_crosses_the_flip_without_missing_a_height():
+    """One validator's real ConsensusState with the flip at H=3: every
+    height commits, the blocks below H carry full last-commits and the
+    blocks from H on aggregates that verify against the validator set,
+    and nothing was refused on the way (the in-process half of the
+    rolling-upgrade scenario; the process fleet is ops/localnet's)."""
+    from consensus_common import (
+        TEST_CHAIN_ID,
+        new_consensus_state,
+        rand_gen_state,
+        wait_for_height,
+    )
+
+    state, pvs = rand_gen_state(1)
+    state.genesis_doc.upgrade_height = 3
+    state.genesis_doc.upgrade_format = "aggregate"
+    state.genesis_doc.validate_and_complete()
+    cs = new_consensus_state(state, pvs[0])
+    cs.start()
+    try:
+        assert wait_for_height(cs, 7, timeout=60), cs.rs.height
+    finally:
+        cs.stop()
+    for h in range(2, 7):
+        lc = cs.block_store.load_block(h).last_commit
+        assert commit_is_aggregate(lc) == (h >= 3), f"height {h}"
+        if h >= 3:
+            lc.verify(TEST_CHAIN_ID, state.validators)
+    assert cs.agg_commits_proposed >= 4
+    assert cs.agg_commit_rejects == 0
+
+
 # -- boundary crash / WAL replay (slow tier) --------------------------------
 
 
