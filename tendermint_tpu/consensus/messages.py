@@ -65,6 +65,11 @@ def _bitarray_field(o, key, max_bits=_MAX_BITS):
     bits = v.get("bits")
     if type(bits) is not int or not (0 <= bits <= max_bits):
         raise ValueError(f"bad {key!r} size: {bits!r}")
+    # the mask is hex text of at most one digit per four bits: anything
+    # else would reach int(..., 16) as a TypeError or an unbounded parse
+    elems = v.get("elems")
+    if not isinstance(elems, str) or len(elems) > bits // 4 + 2:
+        raise ValueError(f"bad {key!r} mask")
     return BitArray.from_json(v)
 
 
@@ -214,6 +219,36 @@ class HasVoteMessage:
             _int_field(o, "round", 0, _MAX_ROUND),
             _int_field(o, "type", 0, 255),
             _int_field(o, "index", 0, _MAX_INDEX),
+        )
+
+
+@register("has_votes")
+@dataclass
+class HasVotesMessage:
+    """HasVote for a burst (beyond reference): every set bit of `votes`
+    says what one HasVoteMessage at that index says, for the votes of
+    (height, round, type) that entered our vote set since the last
+    announcement. The receiver ORs it into its mirror of us and never
+    clears a bit: it is not VoteSetBits, which replaces the mirror and
+    names a block id. A node sends this form only (one message a peer
+    a burst, not one a peer a vote) and still hears the single form."""
+
+    height: int
+    round_: int
+    type_: int
+    votes: BitArray
+
+    def to_json(self):
+        return {"height": self.height, "round": self.round_, "type": self.type_,
+                "votes": self.votes.to_json()}
+
+    @classmethod
+    def from_json(cls, o):
+        return cls(
+            _int_field(o, "height", 0, _MAX_HEIGHT),
+            _int_field(o, "round", 0, _MAX_ROUND),
+            _int_field(o, "type", 0, 255),
+            _bitarray_field(o, "votes"),
         )
 
 

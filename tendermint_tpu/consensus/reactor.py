@@ -1,7 +1,7 @@
 """Consensus gossip reactor (reference: consensus/reactor.go).
 
 Four p2p channels (reactor.go:21-24):
-  0x20 STATE       — NewRoundStep / CommitStep / HasVote / ProposalHeartbeat
+  0x20 STATE       — NewRoundStep / CommitStep / HasVote(s) / ProposalHeartbeat
   0x21 DATA        — Proposal / ProposalPOL / BlockPart
   0x22 VOTE        — Vote
   0x23 VOTE_SET_BITS — VoteSetMaj23 / VoteSetBits
@@ -78,6 +78,7 @@ PEER_STATE_KEY = "ConsensusReactor.peerState"
 GOSSIP_COUNTERS = (
     "gossip_sends", "gossip_wakes_event", "gossip_wakes_hold",
     "gossip_wakes_backstop", "gossip_backstop_sends",
+    "gossip_announces_sent", "gossip_announce_bits",
 )
 
 
@@ -478,6 +479,26 @@ class PeerState:
                 return False
         return self.set_has_vote(msg.height, msg.round_, msg.type_, msg.index)
 
+    def apply_has_votes(self, msg: msgs.HasVotesMessage,
+                        allow_last_commit: bool = False) -> tuple[int, list[int]]:
+        """Feed a burst's announcement into the mirror: apply_has_vote's
+        gate and routing, once and under one lock for the whole array,
+        which is ORed in (a bit the mirror holds is never cleared). An
+        array of another size than the mirror's is not ours to
+        interpret and is ignored. Returns how many bits landed and the
+        indices among them the mirror did not hold before."""
+        with self._mtx:
+            if self.prs.height != msg.height and not (
+                allow_last_commit and self.prs.height == msg.height + 1
+            ):
+                return 0, []
+            ba = self._get_vote_bit_array(msg.height, msg.round_, msg.type_)
+            if ba is None or ba.size != msg.votes.size:
+                return 0, []
+            fresh = msg.votes.sub(ba)
+            ba.update(ba.or_(msg.votes))
+            return msg.votes.num_true_bits(), fresh.indices()
+
     def apply_vote_set_bits(self, msg: msgs.VoteSetBitsMessage, our_votes: BitArray | None) -> None:
         """reactor.go:1126-1149. ourVotes is a MASK of what we know we
         hold for that BlockID: keep the peer-bits that aren't ours, OR in
@@ -535,9 +556,25 @@ class ConsensusReactor(Reactor, BaseService):
         self.gossip_wakes_hold = 0
         self.gossip_wakes_backstop = 0
         self.gossip_backstop_sends = 0
+        # has-vote announcements: messages sent (one a peer a flush and
+        # key) and the votes they announced (one a vote a flush). Bits
+        # over (announces / peers) is the mean burst
+        self.gossip_announces_sent = 0
+        self.gossip_announce_bits = 0
         # default_set_proposal fires no event: it calls this
         consensus_state.gossip_wake = self.wake_gossip
         self._relay_wake = _DeferredWake(self.wake_votes_gossip)
+        # votes that entered our vote set and no peer was told of yet:
+        # (height, round, type) -> [validators, mask]. One announcement
+        # a key goes to every peer VOTE_RELAY_DELAY_MIN after the first
+        # pending bit, or before the next step message, whichever is
+        # first (_flush_has_votes). _announce_mtx guards the dict;
+        # _announce_order is held over a whole flush, so that a step
+        # broadcast waits for the bits a flush in flight is sending.
+        self._announce_pending: dict[tuple[int, int, int], list[int]] = {}
+        self._announce_mtx = threading.Lock()
+        self._announce_order = threading.Lock()
+        self._announce_wake = _DeferredWake(self._flush_has_votes)
         # smoothed seconds from our receipt of a vote to a peer's HasVote
         # for it (None until one was seen)
         self._has_vote_lag: float | None = None
@@ -566,7 +603,7 @@ class ConsensusReactor(Reactor, BaseService):
                 self._relay_wake.at(time.monotonic() + hold)
             else:
                 self.wake_votes_gossip()
-            self._broadcast_has_vote(d.vote)
+            self._note_has_vote(d.vote)
 
         def on_part(d):
             self.wake_data_gossip()
@@ -694,29 +731,26 @@ class ConsensusReactor(Reactor, BaseService):
                 ps.apply_commit_step(msg)
                 ps.gossip.wake()
             elif isinstance(msg, msgs.HasVoteMessage):
+                # the single form: what a peer of upstream's code sends
                 if self.gossip_dedup:
-                    # ensure the tracking arrays BEFORE applying — at a
-                    # fresh height the mirror has none yet, and every
-                    # HasVote in that first window used to vanish into
-                    # the set_has_vote no-op (the biggest single source
-                    # of the 2NxN duplicate pushes: peers kept picking
-                    # votes the neighbor had announced long ago)
-                    rs = self.con_s.get_round_state()
-                    size = rs.validators.size() if rs.validators else 0
-                    last_size = rs.last_commit.size() if rs.last_commit else 0
-                    ps.ensure_vote_bit_arrays(rs.height, size)
-                    ps.ensure_vote_bit_arrays(rs.height - 1, last_size)
+                    self._ensure_vote_bit_arrays(ps)
                 if ps.apply_has_vote(msg, allow_last_commit=self.gossip_dedup):
                     self.has_votes_applied += 1
-                # how long after WE received a vote a peer says it has it:
-                # what a relay's hold has to outlast (_relay_delay)
-                got = self.con_s.vote_recv_mono.get(
-                    (msg.height, msg.round_, msg.type_, msg.index))
-                if got is not None:
-                    lag = time.monotonic() - got
-                    old = self._has_vote_lag
-                    self._has_vote_lag = lag if old is None else \
-                        0.9 * old + 0.1 * lag
+                self._note_has_vote_lag(self.con_s.vote_recv_mono.get(
+                    (msg.height, msg.round_, msg.type_, msg.index)))
+            elif isinstance(msg, msgs.HasVotesMessage):
+                # a burst's worth of HasVote: one ensure, one lock, and
+                # one sample of the lag, from the bit that waited longest
+                if self.gossip_dedup:
+                    self._ensure_vote_bit_arrays(ps)
+                landed, fresh = ps.apply_has_votes(
+                    msg, allow_last_commit=self.gossip_dedup)
+                self.has_votes_applied += landed
+                stamps = self.con_s.vote_recv_mono
+                key = (msg.height, msg.round_, msg.type_)
+                self._note_has_vote_lag(min(
+                    (t for t in (stamps.get(key + (i,)) for i in fresh)
+                     if t is not None), default=None))
             elif isinstance(msg, msgs.HasBlockPartMessage):
                 # round 20 part dedup screen: the peer announced a part
                 # it holds — mark the mirror so gossip_data skips it
@@ -755,15 +789,7 @@ class ConsensusReactor(Reactor, BaseService):
             if self.fast_sync:
                 return
             if isinstance(msg, msgs.VoteMessage):
-                rs = self.con_s.get_round_state()
-                height = rs.height
-                size = rs.validators.size() if rs.validators else 0
-                # the height-1 array tracks LastCommit votes, whose set can
-                # differ in size from the current one (reactor.go:291-296
-                # uses cs.LastCommit.Size(), not cs.Validators.Size())
-                last_size = rs.last_commit.size() if rs.last_commit else 0
-                ps.ensure_vote_bit_arrays(height, size)
-                ps.ensure_vote_bit_arrays(height - 1, last_size)
+                self._ensure_vote_bit_arrays(ps)
                 ps.set_has_vote(
                     msg.vote.height, msg.vote.round_, msg.vote.type_,
                     msg.vote.validator_index,
@@ -789,6 +815,32 @@ class ConsensusReactor(Reactor, BaseService):
                 ps.gossip.wake()
             else:
                 self.switch.stop_peer_for_error(peer, f"bad bits msg {type(msg)}")
+
+    def _ensure_vote_bit_arrays(self, ps: PeerState) -> None:
+        """Ensure the peer's tracking arrays at our height BEFORE a vote
+        or (gossip_dedup) an announcement marks them: at a fresh height
+        the mirror has none yet, and every HasVote in that first window
+        used to vanish into the set_has_vote no-op (the biggest single
+        source of the 2NxN duplicate pushes: peers kept picking votes
+        the neighbor had announced long ago). The height-1 array tracks
+        LastCommit votes, whose set can differ in size from the current
+        one (reactor.go:291-296 uses cs.LastCommit.Size(), not
+        cs.Validators.Size())."""
+        rs = self.con_s.get_round_state()
+        size = rs.validators.size() if rs.validators else 0
+        last_size = rs.last_commit.size() if rs.last_commit else 0
+        ps.ensure_vote_bit_arrays(rs.height, size)
+        ps.ensure_vote_bit_arrays(rs.height - 1, last_size)
+
+    def _note_has_vote_lag(self, got: float | None) -> None:
+        """How long after WE received a vote (`got`, its vote_recv_mono
+        stamp) a peer says it has it: what a relay's hold has to outlast
+        (_relay_delay)."""
+        if got is None:
+            return
+        lag = time.monotonic() - got
+        old = self._has_vote_lag
+        self._has_vote_lag = lag if old is None else 0.9 * old + 0.1 * lag
 
     def _screen_agg_commit(self, peer, msg: msgs.AggregateCommitMessage) -> bool:
         """Verify a received aggregate catchup commit on the peer thread
@@ -856,6 +908,7 @@ class ConsensusReactor(Reactor, BaseService):
     def on_stop(self) -> None:
         self.con_s.stop()
         self._relay_wake.stop()
+        self._announce_wake.stop()
         for gw in self._gossips:
             gw.end()
 
@@ -898,17 +951,55 @@ class ConsensusReactor(Reactor, BaseService):
     def _broadcast_step(self) -> None:
         if not hasattr(self, "switch") or self.switch is None:
             return
+        # a step message never overtakes the bits of the round it ends:
+        # a peer's apply_new_round_step resets the arrays they belong to
+        self._flush_has_votes()
         for m in self._round_step_messages():
             self.switch.broadcast(STATE_CHANNEL, _enc(m))
 
-    def _broadcast_has_vote(self, vote) -> None:
+    def _note_has_vote(self, vote) -> None:
+        """A vote entered our vote set: its bit waits for the burst's
+        announcement. The first pending bit arms the one timer; the
+        instant is the shortest hold a peer's relay can have
+        (VOTE_RELAY_DELAY_MIN), and a peer acts on its mirror of us only
+        when a hold of its own ends, so the wait cannot lose the race
+        the announcement exists to win. It is NOT tied to _relay_delay:
+        the lag a peer measures contains this wait, and the two would
+        climb to VOTE_RELAY_DELAY_MAX together."""
         if not hasattr(self, "switch") or self.switch is None:
             return
-        msg = msgs.HasVoteMessage(
-            height=vote.height, round_=vote.round_, type_=vote.type_,
-            index=vote.validator_index,
-        )
-        self.switch.broadcast(STATE_CHANNEL, _enc(msg))
+        rs = self.con_s.get_round_state()
+        if vote.height == rs.height:
+            vals = rs.validators
+        elif vote.height == rs.height - 1:
+            vals = rs.last_commit  # the size a peer's last_commit array has
+        else:
+            return
+        if not vals:
+            return
+        key = (vote.height, vote.round_, vote.type_)
+        with self._announce_mtx:
+            first = not self._announce_pending
+            slot = self._announce_pending.setdefault(key, [vals.size(), 0])
+            slot[1] |= 1 << vote.validator_index
+        if first:
+            self._announce_wake.at(time.monotonic() + VOTE_RELAY_DELAY_MIN)
+
+    def _flush_has_votes(self) -> None:
+        """Every key's pending bits go out as one HasVotesMessage to
+        every peer. try_send like every announcement: a full STATE queue
+        drops it (the votes still dedup the hard way), it never blocks
+        the consensus thread that flushes before a step."""
+        with self._announce_order:
+            with self._announce_mtx:
+                pending, self._announce_pending = self._announce_pending, {}
+            peers = len(self._gossips)
+            for (height, round_, type_), (size, mask) in pending.items():
+                votes = BitArray.from_int(size, mask)
+                self.switch.broadcast(STATE_CHANNEL, _enc(msgs.HasVotesMessage(
+                    height=height, round_=round_, type_=type_, votes=votes)))
+                self.gossip_announces_sent += peers
+                self.gossip_announce_bits += votes.num_true_bits()
 
     def _broadcast_has_part(self, data) -> None:
         """Round 20: a part landed in OUR part-set — announce it so

@@ -129,6 +129,59 @@ def test_last_commit_has_vote_lands_only_with_dedup():
     assert ps.pick_vote_to_send(last) == ("vote", 3)
 
 
+def _has_votes(height, round_, type_, indices, size=4):
+    return msgs.HasVotesMessage(
+        height, round_, type_, BitArray.from_indices(size, indices))
+
+
+def test_has_votes_array_ors_into_the_mirror_and_clears_nothing():
+    """The burst form means HasVote for every set bit: the array is
+    ORed in. A later array with FEWER bits (each announcement holds only
+    what entered since the last) takes nothing back, unlike VoteSetBits,
+    which replaces the mirror."""
+    ps = PeerState(peer=None)
+    ps.prs.height, ps.prs.round_ = 5, 0
+    ps.ensure_vote_bit_arrays(5, 4)
+    ps.set_has_vote(5, 0, VOTE_TYPE_PREVOTE, 2)  # a vote we sent it
+
+    assert ps.apply_has_votes(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [0, 2])) == (2, [0])
+    assert ps.apply_has_votes(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [1])) == (1, [1])
+    assert ps.prs.prevotes.indices() == [0, 1, 2]
+    assert ps.prs.precommits.is_empty()  # the other type's array: untouched
+    # a repeat lands and tells nothing new
+    assert ps.apply_has_votes(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [1])) == (1, [])
+    vs = _VoteSet(5, 0, VOTE_TYPE_PREVOTE, [0, 1, 2, 3])
+    assert ps.pick_vote_to_send(vs) == ("vote", 3)
+    # coordinates no array tracks: dropped, as the single form is
+    assert ps.apply_has_votes(_has_votes(5, 1, VOTE_TYPE_PREVOTE, [3])) == (0, [])
+    assert ps.apply_has_votes(_has_votes(4, 0, VOTE_TYPE_PREVOTE, [3])) == (0, [])
+    assert ps.prs.prevotes.indices() == [0, 1, 2]
+
+
+def test_last_commit_has_votes_land_only_with_dedup():
+    """The height-1 precommit array of a node that just committed routes
+    to the last_commit array, and only with allow_last_commit: the gate
+    and the routing of the single form."""
+    ps = PeerState(peer=None)
+    ps.prs.height, ps.prs.round_ = 6, 0
+    ps.prs.last_commit_round = 0
+    ps.ensure_vote_bit_arrays(5, 4)  # height+1 branch -> last_commit array
+    ps.ensure_vote_bit_arrays(6, 4)
+    announce = _has_votes(5, 0, VOTE_TYPE_PRECOMMIT, [0, 2])
+
+    assert ps.apply_has_votes(announce) == (0, [])  # strict gate: dropped
+    assert ps.prs.last_commit.is_empty()
+    assert ps.apply_has_votes(announce, allow_last_commit=True) == (2, [0, 2])
+    assert ps.prs.last_commit.indices() == [0, 2]
+    assert ps.prs.precommits.is_empty()  # not the current height's array
+    # a height-1 PREVOTE array has no last-commit array to land in
+    assert ps.apply_has_votes(
+        _has_votes(5, 0, VOTE_TYPE_PREVOTE, [1]), allow_last_commit=True
+    ) == (0, [])
+    last = _VoteSet(5, 0, VOTE_TYPE_PRECOMMIT, [2, 3])
+    assert ps.pick_vote_to_send(last) == ("vote", 3)
+
+
 def test_laggard_catchup_branch_unaffected_by_dedup():
     """The stored-commit catchup path (peer >= 2 heights behind) must
     keep working under dedup: HasVotes from the laggard for its OWN
@@ -234,6 +287,140 @@ def test_state_channel_has_vote_dropped_when_dedup_off():
     r.receive(STATE_CHANNEL, peer, raw)
     assert r.has_votes_applied == 0
     assert ps.prs.prevotes is None  # no arrays ensured, announcement lost
+
+
+@pytest.mark.parametrize("dedup,applied", [(True, 2), (False, 0)])
+def test_state_channel_has_votes_ensures_arrays_as_has_vote_does(dedup, applied):
+    """The burst form rides the single form's wiring: with dedup on the
+    arrays are ensured first and has_votes_applied counts the BITS that
+    landed; off, a fresh mirror has no array and the announcement is
+    lost, as before round 20."""
+    r, peer, ps = _reactor_with_peer(gossip_dedup=dedup)
+    r.receive(STATE_CHANNEL, peer, _enc(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [1, 2])))
+    assert r.has_votes_applied == applied
+    if dedup:
+        assert ps.pick_vote_to_send(_VoteSet(5, 0, VOTE_TYPE_PREVOTE, [1, 2])) is None
+    else:
+        assert ps.prs.prevotes is None
+
+
+def test_wrong_sized_has_votes_array_is_ignored_without_a_peer_error():
+    """An array of another size than the mirror's (a peer at another
+    validator set, or one that lies) marks nothing and is no reason to
+    drop the peer: the votes still dedup the hard way."""
+
+    class _Switch:
+        stopped: list = []
+
+        def stop_peer_for_error(self, peer, reason):
+            self.stopped.append(reason)
+
+    r, peer, ps = _reactor_with_peer(gossip_dedup=True)
+    r.switch = _Switch()
+    for size in (3, 5, 0):
+        r.receive(STATE_CHANNEL, peer, _enc(
+            _has_votes(5, 0, VOTE_TYPE_PREVOTE, range(size), size=size)))
+    assert r.has_votes_applied == 0
+    assert ps.prs.prevotes.is_empty() and ps.prs.prevotes.size == 4
+    assert r.switch.stopped == []
+    assert r._has_vote_lag is None
+    # the right size still lands afterwards
+    r.receive(STATE_CHANNEL, peer, _enc(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [3])))
+    assert r.has_votes_applied == 1 and ps.prs.prevotes.indices() == [3]
+
+
+def test_has_vote_lag_takes_one_sample_a_message():
+    """One announcement, one sample of the lag the relay hold follows:
+    the OLDEST receipt among the bits the message newly set (what the
+    hold has to outlast), not one a bit, and none from a bit the mirror
+    held already."""
+    import time as _time
+
+    r, peer, ps = _reactor_with_peer(gossip_dedup=True)
+    ps.ensure_vote_bit_arrays(5, 4)
+    ps.set_has_vote(5, 0, VOTE_TYPE_PREVOTE, 3)
+    now = _time.monotonic()
+    for index, age in ((1, 3.0), (2, 1.0), (3, 50.0)):
+        r.con_s.vote_recv_mono[(5, 0, VOTE_TYPE_PREVOTE, index)] = now - age
+
+    r.receive(STATE_CHANNEL, peer, _enc(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [1, 2, 3])))
+    assert r.has_votes_applied == 3
+    assert r._has_vote_lag == pytest.approx(3.0, abs=0.5)  # first sample: as read
+    # nothing newly set, or a vote we never received from a peer: no sample
+    lag = r._has_vote_lag
+    r.receive(STATE_CHANNEL, peer, _enc(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [1, 2])))
+    r.receive(STATE_CHANNEL, peer, _enc(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [0])))
+    assert r._has_vote_lag == lag
+    # the next sample moves the average by a tenth, once
+    r.con_s.vote_recv_mono[(5, 0, VOTE_TYPE_PRECOMMIT, 0)] = _time.monotonic() - 13.0
+    r.con_s.vote_recv_mono[(5, 0, VOTE_TYPE_PRECOMMIT, 1)] = _time.monotonic() - 13.0
+    r.receive(STATE_CHANNEL, peer, _enc(_has_votes(5, 0, VOTE_TYPE_PRECOMMIT, [0, 1])))
+    assert r._has_vote_lag == pytest.approx(0.9 * lag + 0.1 * 13.0, abs=0.2)
+
+
+def test_pending_bits_are_announced_exactly_once_under_contention(monkeypatch):
+    """The pending set is shared by the thread that adds votes, the
+    timer and the step broadcasts that flush it: with more threads than
+    cores and a short switch interval, every vote noted is announced in
+    exactly one message (a lost update would drop a bit, a flush that
+    raced another would send one twice)."""
+    import sys
+    import threading
+    from types import SimpleNamespace
+
+    from tendermint_tpu.consensus import reactor as reactor_mod
+
+    monkeypatch.setattr(reactor_mod, "VOTE_RELAY_DELAY_MIN", 0.001)
+    n, rounds, workers = 1024, 4, 16
+    r = ConsensusReactor(_ConState(height=5))
+    r.con_s._rs = _RoundState(5, n=n)
+    sent: list = []
+    r.switch = SimpleNamespace(broadcast=lambda ch, raw: sent.append(raw))
+    flushing, done = threading.Event(), threading.Event()
+
+    def note(worker: int) -> None:
+        flushing.wait(30)
+        for index in range(worker, n, workers):
+            for round_ in range(rounds):
+                r._note_has_vote(SimpleNamespace(
+                    height=5, round_=round_, type_=VOTE_TYPE_PREVOTE,
+                    validator_index=index))
+            # as the consensus thread does before a step, beside the
+            # timer's flushes and the other thread's
+            r._flush_has_votes()
+
+    def flush_as_a_step_does() -> None:
+        while not done.is_set():
+            r._flush_has_votes()
+            flushing.set()
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        flusher = threading.Thread(target=flush_as_a_step_does)
+        noters = [threading.Thread(target=note, args=(w,)) for w in range(workers)]
+        flusher.start()
+        for t in noters:
+            t.start()
+        for t in noters:
+            t.join(30)
+        done.set()
+        flusher.join(30)
+        assert not flusher.is_alive() and not any(t.is_alive() for t in noters)
+    finally:
+        sys.setswitchinterval(old)
+        r._announce_wake.stop()
+    r._flush_has_votes()
+    import json
+
+    seen = {round_: [] for round_ in range(rounds)}
+    for raw in sent:
+        m = msgs.msg_from_json(json.loads(raw.decode()))
+        assert (m.height, m.type_, m.votes.size) == (5, VOTE_TYPE_PREVOTE, n)
+        seen[m.round_].extend(m.votes.indices())
+    for round_ in range(rounds):
+        assert sorted(seen[round_]) == list(range(n)), round_
+    assert r.gossip_announce_bits == n * rounds
 
 
 def test_has_block_part_announcement_marks_mirror():
@@ -501,12 +688,22 @@ def test_wake_ups_do_not_raise_the_duplicate_ratio_on_live_net(tmp_path):
         sends = sum(r.gossip_sends for r in reactors)
         backstop_sends = sum(r.gossip_backstop_sends for r in reactors)
         woken = sum(r.gossip_wakes_event for r in reactors)
+        announces = sum(r.gossip_announces_sent for r in reactors)
+        announced = sum(r.gossip_announce_bits for r in reactors)
+        applied = sum(r.has_votes_applied for r in reactors)
     finally:
         net.stop()
     assert acc >= 4 * 2 * 9 * 3
     assert dups / acc <= 0.34, f"duplicate ratio {dups}/{acc} above the parent's"
     assert sends > 0 and woken > 0
     assert backstop_sends <= 0.05 * sends, (backstop_sends, sends)
+    # the announcements coalesce (PR 31): one HasVote a peer a vote was
+    # more than vote_accepted x 3 peers messages (own votes on top);
+    # every vote is still announced, and the bits reach the mirrors
+    assert 0 < announces < acc * 3, (announces, acc)
+    assert announced >= acc
+    assert announced > announces / 3, "a flush held one vote on average"
+    assert applied > 0
 
 
 @pytest.mark.slow

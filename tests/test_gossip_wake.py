@@ -142,11 +142,25 @@ class _Peer:
         return True
 
 
+class _Switch:
+    """Switch.broadcast over the net's stub peers."""
+
+    def __init__(self, peers: list):
+        self.peers = peers
+
+    def broadcast(self, ch, raw):
+        for p in self.peers:
+            p.try_send(ch, raw)
+
+
 class _Net:
     """One reactor over a stub consensus state, with stub peers whose
-    mirrors sit at our height and round."""
+    mirrors sit at our height and round. `switch` gives the reactor one
+    to broadcast on (without it, as in a harness reactor, a broadcast
+    is a no-op)."""
 
-    def __init__(self, n_peers: int = 1, at_our_height: bool = True):
+    def __init__(self, n_peers: int = 1, at_our_height: bool = True,
+                 switch: bool = False):
         self.cs = _ConState()
         self.r = ConsensusReactor(self.cs)
         self.r._started = True  # the routines guard on is_running()
@@ -154,6 +168,8 @@ class _Net:
         self.evsw.start()
         self.r.set_event_switch(self.evsw)
         self.peers = [_Peer(f"peer-{i:04d}") for i in range(n_peers)]
+        if switch:
+            self.r.switch = _Switch(self.peers)
         for p in self.peers:
             self.r.add_peer(p)
             if at_our_height:
@@ -299,6 +315,8 @@ def _receive_case(what: str):
     parts = PartSet.from_data(b"block" * 8, 64)
     return {
         "has_vote": (STATE_CHANNEL, msgs.HasVoteMessage(HEIGHT, 0, VOTE_TYPE_PREVOTE, 3)),
+        "has_votes": (STATE_CHANNEL, msgs.HasVotesMessage(
+            HEIGHT, 0, VOTE_TYPE_PREVOTE, BitArray.from_indices(4, [1, 3]))),
         "has_block_part": (STATE_CHANNEL, msgs.HasBlockPartMessage(HEIGHT, 0, 0)),
         "vote": (VOTE_CHANNEL, msgs.VoteMessage(vote)),
         "block_part": (DATA_CHANNEL, msgs.BlockPartMessage(HEIGHT, 0, parts.get_part(0))),
@@ -316,15 +334,16 @@ def _receive_case(what: str):
     "what,woken",
     [
         # only ever take away from what is sendable to the peer
-        ("has_vote", 0), ("has_block_part", 0), ("vote", 0), ("block_part", 0),
+        ("has_vote", 0), ("has_votes", 0), ("has_block_part", 0), ("vote", 0),
+        ("block_part", 0),
         # can add to it: that peer's two routines, nobody else's
         ("commit_step", 2), ("proposal_pol", 2), ("vote_set_bits", 2),
         ("vote_set_maj23", 2),
     ],
 )
 def test_a_peers_message_wakes_its_routines_only_if_it_can_add(net_factory, what, woken):
-    """HasVote, HasBlockPart and a received vote's or part's own mirror
-    bit only REDUCE what is sendable: no wake. What the peer asks for or
+    """HasVote, its burst form, HasBlockPart and a received vote's or
+    part's own mirror bit only REDUCE what is sendable: no wake. What the peer asks for or
     steps into wakes that peer's routines, and the other peers' sleep on
     (three peers here: six waits would end if every routine woke)."""
     net = net_factory(n_peers=3)
@@ -477,6 +496,89 @@ def test_on_stop_ends_every_peers_routines(net_factory):
     for t in gossip:
         t.join(SOON)
     assert not any(t.is_alive() for t in gossip)
+
+
+def _announced(peer) -> list:
+    """What `peer` was told we hold: (key, indices) of each HasVotesMessage."""
+    return [((m.height, m.round_, m.type_), m.votes.indices())
+            for _t, m in peer.of(msgs.HasVotesMessage)]
+
+
+def test_a_burst_of_votes_is_one_announcement_a_peer(net_factory, monkeypatch):
+    """k votes that enter our vote set inside the delay are ONE
+    HasVotesMessage of k bits to each peer, VOTE_RELAY_DELAY_MIN after
+    the first of them, and no single HasVote; the set is emptied, so a
+    second burst is a second message that holds its own bits only. (The
+    delay is stretched so that a loaded machine cannot split a burst.)"""
+    monkeypatch.setattr(reactor_mod, "VOTE_RELAY_DELAY_MIN", 0.3)
+    net = net_factory(n_peers=3, switch=True)
+    net.settle()
+    pre, pc = net.cs.rs.votes.pre, net.cs.rs.votes.pc
+    t0 = time.monotonic()
+    for i in (0, 2, 3):
+        net.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(pre.add(i)))
+    key = (HEIGHT, 0, VOTE_TYPE_PREVOTE)
+    for p in net.peers:
+        assert p.wait_for(msgs.HasVotesMessage)
+        assert _announced(p) == [(key, [0, 2, 3])]
+        t_sent, sent = p.of(msgs.HasVotesMessage)[0]
+        assert sent.votes.size == 4
+        assert t_sent - t0 >= reactor_mod.VOTE_RELAY_DELAY_MIN, "sent before the burst was over"
+        assert not p.of(msgs.HasVoteMessage)
+    assert net.r.gossip_announces_sent == 3 and net.r.gossip_announce_bits == 3
+
+    # the second burst spans two vote sets: one message a key
+    net.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(pre.add(1)))
+    net.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(pc.add(2)))
+    for p in net.peers:
+        assert p.wait_for(msgs.HasVotesMessage, n=3)
+        assert _announced(p)[1:] == [
+            (key, [1]), ((HEIGHT, 0, VOTE_TYPE_PRECOMMIT), [2])]
+        assert not p.of(msgs.HasVoteMessage)
+    assert net.r.gossip_announces_sent == 9 and net.r.gossip_announce_bits == 5
+
+
+def test_a_step_broadcast_flushes_the_pending_bits_first(net_factory):
+    """A peer's apply_new_round_step resets the arrays of the round a
+    step ends, so the bits of that round go out BEFORE the step: on the
+    wire HasVotesMessage, then NewRoundStepMessage, without waiting out
+    the delay; the timer then finds nothing left to send."""
+    net = net_factory(n_peers=2, switch=True)
+    net.settle()
+    greeted = [len(p.sent) for p in net.peers]  # add_peer sent our step
+    vote = net.cs.rs.votes.pre.add(1)
+    net.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(vote))
+    net.cs.rs.step = RoundStep.PRECOMMIT
+    net.evsw.fire_event(tev.EVENT_NEW_ROUND_STEP, None)
+    for p, n in zip(net.peers, greeted):
+        # both were sent inline by the two fire_event calls above
+        order = [type(m) for _t, ch, m in p.sent[n:] if ch == STATE_CHANNEL]
+        assert order == [msgs.HasVotesMessage, msgs.NewRoundStepMessage]
+    time.sleep(2 * reactor_mod.VOTE_RELAY_DELAY_MIN)
+    for p in net.peers:
+        assert _announced(p) == [((HEIGHT, 0, VOTE_TYPE_PREVOTE), [1])]
+    assert net.r.gossip_announces_sent == 2 and net.r.gossip_announce_bits == 1
+
+
+def test_a_harness_reactor_without_a_switch_keeps_no_bits(net_factory):
+    """No switch, nobody to tell: nothing pends and no timer starts."""
+    net = net_factory()
+    net.evsw.fire_event(
+        tev.EVENT_VOTE, tev.EventDataVote(net.cs.rs.votes.pre.add(0)))
+    assert net.r._announce_pending == {}
+    assert net.r._announce_wake._thread is None
+
+
+def test_on_stop_ends_the_announcement_timer(net_factory):
+    net = net_factory(switch=True)
+    net.evsw.fire_event(
+        tev.EVENT_VOTE, tev.EventDataVote(net.cs.rs.votes.pre.add(0)))
+    timer = net.r._announce_wake._thread
+    assert timer is not None and timer.is_alive()
+    net.cs.stop = lambda: None
+    net.r.on_stop()
+    timer.join(SOON)
+    assert not timer.is_alive()
 
 
 def test_a_burst_of_vote_events_is_a_handful_of_wakes(net_factory):

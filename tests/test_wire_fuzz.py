@@ -45,8 +45,8 @@ def _rand_json(rng, depth=0):
 
 MSG_TYPES = [
     "new_round_step", "commit_step", "proposal", "proposal_pol",
-    "block_part", "vote", "has_vote", "vote_set_maj23", "vote_set_bits",
-    "heartbeat",
+    "block_part", "vote", "has_vote", "has_votes", "vote_set_maj23",
+    "vote_set_bits", "heartbeat",
 ]
 
 
@@ -85,6 +85,9 @@ def _valid_messages():
         {"type": "proposal_pol",
          "data": {"height": 1, "proposal_pol_round": 0,
                   "proposal_pol": {"bits": 4, "elems": "f"}}},
+        {"type": "has_votes",
+         "data": {"height": 2, "round": 1, "type": 2,
+                  "votes": {"bits": 16, "elems": "a005"}}},
     ]
     return msgs
 
