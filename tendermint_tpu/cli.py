@@ -84,6 +84,10 @@ def cmd_node(args) -> int:
         cfg.p2p.pex_reactor = True
     if args.addr_book_strict is not None:
         cfg.p2p.addr_book_strict = args.addr_book_strict == "true"
+    for attr in ("test_link_region", "test_link_rtt_ms"):
+        v = getattr(args, attr)
+        if v is not None:
+            setattr(cfg.p2p, attr, v)
 
     # TENDERMINT_RACECHECK=1 == running the reference under `go test -race`:
     # every lock the node builds joins a process-wide order graph, reported
@@ -297,6 +301,15 @@ def build_parser() -> argparse.ArgumentParser:
         choices=["true", "false"],
         help="only store globally-routable peer addresses (turn off for "
         "loopback testnets; p2p/addrbook.py routability)",
+    )
+    sp.add_argument(
+        "--p2p.test_link_region", dest="test_link_region", default=None,
+        help="test option: the region this node is in (p2p/delay_line.py)",
+    )
+    sp.add_argument(
+        "--p2p.test_link_rtt_ms", dest="test_link_rtt_ms", default=None,
+        help="test option: round-trip times between regions, "
+        "'a:a=1,a:b=90,b:b=1'; every link of the node is delayed by half",
     )
     sp.add_argument("--log_level", default="info")
     sp.add_argument("--db_backend", default=None, help="sqlite | filedb | memdb")

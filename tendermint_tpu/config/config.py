@@ -92,6 +92,14 @@ class P2PConfig:
     max_msg_packet_payload_size: int = 1024
     send_rate: int = 512_000  # bytes/sec (p2p/connection.go:33-34)
     recv_rate: int = 512_000
+    # test options, as upstream's test_fuzz is: a constant one-way delay
+    # on every link, inside this node's own p2p stack
+    # (p2p/delay_line.py). test_link_region is the region this node is
+    # in; test_link_rtt_ms the round-trip times between regions, the same
+    # on every node: "a:a=1,a:b=90,b:b=1" (each pair once, a region with
+    # itself included). Both empty: no delay line, no thread.
+    test_link_region: str = ""
+    test_link_rtt_ms: str = ""
 
     def addr_book(self) -> str:
         return _root_join(self.root_dir, self.addr_book_file)

@@ -68,8 +68,14 @@ VOTE_RELAY_DELAY = PEER_GOSSIP_SLEEP
 # one leg each way too), clamped to [0.5x, 4x] of the constant so a
 # garbage sample can neither disable the hold nor stall relays. The
 # constant remains the exact no-sample fallback.
+# Round 32: the RTT is the PEER'S OWN (one smoothed value over a mesh
+# whose links are 1-312 ms was wrong for every link of it), a link has
+# its first sample as it starts (p2p/conn.py pings at once), and the
+# upper clamp is a fixed second: above twice the longest round trip two
+# public-cloud regions have (2 x 312 ms), so that the clamp bounds a
+# garbage sample and never a real link.
 VOTE_RELAY_DELAY_MIN = 0.5 * VOTE_RELAY_DELAY
-VOTE_RELAY_DELAY_MAX = 4.0 * VOTE_RELAY_DELAY
+VOTE_RELAY_DELAY_MAX = 1.0
 
 PEER_STATE_KEY = "ConsensusReactor.peerState"
 
@@ -154,29 +160,39 @@ class _PeerGossip:
         self.wake()  # the stop must also end a routine's wait
 
 
-class _DeferredWake:
-    """One timer for the whole reactor: `at(deadline)` asks for ONE call
-    of `fire` at that instant (time.monotonic). A call asked for while
-    one is pending rides it (the earlier instant stands), so a burst of
-    31 votes is one wake of the votes routines when the first hold ends,
-    not 31. Its thread starts with the first call and ends with
-    `stop()`."""
+class _DeferredWakes:
+    """One timer for the whole reactor: `at(fire, deadline)` asks for ONE
+    call of `fire` at that instant (time.monotonic). A call asked for
+    while the same `fire` is pending rides it (the earlier instant
+    stands), so a burst of 31 votes is one wake of a peer's votes routine
+    when its first hold ends, and one announcement when the burst's first
+    bit has waited its time, not 31 of either. The calls are made on the
+    timer's thread, outside its lock; the thread starts with the first
+    call and ends with `stop()`."""
 
-    def __init__(self, fire):
-        self._fire = fire
+    def __init__(self):
         self._cond = threading.Condition()
-        self._due: float | None = None
+        self._due: dict = {}   # fire -> deadline
         self._stopped = False
         self._thread: threading.Thread | None = None
 
-    def at(self, deadline: float) -> None:
+    def at(self, fire, deadline: float) -> None:
+        self.at_many(((fire, deadline),))
+
+    def at_many(self, calls) -> None:
         with self._cond:
-            if self._stopped or self._due is not None:
+            if self._stopped:
                 return
-            self._due = deadline
+            fresh = False
+            for fire, deadline in calls:
+                if fire not in self._due:
+                    self._due[fire] = deadline
+                    fresh = True
+            if not fresh:
+                return
             if self._thread is None:
                 self._thread = threading.Thread(
-                    target=self._run, daemon=True, name="conR.relayWake")
+                    target=self._run, daemon=True, name="conR.deferredWakes")
                 self._thread.start()
             self._cond.notify()
 
@@ -188,19 +204,29 @@ class _DeferredWake:
     def _run(self) -> None:
         with self._cond:
             while not self._stopped:
-                if self._due is None:
+                if not self._due:
                     self._cond.wait()
                     continue
-                left = self._due - time.monotonic()
+                now = time.monotonic()
+                left = min(self._due.values()) - now
                 if left > 0:
                     self._cond.wait(left)
                     continue
-                self._due = None
+                fire = [f for f, t in self._due.items() if t <= now]
+                for f in fire:
+                    del self._due[f]
                 self._cond.release()
                 try:
-                    self._fire()
+                    for f in fire:
+                        f()
                 finally:
                     self._cond.acquire()
+
+
+def _smoothed(old: float | None, sample: float) -> float:
+    """The has-vote lags' average: the first sample as read, then a tenth
+    of each new one."""
+    return sample if old is None else 0.9 * old + 0.1 * sample
 
 
 def _peer_label(peer) -> str:
@@ -240,6 +266,26 @@ class PeerState:
         # height, re-armed after a hold so a lost frame can't wedge the
         # peer — (height, monotonic send time) of the last send
         self._agg_commit_sent: tuple[int, float] | None = None
+        # smoothed seconds from our receipt of a vote to THIS peer's
+        # announcement of it (None until one was seen): what a relay to
+        # this peer has to outlast (ConsensusReactor._relay_delay)
+        self.has_vote_lag: float | None = None
+
+    def note_has_vote_lag(self, got: float | None) -> float | None:
+        """`got`: the vote_recv_mono stamp of a vote this peer has just
+        said it holds. Returns the lag it shows, for the reactor's own
+        average over all peers."""
+        if got is None:
+            return None
+        lag = time.monotonic() - got
+        self.has_vote_lag = _smoothed(self.has_vote_lag, lag)
+        return lag
+
+    def rtt_s(self) -> float | None:
+        """The link's smoothed ping round trip (None before a sample, and
+        for harness peers that measure none)."""
+        rtt = getattr(self.peer, "rtt_s", None)
+        return rtt() if rtt is not None else None
 
     # -- reads -------------------------------------------------------------
 
@@ -521,11 +567,11 @@ class ConsensusReactor(Reactor, BaseService):
         self.fast_sync = fast_sync
         self.evsw = None
         self._peer_threads: dict[str, list] = {}
-        self._peer_gossip: dict[str, _PeerGossip] = {}
+        self._peer_states: dict[str, PeerState] = {}
         # what wake_gossip walks: replaced whole under _mtx when a peer
         # comes or goes and read without it, so the consensus receive
         # routine (which fires the events) never waits for a lock
-        self._gossips: tuple[_PeerGossip, ...] = ()
+        self._states: tuple[PeerState, ...] = ()
         self._mtx = threading.Lock()
         # has-vote-aware gossip dedup (round 20): when on, STATE-channel
         # HasVotes ensure the tracking arrays before applying (a fresh
@@ -563,7 +609,12 @@ class ConsensusReactor(Reactor, BaseService):
         self.gossip_announce_bits = 0
         # default_set_proposal fires no event: it calls this
         consensus_state.gossip_wake = self.wake_gossip
-        self._relay_wake = _DeferredWake(self.wake_votes_gossip)
+        # relay holds' ends and the announcement's instant: one thread
+        self._wakes = _DeferredWakes()
+        # smoothed seconds from our receipt of a vote to ANY peer's
+        # announcement of it (None until one was seen); each peer keeps
+        # its own beside it (PeerState.has_vote_lag)
+        self._has_vote_lag: float | None = None
         # votes that entered our vote set and no peer was told of yet:
         # (height, round, type) -> [validators, mask]. One announcement
         # a key goes to every peer VOTE_RELAY_DELAY_MIN after the first
@@ -574,10 +625,6 @@ class ConsensusReactor(Reactor, BaseService):
         self._announce_pending: dict[tuple[int, int, int], list[int]] = {}
         self._announce_mtx = threading.Lock()
         self._announce_order = threading.Lock()
-        self._announce_wake = _DeferredWake(self._flush_has_votes)
-        # smoothed seconds from our receipt of a vote to a peer's HasVote
-        # for it (None until one was seen)
-        self._has_vote_lag: float | None = None
 
     # -- wiring ------------------------------------------------------------
 
@@ -598,11 +645,8 @@ class ConsensusReactor(Reactor, BaseService):
             # says so with HasVote): waking 31 routines to find it held,
             # and again when its hold ends, is 62 thread switches a
             # vote; one deferred wake at the hold's end serves the burst
-            hold = self._relay_hold(d.vote)
-            if hold > 0:
-                self._relay_wake.at(time.monotonic() + hold)
-            else:
-                self.wake_votes_gossip()
+            # the burst. The hold is each peer's own (_relay_delay).
+            self._wake_for_vote(d.vote)
             self._note_has_vote(d.vote)
 
         def on_part(d):
@@ -672,8 +716,8 @@ class ConsensusReactor(Reactor, BaseService):
             )
             threads.append(t)
         with self._mtx:
-            self._peer_gossip[peer.id()] = gw
-            self._gossips = tuple(self._peer_gossip.values())
+            self._peer_states[peer.id()] = ps
+            self._states = tuple(self._peer_states.values())
             self._peer_threads[peer.id()] = threads
         for t in threads:
             t.start()
@@ -684,28 +728,54 @@ class ConsensusReactor(Reactor, BaseService):
 
     def remove_peer(self, peer, reason) -> None:
         with self._mtx:
-            gw = self._peer_gossip.pop(peer.id(), None)
-            self._gossips = tuple(self._peer_gossip.values())
+            ps = self._peer_states.pop(peer.id(), None)
+            self._states = tuple(self._peer_states.values())
             self._peer_threads.pop(peer.id(), None)
-        if gw:
-            gw.end()
+        if ps:
+            ps.gossip.end()
 
     def wake_gossip(self) -> None:
         """Our own round state changed (a step, the proposal): every
         peer's routines look again now. O(peers) flag sets, no lock,
         never blocks — this runs on the consensus receive routine."""
-        for gw in self._gossips:
-            gw.wake()
+        for ps in self._states:
+            ps.gossip.wake()
 
     def wake_votes_gossip(self) -> None:
         """A vote entered our round state: the votes routines alone."""
-        for gw in self._gossips:
-            gw.wake_votes()
+        for ps in self._states:
+            ps.gossip.wake_votes()
 
     def wake_data_gossip(self) -> None:
         """A part entered our round state: the data routines alone."""
-        for gw in self._gossips:
-            gw.wake_data()
+        for ps in self._states:
+            ps.gossip.wake_data()
+
+    def _wake_for_vote(self, vote) -> None:
+        """Wake each peer's votes routine when `vote` may go to it: now
+        for our own vote, at the end of the peer's own hold for one we
+        received. The mean hold applied goes onto the height's trace
+        (aux relay_hold_s over relay_holds). Receive routine only."""
+        got = self.con_s.vote_recv_mono.get(
+            (vote.height, vote.round_, vote.type_, vote.validator_index)
+        ) if self.gossip_dedup else None
+        states = self._states
+        if got is None or not states:
+            self.wake_votes_gossip()
+            return
+        now = time.monotonic()
+        later, total = [], 0.0
+        for ps in states:
+            delay = self._relay_delay(ps)
+            total += delay
+            if got + delay > now:
+                later.append((ps.gossip.wake_votes, got + delay))
+            else:
+                ps.gossip.wake_votes()
+        if later:
+            self._wakes.at_many(later)
+        self.con_s.trace.note("relay_hold_s", total / len(states))
+        self.con_s.trace.note("relay_holds", 1)
 
     def receive(self, ch_id: int, peer, msg_bytes: bytes) -> None:
         """reactor.go:159-302."""
@@ -736,7 +806,7 @@ class ConsensusReactor(Reactor, BaseService):
                     self._ensure_vote_bit_arrays(ps)
                 if ps.apply_has_vote(msg, allow_last_commit=self.gossip_dedup):
                     self.has_votes_applied += 1
-                self._note_has_vote_lag(self.con_s.vote_recv_mono.get(
+                self._note_has_vote_lag(ps, self.con_s.vote_recv_mono.get(
                     (msg.height, msg.round_, msg.type_, msg.index)))
             elif isinstance(msg, msgs.HasVotesMessage):
                 # a burst's worth of HasVote: one ensure, one lock, and
@@ -748,7 +818,7 @@ class ConsensusReactor(Reactor, BaseService):
                 self.has_votes_applied += landed
                 stamps = self.con_s.vote_recv_mono
                 key = (msg.height, msg.round_, msg.type_)
-                self._note_has_vote_lag(min(
+                self._note_has_vote_lag(ps, min(
                     (t for t in (stamps.get(key + (i,)) for i in fresh)
                      if t is not None), default=None))
             elif isinstance(msg, msgs.HasBlockPartMessage):
@@ -832,15 +902,13 @@ class ConsensusReactor(Reactor, BaseService):
         ps.ensure_vote_bit_arrays(rs.height, size)
         ps.ensure_vote_bit_arrays(rs.height - 1, last_size)
 
-    def _note_has_vote_lag(self, got: float | None) -> None:
+    def _note_has_vote_lag(self, ps: PeerState, got: float | None) -> None:
         """How long after WE received a vote (`got`, its vote_recv_mono
         stamp) a peer says it has it: what a relay's hold has to outlast
-        (_relay_delay)."""
-        if got is None:
-            return
-        lag = time.monotonic() - got
-        old = self._has_vote_lag
-        self._has_vote_lag = lag if old is None else 0.9 * old + 0.1 * lag
+        (_relay_delay), kept for that peer and over all of them."""
+        lag = ps.note_has_vote_lag(got)
+        if lag is not None:
+            self._has_vote_lag = _smoothed(self._has_vote_lag, lag)
 
     def _screen_agg_commit(self, peer, msg: msgs.AggregateCommitMessage) -> bool:
         """Verify a received aggregate catchup commit on the peer thread
@@ -907,10 +975,9 @@ class ConsensusReactor(Reactor, BaseService):
 
     def on_stop(self) -> None:
         self.con_s.stop()
-        self._relay_wake.stop()
-        self._announce_wake.stop()
-        for gw in self._gossips:
-            gw.end()
+        self._wakes.stop()
+        for ps in self._states:
+            ps.gossip.end()
 
     def switch_to_consensus(self, state) -> None:
         """Fast sync complete (reactor.go:78-90). Note: update BEFORE
@@ -983,7 +1050,8 @@ class ConsensusReactor(Reactor, BaseService):
             slot = self._announce_pending.setdefault(key, [vals.size(), 0])
             slot[1] |= 1 << vote.validator_index
         if first:
-            self._announce_wake.at(time.monotonic() + VOTE_RELAY_DELAY_MIN)
+            self._wakes.at(self._flush_has_votes,
+                           time.monotonic() + VOTE_RELAY_DELAY_MIN)
 
     def _flush_has_votes(self) -> None:
         """Every key's pending bits go out as one HasVotesMessage to
@@ -993,7 +1061,7 @@ class ConsensusReactor(Reactor, BaseService):
         with self._announce_order:
             with self._announce_mtx:
                 pending, self._announce_pending = self._announce_pending, {}
-            peers = len(self._gossips)
+            peers = len(self._states)
             for (height, round_, type_), (size, mask) in pending.items():
                 votes = BitArray.from_int(size, mask)
                 self.switch.broadcast(STATE_CHANNEL, _enc(msgs.HasVotesMessage(
@@ -1230,39 +1298,37 @@ class ConsensusReactor(Reactor, BaseService):
             fr.record("gossip_send_fail", peer=_peer_label(peer))
         return False
 
-    def _relay_delay(self) -> float:
-        """The current lazy-relay hold: RTT-adaptive when the switch's
-        registry carries ping RTT samples (adaptive_relay_delay), the
-        VOTE_RELAY_DELAY constant otherwise — including for harness
-        reactors with no switch at all."""
-        reg = getattr(getattr(self, "switch", None), "metrics_registry",
-                      None)
-        if reg is None:
-            hold = VOTE_RELAY_DELAY
-        else:
-            from tendermint_tpu.p2p.telemetry import peer_metrics
-
-            hold = adaptive_relay_delay(
-                peer_metrics(reg)["ping_rtt_ewma"].value())
-        # a ping every 40 s says what the link costs, not how far behind
-        # a peer's process runs: on a host with fewer cores than
-        # validators the HasVotes came 0.1-0.3 s after the vote, every
-        # relay fired inside that, and a node received 3.5 duplicates for
-        # each vote it accepted (PERF.md, PR 27). So the hold also
-        # outlasts twice the lag the HasVotes are seen to have.
-        lag = self._has_vote_lag
-        if lag is not None:
-            hold = min(VOTE_RELAY_DELAY_MAX, max(hold, 2.0 * lag))
+    def _relay_delay(self, ps: PeerState) -> float:
+        """The lazy-relay hold of votes for ONE peer: RTT-adaptive from
+        that link's own ping samples (adaptive_relay_delay; the
+        VOTE_RELAY_DELAY constant before the first, and for harness peers
+        that measure none). A net across datacenters has links of 1 ms
+        and of 312 ms at one node: the announcement that makes a relay
+        needless comes back over the link the relay would take."""
+        hold = adaptive_relay_delay(ps.rtt_s())
+        # a ping says what the link costs, not how far behind a peer's
+        # process runs: on a host with fewer cores than validators the
+        # HasVotes came 0.1-0.3 s after the vote, every relay fired
+        # inside that, and a node received 3.5 duplicates for each vote
+        # it accepted (PERF.md, PR 27). So the hold also outlasts twice
+        # the lag the announcements are seen to have: this peer's own
+        # (across datacenters it is the link's round trip and a little)
+        # or all peers' (on one host a peer's lag is the scheduler's
+        # mood of the moment, and its own average alone let twice the
+        # duplicates through: PERF.md, PR 32), whichever is longer.
+        lags = [x for x in (ps.has_vote_lag, self._has_vote_lag)
+                if x is not None]
+        if lags:
+            hold = min(VOTE_RELAY_DELAY_MAX, max(hold, 2.0 * max(lags)))
         return hold
 
-    def _relay_hold(self, vote, delay: float | None = None) -> float:
+    def _relay_hold(self, vote, delay: float) -> float:
         """The lazy-relay screen: seconds a re-push of `vote` is still
-        held (0.0: relay now). A vote we received less than
-        _relay_delay() ago (VOTE_RELAY_DELAY, RTT-adapted when samples
-        exist) is held; unstamped votes — our own, and store-backed
-        catchup commits — relay immediately. A held vote stays pickable
-        and goes out when its hold ends if the peer's mirror bit is
-        still clear then."""
+        held (0.0: relay now), `delay` being the peer's _relay_delay. A
+        vote we received less than that ago is held; unstamped votes —
+        our own, and store-backed catchup commits — relay immediately. A
+        held vote stays pickable and goes out when its hold ends if the
+        peer's mirror bit is still clear then."""
         if not self.gossip_dedup:
             return 0.0
         t = self.con_s.vote_recv_mono.get(
@@ -1270,8 +1336,6 @@ class ConsensusReactor(Reactor, BaseService):
         )
         if t is None:
             return 0.0
-        if delay is None:
-            delay = self._relay_delay()
         return max(0.0, t + delay - time.monotonic())
 
     def _pick_and_send_vote(self, peer, ps: PeerState, rs,
@@ -1283,7 +1347,7 @@ class ConsensusReactor(Reactor, BaseService):
         them may go — what the routine waits, instead of a whole
         back-stop on top of the hold."""
         hold_s: float | None = None
-        delay = self._relay_delay() if self.gossip_dedup else 0.0
+        delay = self._relay_delay(ps) if self.gossip_dedup else 0.0
 
         def hold_of(vote) -> float:
             nonlocal hold_s

@@ -880,6 +880,7 @@ class ConsensusState(BaseService):
         self._schedule_timeout(self.config.propose(round_), height, round_, RoundStep.PROPOSE)
 
         if self.priv_validator is not None and self.is_proposer():
+            self.trace.mark_arrival("propose_as_proposer")
             self.decide_proposal(height, round_)
         defer_()
 
@@ -1329,6 +1330,9 @@ class ConsensusState(BaseService):
         # full block are in hand; the fleet aggregator reads commit skew
         # off this instant across nodes
         self.trace.mark_arrival("commit")
+        self.trace.note("last_commit_precommits", sum(
+            1 for pc in getattr(block.last_commit, "precommits", None) or ()
+            if pc is not None))
         # trace: the commit-wait segment ends here; the finalize
         # sub-phases (save -> apply -> snapshot hook -> events, or
         # save -> submit when pipelined) partition the rest of the
@@ -1812,6 +1816,9 @@ class ConsensusState(BaseService):
                 return False
             added = self._split_add(rs.last_commit, vote, peer_id=peer_id)
             if added:
+                # a precommit that came after we committed: it joins the
+                # LastCommit this height's block will carry
+                self.trace.note("last_commit_late_precommits", 1)
                 self.logger.info("added to last_commit: %r", rs.last_commit)
                 self._fire(tev.EVENT_VOTE, tev.EventDataVote(vote))
                 if self.config.skip_timeout_commit and rs.last_commit.has_all():
@@ -2007,6 +2014,8 @@ class ConsensusState(BaseService):
             if not self.replay_mode:
                 self.logger.exception("error signing vote %d/%d", rs.height, rs.round_)
             return None
+        self.trace.mark_arrival(
+            "own_prevote" if type_ == VOTE_TYPE_PREVOTE else "own_precommit")
         self.send_internal_message(MsgInfo(msgs.VoteMessage(vote)))
         self.logger.info("signed and pushed vote %r", vote)
         return vote

@@ -85,9 +85,14 @@ def step_segment(step: int) -> str:
 # aggregator (ops/fleet.py) compares them ACROSS nodes to reconstruct
 # proposer->peer propagation lag, quorum-formation time, and commit skew
 ARRIVALS = (
+    "propose_as_proposer",  # this node entered `propose` as the round's
+                         # proposer (the first such round's instant): what
+                         # a height's quorum-arrival floor counts from
     "proposal",          # proposal message accepted
     "first_block_part",  # first proposal part added (build or gossip)
+    "own_prevote",       # our prevote signed
     "prevote_quorum",    # +2/3 prevotes for a block observed
+    "own_precommit",     # our precommit signed
     "precommit_quorum",  # +2/3 precommits for a block observed
     "commit",            # finalize began (quorum AND full block held)
 )
@@ -288,6 +293,13 @@ class TraceRecorder:
         for k, v in overlay.items():
             self._aux[k] = self._aux.get(k, 0.0) + v
         self._aux["cpu_s"] = time.process_time() - self._cpu0
+        # own vote cast to more than 2/3 seen: what a vote round waits
+        # for the net (a link's delay shows here, not in a segment)
+        for phase in ("prevote", "precommit"):
+            cast = self._arrivals.get(f"own_{phase}")
+            seen = self._arrivals.get(f"{phase}_quorum")
+            if cast is not None and seen is not None:
+                self._aux[f"{phase}_quorum_wait_s"] = max(0.0, seen - cast)
         end = self._probe()
         self._dev_carry = end  # the next begin() starts from this reading
         start = self._dev_start

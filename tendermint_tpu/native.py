@@ -93,6 +93,44 @@ def available() -> bool:
     return get_lib() is not None
 
 
+_delay_line = None
+
+
+def delay_line_api():
+    """The entry points of native/src/delay_line.cc as (lib, pylib), or
+    None where the library cannot be had. `lib` releases the interpreter
+    lock around a call (new, free, add_link, close_link: they start or
+    wait for a thread), `pylib` keeps it (put, stats: a mutex and a copy,
+    cheaper than handing the lock over and taking it back)."""
+    global _delay_line
+    lib = get_lib()
+    if lib is None or not hasattr(lib, "tm_delay_line_new"):
+        return None
+    with _lib_mtx:
+        if _delay_line is None:
+            pylib = ctypes.PyDLL(_LIB_PATH)
+            vp, ci = ctypes.c_void_p, ctypes.c_int
+            lib.tm_delay_line_new.argtypes = []
+            lib.tm_delay_line_new.restype = vp
+            lib.tm_delay_line_free.argtypes = [vp]
+            lib.tm_delay_line_free.restype = None
+            lib.tm_delay_line_add_link.argtypes = [vp, ci]
+            lib.tm_delay_line_add_link.restype = ci
+            lib.tm_delay_line_close_link.argtypes = [vp, ci]
+            lib.tm_delay_line_close_link.restype = None
+            pylib.tm_delay_line_put.argtypes = [
+                vp, ci, ctypes.c_double, ctypes.c_char_p, ctypes.c_uint64]
+            pylib.tm_delay_line_put.restype = ci
+            pylib.tm_delay_line_stats.argtypes = [
+                vp, ci, ctypes.POINTER(ctypes.c_double)]
+            pylib.tm_delay_line_stats.restype = None
+            lib.tm_delay_line_late_edges.argtypes = [
+                ctypes.POINTER(ctypes.c_double), ci]
+            lib.tm_delay_line_late_edges.restype = ci
+            _delay_line = (lib, pylib)
+    return _delay_line
+
+
 def ready() -> bool:
     """available() WITHOUT triggering a build: True only when the library
     is already loaded or the prebuilt .so is current. Hot paths (the

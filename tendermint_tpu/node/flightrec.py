@@ -57,8 +57,10 @@ Dumps are JSON files under ``<node home>/flightrec/`` named
 ``dump-<utc>-<reason>.json``: the event ring, the trigger, a counter
 snapshot (p2p gossip totals + consensus position via ``counters_fn``,
 wired by node/node.py) so picks-vs-sends is readable without a second
-artifact, and ``consensus_traces``: the per-height traces' ring as the
-``consensus_trace`` RPC serves it (``traces_fn``, wired the same way).
+artifact, ``consensus_traces``: the per-height traces' ring as the
+``consensus_trace`` RPC serves it (``traces_fn``, wired the same way),
+and ``links``: one record a peer (``links_fn``: ping round trips, the
+relay hold, the delay line's counters where one is configured).
 
 ``record()`` is one enabled-check + one deque.append (GIL-atomic) — the
 TENDERMINT_FLIGHTREC_DISABLE kill switch makes it a single attribute
@@ -106,6 +108,10 @@ class FlightRecorder:
         # optional provider of the per-height consensus traces (newest
         # first, as the consensus_trace RPC serves them) for dumps
         self.traces_fn = None
+        # optional provider of one record a peer: the link's ping round
+        # trips, its relay hold, and its delay line's counters where
+        # `[p2p]` configures one (node/node.py)
+        self.links_fn = None
         self._watch_stop: threading.Event | None = None
 
     @property
@@ -237,6 +243,7 @@ class FlightRecorder:
             "counters": self._snapshot(self.counters_fn, {}),
             "events": self.events(),
             "consensus_traces": self._snapshot(self.traces_fn, []),
+            "links": self._snapshot(self.links_fn, []),
         }
         self.dumps += 1
         if self.dump_dir is None:

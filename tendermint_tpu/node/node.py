@@ -374,7 +374,10 @@ class Node(BaseService):
         self.blockchain_reactor.horizon_fallback = self._on_below_horizon
 
         # -- p2p switch (node.go:231-245) ---------------------------------
+        from tendermint_tpu.p2p.delay_line import LinkDelays
+
         peer_config = PeerConfig(
+            link_delays=LinkDelays.from_config(config.p2p),
             mconfig=MConnConfig(
                 send_rate=float(config.p2p.send_rate),
                 recv_rate=float(config.p2p.recv_rate),
@@ -485,6 +488,32 @@ class Node(BaseService):
         trace = self.consensus_state.trace
         self.flightrec.traces_fn = lambda: [
             t.to_json() for t in trace.last(trace.ring_size)]
+        self.flightrec.links_fn = self.link_records
+
+    def link_records(self) -> list[dict]:
+        """One record a peer for the flight recorder's dumps: who it is,
+        its ping round trips (count / min / last / smoothed), the relay
+        hold the consensus reactor applies to it now, and, where `[p2p]`
+        configures link delays, the delay line's counters of the link
+        (region, delay, frames, bytes, deepest queue, lateness)."""
+        from tendermint_tpu.consensus.reactor import PEER_STATE_KEY
+
+        out = []
+        for peer in self.sw.peers.list():
+            rec = {
+                "peer": peer.id(),
+                "moniker": peer.node_info.moniker if peer.node_info else "",
+                "rtt": peer.mconn.rtt_record(),
+            }
+            ps = peer.get(PEER_STATE_KEY)
+            if ps is not None:
+                rec["relay_hold_s"] = round(
+                    self.consensus_reactor._relay_delay(ps), 6)
+                rec["has_vote_lag_s"] = ps.has_vote_lag
+            if peer.link is not None:
+                rec["link"] = peer.link.stats()
+            out.append(rec)
+        return out
 
     # -- retention wiring --------------------------------------------------
 
@@ -671,6 +700,10 @@ class Node(BaseService):
                 f"commit_schedule={self.genesis_doc.schedule_string()}",
             ],
         )
+        delays = self.sw.peer_config.link_delays
+        if delays is not None:
+            # which end of the link table this node is (p2p/delay_line.py)
+            info.other.append(f"region={delays.region}")
         self.sw.set_node_info(info)
         if self.listener:
             self.addr_book.add_our_address(self.listener.external_address())

@@ -247,6 +247,17 @@ class MConnection(BaseService):
         else:
             pm.send_failure(ch.id)
 
+    def rtt_s(self) -> float | None:
+        """This link's smoothed ping round trip; None before a sample."""
+        pm = self._pm
+        return pm.rtt.value() if pm is not None else None
+
+    def rtt_record(self) -> dict | None:
+        """count / min / last / smoothed of this link's ping round trips
+        (telemetry.PeerRtt); None for an uninstrumented connection."""
+        pm = self._pm
+        return pm.rtt.record() if pm is not None else None
+
     def can_send(self, ch_id: int) -> bool:
         ch = self.channels.get(ch_id)
         return ch is not None and ch.send_queue_size() < ch.desc.send_queue_capacity
@@ -273,7 +284,11 @@ class MConnection(BaseService):
 
     def _send_routine(self) -> None:
         cfg = self.config
-        last_ping = time.monotonic()
+        # the first ping leaves as the connection starts: a link's round
+        # trip is known (telemetry's per-peer record, the reactor's relay
+        # hold) before anything depends on it, not ping_interval later
+        last_ping = time.monotonic() - cfg.ping_interval
+        self._send_signal.set()
         try:
             while self.is_running() and not self._errored.is_set():
                 # a send, a pong to write or the stop sets the signal; with
@@ -291,9 +306,11 @@ class MConnection(BaseService):
                     self._write(bytes([PACKET_TYPE_PONG]))
                 if now - last_ping >= cfg.ping_interval:
                     last_ping = now
-                    self._write(bytes([PACKET_TYPE_PING]))
+                    # stamped BEFORE the write: the round trip then holds
+                    # everything the ping met on its way out
                     if self._pm is not None:
                         self._pm.ping_sent()
+                    self._write(bytes([PACKET_TYPE_PING]))
                     if now - self._last_pong > cfg.ping_interval + cfg.pong_timeout:
                         raise TimeoutError("pong timeout")
                 # drain up to a burst of packets, fairly, and hand them to
@@ -376,10 +393,13 @@ class MConnection(BaseService):
             self._fatal(exc)
 
     def status(self) -> dict:
-        return {
+        st = {
             "send_rate": self.send_monitor.status().avg_rate,
             "recv_rate": self.recv_monitor.status().avg_rate,
             "channels": {
                 f"{ch.id:#x}": ch.send_queue_size() for ch in self.channels.values()
             },
         }
+        if self._pm is not None:
+            st["rtt"] = self.rtt_record()
+        return st

@@ -21,7 +21,13 @@ docs/secure-p2p.md threat model).
 
 Delay modes (`prob_sleep`, `max_delay`) are unchanged — reads are only
 ever delayed, never dropped, since dropping reads would desync framing
-on our own side.
+on our own side. `prob_sleep` SLEEPS IN THE CALLER before the read or
+write: it stalls the writer (head-of-line blocking, a cut in frames a
+second), which is what a retransmit does to a TCP stream. It is not a
+propagation delay. A link's propagation delay — a frame written at t
+leaves at t + d, the writer does not wait, order holds — is the delay
+line's (p2p/delay_line.py, `[p2p] test_link_region` /
+`test_link_rtt_ms`), which sits at this same place in the chain.
 """
 
 from __future__ import annotations

@@ -1,8 +1,9 @@
 """Peer: one remote node (reference: p2p/peer.go).
 
-Connection layering: raw stream -> [fuzz wrapper] -> [secret connection]
--> NodeInfo handshake -> MConnection. AuthEnc defaults on
-(p2p/peer.go:54-77).
+Connection layering: raw stream -> [delay line] -> [fuzz wrapper] ->
+[secret connection] -> NodeInfo handshake -> MConnection. AuthEnc
+defaults on (p2p/peer.go:54-77). The delay line (p2p/delay_line.py) is
+in the chain only when `[p2p]` configures link delays.
 """
 
 from __future__ import annotations
@@ -27,6 +28,8 @@ class PeerConfig:
     dial_timeout: float = 3.0
     fuzz: bool = False
     fuzz_config: dict = field(default_factory=dict)
+    # delay_line.LinkDelays, or None: no wrapper in the chain, no thread
+    link_delays: object | None = None
     mconfig: MConnConfig = field(default_factory=MConnConfig)
 
 
@@ -141,6 +144,11 @@ class Peer(BaseService):
         # falls back to the process-wide registry
         self.metrics_registry = None
 
+        self.link = None
+        if config.link_delays is not None:
+            from tendermint_tpu.p2p.delay_line import DelayedStream
+
+            stream = self.link = DelayedStream(stream, config.link_delays.line)
         if config.fuzz:
             from tendermint_tpu.p2p.fuzz import FuzzedStream
 
@@ -170,6 +178,14 @@ class Peer(BaseService):
             # be the identity claimed in NodeInfo (p2p/peer.go:181-191)
             if self.stream.remote_pubkey().raw != self.node_info.pub_key.raw:
                 raise ConnectionError("node info pubkey != secret conn pubkey")
+        if self.link is not None:
+            # from here on this end's writes take the link's one-way
+            # delay; a peer that is no link of the table is refused
+            from tendermint_tpu.p2p.delay_line import region_of
+
+            region = region_of(self.node_info)
+            self.link.set_delay(
+                self.config.link_delays.one_way_s(region), region)
         self.mconn._name = f"mconn:{self.id()[:8]}"
         # identity is known now: arm the per-peer instrument families
         # (p2p/telemetry.py) on whichever registry scopes this peer
@@ -230,6 +246,10 @@ class Peer(BaseService):
     def can_send(self, ch_id: int) -> bool:
         return self.mconn.can_send(ch_id)
 
+    def rtt_s(self) -> float | None:
+        """This link's smoothed ping round trip; None before a sample."""
+        return self.mconn.rtt_s()
+
     def last_recv_age(self) -> float:
         """Seconds since ANY packet arrived on this connection — the
         per-peer staleness signal (p2p_peer_last_recv_age_seconds,
@@ -245,6 +265,8 @@ class Peer(BaseService):
     def status(self) -> dict:
         st = self.mconn.status()
         st["node_info"] = self.node_info.to_json() if self.node_info else None
+        if self.link is not None:
+            st["link"] = self.link.stats()
         return st
 
     def __repr__(self) -> str:

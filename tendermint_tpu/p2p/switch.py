@@ -177,6 +177,8 @@ class Switch(BaseService):
             self._stop_and_remove(peer, "switch stopping")
         for reactor in self.reactors.values():
             reactor.stop()
+        if self.peer_config.link_delays is not None:
+            self.peer_config.link_delays.line.stop()
 
     # -- listeners ---------------------------------------------------------
 

@@ -15,6 +15,11 @@ gossip plane observable per link:
     p2p_peer_send_queue{peer,channel}            queue depth at last enqueue
     p2p_peer_send_queue_high_water{peer,channel} max depth seen
     p2p_peer_ping_rtt_seconds{peer}              ping->pong round trip
+                                                 (count / min / last /
+                                                 smoothed of each link:
+                                                 PeerRtt, in net_info's
+                                                 connection_status.rtt
+                                                 and the dumps' links)
     p2p_peer_last_recv_age_seconds{peer}         seconds since any packet
                                                  (refreshed at collect by
                                                  node/telemetry.py)
@@ -56,35 +61,47 @@ from tendermint_tpu.libs import telemetry
 _CACHE_ATTR = "_p2p_peer_family_cache"
 
 
-class RttEwma:
-    """Registry-scoped EWMA over every peer's ping RTT samples (round
-    21): the one-number RTT summary the RTT-adaptive lazy-relay hold
-    reads (consensus/reactor.adaptive_relay_delay). Not an instrument —
-    the per-peer distribution already rides the ping_rtt histogram; this
-    is the cheap cross-peer smoother the hot relay path polls."""
+class PeerRtt:
+    """One link's ping round trips: an EWMA over this peer's samples
+    alone, which the RTT-adaptive lazy-relay hold reads
+    (consensus/reactor.adaptive_relay_delay), and beside it the count,
+    the smallest and the last, which a judge compares with the link's
+    configured round trip (the ping_rtt histogram has buckets, not a
+    minimum). Until round 32 ONE smoother served all of a node's peers:
+    wrong for every link of a mesh whose links are 1-312 ms."""
 
     ALPHA = 0.2
 
-    __slots__ = ("_mtx", "_value", "_samples")
+    __slots__ = ("_mtx", "_value", "_samples", "_min", "_last")
 
     def __init__(self):
         self._mtx = threading.Lock()
         self._value = 0.0
         self._samples = 0
+        self._min = 0.0
+        self._last = 0.0
 
     def observe(self, rtt_s: float) -> None:
         with self._mtx:
             self._samples += 1
+            self._last = rtt_s
             if self._samples == 1:
-                self._value = rtt_s
+                self._value = self._min = rtt_s
             else:
                 self._value += self.ALPHA * (rtt_s - self._value)
+                self._min = min(self._min, rtt_s)
 
     def value(self) -> float | None:
         """The smoothed RTT in seconds; None before any sample (the
         relay hold then keeps its constant fallback)."""
         with self._mtx:
             return self._value if self._samples else None
+
+    def record(self) -> dict:
+        with self._mtx:
+            return {"count": self._samples, "min_s": round(self._min, 6),
+                    "last_s": round(self._last, 6),
+                    "smoothed_s": round(self._value, 6)}
 
 
 def peer_metrics(reg: "telemetry.Registry | None" = None) -> dict:
@@ -176,9 +193,6 @@ def peer_metrics(reg: "telemetry.Registry | None" = None) -> dict:
             labelnames=p,
         ),
     }
-    # not an instrument: the cross-peer RTT smoother rides the same
-    # cache so reactors sharing the registry read one EWMA (round 21)
-    fams["ping_rtt_ewma"] = RttEwma()
     setattr(reg, _CACHE_ATTR, fams)
     return fams
 
@@ -215,7 +229,7 @@ class PeerConnMetrics:
     __slots__ = ("peer_id", "_send_bytes", "_recv_bytes", "_send_msgs",
                  "_recv_msgs", "_send_failures", "_send_queue",
                  "_send_queue_hw", "_hw", "_hw_mtx", "_ping_rtt",
-                 "_rtt_ewma", "_ping_sent_at")
+                 "_ping_sent_at", "rtt")
 
     def __init__(self, peer_id: str, channel_ids, reg=None):
         fams = peer_metrics(reg)
@@ -237,8 +251,8 @@ class PeerConnMetrics:
         self._hw = {ch: 0 for ch in channel_ids}
         self._hw_mtx = threading.Lock()
         self._ping_rtt = fams["ping_rtt"].labels(peer=peer_id)
-        self._rtt_ewma = fams["ping_rtt_ewma"]
         self._ping_sent_at = 0.0
+        self.rtt = PeerRtt()
 
     # -- send side ---------------------------------------------------------
 
@@ -288,5 +302,5 @@ class PeerConnMetrics:
         if self._ping_sent_at > 0:
             rtt = time.monotonic() - self._ping_sent_at
             self._ping_rtt.observe(rtt)
-            self._rtt_ewma.observe(rtt)
+            self.rtt.observe(rtt)
             self._ping_sent_at = 0.0

@@ -285,8 +285,12 @@ def test_flooding_peer_cannot_halt_chain():
         # STOP it)
         start = min(len(n.blocks) for n in nodes)
         deadline = min(300.0, max(60.0, 8.0 * t_two_blocks))
+        # (and the flood has to have been one: a chain that commits two
+        # blocks inside a tenth of a second otherwise ends the wait before
+        # the paced sender has sent its twenty)
         ok = wait_until(
-            lambda: all(len(n.blocks) >= start + 2 for n in nodes),
+            lambda: stats["sent"] > 20
+            and all(len(n.blocks) >= start + 2 for n in nodes),
             timeout=deadline,
         )
         stop_flood.set()

@@ -411,6 +411,12 @@ def test_resolve_platform_waits_out_claiming_daemon(
     assert state["pings"] >= 3  # it actually polled through "warming"
     assert time.time() - t0 < 25  # and "failed" did not wait out the bound
     gateway._platform_cache.pop("v", None)
+    # what a daemon said is remembered process-wide ("daemon_said"): left
+    # in, every default Verifier of a LATER test file on this xdist worker
+    # routes to a daemon that is gone (tests/test_types.py read
+    # tpu_sigs 0 for 8 when the schedule put it behind this file)
+    gateway._platform_cache.pop("daemon_said", None)
+    devd.bust_avail_cache()
 
 
 # -- the rules of the device plane (PR 22) ------------------------------------

@@ -323,7 +323,7 @@ def test_wrong_sized_has_votes_array_is_ignored_without_a_peer_error():
     assert r.has_votes_applied == 0
     assert ps.prs.prevotes.is_empty() and ps.prs.prevotes.size == 4
     assert r.switch.stopped == []
-    assert r._has_vote_lag is None
+    assert ps.has_vote_lag is None and r._has_vote_lag is None
     # the right size still lands afterwards
     r.receive(STATE_CHANNEL, peer, _enc(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [3])))
     assert r.has_votes_applied == 1 and ps.prs.prevotes.indices() == [3]
@@ -345,17 +345,18 @@ def test_has_vote_lag_takes_one_sample_a_message():
 
     r.receive(STATE_CHANNEL, peer, _enc(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [1, 2, 3])))
     assert r.has_votes_applied == 3
-    assert r._has_vote_lag == pytest.approx(3.0, abs=0.5)  # first sample: as read
+    assert ps.has_vote_lag == pytest.approx(3.0, abs=0.5)  # first sample: as read
     # nothing newly set, or a vote we never received from a peer: no sample
-    lag = r._has_vote_lag
+    lag = ps.has_vote_lag
     r.receive(STATE_CHANNEL, peer, _enc(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [1, 2])))
     r.receive(STATE_CHANNEL, peer, _enc(_has_votes(5, 0, VOTE_TYPE_PREVOTE, [0])))
-    assert r._has_vote_lag == lag
+    assert ps.has_vote_lag == lag
     # the next sample moves the average by a tenth, once
     r.con_s.vote_recv_mono[(5, 0, VOTE_TYPE_PRECOMMIT, 0)] = _time.monotonic() - 13.0
     r.con_s.vote_recv_mono[(5, 0, VOTE_TYPE_PRECOMMIT, 1)] = _time.monotonic() - 13.0
     r.receive(STATE_CHANNEL, peer, _enc(_has_votes(5, 0, VOTE_TYPE_PRECOMMIT, [0, 1])))
-    assert r._has_vote_lag == pytest.approx(0.9 * lag + 0.1 * 13.0, abs=0.2)
+    assert ps.has_vote_lag == pytest.approx(0.9 * lag + 0.1 * 13.0, abs=0.2)
+    assert r._has_vote_lag == pytest.approx(ps.has_vote_lag)   # one peer: the same
 
 
 def test_pending_bits_are_announced_exactly_once_under_contention(monkeypatch):
@@ -409,7 +410,7 @@ def test_pending_bits_are_announced_exactly_once_under_contention(monkeypatch):
         assert not flusher.is_alive() and not any(t.is_alive() for t in noters)
     finally:
         sys.setswitchinterval(old)
-        r._announce_wake.stop()
+        r._wakes.stop()
     r._flush_has_votes()
     import json
 
@@ -490,23 +491,26 @@ def test_relay_screen_holds_fresh_votes_only():
     class _V:
         height, round_, type_, validator_index = 5, 0, VOTE_TYPE_PREVOTE, 1
 
-    r = ConsensusReactor(_ConState(gossip_dedup=True))
-    assert r._relay_hold(_V()) == 0.0  # unstamped: our own vote
+    r, _peer, ps = _reactor_with_peer(gossip_dedup=True)
+    delay = r._relay_delay(ps)
+    assert delay == VOTE_RELAY_DELAY  # a peer that measures no round trip
+    assert r._relay_hold(_V(), delay) == 0.0  # unstamped: our own vote
 
     key = (5, 0, VOTE_TYPE_PREVOTE, 1)
     r.con_s.vote_recv_mono[key] = _time.monotonic()
-    assert 0.0 < r._relay_hold(_V()) <= VOTE_RELAY_DELAY  # just received: held
+    assert 0.0 < r._relay_hold(_V(), delay) <= VOTE_RELAY_DELAY  # just received: held
     r.con_s.vote_recv_mono[key] = _time.monotonic() - VOTE_RELAY_DELAY - 0.01
-    assert r._relay_hold(_V()) == 0.0  # hold expired: genuinely needed
+    assert r._relay_hold(_V(), delay) == 0.0  # hold expired: genuinely needed
 
     r_off = ConsensusReactor(_ConState(gossip_dedup=False))
     r_off.con_s.vote_recv_mono[key] = _time.monotonic()
-    assert r_off._relay_hold(_V()) == 0.0  # pre-round-20 gossip: no hold
+    assert r_off._relay_hold(_V(), delay) == 0.0  # pre-round-20 gossip: no hold
 
 
 def test_adaptive_relay_delay_clamp_and_fallback():
     """Round 21 satellite: the lazy-relay hold tracks 2x the smoothed
-    peer RTT, clamped to [0.5x, 4x] of the constant; no samples keeps
+    peer RTT, clamped to [half the constant, one second: above twice the
+    longest round trip between two regions, round 32]; no samples keeps
     the constant exactly."""
     from tendermint_tpu.consensus.reactor import (
         VOTE_RELAY_DELAY,
@@ -516,7 +520,7 @@ def test_adaptive_relay_delay_clamp_and_fallback():
     )
 
     assert VOTE_RELAY_DELAY_MIN == pytest.approx(0.5 * VOTE_RELAY_DELAY)
-    assert VOTE_RELAY_DELAY_MAX == pytest.approx(4.0 * VOTE_RELAY_DELAY)
+    assert VOTE_RELAY_DELAY_MAX == 1.0 > 2 * 0.312
     # no samples: the constant, byte-for-byte
     assert adaptive_relay_delay(None) == VOTE_RELAY_DELAY
     # fast LAN: clamps at the floor, never disables the hold
@@ -524,48 +528,71 @@ def test_adaptive_relay_delay_clamp_and_fallback():
     assert adaptive_relay_delay(0.0) == VOTE_RELAY_DELAY_MIN
     # mid-range: tracks 2x RTT
     assert adaptive_relay_delay(0.08) == pytest.approx(0.16)
-    # slow WAN / garbage sample: clamps at the ceiling
+    # the longest link of the seven-datacenter net: not clamped
+    assert adaptive_relay_delay(0.312) == pytest.approx(0.624)
+    # garbage sample: clamps at the ceiling
     assert adaptive_relay_delay(1.5) == VOTE_RELAY_DELAY_MAX
 
 
-def test_reactor_relay_delay_reads_rtt_ewma():
-    """The reactor's hold: constant with no switch, no registry, or no
-    samples; RTT-adaptive once the switch's registry carries ping
-    samples (fed by PeerConnMetrics.pong_received)."""
-    from tendermint_tpu.consensus.reactor import VOTE_RELAY_DELAY
-    from tendermint_tpu.libs import telemetry
-    from tendermint_tpu.p2p.telemetry import peer_metrics
+def test_reactor_relay_delay_follows_each_peers_own_rtt():
+    """The reactor's hold is per peer: constant for a peer with no
+    sample, RTT-adaptive from THAT link's ping samples (fed by
+    PeerConnMetrics.pong_received) once it has one; a far peer's samples
+    move a near peer's hold by nothing, and the lag a peer's
+    announcements show raises its own hold alone."""
+    from tendermint_tpu.consensus.reactor import (
+        VOTE_RELAY_DELAY,
+        VOTE_RELAY_DELAY_MAX,
+        VOTE_RELAY_DELAY_MIN,
+    )
+    from tendermint_tpu.p2p.telemetry import PeerRtt
+
+    class _RttPeer(_StubPeer):
+        def __init__(self):
+            super().__init__()
+            self.rtt = PeerRtt()
+
+        def rtt_s(self):
+            return self.rtt.value()
 
     r = ConsensusReactor(_ConState(gossip_dedup=True))
-    assert r._relay_delay() == VOTE_RELAY_DELAY  # no switch at all
-
-    class _Switch:
-        metrics_registry = None
-
-    r.switch = _Switch()
-    assert r._relay_delay() == VOTE_RELAY_DELAY  # switch, no registry
-
-    reg = telemetry.Registry()  # fresh: no cross-test samples
-    r.switch.metrics_registry = reg
-    assert r._relay_delay() == VOTE_RELAY_DELAY  # registry, no samples
-
-    peer_metrics(reg)["ping_rtt_ewma"].observe(0.08)
-    assert r._relay_delay() == pytest.approx(0.16)
+    near, far, blind = PeerState(_RttPeer()), PeerState(_RttPeer()), PeerState(_StubPeer())
+    for ps in (near, far, blind):
+        assert r._relay_delay(ps) == VOTE_RELAY_DELAY  # no sample yet
+    near.peer.rtt.observe(0.001)
+    far.peer.rtt.observe(0.312)
+    assert r._relay_delay(near) == VOTE_RELAY_DELAY_MIN
+    assert r._relay_delay(far) == pytest.approx(0.624)
+    assert r._relay_delay(blind) == VOTE_RELAY_DELAY
     # EWMA moves with new samples, and the clamp still rules
     for _ in range(64):
-        peer_metrics(reg)["ping_rtt_ewma"].observe(5.0)
-    assert r._relay_delay() == pytest.approx(4.0 * VOTE_RELAY_DELAY)
+        far.peer.rtt.observe(5.0)
+    assert r._relay_delay(far) == VOTE_RELAY_DELAY_MAX
+    assert r._relay_delay(near) == VOTE_RELAY_DELAY_MIN
+    # a peer whose announcements lag: twice the lag, its own hold alone
+    near.has_vote_lag = 0.2
+    assert r._relay_delay(near) == pytest.approx(0.4)
+    assert r._relay_delay(blind) == VOTE_RELAY_DELAY
+    # the lag over all peers is a floor under every peer's hold (one
+    # host: a peer's own average says little of its next announcement)
+    r._has_vote_lag = 0.15
+    assert r._relay_delay(blind) == pytest.approx(0.3)
+    assert r._relay_delay(near) == pytest.approx(0.4)     # its own is longer
+    assert r._relay_delay(far) == VOTE_RELAY_DELAY_MAX
 
 
-def test_rtt_ewma_smoothing():
-    from tendermint_tpu.p2p.telemetry import RttEwma
+def test_peer_rtt_smoothing_and_record():
+    from tendermint_tpu.p2p.telemetry import PeerRtt
 
-    e = RttEwma()
-    assert e.value() is None
+    e = PeerRtt()
+    assert e.value() is None and e.record()["count"] == 0
     e.observe(0.1)
     assert e.value() == pytest.approx(0.1)  # first sample seeds exactly
     e.observe(0.2)
     assert e.value() == pytest.approx(0.1 + 0.2 * (0.2 - 0.1))
+    e.observe(0.05)
+    rec = e.record()
+    assert (rec["count"], rec["min_s"], rec["last_s"]) == (3, 0.05, 0.05)
 
 
 def test_vote_recv_stamp_is_bounded():

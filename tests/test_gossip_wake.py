@@ -367,7 +367,7 @@ def test_held_vote_goes_out_when_its_hold_ends(net_factory):
     net = net_factory()
     peer = net.peers[0]
     net.settle()
-    hold = net.r._relay_delay()
+    hold = net.r._relay_delay(peer.get(PEER_STATE_KEY))
     assert 0.0 < hold < SOON < BACKSTOP
     before = net.waits()
 
@@ -483,7 +483,7 @@ def test_remove_peer_ends_both_routines_promptly(net_factory):
         t.join(SOON)
     assert not any(t.is_alive() for t in gossip)
     assert time.monotonic() - t0 < SOON
-    assert net.r._gossips == ()
+    assert net.r._states == ()
 
 
 def test_on_stop_ends_every_peers_routines(net_factory):
@@ -566,14 +566,14 @@ def test_a_harness_reactor_without_a_switch_keeps_no_bits(net_factory):
     net.evsw.fire_event(
         tev.EVENT_VOTE, tev.EventDataVote(net.cs.rs.votes.pre.add(0)))
     assert net.r._announce_pending == {}
-    assert net.r._announce_wake._thread is None
+    assert net.r._wakes._thread is None
 
 
 def test_on_stop_ends_the_announcement_timer(net_factory):
     net = net_factory(switch=True)
     net.evsw.fire_event(
         tev.EVENT_VOTE, tev.EventDataVote(net.cs.rs.votes.pre.add(0)))
-    timer = net.r._announce_wake._thread
+    timer = net.r._wakes._thread
     assert timer is not None and timer.is_alive()
     net.cs.stop = lambda: None
     net.r.on_stop()
