@@ -6,10 +6,17 @@ Four p2p channels (reactor.go:21-24):
   0x22 VOTE        — Vote
   0x23 VOTE_SET_BITS — VoteSetMaj23 / VoteSetBits
 
-Each peer gets a mirrored PeerRoundState and three gossip threads
-(reactor.go:133-135): gossip_data (block parts + catch-up), gossip_votes
-(needed-vote picker), query_maj23. Step transitions and new votes are
-broadcast event-driven via the event switch (reactor.go:321-337).
+Each peer gets a mirrored PeerRoundState. The reference runs three
+goroutines a peer over it (reactor.go:133-135: gossipData, gossipVotes,
+queryMaj23); here ONE routine a reactor, `conR.gossip`, does the three
+duties for all peers (round 33): a thread a duty a peer was 45 threads at
+15 peers, each woken under one GIL to find nothing to send. An event marks
+the peers it concerns and sets the routine's one signal; the routine takes
+the marks, reads the round state once, and sweeps the marked peers: block
+parts + catch-up, the needed-vote picker, every PEER_QUERY_MAJ23_SLEEP the
+maj23 claims. It never blocks on a peer (try_send). Step transitions and
+new votes are broadcast event-driven via the event switch
+(reactor.go:321-337).
 """
 
 from __future__ import annotations
@@ -23,7 +30,7 @@ from tendermint_tpu.consensus import messages as msgs
 from tendermint_tpu.consensus.round_state import RoundStep
 from tendermint_tpu.libs.bitarray import BitArray
 from tendermint_tpu.libs.service import BaseService
-from tendermint_tpu.p2p.conn import ChannelDescriptor
+from tendermint_tpu.p2p.conn import ChannelDescriptor, MConnConfig
 from tendermint_tpu.p2p.switch import Reactor
 from tendermint_tpu.types import events as tev
 from tendermint_tpu.types.agg_commit import AggregateLastCommit, commit_is_aggregate
@@ -37,20 +44,20 @@ VOTE_CHANNEL = 0x22
 VOTE_SET_BITS_CHANNEL = 0x23
 
 # reactor.go peerGossipSleepDuration. Since round 26 the idle back-stop
-# of the gossip routines' wait (_gossip_wait), not their pace: a routine
-# with nothing to send blocks on its wake signal and is woken by the
-# event that makes something sendable (wake_gossip, PeerState.gossip).
+# of the gossip routine's wait, not its pace: with nothing to send it
+# blocks on its signal and is woken by the event that makes something
+# sendable (wake_gossip, PeerState.gossip).
 PEER_GOSSIP_SLEEP = 0.1
 PEER_QUERY_MAJ23_SLEEP = 2.0
-# a routine whose back-stop ran out and found nothing doubles its next
-# back-stop, up to this many PEER_GOSSIP_SLEEPs; any wake by an event, a
-# hold or a send puts it back to one. A committee's node has 62 routines,
-# and a timed wait that ends is a thread switch whatever the look then
-# costs (21 us): at ten a second each they were 620 wake-ups a second a
-# node that found nothing, on a host with fewer cores than validators
-# (PERF.md, PR 27: `gossip_backstop_sends` 0 of 37,209 sends in
-# `net4.steady`).
+# a back-stop that ran out and found nothing doubles the next one, up to
+# this many PEER_GOSSIP_SLEEPs; any wake by an event, a hold or a send
+# puts it back to one. A timed wait that ends is a thread switch whatever
+# the look then costs (PERF.md, PR 27: `gossip_backstop_sends` 0 of
+# 37,209 sends in `net4.steady`). A back-stop looks at ALL peers.
 GOSSIP_BACKSTOP_MAX_SLEEPS = 8
+# a peer whose channel refused an item (try_send: the queue of 100 is
+# full) is looked at again after one flush of its connection
+SEND_FULL_RETRY = MConnConfig.flush_throttle
 # lazy-relay hold (round 20, gossip_dedup): a vote we RECEIVED moments
 # ago is being fanned out by its origin right now, and every recipient
 # announces it via HasVote within the same window — re-pushing it
@@ -79,13 +86,21 @@ VOTE_RELAY_DELAY_MAX = 1.0
 
 PEER_STATE_KEY = "ConsensusReactor.peerState"
 
-# the reactor's flat counters of its gossip routines (round 26), as the
-# `consensus` producer and the flight recorder's dumps list them
+# the reactor's flat counters of its gossip routine (round 26; the
+# sweep's own since round 33), as the `consensus` producer and the
+# flight recorder's dumps list them
 GOSSIP_COUNTERS = (
     "gossip_sends", "gossip_wakes_event", "gossip_wakes_hold",
     "gossip_wakes_backstop", "gossip_backstop_sends",
     "gossip_announces_sent", "gossip_announce_bits",
+    "gossip_sweeps", "gossip_peer_looks", "gossip_send_full",
 )
+
+# what one look at one peer came to (_gossip_data_pass,
+# _pick_and_send_vote): nothing to send; an item sent; the peer's channel
+# refused it; the item has to be read from the store, which waits for
+# the sweep's second half
+_IDLE, _SENT, _FULL, _STORE = range(4)
 
 
 def adaptive_relay_delay(rtt_s: float | None) -> float:
@@ -99,6 +114,15 @@ def adaptive_relay_delay(rtt_s: float | None) -> float:
 
 def _enc(msg) -> bytes:
     return json.dumps(msgs.msg_to_json(msg), sort_keys=True).encode()
+
+
+def _enc_once(once: dict, key, make, *args) -> bytes:
+    """The encoding of `make(*args)`, made once for the sweep that keeps
+    `once` and sent to every peer that needs the item."""
+    raw = once.get(key)
+    if raw is None:
+        raw = once[key] = _enc(make(*args))
+    return raw
 
 
 def _dec(raw: bytes):
@@ -127,45 +151,56 @@ class PeerRoundState:
 
 
 class _PeerGossip:
-    """One peer's stop flag and the wake signal of each of its two
-    gossip routines. A routine clears its signal BEFORE it reads the
-    round state and waits on it after a pass that sent nothing, so an
-    event landing between the look and the wait ends the wait at once.
-    A signal that is set is left alone (one attribute read, no lock), so
-    a burst of events is one wake and costs the thread that fires them
-    next to nothing: whoever wakes has changed the state first, and the
-    routine that clears the signal looks at the state after that."""
+    """One peer's marks for the reactor's gossip routine: whether its
+    data, its votes or both are *to be looked at*, when the earliest
+    lazy-relay hold of a vote it needs ends, and whether it is gone.
+    `wake*()` sets the mark and then the routine's ONE signal
+    (`signal`, the reactor's; None on a PeerState no reactor holds). A
+    mark that is set is left alone (one attribute read, no lock), so a
+    burst of events costs the thread that fires them next to nothing:
+    whoever marks has changed the state first, and the routine takes a
+    mark (clears it only where it read it set) BEFORE it reads the state."""
 
-    __slots__ = ("stop", "data", "votes")
+    __slots__ = ("data", "votes", "hold_until", "stopped", "signal")
 
     def __init__(self):
-        self.stop = threading.Event()
-        self.data = threading.Event()
-        self.votes = threading.Event()
+        self.data = False
+        self.votes = False
+        self.hold_until: float | None = None
+        self.stopped = False
+        self.signal: threading.Event | None = None
 
     def wake(self) -> None:
         self.wake_data()
         self.wake_votes()
 
     def wake_data(self) -> None:
-        if not self.data.is_set():
-            self.data.set()
+        if not self.data:
+            self.data = True
+            self._set()
 
     def wake_votes(self) -> None:
-        if not self.votes.is_set():
-            self.votes.set()
+        if not self.votes:
+            self.votes = True
+            self._set()
+
+    def _set(self) -> None:
+        signal = self.signal
+        if signal is not None and not signal.is_set():
+            signal.set()
 
     def end(self) -> None:
-        self.stop.set()
-        self.wake()  # the stop must also end a routine's wait
+        """The peer is gone: a sweep under way gives it nothing more."""
+        self.stopped = True
+        self.signal = None
 
 
 class _DeferredWakes:
     """One timer for the whole reactor: `at(fire, deadline)` asks for ONE
     call of `fire` at that instant (time.monotonic). A call asked for
     while the same `fire` is pending rides it (the earlier instant
-    stands), so a burst of 31 votes is one wake of a peer's votes routine
-    when its first hold ends, and one announcement when the burst's first
+    stands), so a burst of 31 votes is one mark of a peer's votes when
+    its first hold ends, and one announcement when the burst's first
     bit has waited its time, not 31 of either. The calls are made on the
     timer's thread, outside its lock; the thread starts with the first
     call and ends with `stop()`."""
@@ -245,9 +280,9 @@ class PeerState:
         self.peer = peer
         self.prs = PeerRoundState()
         self._mtx = threading.RLock()
-        # stop flag + wake signals of this peer's gossip routines;
-        # receive() wakes them when the mirror changes in a way that can
-        # make MORE sendable to this peer
+        # this peer's marks for the gossip routine; receive() marks it
+        # when the mirror changes in a way that can make MORE sendable
+        # to this peer
         self.gossip = _PeerGossip()
         # per-peer gossip instrumentation (round 15): child series
         # resolved once — picks vs successful sends is the signal that
@@ -566,7 +601,6 @@ class ConsensusReactor(Reactor, BaseService):
         self.con_s = consensus_state
         self.fast_sync = fast_sync
         self.evsw = None
-        self._peer_threads: dict[str, list] = {}
         self._peer_states: dict[str, PeerState] = {}
         # what wake_gossip walks: replaced whole under _mtx when a peer
         # comes or goes and read without it, so the consensus receive
@@ -592,16 +626,28 @@ class ConsensusReactor(Reactor, BaseService):
         # aggregate-format catchup accounting (round 22, docs/upgrade.md)
         self.agg_commits_sent = 0      # whole-commit catchup sends
         self.agg_commits_rejected = 0  # forged/sub-quorum screened out
-        # GOSSIP_COUNTERS: passes that found an item to send; how the
-        # routines' waits ended — by a signal, by a lazy-relay hold
-        # running out, by the idle back-stop; and how often a back-stop
-        # wake then found something to send, which is an event nobody
-        # signalled (must stay near 0 beside gossip_sends)
+        # the one gossip routine (conR.gossip, started in on_start), the
+        # signal every mark sets, its stop, and where the next sweep's
+        # order of peers starts
+        self._gossip_signal = threading.Event()
+        self._gossip_stop = threading.Event()
+        self._gossip_thread: threading.Thread | None = None
+        self._gossip_turn = 0
+        # GOSSIP_COUNTERS: items sent; how the routine's waits ended — by
+        # a signal, by a lazy-relay hold running out, by the idle
+        # back-stop; and the sends to a peer that only a back-stop's look
+        # found, which is an event nobody signalled (must stay near 0
+        # beside gossip_sends)
         self.gossip_sends = 0
         self.gossip_wakes_event = 0
         self.gossip_wakes_hold = 0
         self.gossip_wakes_backstop = 0
         self.gossip_backstop_sends = 0
+        # sweeps that looked at a peer, the peers they looked at, and the
+        # items a full channel refused (retried after SEND_FULL_RETRY)
+        self.gossip_sweeps = 0
+        self.gossip_peer_looks = 0
+        self.gossip_send_full = 0
         # has-vote announcements: messages sent (one a peer a flush and
         # key) and the votes they announced (one a vote a flush). Bits
         # over (announces / peers) is the mean burst
@@ -630,8 +676,8 @@ class ConsensusReactor(Reactor, BaseService):
 
     def set_event_switch(self, evsw) -> None:
         """Subscribe broadcast triggers (reactor.go:321-337). The three
-        events that change what OUR round state holds also wake every
-        peer's gossip routines."""
+        events that change what OUR round state holds also mark every
+        peer for the gossip routine."""
         self.evsw = evsw
 
         def on_step(_d):
@@ -639,13 +685,12 @@ class ConsensusReactor(Reactor, BaseService):
             self._broadcast_step()
 
         def on_vote(d):
-            # a vote is the votes routines' business alone. Our own goes
-            # out now. One we RECEIVED is held back from relay for
-            # _relay_hold (its origin is fanning it out, and every peer
-            # says so with HasVote): waking 31 routines to find it held,
-            # and again when its hold ends, is 62 thread switches a
-            # vote; one deferred wake at the hold's end serves the burst
-            # the burst. The hold is each peer's own (_relay_delay).
+            # a vote marks the peers' votes alone. Our own goes out now.
+            # One we RECEIVED is held back from relay for _relay_hold
+            # (its origin is fanning it out, and every peer says so with
+            # HasVote): a look that finds it held is a wake for nothing,
+            # so each peer is marked when its hold ends, once a burst.
+            # The hold is each peer's own (_relay_delay).
             self._wake_for_vote(d.vote)
             self._note_has_vote(d.vote)
 
@@ -701,26 +746,14 @@ class ConsensusReactor(Reactor, BaseService):
         ]
 
     def add_peer(self, peer) -> None:
+        """Starts no thread: the peer joins what the one routine sweeps."""
         ps = PeerState(peer)
         peer.set(PEER_STATE_KEY, ps)
-        gw = ps.gossip
-        threads = []
-        for fn, arg, nm in (
-            (self._gossip_data_routine, gw, "gossipData"),
-            (self._gossip_votes_routine, gw, "gossipVotes"),
-            (self._query_maj23_routine, gw.stop, "queryMaj23"),
-        ):
-            t = threading.Thread(
-                target=fn, args=(peer, ps, arg), daemon=True,
-                name=f"conR.{nm}:{peer.id()[:8]}",
-            )
-            threads.append(t)
+        ps.gossip.signal = self._gossip_signal
         with self._mtx:
             self._peer_states[peer.id()] = ps
             self._states = tuple(self._peer_states.values())
-            self._peer_threads[peer.id()] = threads
-        for t in threads:
-            t.start()
+        ps.gossip.wake()
         # tell the new peer our current state
         if not self.fast_sync:
             for m in self._round_step_messages():
@@ -730,30 +763,29 @@ class ConsensusReactor(Reactor, BaseService):
         with self._mtx:
             ps = self._peer_states.pop(peer.id(), None)
             self._states = tuple(self._peer_states.values())
-            self._peer_threads.pop(peer.id(), None)
         if ps:
             ps.gossip.end()
 
     def wake_gossip(self) -> None:
         """Our own round state changed (a step, the proposal): every
-        peer's routines look again now. O(peers) flag sets, no lock,
-        never blocks — this runs on the consensus receive routine."""
+        peer is looked at again now. O(peers) flag sets, no lock, never
+        blocks — this runs on the consensus receive routine."""
         for ps in self._states:
             ps.gossip.wake()
 
     def wake_votes_gossip(self) -> None:
-        """A vote entered our round state: the votes routines alone."""
+        """A vote entered our round state: every peer's votes alone."""
         for ps in self._states:
             ps.gossip.wake_votes()
 
     def wake_data_gossip(self) -> None:
-        """A part entered our round state: the data routines alone."""
+        """A part entered our round state: every peer's data alone."""
         for ps in self._states:
             ps.gossip.wake_data()
 
     def _wake_for_vote(self, vote) -> None:
-        """Wake each peer's votes routine when `vote` may go to it: now
-        for our own vote, at the end of the peer's own hold for one we
+        """Mark each peer's votes when `vote` may go to it: now for our
+        own vote, at the end of the peer's own hold for one we
         received. The mean hold applied goes onto the height's trace
         (aux relay_hold_s over relay_holds). Receive routine only."""
         got = self.con_s.vote_recv_mono.get(
@@ -970,14 +1002,20 @@ class ConsensusReactor(Reactor, BaseService):
     # -- lifecycle ---------------------------------------------------------
 
     def on_start(self) -> None:
+        self._start_gossip()
         if not self.fast_sync:
             self.con_s.start()
+
+    def _start_gossip(self) -> None:
+        self._gossip_thread = threading.Thread(
+            target=self._gossip_routine, daemon=True, name="conR.gossip")
+        self._gossip_thread.start()
 
     def on_stop(self) -> None:
         self.con_s.stop()
         self._wakes.stop()
-        for ps in self._states:
-            ps.gossip.end()
+        self._gossip_stop.set()
+        self._gossip_signal.set()  # the stop must also end the wait
 
     def switch_to_consensus(self, state) -> None:
         """Fast sync complete (reactor.go:78-90). Note: update BEFORE
@@ -1093,60 +1131,175 @@ class ConsensusReactor(Reactor, BaseService):
             STATE_CHANNEL, _enc(msgs.ProposalHeartbeatMessage(heartbeat))
         )
 
-    # -- the loop both gossip routines run ----------------------------------
+    # -- the one gossip routine ---------------------------------------------
 
-    def _gossip_routine(self, gw: _PeerGossip, wake: threading.Event,
-                        gossip_pass) -> None:
-        """`gossip_pass()` looks at the round state and the peer's mirror
-        and sends at most ONE item; it returns (sent, hold_s), hold_s
-        being the seconds until the earliest lazy-relay hold ends when
-        held votes were all it found. The signal is cleared BEFORE the
-        look, so whatever lands after it ends the wait at once; a pass
-        that sent keeps going without waiting (a burst of events is one
-        wake); a pass that found nothing goes back to a full wait."""
-        ran_out = False  # the last wait ended on the idle back-stop
-        idle = 0         # back-stops in a row that found nothing to send
+    def _gossip_routine(self) -> None:
+        """gossipData, gossipVotes and queryMaj23 for ALL peers. The
+        signal is cleared and the marks are taken BEFORE the round state
+        is read, so whatever lands after that ends the next wait at once.
+        The wait lasts until the signal, until the earliest lazy-relay
+        hold of any peer ends, or for the back-stop, whichever is first:
+        PEER_GOSSIP_SLEEP, doubled for every back-stop in a row that
+        found nothing, up to GOSSIP_BACKSTOP_MAX_SLEEPS of them. A
+        back-stop looks at every peer; an event never puts it off, it
+        only brings it back to one PEER_GOSSIP_SLEEP from now."""
+        signal, stop = self._gossip_signal, self._gossip_stop
+        idle = 0          # back-stops in a row that found nothing to send
+        ran_out = False   # the last wait ended on the back-stop
+        now = time.monotonic()
+        backstop_at = now + PEER_GOSSIP_SLEEP
+        maj23_at = now + PEER_QUERY_MAJ23_SLEEP
         while self.is_running():
             if self.fast_sync:
-                if gw.stop.wait(PEER_GOSSIP_SLEEP):
+                if stop.wait(PEER_GOSSIP_SLEEP):
                     return
                 continue
-            wake.clear()
+            signal.clear()
             # the stop is read AFTER the clear, like the round state: a
             # stop that lands from here on still finds the signal to set
-            if gw.stop.is_set():
+            if stop.is_set():
                 return
-            sent, hold_s = gossip_pass()
-            if sent:
-                self.gossip_sends += 1
-                idle = 0
-                if ran_out:
-                    # nothing told us: an event this reactor fails to
-                    # signal, or a hold that outlived the wait
-                    self.gossip_backstop_sends += 1
-                    ran_out = False
+            now = time.monotonic()
+            try:
+                if now >= maj23_at:
+                    maj23_at = now + PEER_QUERY_MAJ23_SLEEP
+                    self._query_maj23_sweep()
+                sent, hold_at = self._gossip_sweep(everyone=ran_out)
+            except Exception:  # noqa: BLE001 — the one routine all peers have
+                self.logger.exception("gossip sweep")
+                sent, hold_at = 0, None
+            now = time.monotonic()
+            if ran_out:
+                ran_out = False
+                idle = 0 if sent else min(idle + 1, GOSSIP_BACKSTOP_MAX_SLEEPS)
+                backstop_at = now + PEER_GOSSIP_SLEEP * min(
+                    2 ** idle, GOSSIP_BACKSTOP_MAX_SLEEPS)
+            until = min(backstop_at, maj23_at)
+            held = hold_at is not None and hold_at < until
+            if signal.wait(max(0.0, (hold_at if held else until) - now)):
+                self.gossip_wakes_event += 1
+            elif held:
+                self.gossip_wakes_hold += 1
+            elif until == backstop_at:
+                self.gossip_wakes_backstop += 1
+                ran_out = True
                 continue
-            ran_out = self._gossip_wait(wake, hold_s, idle)
-            idle = min(idle + 1, GOSSIP_BACKSTOP_MAX_SLEEPS) if ran_out else 0
+            else:
+                continue  # the maj23 claims are due, and nothing else
+            idle = 0
+            backstop_at = min(backstop_at,
+                              time.monotonic() + PEER_GOSSIP_SLEEP)
 
-    def _gossip_wait(self, wake: threading.Event,
-                     hold_s: float | None = None, idle: int = 0) -> bool:
-        """Block until signalled, until the earliest relay hold ends, or
-        for the back-stop, whichever is first: PEER_GOSSIP_SLEEP, doubled
-        for every back-stop in a row (`idle`) that found nothing, up to
-        GOSSIP_BACKSTOP_MAX_SLEEPS of them. True when the back-stop ran
-        out."""
-        backstop = PEER_GOSSIP_SLEEP * min(2 ** idle,
-                                           GOSSIP_BACKSTOP_MAX_SLEEPS)
-        held = hold_s is not None and hold_s < backstop
-        if wake.wait(hold_s if held else backstop):
-            self.gossip_wakes_event += 1
-            return False
-        if held:
-            self.gossip_wakes_hold += 1
-            return False
-        self.gossip_wakes_backstop += 1
-        return True
+    def _gossip_sweep(self, everyone: bool = False) -> tuple[int, float | None]:
+        """One look at every marked peer (at all of them for a back-stop),
+        in an order that starts one peer further each sweep. Returns the
+        items sent and the instant the earliest relay hold of ANY peer
+        ends (None: no vote is held). First the peers served from the
+        round state, each until it needs nothing more or its channel is
+        full; then ONE stored item for each peer that lags behind our
+        height, so that a node catching up holds no vote round back."""
+        states = self._states
+        n = len(states)
+        now = time.monotonic()
+        looks = []
+        start = self._gossip_turn % n if n else 0
+        for i in range(n):
+            ps = states[(start + i) % n]
+            g = ps.gossip
+            data = votes = told = False
+            if g.data:
+                g.data = False
+                data = told = True
+            if g.votes:
+                g.votes = False
+                votes = told = True
+            if g.hold_until is not None and g.hold_until <= now:
+                votes = told = True
+            if told or everyone:
+                looks.append((ps, data or everyone, votes or everyone, told))
+        sent = 0
+        if looks:
+            self._gossip_turn += 1
+            self.gossip_sweeps += 1
+            self.gossip_peer_looks += len(looks)
+            rs = self.con_s.get_round_state()  # once, AFTER the marks
+            once: dict = {}   # an item's encoding, made once a sweep
+            stored = []
+            for ps, data, votes, told in looks:
+                k = self._guarded(self._gossip_peer, ps, rs, once, data,
+                                  votes, stored)
+                sent += k
+                if not told:
+                    self.gossip_backstop_sends += k
+            for ps, duties in stored:
+                sent += self._guarded(self._gossip_peer_stored, ps, rs,
+                                      once, duties)
+            self.gossip_sends += sent
+        holds = [ps.gossip.hold_until for ps in states
+                 if ps.gossip.hold_until is not None]
+        return sent, min(holds, default=None)
+
+    def _gossip_peer(self, ps: PeerState, rs, once: dict, data: bool,
+                     votes: bool, stored: list) -> int:
+        """Everything the round state holds for one peer, its data and
+        then its votes, each as (its look, the mark that asks for it
+        again); a peer that needs the store goes onto `stored` with the
+        duties that said so."""
+        g = ps.gossip
+        duties = []
+        if data:
+            duties.append((self._gossip_data_pass, g.wake_data))
+        if votes:
+            g.hold_until = None
+            duties.append((self._gossip_votes_pass, g.wake_votes))
+        sent, behind = 0, []
+        for look, again in duties:
+            while not g.stopped:
+                got, hold_s = look(ps, rs, once)
+                if got == _SENT:
+                    sent += 1
+                    continue
+                if got == _STORE:
+                    behind.append((look, again))
+                elif got == _FULL:
+                    self._send_full(again)
+                elif hold_s is not None:
+                    g.hold_until = time.monotonic() + hold_s
+                break
+        if behind:
+            stored.append((ps, behind))
+        return sent
+
+    def _gossip_peer_stored(self, ps: PeerState, rs, once: dict,
+                            duties: list) -> int:
+        """ONE item of a committed height for a peer behind ours, read
+        from the store; given one, the peer stays marked for the next."""
+        for look, again in duties:
+            if ps.gossip.stopped:
+                break
+            got, _hold = look(ps, rs, once, stored=True)
+            if got == _SENT:
+                for _look, mark in duties:
+                    mark()
+                return 1
+            if got == _FULL:
+                self._send_full(again)
+        return 0
+
+    def _guarded(self, look, ps: PeerState, *args) -> int:
+        """One peer's fault (a connection that breaks under the sweep's
+        hands) ends no other peer's gossip."""
+        try:
+            return look(ps, *args)
+        except Exception:  # noqa: BLE001
+            self.logger.exception("gossip to peer %s", _peer_label(ps.peer))
+            return 0
+
+    def _send_full(self, again) -> None:
+        """A peer's channel refused an item: its mirror bit stays clear,
+        and `again` marks the peer once its connection has flushed."""
+        self.gossip_send_full += 1
+        self._wakes.at(again, time.monotonic() + SEND_FULL_RETRY)
 
     def _note_own_send(self, height: int, key: tuple) -> None:
         """The first send of an item of OUR OWN origin (our proposal, its
@@ -1164,72 +1317,69 @@ class ConsensusReactor(Reactor, BaseService):
 
     # -- gossip_data (reactor.go:413-535) ----------------------------------
 
-    def _gossip_data_routine(self, peer, ps: PeerState, gw: _PeerGossip) -> None:
-        self._gossip_routine(
-            gw, gw.data, lambda: (self._gossip_data_pass(peer, ps), None)
-        )
+    def _gossip_data_pass(self, ps: PeerState, rs, once: dict,
+                          stored: bool = False) -> tuple[int, None]:
+        """One look of gossip_data at one peer: at most ONE item."""
+        return self._send_data(ps.peer, ps, rs, once, stored), None
 
-    def _gossip_data_pass(self, peer, ps: PeerState) -> bool:
-        """One look of gossip_data; True when it found something to send."""
-        rs = self.con_s.get_round_state()
+    def _send_data(self, peer, ps: PeerState, rs, once: dict,
+                   stored: bool) -> int:
         prs = ps.get_round_state()
+        parts = rs.proposal_block_parts
         # 1. send a block part the peer lacks
         if (
-            rs.proposal_block_parts is not None
+            parts is not None
             and prs.proposal_block_parts is not None
             and rs.height == prs.height
             and rs.round_ == prs.round_
         ):
-            have = rs.proposal_block_parts.bit_array()
-            needed = have.sub(prs.proposal_block_parts)
+            needed = parts.bit_array().sub(prs.proposal_block_parts)
             if not needed.is_empty():
                 index, ok = needed.pick_random()
                 if ok:
-                    part = rs.proposal_block_parts.get_part(index)
-                    msg = msgs.BlockPartMessage(rs.height, rs.round_, part)
-                    if peer.send(DATA_CHANNEL, _enc(msg)):
-                        ps.set_has_proposal_block_part(prs.height, prs.round_, index)
-                        self._note_own_send(
-                            rs.height, ("part", rs.height, rs.round_, index)
-                        )
-                    return True
+                    key = ("part", prs.height, prs.round_, index)
+                    raw = _enc_once(once, key, msgs.BlockPartMessage,
+                                    prs.height, prs.round_, parts.get_part(index))
+                    if not peer.try_send(DATA_CHANNEL, raw):
+                        return _FULL
+                    ps.set_has_proposal_block_part(prs.height, prs.round_, index)
+                    self._note_own_send(prs.height, key)
+                    return _SENT
         # 2. peer is on an older height: catch them up from the store
         if prs.height != 0 and rs.height > prs.height:
+            if not stored:
+                return _STORE
             return self._gossip_data_catchup(peer, ps, prs)
         # 3. send the proposal (+POL) if the peer doesn't have it
+        proposal = rs.proposal
         if (
             rs.height == prs.height
             and rs.round_ == prs.round_
-            and rs.proposal is not None
+            and proposal is not None
             and not prs.proposal
         ):
-            if peer.send(DATA_CHANNEL, _enc(msgs.ProposalMessage(rs.proposal))):
-                ps.set_has_proposal(rs.proposal)
-                self._note_own_send(
-                    rs.height, ("proposal", rs.height, rs.round_)
-                )
-            if 0 <= rs.proposal.pol_round < rs.round_ and rs.votes is not None:
-                pol = rs.votes.prevotes(rs.proposal.pol_round)
+            key = ("proposal", prs.height, prs.round_)
+            raw = _enc_once(once, key, msgs.ProposalMessage, proposal)
+            if not peer.try_send(DATA_CHANNEL, raw):
+                return _FULL
+            ps.set_has_proposal(proposal)
+            self._note_own_send(prs.height, key)
+            if 0 <= proposal.pol_round < rs.round_ and rs.votes is not None:
+                pol = rs.votes.prevotes(proposal.pol_round)
                 if pol is not None:
-                    peer.send(
-                        DATA_CHANNEL,
-                        _enc(
-                            msgs.ProposalPOLMessage(
-                                rs.height, rs.proposal.pol_round, pol.bit_array()
-                            )
-                        ),
-                    )
-            return True
-        return False
+                    peer.try_send(DATA_CHANNEL, _enc(msgs.ProposalPOLMessage(
+                        rs.height, proposal.pol_round, pol.bit_array())))
+            return _SENT
+        return _IDLE
 
-    def _gossip_data_catchup(self, peer, ps: PeerState, prs: PeerRoundState) -> bool:
+    def _gossip_data_catchup(self, peer, ps: PeerState, prs: PeerRoundState) -> int:
         """Send a part of a committed block (reactor.go:494-535)."""
         store = getattr(self.con_s, "block_store", None)
         if store is None:
-            return False
+            return _IDLE
         meta = store.load_block_meta(prs.height)
         if meta is None:
-            return False
+            return _IDLE
         if prs.proposal_block_parts is None:
             # init from the committed block's part-set header
             ps_header = meta.block_id.parts_header
@@ -1240,33 +1390,31 @@ class ConsensusReactor(Reactor, BaseService):
                     block_parts=BitArray(ps_header.total),
                 )
             )
-            return True
+            prs = ps.get_round_state()
+            if prs.proposal_block_parts is None:
+                return _IDLE  # the peer moved on meanwhile
         if meta.block_id.parts_header != prs.proposal_block_parts_header:
-            return False
+            return _IDLE
         needed = prs.proposal_block_parts.not_()
         if needed.is_empty():
-            return False
+            return _IDLE
         index, ok = needed.pick_random()
         if not ok:
-            return False
+            return _IDLE
         part = store.load_block_part(prs.height, index)
         if part is None:
-            return False
+            return _IDLE
         msg = msgs.BlockPartMessage(prs.height, prs.round_, part)
-        if peer.send(DATA_CHANNEL, _enc(msg)):
-            ps.set_has_proposal_block_part(prs.height, prs.round_, index)
-        return True
+        if not peer.try_send(DATA_CHANNEL, _enc(msg)):
+            return _FULL
+        ps.set_has_proposal_block_part(prs.height, prs.round_, index)
+        return _SENT
 
     # -- gossip_votes (reactor.go:537-645) ---------------------------------
 
-    def _gossip_votes_routine(self, peer, ps: PeerState, gw: _PeerGossip) -> None:
-        self._gossip_routine(
-            gw, gw.votes, lambda: self._gossip_votes_pass(peer, ps)
-        )
-
-    def _gossip_votes_pass(self, peer, ps: PeerState) -> tuple[bool, float | None]:
-        rs = self.con_s.get_round_state()
-        prs = ps.get_round_state()
+    def _gossip_votes_pass(self, ps: PeerState, rs, once: dict,
+                           stored: bool = False) -> tuple[int, float | None]:
+        """One look of gossip_votes at one peer: at most ONE vote."""
         if rs.validators is not None:
             ps.ensure_vote_bit_arrays(rs.height, rs.validators.size())
             # a peer lagging one height needs last-commit bit arrays
@@ -1275,16 +1423,20 @@ class ConsensusReactor(Reactor, BaseService):
                 ps.ensure_vote_bit_arrays(
                     rs.height - 1, rs.last_validators.size()
                 )
-        return self._pick_and_send_vote(peer, ps, rs, prs)
+        return self._pick_and_send_vote(
+            ps.peer, ps, rs, ps.get_round_state(), once, stored)
 
-    def _send_vote(self, peer, ps: PeerState, vote) -> bool:
-        """Send one vote and, ONLY on success, mark the peer as having
-        it (the vote carries its own coordinates). A failed send leaves
-        the bit clear so the gossip loop retries it later — and counts
-        on the per-peer failure series, so a wedge shows up as picks
-        outrunning sends instead of a frozen height vector."""
+    def _send_vote(self, peer, ps: PeerState, vote, raw: bytes | None = None) -> bool:
+        """Send one vote (`raw`: its encoding, where the sweep made it
+        already) and, ONLY on success, mark the peer as having it (the
+        vote carries its own coordinates). A refused send leaves the bit
+        clear so the gossip routine retries it later — and counts on the
+        per-peer failure series, so a wedge shows up as picks outrunning
+        sends instead of a frozen height vector."""
         ps.m_vote_picks.inc()
-        if peer.send(VOTE_CHANNEL, _enc(msgs.VoteMessage(vote))):
+        if raw is None:
+            raw = _enc(msgs.VoteMessage(vote))
+        if peer.try_send(VOTE_CHANNEL, raw):
             ps.set_has_vote(
                 vote.height, vote.round_, vote.type_, vote.validator_index
             )
@@ -1338,14 +1490,16 @@ class ConsensusReactor(Reactor, BaseService):
             return 0.0
         return max(0.0, t + delay - time.monotonic())
 
-    def _pick_and_send_vote(self, peer, ps: PeerState, rs,
-                            prs: PeerRoundState) -> tuple[bool, float | None]:
+    def _pick_and_send_vote(self, peer, ps: PeerState, rs, prs: PeerRoundState,
+                            once: dict, stored: bool = False,
+                            ) -> tuple[int, float | None]:
         """One needed vote, if any (reactor.go:609-645 gossipVotesForHeight
-        + same-height/lastCommit/catchup cases). Returns (sent, hold_s):
-        when nothing went out and votes the peer needs sit behind the
-        lazy-relay screen, hold_s is the time until the earliest of
-        them may go — what the routine waits, instead of a whole
-        back-stop on top of the hold."""
+        + same-height/lastCommit/catchup cases). Returns (what it came
+        to, hold_s): when nothing went out and votes the peer needs sit
+        behind the lazy-relay screen, hold_s is the time until the
+        earliest of them may go — what the routine waits, instead of a
+        whole back-stop on top of the hold. The stored seen-commit of a
+        peer far behind is read only with `stored` (_STORE asks for it)."""
         hold_s: float | None = None
         delay = self._relay_delay(ps) if self.gossip_dedup else 0.0
 
@@ -1356,41 +1510,40 @@ class ConsensusReactor(Reactor, BaseService):
                 hold_s = left
             return left
 
-        def send(vote) -> tuple[bool, None]:
-            ok = self._send_vote(peer, ps, vote)
-            if ok:
-                self._note_own_send(
-                    vote.height,
-                    (vote.height, vote.round_, vote.type_,
-                     vote.validator_index),
-                )
-            return ok, None
+        def send(vote) -> tuple[int, None]:
+            key = (vote.height, vote.round_, vote.type_, vote.validator_index)
+            raw = _enc_once(once, key, msgs.VoteMessage, vote)
+            if not self._send_vote(peer, ps, vote, raw):
+                return _FULL, None
+            self._note_own_send(vote.height, key)
+            return _SENT, None
 
+        votes = rs.votes
         # same height
-        if rs.height == prs.height and rs.votes is not None:
+        if rs.height == prs.height and votes is not None:
             # peer is lagging in rounds: their POL prevotes
             if prs.step <= RoundStep.PROPOSE and prs.round_ != -1 and \
                prs.round_ <= rs.round_ and prs.proposal_pol_round != -1:
-                pol = rs.votes.prevotes(prs.proposal_pol_round)
+                pol = votes.prevotes(prs.proposal_pol_round)
                 vote = ps.pick_vote_to_send(pol, hold_of) if pol else None
                 if vote is not None:
                     return send(vote)
             if prs.step <= RoundStep.PREVOTE_WAIT and prs.round_ != -1 and \
                prs.round_ <= rs.round_:
                 vote = ps.pick_vote_to_send(
-                    rs.votes.prevotes(prs.round_), hold_of
+                    votes.prevotes(prs.round_), hold_of
                 )
                 if vote is not None:
                     return send(vote)
             if prs.step <= RoundStep.PRECOMMIT_WAIT and prs.round_ != -1 and \
                prs.round_ <= rs.round_:
                 vote = ps.pick_vote_to_send(
-                    rs.votes.precommits(prs.round_), hold_of
+                    votes.precommits(prs.round_), hold_of
                 )
                 if vote is not None:
                     return send(vote)
             if prs.proposal_pol_round != -1:
-                pol = rs.votes.prevotes(prs.proposal_pol_round)
+                pol = votes.prevotes(prs.proposal_pol_round)
                 vote = ps.pick_vote_to_send(pol, hold_of) if pol else None
                 if vote is not None:
                     return send(vote)
@@ -1404,27 +1557,30 @@ class ConsensusReactor(Reactor, BaseService):
         # hole wedged 2-2 height splits permanently: the two ahead nodes
         # couldn't advance (no quorum at the new height), so the +2
         # branch never engaged, and the laggards never saw the commit.
-        if rs.height == prs.height + 1 and rs.last_commit is not None:
-            if isinstance(rs.last_commit, AggregateLastCommit):
+        last_commit = rs.last_commit
+        if rs.height == prs.height + 1 and last_commit is not None:
+            if isinstance(last_commit, AggregateLastCommit):
                 # our last commit exists only in aggregate form (we
                 # ourselves finalized from a proof): no per-vote sends
                 # possible — ship the whole commit
                 return self._send_agg_commit(
-                    peer, ps, prs.height, rs.last_commit.agg
+                    peer, ps, prs.height, last_commit.agg
                 ), None
             if rs.last_validators is not None:
                 ps.ensure_catchup_commit_round(
-                    prs.height, rs.last_commit.round_,
+                    prs.height, last_commit.round_,
                     rs.last_validators.size(),
                 )
                 prs = ps.get_round_state()
-            vote = ps.pick_vote_to_send(rs.last_commit, hold_of)
+            vote = ps.pick_vote_to_send(last_commit, hold_of)
             if vote is not None:
                 return send(vote)
         # peer is far behind: catch up with the stored seen-commit
         if rs.height >= prs.height + 2 and prs.height > 0:
             store = getattr(self.con_s, "block_store", None)
             if store is not None:
+                if not stored:
+                    return _STORE, None
                 commit = store.load_block_commit(prs.height)
                 if commit is not None:
                     if commit_is_aggregate(commit):
@@ -1440,26 +1596,27 @@ class ConsensusReactor(Reactor, BaseService):
                     )
                     vote = self._pick_commit_vote_to_send(ps, prs, commit)
                     if vote is not None:
-                        return self._send_vote(peer, ps, vote), None
-        return False, hold_s
+                        return (_SENT if self._send_vote(peer, ps, vote)
+                                else _FULL), None
+        return _IDLE, hold_s
 
-    def _send_agg_commit(self, peer, ps: PeerState, height: int, agg) -> bool:
+    def _send_agg_commit(self, peer, ps: PeerState, height: int, agg) -> int:
         """One whole-commit catchup send, per-peer deduplicated: the
         aggregate replaces N per-vote sends, so it goes out once per
         lagging height (re-armed after a short hold in case the frame
         was lost). Marks only on successful send, like _send_vote."""
         if not ps.agg_commit_due(height):
-            return False
+            return _IDLE
         msg = msgs.AggregateCommitMessage(height, agg)
-        if peer.send(DATA_CHANNEL, _enc(msg)):
+        if peer.try_send(DATA_CHANNEL, _enc(msg)):
             ps.mark_agg_commit_sent(height)
             ps.m_catchup_commits.inc()
             self.agg_commits_sent += 1
-            return True
+            return _SENT
         fr = getattr(self.con_s, "flightrec", None)
         if fr is not None:
             fr.record("gossip_send_fail", peer=_peer_label(peer))
-        return False
+        return _FULL
 
     def _pick_commit_vote_to_send(self, ps: PeerState, prs: PeerRoundState, commit):
         """Catch-up votes come from a Commit, not a VoteSet. Like
@@ -1483,28 +1640,33 @@ class ConsensusReactor(Reactor, BaseService):
 
     # -- query_maj23 (reactor.go:647-739) ----------------------------------
 
-    def _query_maj23_routine(self, peer, ps: PeerState, stop: threading.Event) -> None:
-        while self.is_running() and not stop.is_set():
-            stop.wait(PEER_QUERY_MAJ23_SLEEP)
-            if self.fast_sync or not self.is_running() or stop.is_set():
+    def _query_maj23_sweep(self) -> None:
+        """Every PEER_QUERY_MAJ23_SLEEP, every peer at our height is told
+        which block we saw +2/3 of the votes for, in its own round."""
+        rs = self.con_s.get_round_state()
+        votes = rs.votes
+        if votes is None:
+            return
+        once: dict = {}  # a claim's encoding, made once for all peers
+        for ps in self._states:
+            if ps.gossip.stopped:
                 continue
-            rs = self.con_s.get_round_state()
             prs = ps.get_round_state()
-            if rs.votes is None or rs.height != prs.height:
+            if rs.height != prs.height:
                 continue
             sends = []
-            prevotes = rs.votes.prevotes(prs.round_)
+            prevotes = votes.prevotes(prs.round_)
             if prevotes is not None:
                 maj = prevotes.two_thirds_majority()
                 if maj is not None:
                     sends.append((prs.round_, VOTE_TYPE_PREVOTE, maj))
-            precommits = rs.votes.precommits(prs.round_)
+            precommits = votes.precommits(prs.round_)
             if precommits is not None:
                 maj = precommits.two_thirds_majority()
                 if maj is not None:
                     sends.append((prs.round_, VOTE_TYPE_PRECOMMIT, maj))
             if prs.proposal_pol_round >= 0:
-                pol = rs.votes.prevotes(prs.proposal_pol_round)
+                pol = votes.prevotes(prs.proposal_pol_round)
                 if pol is not None:
                     maj = pol.two_thirds_majority()
                     if maj is not None:
@@ -1513,7 +1675,6 @@ class ConsensusReactor(Reactor, BaseService):
                 # maj23 claims ride the STATE channel, where receive()
                 # handles them (reference reactor.go:662 sends these on
                 # StateChannel too)
-                peer.try_send(
-                    STATE_CHANNEL,
-                    _enc(msgs.VoteSetMaj23Message(prs.height, round_, type_, block_id)),
-                )
+                ps.peer.try_send(STATE_CHANNEL, _enc_once(
+                    once, (round_, type_), msgs.VoteSetMaj23Message,
+                    prs.height, round_, type_, block_id))

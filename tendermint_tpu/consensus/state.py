@@ -1694,7 +1694,7 @@ class ConsensusState(BaseService):
                 ("proposal", proposal.height, proposal.round_)
             )
         # no event carries this change of the round state, so the
-        # reactor's gossip routines are told directly: the proposer's
+        # reactor's gossip routine is told directly: the proposer's
         # sends begin now, and a relayer's as soon as parts follow
         if self.gossip_wake is not None:
             self.gossip_wake()

@@ -473,7 +473,7 @@ class Node(BaseService):
                 "vote_accepted": self.consensus_state.vote_accepted,
                 "peer_msg_drops": self.consensus_state.peer_msg_drops,
             }
-            # how the gossip routines' waits ended: sends that only the
+            # how the gossip routine's waits ended: sends that only the
             # back-stop found are a missing wake-up (consensus/reactor.py)
             from tendermint_tpu.consensus.reactor import GOSSIP_COUNTERS
 

@@ -154,9 +154,11 @@ def build_registry(node) -> telemetry.Registry:
                 node.consensus_reactor.part_announces_sent,
             "gossip_part_announces_applied":
                 node.consensus_reactor.part_announces_applied,
-            # round 26: the gossip routines wake on events. Passes that
-            # sent, how their waits ended, and the sends that only the
-            # idle back-stop found (a missing signal: near 0)
+            # the reactor's one gossip routine wakes on events (round
+            # 26, 33): items sent, how its waits ended, the sends that
+            # only the idle back-stop found (a missing signal: near 0),
+            # its sweeps, the peers they looked at, the items a full
+            # channel refused
             **{k: getattr(node.consensus_reactor, k)
                for k in GOSSIP_COUNTERS},
         }

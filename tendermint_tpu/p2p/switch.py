@@ -57,6 +57,31 @@ class Reactor:
         pass
 
 
+def _dial(address: tuple[str, int], timeout: float) -> socket.socket:
+    """socket.create_connection with SO_REUSEADDR set before the connect.
+    An outbound connection takes a local port from the ephemeral range,
+    and without the option a listener cannot bind that port while the
+    connection lives: where the range also holds the ports that nodes
+    are about to listen on (a testnet on one host whose launcher drew
+    them with bind(0): PERF.md section 7, fault 9), a peer's dial made a
+    node that booted a moment later die on `Address already in use`.
+    With it on both sockets, as every listener here sets it, the bind
+    holds; nothing else about the connection changes."""
+    err: OSError | None = None
+    for family, kind, proto, _name, sockaddr in socket.getaddrinfo(
+            *address, type=socket.SOCK_STREAM):
+        sock = socket.socket(family, kind, proto)
+        try:
+            sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+            sock.settimeout(timeout)
+            sock.connect(sockaddr)
+            return sock
+        except OSError as exc:
+            err = exc
+            sock.close()
+    raise err or OSError(f"no address to dial for {address!r}")
+
+
 class Switch(BaseService):
     def __init__(
         self,
@@ -357,9 +382,7 @@ class Switch(BaseService):
         try:
             if self.filter_conn_by_addr:
                 self.filter_conn_by_addr(addr)
-            sock = socket.create_connection(
-                addr.dial_string(), timeout=self.peer_config.dial_timeout
-            )
+            sock = _dial(addr.dial_string(), self.peer_config.dial_timeout)
             return self.add_peer_from_stream(
                 SocketStream(sock),
                 outbound=True,

@@ -79,7 +79,7 @@ def test_has_vote_mid_pick_race_is_benign():
         def __init__(self, ok):
             self.ok = ok
 
-        def send(self, ch, raw):
+        def try_send(self, ch, raw):
             return self.ok
 
     class _Vote:

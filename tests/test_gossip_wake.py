@@ -1,10 +1,12 @@
-"""The gossip routines wake on the event they used to poll for (round 26).
+"""The reactor gossips from ONE routine over all its peers (round 33),
+woken by the event it used to poll for (round 26).
 
-Each of a peer's two gossip routines blocks on a wake signal with
-PEER_GOSSIP_SLEEP as the time-out of that wait. Nothing here is timed
-against a 100 ms tick: the back-stop is patched to several seconds, so
-a send that shows up within a second can only have been woken by a
-signal (or by a relay hold's end, where the test says so).
+An event marks the peers it concerns and sets the routine's one signal;
+the routine takes the marks, reads the round state once and sweeps the
+marked peers, with PEER_GOSSIP_SLEEP as the time-out of its wait. Nothing
+here is timed against a 100 ms tick: the back-stop is patched to several
+seconds, so a send that shows up within a second can only have been woken
+by a signal (or by a relay hold's end, where the test says so).
 """
 
 from __future__ import annotations
@@ -98,16 +100,25 @@ class _ConState:
         self.trace = TraceRecorder()
         self.trace.begin(HEIGHT)
         self.gossip_wake = None
+        self.round_state_reads = 0
 
     def get_round_state(self):
+        self.round_state_reads += 1
         return self.rs
+
+    def stop(self):
+        pass
 
 
 class _Peer:
-    def __init__(self, name: str):
+    def __init__(self, name: str, log: list | None = None):
         self._id = name
         self._kv: dict = {}
         self.sent: list = []  # (monotonic, channel, decoded message)
+        self.full: set = set()  # channels whose send queue is "full"
+        self.refused = 0
+        self.on_send = None   # called with the message, before it is kept
+        self._log = log       # the net's: ids in the order of the sends
         self._cond = threading.Condition()
 
     def id(self):
@@ -120,12 +131,21 @@ class _Peer:
         self._kv[k] = v
 
     def send(self, ch, raw):
+        msg = _dec(raw)
+        if self.on_send is not None:
+            self.on_send(msg)
         with self._cond:
-            self.sent.append((time.monotonic(), ch, _dec(raw)))
+            self.sent.append((time.monotonic(), ch, msg))
+            if self._log is not None:
+                self._log.append((self._id, type(msg)))
             self._cond.notify_all()
         return True
 
-    try_send = send
+    def try_send(self, ch, raw):
+        if ch in self.full:
+            self.refused += 1
+            return False
+        return self.send(ch, raw)
 
     def of(self, cls) -> list:
         with self._cond:
@@ -157,19 +177,23 @@ class _Net:
     """One reactor over a stub consensus state, with stub peers whose
     mirrors sit at our height and round. `switch` gives the reactor one
     to broadcast on (without it, as in a harness reactor, a broadcast
-    is a no-op)."""
+    is a no-op). With `start=False` no routine runs and the test makes
+    the sweeps itself (`net.r._gossip_sweep()`), one at a time."""
 
     def __init__(self, n_peers: int = 1, at_our_height: bool = True,
-                 switch: bool = False):
+                 switch: bool = False, start: bool = True):
         self.cs = _ConState()
         self.r = ConsensusReactor(self.cs)
-        self.r._started = True  # the routines guard on is_running()
+        self.r._started = True  # the routine guards on is_running()
         self.evsw = EventSwitch()
         self.evsw.start()
         self.r.set_event_switch(self.evsw)
-        self.peers = [_Peer(f"peer-{i:04d}") for i in range(n_peers)]
+        self.log: list = []
+        self.peers = [_Peer(f"peer-{i:04d}", self.log) for i in range(n_peers)]
         if switch:
             self.r.switch = _Switch(self.peers)
+        if start:
+            self.r._start_gossip()
         for p in self.peers:
             self.r.add_peer(p)
             if at_our_height:
@@ -185,29 +209,35 @@ class _Net:
         )))
 
     def settle(self) -> None:
-        """Let every routine finish the passes its start and the
-        set-up's wakes caused, so that it sits in its wait."""
+        """Let the routine finish the sweeps its start and the set-up's
+        marks caused, so that it sits in its wait."""
         deadline = time.monotonic() + SOON
         while time.monotonic() < deadline:
             before = self.waits()
             time.sleep(0.05)
             if self.waits() == before:
                 return
-        raise AssertionError("routines never came to rest")
+        raise AssertionError("the routine never came to rest")
 
     def waits(self) -> int:
         r = self.r
         return (r.gossip_wakes_event + r.gossip_wakes_hold
-                + r.gossip_wakes_backstop + r.gossip_sends)
+                + r.gossip_wakes_backstop + r.gossip_peer_looks)
 
-    def threads(self) -> list:
-        with self.r._mtx:
-            return [t for ts in self.r._peer_threads.values() for t in ts]
+    def own_vote(self, index: int, type_=VOTE_TYPE_PREVOTE) -> Vote:
+        """Our own vote enters the vote set and its event fires."""
+        votes = self.cs.rs.votes
+        vote = (votes.pre if type_ == VOTE_TYPE_PREVOTE else votes.pc).add(index)
+        self.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(vote))
+        return vote
 
     def close(self) -> None:
         for p in self.peers:
             self.r.remove_peer(p, "test over")
+        self.r.on_stop()
         self.evsw.stop()
+        if self.r._gossip_thread is not None:
+            self.r._gossip_thread.join(SOON)
 
 
 @pytest.fixture
@@ -227,7 +257,7 @@ def net_factory(monkeypatch):
 
 def test_own_vote_goes_out_on_its_event(net_factory):
     """Our own prevote enters the vote set and EVENT_VOTE fires: the
-    peer's vote routine sends it at once, with its lag on the trace."""
+    routine sends it to the peer at once, with its lag on the trace."""
     net = net_factory()
     peer = net.peers[0]
     net.settle()
@@ -252,8 +282,8 @@ def test_own_vote_goes_out_on_its_event(net_factory):
 def test_proposal_then_part_go_out_on_their_signals(net_factory):
     """set_proposal fires no event: the state calls the reactor's wake
     directly (the reactor hangs it on the state). The part follows on
-    EVENT_PROPOSAL_BLOCK_PART. The data routine sends proposal, then
-    part, each once."""
+    EVENT_PROPOSAL_BLOCK_PART. The routine sends proposal, then part,
+    each once."""
     net = net_factory()
     peer = net.peers[0]
     net.settle()
@@ -285,7 +315,7 @@ def test_proposal_then_part_go_out_on_their_signals(net_factory):
 def test_peer_step_into_our_height_wakes_that_peer_only(net_factory):
     """The case that cost the proposer a second sleep: the proposal is
     there, the peer's NewRoundStep for our height is not. When it
-    comes, that peer's routines look again; the other peer's sleep on."""
+    comes, that peer is looked at again; the other peer is not."""
     net = net_factory(n_peers=2, at_our_height=False)
     late, other = net.peers
     rs = net.cs.rs
@@ -299,13 +329,14 @@ def test_peer_step_into_our_height_wakes_that_peer_only(net_factory):
     net.settle()
     assert not late.of(msgs.ProposalMessage) and not late.of(msgs.VoteMessage)
 
-    before = net.r.gossip_wakes_event
+    wakes, looks = net.r.gossip_wakes_event, net.r.gossip_peer_looks
     net.step_to(late, HEIGHT)
     assert late.wait_for(msgs.ProposalMessage)
     assert late.wait_for(msgs.VoteMessage)
     net.settle()
-    # two waits ended on a signal, late's: other's two routines slept on
-    assert net.r.gossip_wakes_event - before == 2
+    # one wait ended on the signal, and the sweep looked at late alone
+    assert net.r.gossip_wakes_event - wakes == 1
+    assert net.r.gossip_peer_looks - looks == 1
     assert not other.of(msgs.ProposalMessage) and not other.of(msgs.VoteMessage)
 
 
@@ -331,45 +362,47 @@ def _receive_case(what: str):
 
 
 @pytest.mark.parametrize(
-    "what,woken",
+    "what,looked_at",
     [
         # only ever take away from what is sendable to the peer
         ("has_vote", 0), ("has_votes", 0), ("has_block_part", 0), ("vote", 0),
         ("block_part", 0),
-        # can add to it: that peer's two routines, nobody else's
-        ("commit_step", 2), ("proposal_pol", 2), ("vote_set_bits", 2),
-        ("vote_set_maj23", 2),
+        # can add to it: that peer, nobody else
+        ("commit_step", 1), ("proposal_pol", 1), ("vote_set_bits", 1),
+        ("vote_set_maj23", 1),
     ],
 )
-def test_a_peers_message_wakes_its_routines_only_if_it_can_add(net_factory, what, woken):
+def test_a_peers_message_wakes_its_routines_only_if_it_can_add(
+        net_factory, what, looked_at):
     """HasVote, its burst form, HasBlockPart and a received vote's or
-    part's own mirror bit only REDUCE what is sendable: no wake. What the peer asks for or
-    steps into wakes that peer's routines, and the other peers' sleep on
-    (three peers here: six waits would end if every routine woke)."""
+    part's own mirror bit only REDUCE what is sendable: no mark, no wake.
+    What the peer asks for or steps into marks that peer, and the sweep
+    looks at it alone (three peers here: three looks if it took all)."""
     net = net_factory(n_peers=3)
     net.cs.add_peer_message = lambda msg, peer_id: None
     net.cs.rs.votes.set_peer_maj23 = lambda *a: None
     net.settle()
-    before = net.waits()
+    wakes, looks = net.r.gossip_wakes_event, net.r.gossip_peer_looks
     ch, msg = _receive_case(what)
     net.r.receive(ch, net.peers[0], _enc(msg))
     net.settle()
-    assert net.waits() - before == woken
+    assert net.r.gossip_wakes_event - wakes == looked_at
+    assert net.r.gossip_peer_looks - looks == looked_at
     assert net.r.gossip_sends == 0
 
 
 def test_held_vote_goes_out_when_its_hold_ends(net_factory):
     """A vote we received moments ago is held by the lazy-relay screen.
-    Its event wakes no routine while it is held (PR 27: that was a
-    thread switch to find it held and one more at the hold's end, per
-    peer and vote); ONE deferred wake when the hold ends does — not a
-    back-stop on top of it — and nothing is sent before."""
+    Its event marks no peer while it is held (PR 27: that was a wake to
+    find it held and one more at the hold's end, per peer and vote); ONE
+    deferred mark when the hold ends does — not a back-stop on top of
+    it — and nothing is sent before."""
     net = net_factory()
     peer = net.peers[0]
     net.settle()
     hold = net.r._relay_delay(peer.get(PEER_STATE_KEY))
     assert 0.0 < hold < SOON < BACKSTOP
-    before = net.waits()
+    wakes, looks = net.r.gossip_wakes_event, net.r.gossip_peer_looks
 
     vote = net.cs.rs.votes.pre.add(1)
     net.cs.vote_recv_mono[(HEIGHT, 0, VOTE_TYPE_PREVOTE, 1)] = t0 = time.monotonic()
@@ -380,8 +413,29 @@ def test_held_vote_goes_out_when_its_hold_ends(net_factory):
     assert t_sent - t0 >= hold, "sent inside its hold"
     assert t_sent - t0 < hold + SOON, "waited a back-stop on top of the hold"
     net.settle()
-    # the votes routine alone: one wake at the hold's end, one send
-    assert net.waits() - before == 2 and net.r.gossip_sends == 1
+    # one wake at the hold's end, one look, one send
+    assert net.r.gossip_wakes_event - wakes == 1
+    assert net.r.gossip_peer_looks - looks == 1 and net.r.gossip_sends == 1
+    assert net.r.gossip_backstop_sends == 0
+
+
+def test_a_hold_found_at_the_look_ends_the_wait_by_itself(net_factory):
+    """A look that finds only held votes (a mark brought it there before
+    their time) makes the routine wait for the earliest hold of any
+    peer, not for the back-stop: no deferred mark is pending here."""
+    net = net_factory(n_peers=2)
+    net.settle()
+    hold = 0.3
+    vote = net.cs.rs.votes.pre.add(1)
+    key = (HEIGHT, 0, VOTE_TYPE_PREVOTE, 1)
+    net.cs.vote_recv_mono[key] = time.monotonic() + hold - net.r._relay_delay(
+        net.ps(net.peers[0]))
+    holds = net.r.gossip_wakes_hold
+    net.r.wake_votes_gossip()   # not EVENT_VOTE: nothing is deferred
+    for p in net.peers:
+        assert p.wait_for(msgs.VoteMessage, timeout=hold + SOON)
+        assert p.of(msgs.VoteMessage)[0][1].vote == vote
+    assert net.r.gossip_wakes_hold > holds
     assert net.r.gossip_backstop_sends == 0
 
 
@@ -403,56 +457,101 @@ def test_own_vote_is_not_kept_back_by_a_held_one(net_factory):
 
 
 def test_event_between_the_look_and_the_wait_is_not_lost(net_factory):
-    """The signal is cleared BEFORE the round state is read. Drive the
-    worst interleaving with a hook: the vote appears and its event
-    fires after the pass has looked (and found nothing) and before the
-    routine waits. The wait must end at once."""
+    """The signal is cleared and the marks are taken BEFORE the round
+    state is read. Drive the worst interleaving with a hook: the vote
+    appears and its event fires after the sweep has looked (and found
+    nothing) and before the routine waits. The wait must end at once."""
     net = net_factory()
     peer = net.peers[0]
     net.settle()
-    real_wait = net.r._gossip_wait
-    votes_wake = net.ps(peer).gossip.votes
+    real_sweep = net.r._gossip_sweep
     fired = threading.Event()
 
-    def wait_after_a_late_event(wake, hold_s=None, idle=0):
-        if wake is votes_wake and not fired.is_set():
+    def sweep_then_a_late_event(everyone=False):
+        out = real_sweep(everyone)
+        if not fired.is_set():
             fired.set()
-            vote = net.cs.rs.votes.pre.add(0)
-            net.evsw.fire_event(tev.EVENT_VOTE, tev.EventDataVote(vote))
-        return real_wait(wake, hold_s, idle)
+            net.own_vote(0)
+        return out
 
-    net.r._gossip_wait = wait_after_a_late_event
-    # one more (empty) pass of the vote routine, ending in the hooked wait
-    votes_wake.set()
+    net.r._gossip_sweep = sweep_then_a_late_event
+    # one more (empty) sweep, ending in the hooked moment before the wait
+    net.r.wake_gossip()
     assert fired.wait(SOON)
     assert peer.wait_for(msgs.VoteMessage), "the wake-up was lost"
 
 
+def test_marks_set_from_many_threads_are_never_lost(net_factory):
+    """The marks are plain attributes that the threads firing events set
+    and the routine takes without a lock. More markers than cores, a
+    switch interval of 10 us, and in every round each marker makes one
+    vote sendable and then marks all peers, all at the same instant: a
+    mark lost to the routine's take would leave a vote to the back-stop
+    (5 s here), and the round would not complete."""
+    import sys
+
+    net = net_factory(n_peers=3)
+    pre = net.cs.rs.votes.pre
+    pre._size = 240
+    net.cs.rs.validators = SimpleNamespace(size=lambda: 240)
+    net.r.wake_gossip()
+    net.settle()
+    markers, rounds = 16, 15
+    gate = threading.Barrier(markers)
+
+    def mark(k: int) -> None:
+        for r in range(rounds):
+            gate.wait(SOON * 10)
+            pre.add(r * markers + k)       # the state first, then the mark
+            net.r.wake_votes_gossip()
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=mark, args=(k,)) for k in range(markers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(SOON * 20)
+        assert not any(t.is_alive() for t in threads)
+        for p in net.peers:
+            assert p.wait_for(msgs.VoteMessage, n=markers * rounds), \
+                len(p.of(msgs.VoteMessage))
+    finally:
+        sys.setswitchinterval(was)
+    for p in net.peers:
+        got = sorted(m.vote.validator_index for _t, m in p.of(msgs.VoteMessage))
+        assert got == list(range(markers * rounds))
+    assert net.r.gossip_backstop_sends == 0 and net.r.gossip_wakes_backstop == 0
+
+
 def test_idle_routine_backs_its_back_stop_off_and_an_event_resets_it():
-    """No spin, at the real back-stop: a routine with nothing to send,
+    """No spin, at the real back-stop: the routine with nothing to send,
     woken by nothing, looks after 0.1, 0.2, 0.4 and then every 0.8 s
     (PR 27: at ten looks a second each, a committee node's 62 routines
-    were 620 thread switches a second that found nothing). An event puts
-    it back to 0.1 s."""
+    were 620 thread switches a second that found nothing; it is one
+    routine now). An event puts it back to 0.1 s."""
     sleep = reactor_mod.PEER_GOSSIP_SLEEP
     top = reactor_mod.GOSSIP_BACKSTOP_MAX_SLEEPS
     assert top == 8 and sleep == 0.1
-    net = _Net()
+    net = _Net(n_peers=3)
     try:
         time.sleep(2.0)  # 0.1 + 0.2 + 0.4 + 0.8: at the longest wait now
-        before = net.r.gossip_wakes_backstop, net.r.gossip_wakes_event
+        before = (net.r.gossip_wakes_backstop, net.r.gossip_wakes_event,
+                  net.r.gossip_peer_looks)
         time.sleep(2.0)
         backstops = net.r.gossip_wakes_backstop - before[0]
-        events = net.r.gossip_wakes_event - before[1]
-        assert events == 0
-        # two routines of one peer, a look every 0.8 s each
-        assert 2 <= backstops <= 2 * 3 + 1, backstops
+        assert net.r.gossip_wakes_event - before[1] == 0
+        # ONE routine whatever the number of peers, a look every 0.8 s,
+        # and a back-stop looks at all three
+        assert 1 <= backstops <= 3, backstops
+        assert net.r.gossip_peer_looks - before[2] == 3 * backstops
         net.r.wake_gossip()          # found nothing either: back to 0.1 s
         time.sleep(0.05)
         before = net.r.gossip_wakes_backstop
         time.sleep(0.65)             # 0.1 + 0.2 end inside, 0.4 may
         fast = net.r.gossip_wakes_backstop - before
-        assert 4 <= fast <= 6, fast
+        assert 2 <= fast <= 3, fast
     finally:
         net.close()
     assert net.r.gossip_sends == 0 and net.r.gossip_backstop_sends == 0
@@ -461,41 +560,263 @@ def test_idle_routine_backs_its_back_stop_off_and_an_event_resets_it():
 def test_a_wake_that_finds_nothing_goes_back_to_a_full_wait(net_factory):
     net = net_factory()
     net.settle()
-    passes = net.waits()
+    wakes, looks = net.r.gossip_wakes_event, net.r.gossip_peer_looks
     for _ in range(3):
         net.r.wake_gossip()
         net.settle()
-    # three wakes, two routines: six waits ended, and no more than that
-    assert net.waits() - passes == 6
-    assert net.r.gossip_wakes_event >= 6 and net.r.gossip_sends == 0
+    # three wakes, one routine: three waits ended, and no more than that
+    assert net.r.gossip_wakes_event - wakes == 3
+    assert net.r.gossip_peer_looks - looks == 3
+    assert net.r.gossip_sends == 0 and net.r.gossip_wakes_backstop == 0
 
 
 def test_remove_peer_ends_both_routines_promptly(net_factory):
-    """The stop must end the wait too: with a back-stop of seconds the
-    routines are gone in far less."""
-    net = net_factory()
+    """A peer that goes has no routine to end any more: it is out of the
+    sweep at once, a mark that still lands on it wakes nobody, and the
+    one routine serves the peer that stays."""
+    net = net_factory(n_peers=2)
+    gone, stays = net.peers
     net.settle()
-    gossip = [t for t in net.threads() if "gossip" in t.name]
-    assert len(gossip) == 2 and all(t.is_alive() for t in gossip)
-    t0 = time.monotonic()
-    net.r.remove_peer(net.peers[0], "gone")
-    for t in gossip:
-        t.join(SOON)
-    assert not any(t.is_alive() for t in gossip)
-    assert time.monotonic() - t0 < SOON
-    assert net.r._states == ()
+    gossip = net.r._gossip_thread
+    assert gossip.is_alive() and gossip.name == "conR.gossip"
+    marks = net.ps(gone).gossip
+    net.r.remove_peer(gone, "gone")
+    assert net.r._states == (net.ps(stays),)
+    assert marks.stopped
+    wakes = net.r.gossip_wakes_event
+    marks.wake()                      # a deferred mark that was pending
+    net.settle()
+    assert net.r.gossip_wakes_event == wakes
+
+    net.own_vote(2)
+    assert stays.wait_for(msgs.VoteMessage)
+    net.settle()
+    assert not gone.of(msgs.VoteMessage)
+    assert gossip.is_alive()
 
 
 def test_on_stop_ends_every_peers_routines(net_factory):
+    """The stop must end the wait too: with a back-stop of seconds the
+    one routine is gone in far less."""
     net = net_factory(n_peers=3)
     net.settle()
-    gossip = [t for t in net.threads() if "gossip" in t.name]
-    assert len(gossip) == 6
-    net.cs.stop = lambda: None
+    gossip = net.r._gossip_thread
+    assert gossip.is_alive()
+    t0 = time.monotonic()
     net.r.on_stop()
-    for t in gossip:
-        t.join(SOON)
-    assert not any(t.is_alive() for t in gossip)
+    gossip.join(SOON)
+    assert not gossip.is_alive()
+    assert time.monotonic() - t0 < SOON
+
+
+def test_add_peer_starts_no_thread(net_factory):
+    """The reactor's thread count is independent of the number of its
+    peers: on_start starts conR.gossip, add_peer starts nothing."""
+    net = net_factory(n_peers=1)
+    net.settle()
+    before = {t.ident for t in threading.enumerate()}
+    more = [_Peer(f"more-{i:04d}") for i in range(15)]
+    for p in more:
+        net.r.add_peer(p)
+        net.step_to(p, HEIGHT)
+    net.peers.extend(more)
+    net.own_vote(1)
+    for p in more:
+        assert p.wait_for(msgs.VoteMessage)
+    started = [t.name for t in threading.enumerate()
+               if t.ident not in before and t.name.startswith("conR")]
+    assert started == []
+    ours = [t.name for t in threading.enumerate() if t.name.startswith("conR.")]
+    assert not [n for n in ours if n.startswith(
+        ("conR.gossipData", "conR.gossipVotes", "conR.queryMaj23"))]
+    assert net.r._gossip_thread.is_alive()
+
+
+def test_a_full_vote_channel_delays_no_other_peer_and_is_retried(
+        net_factory, monkeypatch):
+    """try_send, never send: a peer whose VOTE queue is full is passed
+    over, the same vote reaches the other peers in the same sweep, the
+    full peer's mirror bit stays clear, and it is looked at again after
+    SEND_FULL_RETRY (a deferred mark, not the back-stop)."""
+    monkeypatch.setattr(reactor_mod, "SEND_FULL_RETRY", 0.05)
+    net = net_factory(n_peers=3)
+    a, slow, c = net.peers
+    slow.send = None   # a blocking send to this peer would raise
+    slow.full.add(VOTE_CHANNEL)
+    net.settle()
+    t0 = time.monotonic()
+    net.own_vote(2)
+    for p in (a, c):
+        assert p.wait_for(msgs.VoteMessage)
+        assert p.of(msgs.VoteMessage)[0][0] - t0 < SOON
+    # retried, and refused, until the queue has room
+    deadline = time.monotonic() + SOON
+    while slow.refused < 3 and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert slow.refused >= 3
+    assert net.r.gossip_send_full == slow.refused
+    assert not net.ps(slow).prs.prevotes.get_index(2), "marked without a send"
+    assert not slow.of(msgs.VoteMessage)
+    slow.send = _Peer.send.__get__(slow)
+    slow.full.clear()
+    assert slow.wait_for(msgs.VoteMessage)
+    net.settle()
+    assert net.ps(slow).prs.prevotes.get_index(2)
+    assert [len(p.of(msgs.VoteMessage)) for p in net.peers] == [1, 1, 1]
+    assert net.r.gossip_backstop_sends == 0 and net.r.gossip_wakes_backstop == 0
+
+
+def test_the_order_of_peers_rotates_from_sweep_to_sweep(net_factory):
+    net = net_factory(n_peers=3, start=False)
+    net.r._gossip_sweep()  # the marks of the set-up
+    turn = net.r._gossip_turn
+    orders = []
+    for index in range(3):
+        del net.log[:]
+        net.own_vote(index)
+        sent, _hold = net.r._gossip_sweep()
+        assert sent == 3
+        orders.append([int(pid[-1]) for pid, cls in net.log
+                       if cls is msgs.VoteMessage])
+    first = turn % 3
+    assert orders == [[(first + k + i) % 3 for i in range(3)] for k in range(3)]
+
+
+def test_a_peer_removed_in_the_middle_of_a_sweep_gets_nothing_more(net_factory):
+    net = net_factory(n_peers=3, start=False)
+    net.r._gossip_sweep()
+    first = net.peers[net.r._gossip_turn % 3]
+    rest = [p for p in net.peers if p is not first]
+    # the sweep's first send takes the other two out: one as remove_peer
+    # does it, one whose connection breaks under the sweep's hands
+    def boom(ch, raw):
+        raise OSError("connection reset")
+
+    def first_send(msg):
+        first.on_send = None
+        net.r.remove_peer(rest[0], "gone mid-sweep")
+        rest[1].try_send = boom
+
+    first.on_send = first_send
+    for i in (0, 1):
+        net.cs.rs.votes.pre.add(i)
+    net.r.wake_votes_gossip()
+    sent, _hold = net.r._gossip_sweep()
+    assert sent == 2 and len(first.of(msgs.VoteMessage)) == 2
+    assert not rest[0].of(msgs.VoteMessage) and not rest[1].of(msgs.VoteMessage)
+    assert net.r.gossip_peer_looks >= 3
+
+
+class _Store:
+    """A block store that holds one committed height."""
+
+    def __init__(self, height: int, parts: PartSet, precommits: list):
+        self.height, self.parts, self.precommits = height, parts, precommits
+        self.reads: list = []
+
+    def load_block_meta(self, height):
+        if height != self.height:
+            return None
+        return SimpleNamespace(block_id=SimpleNamespace(
+            parts_header=self.parts.header()))
+
+    def load_block_part(self, height, index):
+        self.reads.append(("part", height, index))
+        return self.parts.get_part(index)
+
+    def load_block_commit(self, height):
+        self.reads.append(("commit", height))
+        return SimpleNamespace(precommits=self.precommits, round_=lambda: 0)
+
+
+def test_a_peer_behind_gets_one_stored_item_a_sweep(net_factory):
+    """Store-backed catch-up is one item a lagging peer a sweep, after
+    the peers at our height, which get all of theirs; the lagging peer
+    stays marked, so the next sweep follows at once, and it ends up with
+    every part and every precommit of the height it is at."""
+    net = net_factory(n_peers=3, at_our_height=False, start=False)
+    behind, here, there = net.peers
+    parts = PartSet.from_data(bytes(range(200)), 64)
+    old = _VoteSet(VOTE_TYPE_PRECOMMIT)
+    old.height = HEIGHT - 2
+    net.cs.block_store = _Store(
+        HEIGHT - 2, parts, [old.add(i) for i in range(3)] + [None])
+    net.step_to(behind, HEIGHT - 2)
+    for p in (here, there):
+        net.step_to(p, HEIGHT)
+    for i in range(3):
+        net.cs.rs.votes.pre.add(i)
+    net.r.wake_gossip()
+
+    sent, _hold = net.r._gossip_sweep()
+    assert sent == 3 + 3 + 1
+    for p in (here, there):
+        assert len(p.of(msgs.VoteMessage)) == 3
+    assert len(net.cs.block_store.reads) == 1
+    # the peers at our height were served before the store was read
+    assert [pid for pid, _cls in net.log[-1:]] == [behind.id()]
+    assert net.r._gossip_signal.is_set(), "the lagging peer is not marked"
+
+    sweeps = 1
+    while net.r._gossip_sweep()[0]:
+        sweeps += 1
+        assert len(net.cs.block_store.reads) <= sweeps + 1
+        assert sweeps < 20
+    got_parts = sorted(m.part.index for _t, m in behind.of(msgs.BlockPartMessage))
+    assert got_parts == list(range(parts.total)) and parts.total == 4
+    got_votes = sorted(m.vote.validator_index for _t, m in behind.of(msgs.VoteMessage))
+    assert got_votes == [0, 1, 2]
+    assert sweeps == parts.total + 3
+    assert len(here.of(msgs.VoteMessage)) == 3
+
+
+def test_maj23_claims_reach_every_peer_within_the_query_sleep(
+        net_factory, monkeypatch):
+    """queryMaj23 is a duty of the same routine: every
+    PEER_QUERY_MAJ23_SLEEP, all peers at our height in one pass."""
+    monkeypatch.setattr(reactor_mod, "PEER_QUERY_MAJ23_SLEEP", 0.2)
+    net = net_factory(n_peers=3, at_our_height=False)
+    maj = BlockID(hash=b"\x07" * 20)
+    net.cs.rs.votes.pre.two_thirds_majority = lambda: maj
+    net.cs.rs.votes.pc.two_thirds_majority = lambda: None
+    for p in net.peers[:2]:
+        net.step_to(p, HEIGHT)
+    t0 = time.monotonic()
+    for p in net.peers[:2]:
+        assert p.wait_for(msgs.VoteSetMaj23Message, timeout=0.2 + SOON)
+        t, claim = p.of(msgs.VoteSetMaj23Message)[0]
+        assert t - t0 < 0.2 + SOON
+        assert (claim.height, claim.round_, claim.type_) == (HEIGHT, 0, VOTE_TYPE_PREVOTE)
+        assert claim.block_id == maj
+        assert p.wait_for(msgs.VoteSetMaj23Message, n=2, timeout=0.2 + SOON)
+    assert not net.peers[2].of(msgs.VoteSetMaj23Message)  # not at our height
+    assert net.r.gossip_wakes_backstop == 0  # the claims' own timer
+
+
+def test_a_vote_is_encoded_once_for_fifteen_peers(net_factory, monkeypatch):
+    """One json.dumps a vote a sweep, not one a peer; and the round state
+    is read once a sweep, not once a peer an item."""
+    net = net_factory(n_peers=15, start=False)
+    net.r._gossip_sweep()
+    encoded = []
+    real_enc = reactor_mod._enc
+
+    def counting_enc(msg):
+        encoded.append(type(msg))
+        return real_enc(msg)
+
+    monkeypatch.setattr(reactor_mod, "_enc", counting_enc)
+    for i in (0, 1):
+        net.cs.rs.votes.pre.add(i)
+    net.r.wake_votes_gossip()
+    reads = net.cs.round_state_reads
+    sent, _hold = net.r._gossip_sweep()
+    assert sent == 30 and net.r.gossip_sends == 30
+    assert encoded.count(msgs.VoteMessage) == 2
+    assert net.cs.round_state_reads - reads == 1
+    for p in net.peers:
+        assert sorted(m.vote.validator_index
+                      for _t, m in p.of(msgs.VoteMessage)) == [0, 1]
 
 
 def _announced(peer) -> list:

@@ -304,6 +304,33 @@ def test_switch_tcp_listener_end_to_end():
         sw_b.stop()
 
 
+def test_an_outbound_connection_does_not_keep_a_listener_off_its_port():
+    """PERF.md section 7, fault 9: a peer's dial took, as its LOCAL port,
+    the port a node that booted a moment later was to serve RPC on, and
+    that node died on `Address already in use`. The dial sets
+    SO_REUSEADDR, so a listener (which sets it too) binds the port of a
+    live outbound connection."""
+    import socket
+
+    from tendermint_tpu.p2p.listener import Listener
+    from tendermint_tpu.p2p.switch import _dial
+
+    lst = Listener("127.0.0.1:0")
+    out = late = None
+    try:
+        out = _dial(("127.0.0.1", lst.internal_address().port), 3.0)
+        assert out.getsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR)
+        assert out.gettimeout() == 3.0
+        late = Listener(f"127.0.0.1:{out.getsockname()[1]}")
+        assert late.internal_address().port == out.getsockname()[1]
+    finally:
+        for s in (out, getattr(late, "sock", None), lst.sock):
+            if s is not None:
+                s.close()
+    with pytest.raises(OSError):
+        _dial(("127.0.0.1", lst.internal_address().port), 0.5)  # closed now
+
+
 def test_inbound_ip_range_count_released_on_peer_removal():
     """Regression (round 12, caught by the real-TCP chaos tier): the
     inbound IP-range count is taken on the RAW socket stream, which peer

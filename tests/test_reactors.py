@@ -779,7 +779,7 @@ def test_vote_gossip_marks_peer_only_on_successful_send():
             self.ok = ok
             self.sent = 0
 
-        def send(self, ch, raw):
+        def try_send(self, ch, raw):
             self.sent += 1
             return self.ok
 
