@@ -897,11 +897,26 @@ def _claim(st: _DaemonState, *, accept_cpu: bool,
         logger.info("stream chunk width: %d", st.stream_chunk)
         if verifier.stats()["cpu_sigs"]:
             raise ClaimError("the chunk bake-off was answered by the host")
+    # an open population (ops/ed25519_comb: more keys than pool slots)
+    # misses inside somebody's wait all day: every program a miss can
+    # need runs here once, at every bucket it can have, and the claim
+    # says so. A daemon whose status shows no `miss_programs_s` compiles
+    # them at first use.
+    miss_s: dict = {}
+    if best[1] == "comb":
+        from tendermint_tpu.ops import ed25519_comb as comb
+
+        if comb.open_population():
+            miss_s = comb.compile_miss_programs(make_full)
+            logger.info("open population: miss programs ready %s", miss_s)
+            if verifier.stats()["cpu_sigs"]:
+                raise ClaimError("a miss program was answered by the host")
     st.claim = {
         "cache_dir": cache_dir,
         "kernels": report,
         "served": best[1],
         "chunk_rates": chunk_rates,
+        "miss_programs_s": miss_s,
         "claim_s": round(time.time() - t_claim, 2),
     }
     with st.lock:
@@ -952,7 +967,9 @@ def _comb_pool_stats() -> dict | None:
     if comb is None or not comb._default_pool:
         return None
     pool = comb.default_pool()
-    return {"capacity": pool.capacity, "resident_keys": len(pool._lru),
+    resident = len(pool._lru)
+    return {"capacity": pool.capacity, "resident_keys": resident,
+            "resident_bytes": resident * comb.SLOT_BYTES, "open": pool.open,
             **pool.stats}
 
 
@@ -1530,6 +1547,11 @@ def serve(path: str | None = None) -> None:
         )
         logger.info("devd stopped; %d call records in %s",
                     min(st.spans.count, st.spans.size), spans_path)
+        # an open population's pool: its log of batches beside the records
+        comb = sys.modules.get("tendermint_tpu.ops.ed25519_comb")
+        if comb is not None and comb._default_pool:
+            comb.default_pool().dump_log(
+                devd_spans.dump_path(path, ".pool.jsonl"))
     if st.error is not None:
         raise SystemExit(f"devd: claim failed: {st.error}")
 

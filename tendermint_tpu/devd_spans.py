@@ -64,7 +64,18 @@ FIELDS = (
     # connections it carried, and its lanes. A call that ran alone reads
     # its own seq, 1, 1 and its own lanes.
     "program", "merged", "merged_conns", "program_lanes",
+    # what the comb kernel's pool did for the call (`note`; on the leading
+    # record of a merged program): `ran` is all_hit (every comb lane's key
+    # resident), with_build (a table was built), with_ladder (lanes on the
+    # first-sight ladder beside comb lanes) or ladder_only; the keys built
+    # and the slots evicted for them; the lanes the ladder took; the
+    # host's nanoseconds inside the build and the pool-update calls (both
+    # lie inside the record's `marshal`).
+    "ran", "keys_built", "slots_evicted", "lanes_ladder", "build_ns",
+    "update_ns",
 )
+NOTED = ("keys_built", "slots_evicted", "lanes_ladder", "build_ns",
+         "update_ns")
 RING_SIZE = 65536
 CLOCK_MARK = "devd.clock:"
 
@@ -78,6 +89,14 @@ def mark(phase: str, width: int = 0) -> None:
     rec = getattr(_tls, "rec", None)
     if rec is not None:
         rec.mark(phase, width)
+
+
+def note(**kw) -> None:
+    """Add to the counts (NOTED) of the call this thread is serving."""
+    rec = getattr(_tls, "rec", None)
+    if rec is not None:
+        for k, v in kw.items():
+            setattr(rec, k, getattr(rec, k) + v)
 
 
 def _annotation(name: str, **kw):
@@ -102,7 +121,8 @@ class CallRecord:
 
     __slots__ = ("seq", "conn", "op", "lanes", "width", "rid", "in_flight",
                  "t", "_cur", "_ann", "_counted", "_closed",
-                 "program", "merged", "merged_conns", "program_lanes")
+                 "program", "merged", "merged_conns", "program_lanes",
+                 *NOTED)
 
     def __init__(self, seq: int, conn: int, in_flight: int):
         self.seq = seq
@@ -120,6 +140,8 @@ class CallRecord:
         self.merged = 1
         self.merged_conns = 1
         self.program_lanes = 0
+        self.keys_built = self.slots_evicted = self.lanes_ladder = 0
+        self.build_ns = self.update_ns = 0
         self._ann = _annotation(_NAMES[0], seq=seq)
 
     def mark(self, phase: str, width: int = 0) -> None:
@@ -170,11 +192,21 @@ class CallRecord:
         """t_recv0 until now: what the reply carries as `svc_ns`."""
         return time.time_ns() - self.t[0]
 
+    def ran(self) -> str:
+        if self.keys_built:
+            return "with_build"
+        if not self.lanes_ladder:
+            return "all_hit"
+        lanes = self.program_lanes or self.lanes
+        return "ladder_only" if self.lanes_ladder >= lanes else "with_ladder"
+
     def row(self) -> tuple:
         return (self.seq, self.conn, self.op, self.lanes, self.width,
                 *self.t, self.in_flight, self.rid, self.program,
                 self.merged, self.merged_conns,
-                self.program_lanes or self.lanes)
+                self.program_lanes or self.lanes, self.ran(),
+                self.keys_built, self.slots_evicted, self.lanes_ladder,
+                self.build_ns, self.update_ns)
 
 
 def attach(rec: CallRecord | None) -> None:
@@ -278,10 +310,11 @@ class SpanRing:
             return None
 
 
-def dump_path(sock: str) -> str:
-    """The socket's path with `.sock` replaced by `.spans.jsonl`."""
+def dump_path(sock: str, suffix: str = ".spans.jsonl") -> str:
+    """The socket's path with `.sock` replaced by `.spans.jsonl` (or by
+    another dump's suffix)."""
     stem = sock[:-len(".sock")] if sock.endswith(".sock") else sock
-    return stem + ".spans.jsonl"
+    return stem + suffix
 
 
 class Profile:

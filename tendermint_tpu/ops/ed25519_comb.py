@@ -43,9 +43,13 @@ types/validator_set.go:247-250, blockchain/reactor.go:235.
 
 from __future__ import annotations
 
+import contextlib
+import json
 import logging
 import os
 import threading
+import time
+from array import array
 from collections import OrderedDict
 
 import jax
@@ -53,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tendermint_tpu.crypto import ed25519 as ed_ref
-from tendermint_tpu.devd_spans import mark
+from tendermint_tpu.devd_spans import mark, note
 from tendermint_tpu.ops import ed25519_f32 as base
 
 logger = logging.getLogger("ops.ed25519_comb")
@@ -288,6 +292,59 @@ def _scatter_tables(pool, slots, tables):
 _scatter_jit = jax.jit(_scatter_tables)
 
 
+def _update_pool_impl(pool, slots, tables):
+    """The open population's pool update: write `tables` (n, 1024, 96)
+    f32 over the slots `slots` of the flat pool (C*1024, 96) bf16. The
+    pool is DONATED: the slots are written in place, no second pool
+    exists at any instant, and the runtime orders the write behind every
+    program that was handed the old buffer before this call. One
+    dynamic-update-slice a key, in a loop: the v5e's compiler turns a
+    scatter through a (C, 1024, 96) view into a copy of the whole pool
+    (tests/test_chip_compile.py holds this form to none)."""
+    rows = W_POS * W_ENT
+    tables = tables.astype(jnp.bfloat16)
+
+    def write(i, p):
+        return jax.lax.dynamic_update_slice(p, tables[i], (slots[i] * rows, 0))
+
+    return jax.lax.fori_loop(0, slots.shape[0], write, pool)
+
+
+_update_jit = jax.jit(_update_pool_impl, donate_argnums=(0,))
+
+# An open population (TENDERMINT_TPU_COMB_OPEN=1: more keys than slots,
+# misses all day) runs its miss programs at ONE size and no other: a build
+# and its pool update pad their new keys up to the pool's `miss_bucket`
+# (the padding lanes write the reserved slot 0), the first-sight ladder
+# pads its lanes the same way, and a count past the bucket is several
+# programs. So the programs that exist are the ones `compile_miss_programs`
+# ran at the claim, whatever the traffic. The bucket is 128, on every
+# backend: keys and lanes lie on the minor axis, which the chip computes
+# 128 wide whatever the count, so a narrower program costs the same device
+# time and 8-17 s more of every claim (my chip runs, PR 35: PERF.md section
+# 6). Only a pool with fewer slots than that (a test's) builds at its own
+# size: one batch cannot hold more new keys than the pool has slots.
+MISS_BUCKET = 128
+# ... and its comb program at no more than this many lanes (the widest
+# bucket the serving path keeps warm: devd.MERGE_MAX_LANES): a wider batch
+# is served as batches of this width. One slow height makes a block of 300
+# updates, and a 512-lane program compiled under the pool's lock held every
+# verifier call for its 20 s (my chip run, PR 35: 2,949 of 8,640 failed).
+OPEN_MAX_LANES = 256
+
+SLOT_BYTES = W_POS * W_ENT * COORD_ROWS * 2  # one key's table, bf16
+
+
+def open_population() -> bool:
+    return os.environ.get("TENDERMINT_TPU_COMB_OPEN", "") == "1"
+
+
+def _chunks(n: int, bucket: int):
+    """(start, count) of the programs of `bucket` lanes that cover n."""
+    for at in range(0, n, bucket):
+        yield at, min(bucket, n - at)
+
+
 # ---------------------------------------------------------------------------
 # the pool manager
 # ---------------------------------------------------------------------------
@@ -307,28 +364,65 @@ class CombPool:
     """Device-resident LRU pool of per-validator comb tables.
 
     Slots are leased to pubkeys on first sight; the table build runs on
-    device, batched across all new keys in the request. Capacity grows by
-    doubling up to `cap` (env TENDERMINT_TPU_COMB_CAP, default 12288
-    slots ~= 2.4 GB bf16 — sized for the 10k-validator benchmark on a
-    16 GB v5e). Eviction is LRU; the pool array is rebuilt functionally
-    (no donation: an in-flight verify may still reference the old
-    buffer)."""
+    device, batched across all new keys in the request. Eviction is LRU.
 
-    def __init__(self, capacity: int | None = None, max_capacity: int | None = None):
+    Two populations, chosen where the pool is made (the daemon's
+    configuration: TENDERMINT_TPU_COMB_OPEN):
+
+    - closed (the default: a validator set and a few hundred signers,
+      every key resident after warm-up). Capacity grows by doubling up to
+      `cap` (env TENDERMINT_TPU_COMB_CAP, default 12288 slots ~= 2.4 GB
+      bf16 — sized for the 10k-validator benchmark on a 16 GB v5e); new
+      keys build in one program of their exact count and the pool array
+      is rebuilt functionally (no donation: an in-flight verify may still
+      reference the old buffer).
+    - open (more keys than slots: the pool misses and evicts all day).
+      The pool holds `cap` slots from the start, so every program has ONE
+      pool shape; new keys build in programs of `miss_bucket` keys and a
+      donated update writes their slots in place (`_update_pool_impl`).
+      Nothing reads a half-written pool: a batch leases its slots, builds,
+      updates and DISPATCHES its verify under the pool's lock (the caller
+      holds it: `verify_batch_async`), so between a lease and the program
+      that reads it no other batch can evict, and on the device a program
+      runs behind every update dispatched before it and ahead of every
+      one after. The pool also keeps a log of its batches (`log_batch`)
+      for a plain model to be checked against."""
+
+    def __init__(self, capacity: int | None = None,
+                 max_capacity: int | None = None,
+                 open_pop: bool | None = None):
         self.cap = int(
             max_capacity
             or os.environ.get("TENDERMINT_TPU_COMB_CAP", 12288)
         )
-        c0 = int(capacity or min(self.cap, 256))
+        self.open = open_population() if open_pop is None else bool(open_pop)
+        c0 = int(capacity or (self.cap if self.open else min(self.cap, 256)))
         self._c = c0
+        self.miss_bucket = min(MISS_BUCKET, c0)
         self._pool = jnp.zeros(
             (c0 * W_POS * W_ENT, COORD_ROWS), dtype=jnp.bfloat16
         )
         self._lru: OrderedDict[bytes, int] = OrderedDict()
         self._free: list[int] = list(range(c0 - 1, 0, -1))  # slot 0 reserved
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._tb = jnp.asarray(b_table())
-        self.stats = {"builds": 0, "build_keys": 0, "evictions": 0, "grows": 0}
+        # builds: table-build programs run; build_keys: keys they built;
+        # ladders: first-sight ladder programs run (an open population's);
+        # lanes_*: how the lanes shown to the kernel were served (a key
+        # resident / a key on the ladder before its MIN_SIGHT-th batch / a
+        # key built in that batch)
+        self.stats = {"builds": 0, "build_keys": 0, "evictions": 0, "grows": 0,
+                      "lanes_hit": 0, "lanes_first_sight": 0, "lanes_built": 0,
+                      "ladders": 0}
+        # what the last ensure() did, for the call's record and the log
+        # (read outside the lock by a closed pool's caller: a record's
+        # counts there can be a concurrent call's; an open pool's cannot)
+        self.last: dict = {}
+        self._evicted_ever: set[bytes] = set()
+        # the open pool's log of batches (log_batch / dump_log)
+        self._log: list | None = [] if self.open else None
+        self._log_lanes = 0
+        self._ids: dict[bytes, int] = {}
 
     @property
     def capacity(self) -> int:
@@ -346,7 +440,7 @@ class CombPool:
         self._c = new_c
         self.stats["grows"] += 1
 
-    def _take_slot(self, pinned: set[int]) -> int:
+    def _take_slot(self, pinned: set[int], evicted: list[bytes]) -> int:
         if not self._free:
             self._grow()
         if self._free:
@@ -359,6 +453,9 @@ class CombPool:
             if slot not in pinned:
                 del self._lru[key]
                 self.stats["evictions"] += 1
+                if self._logging():   # the log tells built from rebuilt
+                    self._evicted_ever.add(key)
+                evicted.append(key)
                 return slot
         raise PoolExhausted(
             f"batch needs more distinct validator keys than the comb "
@@ -369,7 +466,9 @@ class CombPool:
         """Lease slots for decompressed keys. keys[i] is the 32-byte
         compressed pubkey; xs/ys are (n, 32) u8 canonical affine limbs of
         A (NOT negated — negation happens here). Returns
-        (slots int32 (n,), pool bf16 array snapshot). Caller must pass
+        (slots int32 (n,), the pool array to verify against: a snapshot
+        in a closed pool; in an open one the array as it stands, good
+        until the caller lets go of the pool's lock). Caller must pass
         only keys whose decompression succeeded. Raises PoolExhausted when
         one batch holds more distinct keys than max capacity (the gateway
         backend falls back to the ladder kernel)."""
@@ -377,6 +476,7 @@ class CombPool:
             missing: dict[bytes, int] = {}
             first_at: dict[bytes, int] = {}
             pinned: set[int] = set()
+            evicted: list[bytes] = []
             slots = np.zeros(len(keys), dtype=np.int32)
             try:
                 for i, k in enumerate(keys):
@@ -388,7 +488,7 @@ class CombPool:
                         continue
                     s = missing.get(k)
                     if s is None:
-                        s = self._take_slot(pinned)
+                        s = self._take_slot(pinned, evicted)
                         missing[k] = s
                         first_at[k] = i
                         self._lru[k] = s
@@ -403,7 +503,18 @@ class CombPool:
                     if self._lru.get(k) == s:
                         del self._lru[k]
                     self._free.append(s)
+                # what it evicted on the way stays evicted, and is logged
+                self.last = {"leased": set(), "built": {}, "rebuilt": set(),
+                             "evicted": evicted, "build_ns": 0, "update_ns": 0}
                 raise
+            self.last = last = {
+                "leased": set(keys) if self.open else (), "built": missing,
+                "evicted": evicted,
+                "rebuilt": {k for k in missing if k in self._evicted_ever},
+                "build_ns": 0, "update_ns": 0}
+            n_built = sum(1 for k in keys if k in missing)
+            self.stats["lanes_built"] += n_built
+            self.stats["lanes_hit"] += len(keys) - n_built
             if missing:
                 uniq = list(missing.keys())
                 idx = [first_at[k] for k in uniq]
@@ -415,21 +526,108 @@ class CombPool:
                     )
                     qx[:, j] = nx.astype(np.float32)
                     qy[:, j] = ys[i].astype(np.float32)
-                tables = _build_jit(jnp.asarray(qx), jnp.asarray(qy))
                 tslots = np.asarray(
                     [missing[k] for k in uniq], dtype=np.int32
                 )
-                # scatter whole-slot row blocks: view pool as (C, 1024, 96)
-                pool3 = self._pool.reshape(self._c, W_POS * W_ENT, COORD_ROWS)
-                pool3 = _scatter_jit(
-                    pool3, jnp.asarray(tslots), tables.astype(jnp.bfloat16)
-                )
-                self._pool = pool3.reshape(
-                    self._c * W_POS * W_ENT, COORD_ROWS
-                )
-                self.stats["builds"] += 1
+                if self.open:
+                    self._install_in_place(qx, qy, tslots, last)
+                else:
+                    self._install_rebuilt(qx, qy, tslots)
                 self.stats["build_keys"] += len(uniq)
             return slots, self._pool
+
+    def _install_rebuilt(self, qx, qy, tslots) -> None:
+        """The closed pool's install: one build program of the exact
+        count, the pool array rebuilt beside the old one."""
+        tables = _build_jit(jnp.asarray(qx), jnp.asarray(qy))
+        # scatter whole-slot row blocks: view pool as (C, 1024, 96)
+        pool3 = self._pool.reshape(self._c, W_POS * W_ENT, COORD_ROWS)
+        pool3 = _scatter_jit(
+            pool3, jnp.asarray(tslots), tables.astype(jnp.bfloat16)
+        )
+        self._pool = pool3.reshape(self._c * W_POS * W_ENT, COORD_ROWS)
+        self.stats["builds"] += 1
+
+    def _install_in_place(self, qx, qy, tslots, last: dict) -> None:
+        """The open pool's install: programs of `miss_bucket` keys, each
+        followed by the donated update of its slots. Padding lanes repeat
+        the first key and write slot 0, which no key is ever leased."""
+        for at, count in _chunks(qx.shape[1], self.miss_bucket):
+            pad = self.miss_bucket - count
+            cx = np.pad(qx[:, at:at + count], ((0, 0), (0, pad)), mode="edge")
+            cy = np.pad(qy[:, at:at + count], ((0, 0), (0, pad)), mode="edge")
+            cs = np.pad(tslots[at:at + count], (0, pad))
+            t0 = time.perf_counter_ns()
+            tables = _build_jit(jnp.asarray(cx), jnp.asarray(cy))
+            t1 = time.perf_counter_ns()
+            self._pool = _update_jit(self._pool, jnp.asarray(cs), tables)
+            t2 = time.perf_counter_ns()
+            last["build_ns"] += t1 - t0
+            last["update_ns"] += t2 - t1
+            self.stats["builds"] += 1
+
+    # -- the open pool's log ---------------------------------------------
+
+    LOG_MAX_LANES = 1 << 22
+    # a lane's route, as the log holds it
+    ROUTES = ("malformed", "hit", "first_sight", "built", "rebuilt",
+              "undecodable")
+
+    def _logging(self) -> bool:
+        return self._log is not None and self._log_lanes <= self.LOG_MAX_LANES
+
+    def log_batch(self, keys: list, comb_lanes: set[int], last: dict) -> None:
+        """One batch as the pool served it: every lane's key and route
+        (an index into ROUTES) in lane order, and the keys evicted in
+        order. `comb_lanes`: the lanes that rode tables; `last`: what
+        ensure() did for them. Keys are numbered as they first appear
+        (0: no key)."""
+        if not self._logging():
+            return
+        built, rebuilt = last.get("built", {}), last.get("rebuilt", ())
+        leased = last.get("leased", ())
+
+        def route(i: int, k) -> int:
+            if k is None:
+                return 0
+            if i not in comb_lanes:
+                return 2
+            if k not in leased:
+                return 5
+            return 1 if k not in built else 4 if k in rebuilt else 3
+
+        ids = self._ids
+        self._log.append((
+            array("I", [0 if k is None else ids.setdefault(k, len(ids) + 1)
+                        for k in keys]),
+            bytes(route(i, k) for i, k in enumerate(keys)),
+            [ids.setdefault(k, len(ids) + 1) for k in last.get("evicted", [])]))
+        self._log_lanes += len(keys)
+
+    def dump_log(self, path: str) -> str | None:
+        """The log as JSON lines: a header (the routes' names, capacity,
+        MIN_SIGHT, the keys by number), then {"k", "r", "e"} a batch.
+        Never raises: the daemon is stopping."""
+        with self._lock:
+            if self._log is None:
+                return None
+            log, ids = list(self._log), dict(self._ids)
+            whole = self._log_lanes <= self.LOG_MAX_LANES
+        head = {"routes": list(self.ROUTES), "capacity": self._c,
+                "usable_slots": self._c - 1, "min_sight": _min_sight(),
+                "batches": len(log), "whole": whole,
+                "keys": [k.hex() for k in sorted(ids, key=ids.get)]}
+        try:
+            with open(path + ".tmp", "w") as f:
+                f.write(json.dumps(head) + "\n")
+                for k, r, e in log:
+                    f.write(json.dumps(
+                        {"k": k.tolist(), "r": "".join(map(str, r)), "e": e},
+                        separators=(",", ":")) + "\n")
+            os.replace(path + ".tmp", path)
+            return path
+        except OSError:
+            return None
 
     def table_b(self):
         return self._tb
@@ -515,6 +713,10 @@ def _dispatch_comb(items, kidx, keys, pool_mgr):
         slots[np.asarray(vidx)] = leased
     else:
         pool_arr = pool_mgr.ensure([], np.zeros((0, 32)), np.zeros((0, 32)))[1]
+    last = pool_mgr.last
+    if last["built"]:
+        note(keys_built=len(last["built"]), slots_evicted=len(last["evicted"]),
+             build_ns=last["build_ns"], update_ns=last["update_ns"])
     # the daemon's per-call record (devd_spans): arrays ready / the jit
     # call returned / verdicts on the host. One attribute test each where
     # no record is open, which is everywhere but inside devd.
@@ -547,43 +749,63 @@ def verify_batch_async(items: list[tuple[bytes, bytes, bytes]]):
     ride the comb kernel (building tables as needed); the rest, plus any
     malformed lanes, verify on the f32 ladder in the same call. Both
     dispatches are enqueued before either resolves, so device work
-    overlaps."""
+    overlaps.
+
+    With an open population the routing, the leases, the builds and the
+    comb dispatch of one batch happen under the pool's lock (CombPool's
+    docstring says why), the batch is logged there in that order, the
+    ladder runs at the pool's `miss_bucket`, and a batch wider than
+    OPEN_MAX_LANES is served as batches of that width."""
     n = len(items)
     if n == 0:
         return lambda: np.zeros(0, dtype=bool)
     pool_mgr = default_pool()
+    if pool_mgr.open and n > OPEN_MAX_LANES:
+        parts = [verify_batch_async(items[at:at + OPEN_MAX_LANES])
+                 for at in range(0, n, OPEN_MAX_LANES)]
+        return lambda: np.concatenate([np.asarray(r()) for r in parts])
     keys = [
         bytes(p) if len(p) == 32 and len(s) == 64 else None
         for p, _m, s in items
     ]
-    counts = _bump_seen({k for k in keys if k is not None})
-    min_sight = _min_sight()
-    with pool_mgr._lock:
-        in_pool = {
-            k for k in counts if k in pool_mgr._lru
-        }
-    comb_idx = [
-        i
-        for i, k in enumerate(keys)
-        if k is not None and (k in in_pool or counts[k] >= min_sight)
-    ]
-    cset = set(comb_idx)
-    ladder_idx = [i for i in range(n) if i not in cset]
-    resolvers: list[tuple[list[int], object]] = []
-    if comb_idx:
-        try:
-            r = _dispatch_comb(
-                items, comb_idx, [keys[i] for i in comb_idx], pool_mgr
-            )
-            resolvers.append((comb_idx, r))
-        except PoolExhausted:
-            logger.warning(
-                "comb pool exhausted (%d lanes); ladder fallback",
-                len(comb_idx),
-            )
-            ladder_idx = sorted(ladder_idx + comb_idx)
+    # an open pool routes, leases, builds and dispatches under its lock
+    with pool_mgr._lock if pool_mgr.open else contextlib.nullcontext():
+        counts = _bump_seen({k for k in keys if k is not None})
+        min_sight = _min_sight()
+        with pool_mgr._lock:
+            in_pool = {
+                k for k in counts if k in pool_mgr._lru
+            }
+        comb_idx = [
+            i
+            for i, k in enumerate(keys)
+            if k is not None and (k in in_pool or counts[k] >= min_sight)
+        ]
+        cset = set(comb_idx)
+        ladder_idx = [i for i in range(n) if i not in cset]
+        pool_mgr.stats["lanes_first_sight"] += sum(
+            1 for i in ladder_idx if keys[i] is not None)
+        resolvers: list[tuple[list[int], object]] = []
+        if comb_idx:
+            try:
+                r = _dispatch_comb(
+                    items, comb_idx, [keys[i] for i in comb_idx], pool_mgr
+                )
+                resolvers.append((comb_idx, r))
+            except PoolExhausted:
+                logger.warning(
+                    "comb pool exhausted (%d lanes); ladder fallback",
+                    len(comb_idx),
+                )
+                ladder_idx = sorted(ladder_idx + comb_idx)
+                cset = set()
+        if pool_mgr.open:
+            pool_mgr.log_batch(keys, cset, pool_mgr.last if comb_idx else {})
     if ladder_idx:
-        r = base.verify_batch_async([items[i] for i in ladder_idx])
+        note(lanes_ladder=len(ladder_idx))
+        sub = [items[i] for i in ladder_idx]
+        r = _ladder_bucketed(sub, pool_mgr) if pool_mgr.open \
+            else base.verify_batch_async(sub)
         resolvers.append((ladder_idx, r))
 
     def resolve():
@@ -593,6 +815,46 @@ def verify_batch_async(items: list[tuple[bytes, bytes, bytes]]):
         return out
 
     return resolve
+
+
+def _ladder_bucketed(items: list, pool_mgr: CombPool):
+    """The f32 ladder over `items` in programs of the pool's `miss_bucket`
+    lanes (padding repeats the first lane; its verdicts are dropped)."""
+    parts = []
+    for at, count in _chunks(len(items), pool_mgr.miss_bucket):
+        part = items[at:at + count]
+        pool_mgr.stats["ladders"] += 1
+        parts.append((count, base.verify_batch_async(
+            part + [part[0]] * (pool_mgr.miss_bucket - count))))
+    return lambda: np.concatenate([np.asarray(r())[:c] for c, r in parts])
+
+
+def compile_miss_programs(make_full) -> dict:
+    """Run every program a miss can need once, at the pool's bucket, so
+    that none is traced or compiled at first use: the build and the pool
+    update (on the base point, into the reserved slot 0: no key becomes
+    resident) and the first-sight ladder (`make_full(n)`: n lanes of
+    valid signatures). Returns the seconds each took."""
+    pool_mgr = default_pool()
+    b = pool_mgr.miss_bucket
+    out: dict[str, float] = {}
+    qx = np.repeat(np.asarray(base._BX, dtype=np.float32)[:, None], b, axis=1)
+    qy = np.repeat(np.asarray(base._BY, dtype=np.float32)[:, None], b, axis=1)
+    with pool_mgr._lock:
+        t0 = time.time()
+        tables = _build_jit(jnp.asarray(qx), jnp.asarray(qy))
+        tables.block_until_ready()
+        out[f"build_{b}"] = round(time.time() - t0, 3)
+        t0 = time.time()
+        pool_mgr._pool = _update_jit(
+            pool_mgr._pool, jnp.zeros(b, dtype=jnp.int32), tables)
+        pool_mgr._pool.block_until_ready()
+        out[f"update_{b}"] = round(time.time() - t0, 3)
+    t0 = time.time()
+    if not all(base.verify_batch_async(make_full(b))()):
+        raise RuntimeError(f"the ladder rejected a valid lane at {b}")
+    out[f"ladder_{b}"] = round(time.time() - t0, 3)
+    return out
 
 
 def verify_batch(items: list[tuple[bytes, bytes, bytes]]) -> np.ndarray:

@@ -132,3 +132,45 @@ def test_f32p_pallas_ladder_compiles_for_v5e(one_chip, no_compile_cache, items):
         f32p._make_verify(f32p.S_TILE, interpret=False), _on(one_chip, args)
     )
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_open_pool_update_is_in_place_on_v5e(one_chip, no_compile_cache):
+    """ops/ed25519_comb._update_pool_impl at the shipped ceiling (12,288
+    slots, 2.4 GB) and the widest build bucket: the donated pool is the
+    output's buffer, and the program needs no second pool beside it."""
+    from tendermint_tpu.ops import ed25519_comb as comb
+
+    cap, bucket = 12288, comb.MISS_BUCKET
+    rows = comb.W_POS * comb.W_ENT
+    pool = jax.ShapeDtypeStruct((cap * rows, comb.COORD_ROWS), jnp.bfloat16,
+                                sharding=one_chip)
+    slots = jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one_chip)
+    tables = jax.ShapeDtypeStruct((bucket, rows, comb.COORD_ROWS), jnp.float32,
+                                  sharding=one_chip)
+    compiled = jax.jit(comb._update_pool_impl, donate_argnums=(0,)).lower(
+        pool, slots, tables).compile()
+    ma = compiled.memory_analysis()
+    pool_bytes = cap * comb.SLOT_BYTES
+    assert ma.alias_size_in_bytes >= pool_bytes, ma
+    assert ma.temp_size_in_bytes < pool_bytes // 8, ma
+
+
+def test_open_pool_verify_and_build_compile_for_v5e(one_chip, no_compile_cache,
+                                                    items):
+    """The comb program over the 12,288-slot pool at the narrowest width,
+    and the table build at the widest bucket, beside the pool in 16 GB."""
+    from tendermint_tpu.ops import ed25519_comb as comb
+    from tendermint_tpu.ops import ed25519_f32 as f32
+
+    rows = comb.W_POS * comb.W_ENT
+    pool = jax.ShapeDtypeStruct((12288 * rows, comb.COORD_ROWS), jnp.bfloat16,
+                                sharding=one_chip)
+    _ax, _ay, ry, rs, s8, h8, _valid = f32.prepare_batch8(items, 8)
+    tb = jnp.asarray(comb.b_table())
+    slots = jnp.zeros((8,), jnp.int32)
+    _compile(comb._verify_comb_impl,
+             (pool,) + _on(one_chip, (tb, slots, ry, rs, s8, h8)))
+    q = jax.ShapeDtypeStruct((comb.NL, comb.MISS_BUCKET), jnp.float32,
+                             sharding=one_chip)
+    compiled = _compile(comb._build_tables_impl, (q, q))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
