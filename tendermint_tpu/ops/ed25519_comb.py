@@ -210,76 +210,196 @@ _verify_jit = jax.jit(_verify_comb_impl)
 # -- table building on device -------------------------------------------------
 
 
+def _d2(width: int) -> jax.Array:
+    return jnp.broadcast_to(jnp.asarray(base._D2)[:, None], (NL, width))
+
+
+def _multiples(q):
+    """v*Q for v = 1..15 of the extended points q ((32, w) coordinates),
+    as (32, 15*w) coordinates with lanes (v-1, lane): a tree of four
+    levels, each one point_add over every multiple it makes at once
+    (2 = 1+1; 3, 4 = 2+{1, 2}; 5..8 = 4+{1..4}; 9..15 = 8+{1..7})."""
+    w = q[0].shape[-1]
+    mult, m = q, 1
+    while m < W_ENT - 1:
+        k = min(m, W_ENT - 1 - m)
+        top = tuple(jnp.tile(c[:, (m - 1) * w:m * w], (1, k)) for c in mult)
+        low = tuple(c[:, :k * w] for c in mult)
+        new = base.point_add(top, low, _d2(k * w))
+        mult = tuple(jnp.concatenate(ab, axis=1) for ab in zip(mult, new))
+        m += k
+    return mult
+
+
+def _split(x, n: int):
+    return tuple(x[:, i:i + n] for i in range(0, x.shape[-1], n))
+
+
+def _double_wide(p):
+    """base.point_double with its four squarings as ONE multiplication
+    over 4n lanes and its four closing products as another: the same
+    operations on the same operands, two steps of the chain where
+    point_double takes eight."""
+    x1, y1, z1, _ = p
+    n = x1.shape[-1]
+    s = jnp.concatenate([x1, y1, z1, base.fadd(x1, y1)], axis=1)
+    a, b, zz, xy2 = _split(base.fmul(s, s), n)
+    c = base.fadd(zz, zz)
+    h = base.fadd(a, b)
+    e = base.fsub(h, xy2)
+    g = base.fsub(a, b)
+    f = base.fadd(c, g)
+    return _split(base.fmul(jnp.concatenate([e, g, f, e], axis=1),
+                            jnp.concatenate([f, h, g, h], axis=1)), n)
+
+
+def _fcanon_dense(x):
+    """base.fcanon of a wide (32, L) array, L a multiple of 8, computed as
+    (32, 8, L/8): its limb-by-limb carry passes then work on whole (8,
+    128) tiles, where a (1, L) row fills one sublane of eight."""
+    return base.fcanon(x.reshape(NL, 8, -1)).reshape(x.shape)
+
+
+def _montgomery(zs, invert):
+    """Montgomery's batch inversion along the leading axis of zs (k, 32,
+    w): one chain of prefix products, `invert` of their total, one chain
+    back. -> the (k, 32, w) inverses."""
+    one = (zs[0] * 0.0).at[0].set(1.0)
+
+    def fwd(acc, z):
+        return base.fmul(acc, z), acc      # the prefix BEFORE z
+
+    total, prefix = jax.lax.scan(fwd, one, zs)
+
+    def bwd(acc, inp):                     # acc: 1 / (prefix AFTER z)
+        z, pre = inp
+        return base.fmul(acc, z), base.fmul(acc, pre)
+
+    _, inv = jax.lax.scan(bwd, invert(total), (zs, prefix), reverse=True)
+    return inv
+
+
+# Entries a Montgomery chain of the build covers side by side: the 960 Zs
+# of a key are 32 groups of 30, each group a lane block of its own
+INV_GROUPS = 32
+
+
+def _batch_inverse(z, n: int):
+    """z: (32, E*n) with lanes (entry, key), E a multiple of INV_GROUPS.
+    The entries form INV_GROUPS groups; the groups' chains run together
+    on (32, INV_GROUPS*n) lanes, and their totals are inverted by a second
+    chain of INV_GROUPS steps around one finv of n lanes. The grouping
+    changes neither the work (3 multiplications an entry) nor the
+    temporaries (the prefixes are E*n elements whatever the split), only
+    the depth: 2*E/G + 2*G + finv, least at G near sqrt(E) for every n."""
+    g = INV_GROUPS
+    m = z.shape[-1] // (g * n)
+    zs = z.reshape(NL, m, g * n).transpose(1, 0, 2)
+
+    def invert_totals(total):              # (32, g*n), lanes (group, key)
+        t = total.reshape(NL, g, n).transpose(1, 0, 2)
+        inv = _montgomery(t, base.finv)
+        return inv.transpose(1, 0, 2).reshape(NL, g * n)
+
+    inv = _montgomery(zs, invert_totals)
+    return inv.transpose(1, 0, 2).reshape(z.shape)
+
+
+# The most keys one pass of the build takes: a wider build runs as passes
+# of this many keys, one after another inside the same program
+BUILD_KEYS = 128
+
+
 def _build_tables_impl(qx, qy):
     """qx/qy: (32, n) f32 canonical affine limbs of Q = -A per validator.
     Returns (n, W_POS*W_ENT, 96) float32 niels tables (canonical limbs,
-    ready for a bf16 cast).
+    ready for a bf16 cast): row p*16 + v is v * 16^p * Q, row p*16 the
+    identity (1, 1, 0).
 
-    Structure: scan over the 64 window positions carrying Q_p = 16^p * Q;
-    each step emits the 15 extended-coordinate multiples v*Q_p (v=1..15,
-    a chained point_add); then one Montgomery batch inversion over all
-    960 entries x n lanes normalizes to affine, and a final pass forms
-    canonical niels rows. ~13 signature-verifies of device work per
-    validator, amortized over every later verify of that key."""
+    WHY THE SHAPE. A field multiplication over a few hundred lanes costs
+    the chip about a microsecond whatever the lanes hold, so a build of 1
+    to 128 keys pays for how many steps lie in series, not for its work.
+    The first form (PR 35) had 3,184 sequential loop steps (a scan of the
+    64 positions, each 14 chained adds and 4 doublings; a 960-step
+    Montgomery scan each way; a 960-step map to niels rows; finv's loops)
+    and took the chip 39.9 ms at 128 keys and 467 ms at 1. This one does
+    the same arithmetic in 428 steps, the work laid side by side on the
+    lane axis instead (_build_keys): 9.2 ms at 128 keys and 9.0 at 1; 18.2
+    at 216 (50.6 before), 70.7 at 1,024 (104.6) (my chip runs, PR 36,
+    host clock around the program; 8.4 ms of device time at 128 in a
+    traced run of ycsb-a.steady, 38.2 before: ledger, PR 35).
+
+    Past BUILD_KEYS keys the lanes are full and the work is what costs:
+    such a build runs as passes of BUILD_KEYS keys (a lax.map; the last
+    pass padded with the last key, whose tables are dropped), so a step
+    never spans more than BUILD_KEYS keys' lanes and the temporaries stay
+    those of one pass (one pass over 1,024 keys took 143-178 ms in the
+    forms tried, passes 70.7: my chip runs, PR 36)."""
     n = qx.shape[-1]
-    zeros = qx * 0.0
-    one = zeros.at[0].set(1.0)
-    d2 = jnp.broadcast_to(jnp.asarray(base._D2)[:, None], (NL, n))
-    q0 = (qx, qy, one, base.fmul(qx, qy))
+    if n <= BUILD_KEYS:
+        return _build_keys(qx, qy)
+    passes = -(-n // BUILD_KEYS)
+    pad = passes * BUILD_KEYS - n
+    q = jnp.pad(jnp.stack([qx, qy]), ((0, 0), (0, 0), (0, pad)), mode="edge")
+    q = q.reshape(2, NL, passes, BUILD_KEYS).transpose(2, 0, 1, 3)
+    tables = jax.lax.map(lambda c: _build_keys(c[0], c[1]), q)
+    return tables.reshape(passes * BUILD_KEYS, W_POS * W_ENT, COORD_ROWS)[:n]
 
-    def pos_step(q, _):
-        entries = []
-        acc = q
-        for _v in range(1, W_ENT):
-            entries.append(jnp.stack(acc, axis=0))  # (4, 32, n)
-            acc = base.point_add(acc, q, d2)
-        nxt = q
-        for _ in range(4):
-            nxt = base.point_double(nxt)
-        return nxt, jnp.stack(entries, axis=0)  # (15, 4, 32, n)
 
-    _, ext = jax.lax.scan(pos_step, q0, None, length=W_POS)
-    # ext: (64, 15, 4, 32, n) extended entries
-    ext = ext.reshape(W_POS * (W_ENT - 1), 4, NL, n)
-    m = ext.shape[0]  # 960
+def _build_keys(qx, qy):
+    """_build_tables_impl for at most BUILD_KEYS keys, in four phases, each
+    under its `jax.named_scope` (the device trace splits a build by them):
 
-    # Montgomery batch inversion of all entry Zs: forward prefix-product
-    # scan, one shared finv, backward unwind — ~2x960 fmuls instead of 960
-    # full inversions.
-    zs = ext[:, 2]  # (960, 32, n)
+    - q_chain: a scan of 64 steps, 4 doublings each, carrying Q_p =
+      16^p * Q: the one chain that is sequential by nature; a doubling is
+      two multiplications over 4n lanes (_double_wide).
+    - multiples: v*Q_p for all 64 positions at once on (32, 64n) lanes,
+      four levels of point_add (_multiples).
+    - batch_inverse: the 960 Zs of a key in 32 groups side by side
+      (_batch_inverse): 2*30 + 2*32 steps and one finv.
+    - niels: one pass over all 960n entries, fcanon on whole tiles
+      (_fcanon_dense).
 
-    def fwd(carry, z):
-        nxt = base.fmul(carry, z)
-        return nxt, carry  # prefix BEFORE this element
+    Every field operation is one the f32 EXACTNESS ARGUMENT already
+    covers, on operands of the same classes as before (point_add of
+    point_add/point_double outputs, the doubling's own formula, fmul of
+    fmul outputs, fcanon of fadd/fsub/fmul outputs), and the output is
+    canonical: the tables are the first form's bit for bit
+    (tests/test_ops_comb.py)."""
+    n = qx.shape[-1]
+    one = (qx * 0.0).at[0].set(1.0)
 
-    total, prefix = jax.lax.scan(fwd, one, zs)
-    tinv = base.finv(total)
+    with jax.named_scope("q_chain"):
+        def dbl4(q, _):
+            nxt = q
+            for _ in range(4):
+                nxt = _double_wide(nxt)
+            return nxt, q
 
-    def bwd(carry, inp):
-        z, pref = inp
-        inv_z = base.fmul(carry, pref)  # carry = inv(prefix_after)
-        nxt = base.fmul(carry, z)
-        return nxt, inv_z
+        _, qs = jax.lax.scan(dbl4, (qx, qy, one, base.fmul(qx, qy)), None,
+                             length=W_POS)
+        # (64, 32, n) a coordinate -> (32, 64n), lanes (position, key)
+        qp = tuple(c.transpose(1, 0, 2).reshape(NL, W_POS * n) for c in qs)
 
-    _, zinvs_rev = jax.lax.scan(bwd, tinv, (zs[::-1], prefix[::-1]))
-    zinvs = zinvs_rev[::-1]  # (960, 32, n)
+    with jax.named_scope("multiples"):
+        ext = _multiples(qp)               # lanes (v-1, position, key)
 
-    def to_niels(inp):
-        entry, zinv = inp
-        x = base.fmul(entry[0], zinv)
-        y = base.fmul(entry[1], zinv)
-        t2 = base.fmul(base.fmul(x, y), d2)
-        my = base.fcanon(base.fsub(y, x))
-        py = base.fcanon(base.fadd(y, x))
-        t2 = base.fcanon(t2)
-        return jnp.stack([my, py, t2], axis=0)  # (3, 32, n)
+    with jax.named_scope("batch_inverse"):
+        zinv = _batch_inverse(ext[2], n)
 
-    niels = jax.lax.map(to_niels, (ext, zinvs))  # (960, 3, 32, n)
-    niels = niels.reshape(W_POS, W_ENT - 1, COORD_ROWS, n)
-    ident = jnp.zeros((W_POS, 1, COORD_ROWS, n), dtype=jnp.float32)
-    ident = ident.at[:, 0, 0].set(1.0).at[:, 0, NL].set(1.0)
-    full = jnp.concatenate([ident, niels], axis=1)  # (64, 16, 96, n)
-    return full.transpose(3, 0, 1, 2).reshape(n, W_POS * W_ENT, COORD_ROWS)
+    with jax.named_scope("niels"):
+        x = base.fmul(ext[0], zinv)
+        y = base.fmul(ext[1], zinv)
+        t2 = base.fmul(base.fmul(x, y), _d2(x.shape[-1]))
+        rows = jnp.stack([_fcanon_dense(base.fsub(y, x)),
+                          _fcanon_dense(base.fadd(y, x)), _fcanon_dense(t2)])
+        rows = rows.reshape(3, NL, W_ENT - 1, W_POS, n)
+        niels = rows.transpose(4, 3, 2, 0, 1).reshape(
+            n, W_POS, W_ENT - 1, COORD_ROWS)
+        ident = jnp.zeros((n, W_POS, 1, COORD_ROWS), dtype=jnp.float32)
+        ident = ident.at[..., 0].set(1.0).at[..., NL].set(1.0)
+        full = jnp.concatenate([ident, niels], axis=2)  # (n, 64, 16, 96)
+        return full.reshape(n, W_POS * W_ENT, COORD_ROWS)
 
 
 _build_jit = jax.jit(_build_tables_impl)
@@ -319,10 +439,11 @@ _update_jit = jax.jit(_update_pool_impl, donate_argnums=(0,))
 # pads its lanes the same way, and a count past the bucket is several
 # programs. So the programs that exist are the ones `compile_miss_programs`
 # ran at the claim, whatever the traffic. The bucket is 128, on every
-# backend: keys and lanes lie on the minor axis, which the chip computes
-# 128 wide whatever the count, so a narrower program costs the same device
-# time and 8-17 s more of every claim (my chip runs, PR 35: PERF.md section
-# 6). Only a pool with fewer slots than that (a test's) builds at its own
+# backend: a narrower program costs about the same device time (the build
+# 9.0 ms at 1 key and 9.2 at 128, the chain of steps and not the lanes
+# setting it; the ladder's lanes lie on the 128-wide minor axis: my chip
+# runs, PR 35 and 36, PERF.md section 6) and 8-17 s more of every claim.
+# Only a pool with fewer slots than that (a test's) builds at its own
 # size: one batch cannot hold more new keys than the pool has slots.
 MISS_BUCKET = 128
 # ... and its comb program at no more than this many lanes (the widest

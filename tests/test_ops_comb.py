@@ -220,3 +220,106 @@ class TestBTable:
             acc = ed.point_double(acc)
         x, y = comb.base._affine(acc)
         assert np.array_equal(tab[1, 1], comb._niels_rows_np(x, y))
+
+
+def _reference_tables(points) -> np.ndarray:
+    """(n, 1024, 96) niels tables of v * 16^p * Q for extended points Q,
+    in Python integers: the build's contract, computed the plain way."""
+    ident = np.zeros(comb.COORD_ROWS, dtype=np.float32)
+    ident[0] = ident[comb.NL] = 1.0
+    out = np.zeros((len(points), comb.W_POS, comb.W_ENT, comb.COORD_ROWS),
+                   dtype=np.float32)
+    for i, q in enumerate(points):
+        for p in range(comb.W_POS):
+            out[i, p, 0] = ident
+            acc = q
+            for v in range(1, comb.W_ENT):
+                out[i, p, v] = comb._niels_rows_np(*comb.base._affine(acc))
+                acc = ed.point_add(acc, q)
+            for _ in range(4):
+                q = ed.point_double(q)
+    return out.reshape(len(points), comb.W_POS * comb.W_ENT, comb.COORD_ROWS)
+
+
+def _neg_points(rng, n):
+    """n random keys' -A as extended points, and the build's (32, n) limb
+    columns of them."""
+    pts = []
+    for _ in range(n):
+        x, y = comb.base._affine(ed.point_decompress(_keypair(rng)[1]))
+        x = (-x) % comb.P
+        pts.append((x, y, 1, x * y % comb.P))
+    qx = np.stack([comb.base._int_to_limbs_const(p[0]) for p in pts], axis=1)
+    qy = np.stack([comb.base._int_to_limbs_const(p[1]) for p in pts], axis=1)
+    return pts, qx, qy
+
+
+def _build(qx, qy) -> np.ndarray:
+    import jax
+
+    return np.asarray(jax.jit(comb._build_tables_impl)(qx, qy))
+
+
+class TestTableBuild:
+    """_build_tables_impl against Python integers, limb for limb, on the
+    CPU backend: the tables a key's later verifications read."""
+
+    @pytest.mark.parametrize("n", [1, 3])
+    def test_tables_match_python_ints(self, n):
+        pts, qx, qy = _neg_points(np.random.default_rng(40 + n), n)
+        assert np.array_equal(_build(qx, qy), _reference_tables(pts))
+
+    def test_bucket_padded_with_edge_repeats(self):
+        """The open pool's form: 3 keys padded to a bucket of 8 by
+        repeating the last; the padding lanes hold the last key's table."""
+        pts, qx, qy = _neg_points(np.random.default_rng(43), 3)
+        pad = ((0, 0), (0, 5))
+        got = _build(np.pad(qx, pad, mode="edge"),
+                     np.pad(qy, pad, mode="edge"))
+        assert np.array_equal(got, _reference_tables(pts + [pts[-1]] * 5))
+
+    def test_passes_past_build_keys(self, monkeypatch):
+        """More keys than BUILD_KEYS run as passes of that many, the last
+        one padded inside the program: 5 keys in passes of 2."""
+        monkeypatch.setattr(comb, "BUILD_KEYS", 2)
+        pts, qx, qy = _neg_points(np.random.default_rng(44), 5)
+        assert np.array_equal(_build(qx, qy), _reference_tables(pts))
+
+    def test_base_point_reproduces_b_table(self):
+        qx = np.asarray(comb.base._BX, dtype=np.float32)[:, None]
+        qy = np.asarray(comb.base._BY, dtype=np.float32)[:, None]
+        got = _build(qx, qy).reshape(comb.W_POS, comb.W_ENT, comb.COORD_ROWS)
+        assert np.array_equal(got, comb.b_table())
+
+
+def _sequential_steps(jaxpr) -> int:
+    """Loop steps that run one after another: scan lengths, a scan inside
+    a scan multiplied; a while loop has no static trip count here (a
+    fori_loop over static bounds is a scan), so none may appear."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        assert name != "while", "a loop with no static trip count"
+        if name == "scan":
+            total += eqn.params["length"] * max(
+                1, _sequential_steps(eqn.params["jaxpr"].jaxpr))
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    total += _sequential_steps(inner)
+    return total
+
+
+def test_build_is_a_few_hundred_sequential_steps():
+    """The mechanism of PR 36: the parent's build ran 3,184 loop steps in
+    series (the chip then charged 38 ms for 1 key or 128); the work now
+    lies on the lane axis and the chain is 428 steps at the bucket."""
+    import jax
+    import jax.numpy as jnp
+
+    q = jax.ShapeDtypeStruct((comb.NL, comb.MISS_BUCKET), jnp.float32)
+    jaxpr = jax.make_jaxpr(comb._build_tables_impl)(q, q).jaxpr
+    steps = _sequential_steps(jaxpr)
+    assert steps <= 600, steps
