@@ -87,12 +87,15 @@ class SignedKVStoreApp(KVStoreApp):
         Verdicts and responses are identical to the per-tx loop."""
         if len(txs) < 2:
             return [self.deliver_tx(tx) for tx in txs]
+        from tendermint_tpu import devd
         from tendermint_tpu.ops import gateway
 
         verifier = self.deliver_verifier or gateway.default_verifier()
         items = [parse_sig_tx(tx) for tx in txs]
         idx = [i for i, it in enumerate(items) if it is not None]
-        verdicts = verifier.verify_batch([items[i] for i in idx]) if idx else []
+        with devd.asking("block"):
+            verdicts = (verifier.verify_batch([items[i] for i in idx])
+                        if idx else [])
         ok = {i: bool(v) for i, v in zip(idx, verdicts)}
         responses: list[ResponseDeliverTx | None] = [None] * len(txs)
         payloads = []

@@ -942,11 +942,7 @@ class ConsensusState(BaseService):
             self.logger.error("propose without last commit (+2/3 missing)")
             return None, None
         txs = self.mempool.reap(self.config.max_block_size_txs)
-        if self.txtrace is not None:
-            # lifecycle mark: reaped into OUR proposal (a non-proposer
-            # stamps the same stage when the gossiped proposal block
-            # completes — add_proposal_block_part)
-            self.txtrace.stamp_present(txs, "proposal")
+        t_reap = time.time()
         t0 = time.perf_counter()
         # submitted-early future: the tx root starts hashing on the hash
         # plane NOW, overlapping commit/evidence/header assembly below;
@@ -958,7 +954,7 @@ class ConsensusState(BaseService):
         if self.propose_time_source is not None:
             time_ns = self.propose_time_source(rs.height)
         try:
-            return Block.make_block(
+            made = Block.make_block(
                 height=rs.height,
                 chain_id=self.state.chain_id,
                 txs=txs,
@@ -989,6 +985,11 @@ class ConsensusState(BaseService):
             # root) happens INSIDE the propose segment, so it rides the
             # trace's aux table, never the segment sum
             self.trace.note("part_hash_s", time.perf_counter() - t0)
+        if self.txtrace is not None and self.txtrace._active:
+            # lifecycle mark: reaped into OUR proposal; finalize keeps it
+            # only if this block is the one committed
+            self.txtrace.stamp_reap(txs, made[0].hash(), at=t_reap)
+        return made
 
     def _commit_for_proposal(self, commit):
         """The last_commit section in the format the chain's schedule
@@ -1364,9 +1365,8 @@ class ConsensusState(BaseService):
 
         if self.txtrace is not None:
             # lifecycle mark: the block carrying a traced tx is now
-            # chain history (stage 1 done — marker on disk); also
-            # resets the first-K-per-height sampling window
-            self.txtrace.commit(block.data.txs, height)
+            # chain history (stage 1 done — marker on disk)
+            self.txtrace.commit(block.data.txs, height, block_id.hash)
 
         state_copy = self.state.copy()
         event_cache = EventCache(self.evsw) if self.evsw is not None else _NullCache()

@@ -1002,7 +1002,7 @@ def _handle_verify_stream(conn: socket.socket, st: _DaemonState,
     return _serve_stream(
         conn, st, st.stream, n_chunks,
         _unpack_chunk, v.verify_batch_async, _send_result_frame,
-        record=(st.spans, conn_id),
+        record=(st.spans, conn_id, req.get("rid", "")),
     )
 
 
@@ -1065,11 +1065,11 @@ def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
     same keys); `dispatch(items)` returns a zero-arg resolver;
     `send_result(conn, idx, result)` frames one chunk's result;
     `on_result(result)` (optional) observes results in chunk order from
-    the sender thread; `record` = (ring, connection id) makes every chunk
-    one record of the ring (the verify plane): opened on this thread,
-    handed to the sender thread with the chunk. Returns True when the
-    connection stays usable."""
-    ring, conn_id = record or (None, 0)
+    the sender thread; `record` = (ring, connection id, the stream's rid)
+    makes every chunk one record of the ring (the verify plane): opened
+    on this thread, handed to the sender thread with the chunk. Returns
+    True when the connection stays usable."""
+    ring, conn_id, rid = record or (None, 0, "")
     depth = threading.Semaphore(_stream_depth())
     results: queuelib.Queue = queuelib.Queue()
     send_ok = threading.Event()
@@ -1162,7 +1162,7 @@ def _serve_stream(conn: socket.socket, st: _DaemonState, gauges: dict,
                 aborted = True
                 break
             if rec is not None:
-                ring.decoded(rec, "verify_stream", len(items))
+                ring.decoded(rec, "verify_stream", len(items), rid)
             if not acquire_slot():
                 aborted = True
                 break
@@ -1656,12 +1656,58 @@ def _observe_single(op: str, t0: float, rep: dict) -> None:
 
 
 _rid_counter = itertools.count(1)
+# who asks, and why: a request's rid is `<client>-<why>-<counter>`, and
+# the daemon's record of the call parses `node` and `why` back out of it
+# (devd_spans.parse_rid). A node names its process (its moniker); any
+# other process is `p<pid>`. The call site names the purpose on its own
+# thread (`asking`: gate, vote, commit, block, sync; docs/device-daemon.md);
+# what no call site named is `warm`: set-up traffic.
+_client_name = f"p{os.getpid()}"
+_ask = threading.local()
+
+
+def set_client_name(name: str) -> None:
+    global _client_name
+    _client_name = str(name).strip() or f"p{os.getpid()}"
+
+
+class asking:
+    """`with devd.asking("vote"): ...`: every request this thread sends
+    meanwhile names that purpose (nests; the outer one returns after)."""
+
+    __slots__ = ("why", "_prev")
+
+    def __init__(self, why: str):
+        self.why = why
+
+    def __enter__(self):
+        self._prev = getattr(_ask, "why", None)
+        _ask.why = self.why
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _ask.why = self._prev
+
+
+def current_why() -> str | None:
+    return getattr(_ask, "why", None)
+
+
+def take_rid() -> str:
+    """The rid of this thread's last request since the last take ("" if
+    none went out: the call was answered on the host)."""
+    rid = getattr(_ask, "rid", "")
+    _ask.rid = ""
+    return rid
 
 
 def _next_rid() -> str:
-    """The client's name for one request (pid and a counter): the
-    daemon's record of the call carries it."""
-    return f"{os.getpid()}-{next(_rid_counter)}"
+    """The client's name for one request: the daemon's record of the call
+    carries it."""
+    rid = (f"{_client_name}-{getattr(_ask, 'why', None) or 'warm'}"
+           f"-{next(_rid_counter)}")
+    _ask.rid = rid
+    return rid
 
 
 class DevdClient:
@@ -1921,6 +1967,7 @@ class DevdClient:
             "op": "verify_stream",
             "chunks": len(spans),
             "total": sum(len(s) for s in spans),
+            "rid": _next_rid(),
         }
         return self._stream_resolver(
             spans, header, _pack_chunk, self._stream_stats,

@@ -73,6 +73,9 @@ FIELDS = (
     # lie inside the record's `marshal`).
     "ran", "keys_built", "slots_evicted", "lanes_ladder", "build_ns",
     "update_ns",
+    # who asked and why, parsed from the client's rid at decode
+    # (`<node>-<why>-<counter>`, docs/device-daemon.md); "" for a rid of another form
+    "node", "why",
 )
 NOTED = ("keys_built", "slots_evicted", "lanes_ladder", "build_ns",
          "update_ns")
@@ -119,8 +122,8 @@ class CallRecord:
     """One request's instants. `t[0]` is t_recv0, `t[i + 1]` the end of
     PHASES[i]; `_cur` is the phase now running."""
 
-    __slots__ = ("seq", "conn", "op", "lanes", "width", "rid", "in_flight",
-                 "t", "_cur", "_ann", "_counted", "_closed",
+    __slots__ = ("seq", "conn", "op", "lanes", "width", "rid", "node", "why",
+                 "in_flight", "t", "_cur", "_ann", "_counted", "_closed",
                  "program", "merged", "merged_conns", "program_lanes",
                  *NOTED)
 
@@ -130,7 +133,7 @@ class CallRecord:
         self.op = ""
         self.lanes = 0
         self.width = 0
-        self.rid = ""
+        self.rid = self.node = self.why = ""
         self.in_flight = in_flight
         self.t = [time.time_ns(), 0, 0, 0, 0, 0]
         self._cur = 0
@@ -206,7 +209,16 @@ class CallRecord:
                 self.merged, self.merged_conns,
                 self.program_lanes or self.lanes, self.ran(),
                 self.keys_built, self.slots_evicted, self.lanes_ladder,
-                self.build_ns, self.update_ns)
+                self.build_ns, self.update_ns, self.node, self.why)
+
+
+def parse_rid(rid: str) -> tuple[str, str]:
+    """(node, why) of a client's `<node>-<why>-<counter>`; ("", "") for a
+    rid of any other form (an older client's `<pid>-<counter>`)."""
+    parts = rid.rsplit("-", 2)
+    if len(parts) == 3 and parts[0] and parts[1] and parts[2].isdigit():
+        return parts[0], parts[1]
+    return "", ""
 
 
 def attach(rec: CallRecord | None) -> None:
@@ -238,6 +250,7 @@ class SpanRing:
                 rid: str = "") -> None:
         """The request is one the ring keeps: name it, end `decode`."""
         rec.op, rec.lanes, rec.rid = op, lanes, rid
+        rec.node, rec.why = parse_rid(rid)
         with self._lock:
             self.open += 1
         rec._counted = True

@@ -1,87 +1,92 @@
-"""Transaction-lifecycle tracing (round 17, docs/observability.md).
+"""Transaction-lifecycle tracing (round 17, PR 37; docs/observability.md).
 
 The per-height consensus traces (round 11) and the fleet timelines
-(round 15) answer "how is the node/fleet doing"; nothing answered
-"where did MY transaction spend its time". This module is the sampled
-per-tx span recorder: a traced tx is stamped with a wall-clock instant
-at each lifecycle stage it crosses —
+(round 15) answer "how is the node/fleet doing"; this module answers
+"where did ONE write spend its time", from the port it arrived on to its
+reply, on every node that touched it. A traced tx is stamped with a
+wall-clock instant at each lifecycle stage it crosses —
 
     rpc_ingress     check_tx entry (RPC submit, or gossip arrival on a
                     replica — the record carries the source)
-    sig_gate        the batched signature-gate verdict landed
-    mempool_admit   the app's CheckTx accepted it into the pool
+    gate_dispatch   its signature-gate batch went to the verifier (the
+                    record keeps that call's daemon rid, `gate_rid`)
+    sig_gate        the gate's verdict for the batch landed
+    mempool_admit   the app's grouped CheckTx for the batch answered
     p2p_broadcast   first gossip send to any peer succeeded
-    proposal        reaped into our proposal, or seen in a received
-                    complete proposal block (whichever node this is)
+    reap            THIS node reaped it into the block that committed it
+                    (the proposer of that block only; the last reap, where
+                    a round re-proposes)
+    proposal        the proposal block carrying it is whole here
     block_commit    the block carrying it finalized (stage 1: the WAL
                     marker is down; the record learns its height here)
     apply           the block's deferred/serial apply completed
     event_delivery  the tx's DeliverTx event flushed to subscribers
+    rpc_reply       broadcast_tx_commit returns its answer (the node the
+                    write was sent to)
 
-Stamps are keep-first (a re-proposed round re-stamps nothing), absolute
-epoch seconds — the SAME convention as the round-15 gossip arrival
-marks, so `ops/txtrace` can join instants for one tx hash ACROSS nodes
-into a cross-node timeline (submitted on A, committed via B's proposal).
-The tx hash (types/tx.tx_hash — the natural cross-node causal id) is
-computed once, at sampling time, never on the untraced hot path.
+Stamps are keep-first except `reap` (keep-last), absolute epoch seconds —
+the SAME convention as the round-15 gossip arrival marks and the
+daemon's call records (`time.time_ns`), so one tx hash joins ACROSS nodes
+and against the daemon's records with no offset to estimate.
 
-Sampling (env knobs, libs/envknob semantics):
+Sampling: a tx is traced iff crc32 of its first 96 bytes (a signed tx's
+pubkey and signature) is 0 mod N — the same decision on EVERY node, so
+the node a write was sent to and the proposer that reaped it trace the
+same writes. The untraced hot path pays one C-level checksum at check_tx.
 
-    TENDERMINT_TXTRACE_FIRST_K     (2)   trace the first K txs entering
-                                         check_tx after each commit
-    TENDERMINT_TXTRACE_SAMPLE_N    (64)  plus every Nth tx (0 = off)
-    TENDERMINT_TXTRACE_MAX_ACTIVE  (256) in-flight trace bound — beyond
-                                         it the oldest active trace is
-                                         sealed as "evicted"
-    TENDERMINT_TXTRACE_RING        (256) completed-trace ring
-    TENDERMINT_TXTRACE_DISABLE     (0)   kill switch
+    TENDERMINT_TXTRACE_SAMPLE_N    (4)    trace 1 in N txs (0 = off)
+    TENDERMINT_TXTRACE_MAX_ACTIVE  (256)  in-flight trace bound — beyond
+                                          it the oldest active trace is
+                                          sealed as "evicted"
+    TENDERMINT_TXTRACE_RING        (2048) completed-trace ring
+    TENDERMINT_TXTRACE_DISABLE     (0)    kill switch
 
-Hot-path cost discipline (the signed-burst shape through the batched
-gate is the harshest denominator in the repo): an untraced tx pays ONE
-inline countdown at ingress (``rec._tick -= 1`` at the check_tx call
-site — no method call; both sampling arms are folded into the one
-counter, re-armed by the slow path), and the sig-gate/admit stamps run
-at BATCH granularity (``stamp_gate_batch``: one set build per verified
-batch, then one membership probe per in-flight trace — never per-tx
-method calls). Dict keys are the tx BYTES whose hash the mempool cache
-already computed and the bytes object caches. Block-granularity stamp
-sites (`commit`/`stamp_present`/`delivered`) cost one dict.get per
-block tx only while traces are in flight.
+A trace seals at event_delivery, except one whose submitter waits in
+broadcast_tx_commit (`expect_reply`): that one seals at rpc_reply. A
+sealed trace drops its tx bytes and keeps the hash. Batch-granular stamp
+sites (`stamp_present`, `stamp_gate_dispatch`) cost one dict.get per
+batch tx only while traces are in flight.
 
 Metrics (materialized on the node registry by node/telemetry.py):
-``tx_stage_seconds{stage}`` — span from the previous stamped stage —
+``tx_stage_seconds{stage}`` — span from the previous stamped instant —
 plus the end-to-end ``tx_commit_latency_seconds`` (rpc_ingress ->
 block_commit) and ``tx_visible_latency_seconds`` (rpc_ingress ->
 event_delivery) histograms, observed once per sealed trace. The spans
-TELESCOPE: for any sealed trace the stamped spans through block_commit
-sum EXACTLY to its commit latency (tests/test_txtrace.py and
-tests/test_node_rpc.py hold it, guarding the stamping sites).
+TELESCOPE: they follow the stamps in time order, so for any sealed trace
+the spans through block_commit sum EXACTLY to its commit latency.
 
-Served by the ``tx_trace`` RPC (completed ring + in-flight actives —
-a partition-parked tx is visible mid-flight, which is exactly what the
-netchaos wedge triage needs) and the ``python -m
-tendermint_tpu.ops.txtrace`` cross-node CLI.
+Served by the ``tx_trace`` RPC (completed ring + in-flight actives), the
+``python -m tendermint_tpu.ops.txtrace`` cross-node CLI, and the flight
+recorder's stop dump (``tx_traces``), which the benchmark reads.
 """
 
 from __future__ import annotations
 
 import threading
 import time
+import zlib
 from collections import deque
 
 from tendermint_tpu.libs.envknob import env_number as _env_number
 
-# canonical stage order (display + docs/observability.md diagram)
+# canonical stage order (display, docs/observability.md diagram, and the
+# tie-break of two stamps at one instant)
 STAGES = (
-    "rpc_ingress", "sig_gate", "mempool_admit", "p2p_broadcast",
-    "proposal", "block_commit", "apply", "event_delivery",
+    "rpc_ingress", "gate_dispatch", "sig_gate", "mempool_admit",
+    "p2p_broadcast", "reap", "proposal", "block_commit", "apply",
+    "event_delivery", "rpc_reply",
 )
-
-# tick value meaning "sampling disarmed": large enough that a node
-# submitting a billion tx/s would take decades to count it down
-_NEVER = 1 << 60
+_RANK = {s: i for i, s in enumerate(STAGES)}
+# the bytes the sample rule hashes: a signed tx's pubkey and signature
+SAMPLE_BYTES = 96
 
 _hist_attr = "_txtrace_family_cache"
+
+
+def in_sample(tx: bytes, n: int) -> bool:
+    """The sample rule, the same on every node: 1 in n txs by the crc32
+    of their first SAMPLE_BYTES bytes (n <= 0: none)."""
+    return n > 0 and zlib.crc32(tx[:SAMPLE_BYTES]) % n == 0
 
 
 def txtrace_hists(reg=None) -> dict:
@@ -119,12 +124,12 @@ def txtrace_hists(reg=None) -> dict:
 
 class TxTrace:
     """One sampled tx's lifecycle record. Mutated only through the
-    recorder; published (RPC readers) as to_json snapshots. The tx HASH
-    (the cross-node causal id) is computed lazily — at seal or first
-    read, never on the ingress path."""
+    recorder; published (RPC readers, dumps) as to_json snapshots. The
+    tx HASH (the cross-node causal id) is computed lazily — at seal or
+    first read, never on the ingress path."""
 
     __slots__ = ("tx", "hash", "source", "stamps", "height", "outcome",
-                 "completed_at")
+                 "completed_at", "gate_rid", "reap_block", "awaits_reply")
 
     def __init__(self, tx: bytes, source: str):
         self.tx = tx
@@ -132,8 +137,11 @@ class TxTrace:
         self.source = source
         self.stamps: dict[str, float] = {}
         self.height = 0
-        self.outcome: str | None = None  # committed/rejected/evicted
+        self.outcome: str | None = None  # committed/rejected/evicted/...
         self.completed_at = 0.0
+        self.gate_rid = ""
+        self.reap_block: bytes | None = None
+        self.awaits_reply = False
 
     def ensure_hash(self) -> bytes:
         h = self.hash
@@ -145,19 +153,17 @@ class TxTrace:
 
     def spans(self, stamps: dict | None = None) -> dict[str, float]:
         """Span attributed to each stamped stage: seconds since the
-        PREVIOUS stamped stage. Telescoping by construction — summing
-        the spans through block_commit reproduces the commit latency
-        exactly."""
+        PREVIOUS stamped instant, in time order (ties in canonical
+        order). Telescoping by construction — summing the spans through
+        block_commit reproduces the commit latency exactly."""
         if stamps is None:
             stamps = self.stamps
         out: dict[str, float] = {}
         prev = None
-        for stage in STAGES:
-            t = stamps.get(stage)
-            if t is None:
-                continue
+        for t, _rank, stage in sorted(
+                (t, _RANK.get(s, len(STAGES)), s) for s, t in stamps.items()):
             if prev is not None:
-                out[stage] = max(0.0, t - prev)
+                out[stage] = t - prev
             prev = t
         return out
 
@@ -170,7 +176,7 @@ class TxTrace:
         ingress = stamps.get("rpc_ingress")
         commit = stamps.get("block_commit")
         visible = stamps.get("event_delivery")
-        return {
+        out = {
             "hash": self.ensure_hash().hex().upper(),
             "source": self.source,
             "height": self.height,
@@ -188,27 +194,27 @@ class TxTrace:
             ),
             "completed_at": self.completed_at or None,
         }
+        if self.gate_rid:
+            out["gate_rid"] = self.gate_rid
+        return out
 
 
 class TxTraceRecorder:
     """Sampled per-tx lifecycle spans keyed by tx bytes in flight and
     by tx hash at rest (the ring). One recorder per node — the mempool,
-    its reactor, and the consensus state all stamp the same instance
-    (node/node.py wires it; sites guard None for bare-harness tests)."""
+    its reactor, the consensus state and the RPC handlers all stamp the
+    same instance (node/node.py wires it; sites guard None for
+    bare-harness tests)."""
 
-    def __init__(self, ring: int | None = None, first_k: int | None = None,
-                 sample_n: int | None = None, max_active: int | None = None):
+    def __init__(self, ring: int | None = None, sample_n: int | None = None,
+                 max_active: int | None = None):
         import os
 
         self._enabled = os.environ.get(
             "TENDERMINT_TXTRACE_DISABLE", "") != "1"
-        self.first_k = (
-            first_k if first_k is not None
-            else int(_env_number("TENDERMINT_TXTRACE_FIRST_K", 2, cast=int))
-        )
         self.sample_n = (
             sample_n if sample_n is not None
-            else int(_env_number("TENDERMINT_TXTRACE_SAMPLE_N", 64, cast=int))
+            else int(_env_number("TENDERMINT_TXTRACE_SAMPLE_N", 4, cast=int))
         )
         self.max_active = max(1, (
             max_active if max_active is not None
@@ -216,96 +222,68 @@ class TxTraceRecorder:
                                  cast=int))
         ))
         if ring is None:
-            ring = max(1, int(_env_number("TENDERMINT_TXTRACE_RING", 256,
+            ring = max(1, int(_env_number("TENDERMINT_TXTRACE_RING", 2048,
                                           cast=int)))
         self._ring: deque[TxTrace] = deque(maxlen=ring)
         self._mtx = threading.Lock()
         # insertion-ordered (py3.7 dict): the oldest active is the
         # eviction victim when the bound is hit
         self._active: dict[bytes, TxTrace] = {}
-        # THE ingress fast path: one countdown folding both sampling
-        # arms. Call sites run `rec._tick -= 1` inline and only enter
-        # ingress() when it hits zero; ingress() re-arms it — 0 while a
-        # first-K burst is open (every tx enters), sample_n between
-        # 1-in-N samples, effectively-infinite when sampling is off.
-        # Benign GIL races (a lost decrement under concurrent check_tx)
-        # shift WHICH tx samples, never correctness.
-        self._burst_left = self.first_k if self._enabled else 0
-        self._tick = _NEVER
-        # external countdown holders (the mempool keeps its own
-        # `_trace_tick` attribute so its check_tx fast path is a pure
-        # local-attribute decrement — bind_tick registers it and _rearm
-        # pushes every re-arm there too)
-        self._tick_holders: list = []
-        if self._enabled:
-            self._rearm()
-        self._seen = 0          # sampling decisions taken (stats)
+        # sampled txs whose submitter waits in broadcast_tx_commit: their
+        # traces seal at rpc_reply (expect_reply / reply)
+        self._awaiting: set[bytes] = set()
+        # holders of the effective N (the mempool keeps `_trace_n` so its
+        # check_tx fast path reads its OWN attribute; bind() registers it
+        # and set_enabled pushes changes there too)
+        self._holders: list = []
         # flat stats (node/telemetry.py txtrace producer)
         self.sampled = 0
         self.completed = 0
         self.rejected = 0
         self.evicted = 0
-        self.gate_batches = 0  # stamp_gate_batch calls (overhead bench)
         self.metrics_registry = None
+
+    def _n(self) -> int:
+        return self.sample_n if self._enabled else 0
 
     def set_enabled(self, on: bool) -> None:
         self._enabled = bool(on)
-        self._burst_left = self.first_k if on else 0
-        self._rearm()
+        for h in self._holders:
+            h._trace_n = self._n()
+
+    def bind(self, holder) -> None:
+        """Register a holder of the effective N: `holder._trace_n`."""
+        self._holders.append(holder)
+        holder._trace_n = self._n()
 
     # -- sampling decision (check_tx entry) --------------------------------
 
+    def samples(self, tx: bytes) -> bool:
+        return in_sample(tx, self._n())
+
     def maybe_trace(self, tx: bytes, source: str = "rpc",
                     at: float | None = None) -> bool:
-        """The ingress gate: the inline countdown + the slow path. Call
-        sites that can't inline the tick (tests, non-hot paths) use
-        this; mempool.check_tx runs the two-line tick itself."""
-        self._tick -= 1
-        if self._tick <= 0:
+        """The ingress gate: the sample rule + ingress. mempool.check_tx
+        runs the rule inline on its own `_trace_n`."""
+        if self.samples(tx):
             return self.ingress(tx, source, at)
         return False
 
-    def bind_tick(self, holder) -> None:
-        """Register an external countdown holder: `holder._trace_tick`
-        mirrors this recorder's tick so the holder's hot path can run
-        the decrement on its OWN attribute (no cross-object loads)."""
-        self._tick_holders.append(holder)
-        holder._trace_tick = self._tick
-
-    def _rearm(self) -> None:
-        """Set the countdown for the NEXT sample (callers hold no
-        invariant: burst first, then 1-in-N, else never) and push it to
-        every bound holder."""
-        if self._burst_left > 0:
-            tick = 0
-        elif self.sample_n > 0:
-            tick = self.sample_n
-        else:
-            tick = _NEVER
-        self._tick = tick
-        for h in self._tick_holders:
-            h._trace_tick = tick
-
     def ingress(self, tx: bytes, source: str = "rpc",
                 at: float | None = None) -> bool:
-        """The tick hit zero: sample THIS tx (stamping rpc_ingress) and
-        re-arm the countdown. The tx hash is computed only here — never
-        on the untraced path."""
+        """A sampled tx entered check_tx: open its trace (stamping
+        rpc_ingress). The tx hash is computed later — never here."""
         if not self._enabled:
-            self._burst_left = 0
-            self._rearm()
             return False
         victim = None
         with self._mtx:
-            self._seen += 1
-            if self._burst_left > 0:
-                self._burst_left -= 1
-            self._rearm()
             if tx in self._active:
                 return True  # resubmission of a tx already in flight
             self.sampled += 1
             tr = TxTrace(tx, source)
             tr.stamps["rpc_ingress"] = at if at is not None else time.time()
+            if self._awaiting:
+                tr.awaits_reply = tx in self._awaiting
             if len(self._active) >= self.max_active:
                 victim = self._active.pop(next(iter(self._active)))
                 self.evicted += 1
@@ -314,6 +292,16 @@ class TxTraceRecorder:
             # seal OUTSIDE the table lock (_seal appends to the ring
             # under the same mutex)
             self._seal(victim, "evicted")
+        return True
+
+    def expect_reply(self, tx: bytes) -> bool:
+        """broadcast_tx_commit is about to submit `tx` and wait for its
+        commit: if it is in the sample (the answer), its trace seals at
+        reply()."""
+        if not self.samples(tx):
+            return False
+        with self._mtx:
+            self._awaiting.add(tx)
         return True
 
     # -- stamping (hot paths: one dict.get when anything is in flight) -----
@@ -328,40 +316,49 @@ class TxTraceRecorder:
             tr.stamps[stage] = at if at is not None else time.time()
 
     def stamp_present(self, txs, stage: str, at: float | None = None) -> None:
-        """Stamp `stage` for every traced tx present in `txs` (a block's
-        tx list) — one dict.get per block tx, only while traces are in
-        flight."""
-        if not self._active:
-            return
-        at = at if at is not None else time.time()
-        for t in txs:
-            self.stamp(bytes(t), stage, at=at)
-
-    def stamp_gate_batch(self, ok_entries, at: float | None = None) -> None:
-        """Batch-granular sig-gate stamping (the <2% discipline): one
-        set build over the batch's admitted (tx, ctx) entries, then one
-        membership probe per IN-FLIGHT trace — zero per-untraced-tx
-        method calls. Stamps sig_gate AND mempool_admit at the verdict
-        instant: the app dispatch is the same grouped call, and a local
-        app's CheckTx ack lands within the same millisecond (an app
-        REJECT later seals the trace via the mempool's reject path, so
-        the approximation never leaves a wrong committed record)."""
+        """Stamp `stage` (keep-first) for every traced tx present in `txs`
+        (a gate batch, a block's tx list) — one dict.get per tx, only
+        while traces are in flight."""
         active = self._active
         if not active:
             return
-        self.gate_batches += 1
         at = at if at is not None else time.time()
-        if not ok_entries:
+        get = active.get
+        for t in txs:
+            tr = get(bytes(t))
+            if tr is not None and stage not in tr.stamps:
+                tr.stamps[stage] = at
+
+    def stamp_gate_dispatch(self, txs, rid: str,
+                            at: float | None = None) -> None:
+        """The gate's batch went to the verifier at `at` as the daemon
+        request `rid` ("" where the host answered it)."""
+        active = self._active
+        if not active:
             return
-        # C-speed transpose: one zip(*) pass + one set() over the tx
-        # column — the cheapest whole-batch set build CPython offers
-        ok = set(next(zip(*ok_entries)))
-        for tx, tr in list(active.items()):
-            if tx in ok:
-                if "sig_gate" not in tr.stamps:
-                    tr.stamps["sig_gate"] = at
-                if "mempool_admit" not in tr.stamps:
-                    tr.stamps["mempool_admit"] = at
+        at = at if at is not None else time.time()
+        get = active.get
+        for t in txs:
+            tr = get(t)
+            if tr is not None and "gate_dispatch" not in tr.stamps:
+                tr.stamps["gate_dispatch"] = at
+                tr.gate_rid = rid
+
+    def stamp_reap(self, txs, block_hash: bytes,
+                   at: float | None = None) -> None:
+        """This node reaped `txs` into its proposal `block_hash`: keep-
+        LAST (a later round's re-reap replaces it); commit() keeps it only
+        where that block is the one committed."""
+        active = self._active
+        if not active:
+            return
+        at = at if at is not None else time.time()
+        get = active.get
+        for t in txs:
+            tr = get(bytes(t))
+            if tr is not None:
+                tr.stamps["reap"] = at
+                tr.reap_block = block_hash
 
     def reject(self, tx: bytes, reason: str = "rejected") -> None:
         """Seal a traced tx that left the lifecycle early (bad
@@ -374,44 +371,65 @@ class TxTraceRecorder:
             self._seal(tr, reason)
             self.rejected += 1
 
-    # -- commit-side stamps (consensus state) ------------------------------
+    # -- commit-side stamps (consensus state, RPC) -------------------------
 
-    def commit(self, txs, height: int, at: float | None = None) -> None:
+    def commit(self, txs, height: int, block_hash: bytes | None = None,
+               at: float | None = None) -> None:
         """block_commit for every traced tx in the finalized block; the
-        record learns its height here. Also re-opens the first-K
-        sampling window — called exactly once per committed height."""
-        if self._enabled and self.first_k > 0:
-            with self._mtx:
-                self._burst_left = self.first_k
-                self._rearm()
+        record learns its height here, and drops a reap of a block other
+        than `block_hash` (this node's proposal lost the round)."""
         if not self._active:
             return
         at = at if at is not None else time.time()
+        get = self._active.get
         for t in txs:
-            b = bytes(t)
-            tr = self._active.get(b)
+            tr = get(bytes(t))
             if tr is not None:
                 if "block_commit" not in tr.stamps:
                     tr.stamps["block_commit"] = at
                 tr.height = height
+                if tr.reap_block is not None and block_hash is not None \
+                        and tr.reap_block != block_hash:
+                    tr.stamps.pop("reap", None)
+                tr.reap_block = None
 
     def delivered(self, txs, at: float | None = None) -> None:
         """event_delivery for every traced tx in the block, then seal —
-        the trace is complete (called after the event flush, serial and
-        pipelined modes both)."""
+        except a trace whose submitter waits for its reply (called after
+        the event flush, serial and pipelined modes both)."""
         if not self._active:
             return
         at = at if at is not None else time.time()
         done = []
         with self._mtx:
             for t in txs:
-                tr = self._active.pop(bytes(t), None)
-                if tr is not None:
-                    if "event_delivery" not in tr.stamps:
-                        tr.stamps["event_delivery"] = at
+                b = bytes(t)
+                tr = self._active.get(b)
+                if tr is None:
+                    continue
+                if "event_delivery" not in tr.stamps:
+                    tr.stamps["event_delivery"] = at
+                if not tr.awaits_reply:
+                    del self._active[b]
                     done.append(tr)
         for tr in done:
             self._seal(tr, "committed")
+            self.completed += 1
+
+    def reply(self, tx: bytes, at: float | None = None) -> None:
+        """broadcast_tx_commit returns for `tx`: stamp rpc_reply and seal
+        the trace that waited for it (committed, or `unanswered` where
+        the handler gave up first)."""
+        with self._mtx:
+            self._awaiting.discard(tx)
+            tr = self._active.get(tx)
+            if tr is None or not tr.awaits_reply:
+                return
+            del self._active[tx]
+        tr.stamps.setdefault("rpc_reply", at if at is not None else time.time())
+        committed = "block_commit" in tr.stamps
+        self._seal(tr, "committed" if committed else "unanswered")
+        if committed:
             self.completed += 1
 
     # -- sealing + metrics -------------------------------------------------
@@ -420,6 +438,7 @@ class TxTraceRecorder:
         tr.outcome = outcome
         tr.completed_at = time.time()
         tr.ensure_hash()  # off the ingress path by design; pin it now
+        tr.tx = None      # at rest a trace holds its hash, not the bytes
         self._observe(tr)
         with self._mtx:
             self._ring.append(tr)
@@ -444,7 +463,7 @@ class TxTraceRecorder:
         except Exception:  # noqa: BLE001
             pass
 
-    # -- reads (RPC threads) -----------------------------------------------
+    # -- reads (RPC threads, dumps) ----------------------------------------
 
     def active(self) -> list[dict]:
         """In-flight traces, oldest first — a partition-parked tx shows
@@ -459,6 +478,13 @@ class TxTraceRecorder:
         with self._mtx:
             items = list(self._ring)
         return [tr.to_json() for tr in list(reversed(items))[:n]]
+
+    def dump(self) -> list[dict]:
+        """The whole completed ring, oldest first, then the traces still
+        in flight: what the flight recorder's stop dump carries."""
+        with self._mtx:
+            items = list(self._ring) + list(self._active.values())
+        return [tr.to_json() for tr in items]
 
     def stats(self) -> dict:
         """Flat gauges for the canonical map (txtrace_* families)."""

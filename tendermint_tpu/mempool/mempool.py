@@ -16,6 +16,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
+import zlib
 from collections import OrderedDict
 
 from tendermint_tpu.abci.types import (
@@ -26,6 +27,7 @@ from tendermint_tpu.abci.types import (
 from tendermint_tpu.libs.autofile import Group
 from tendermint_tpu.libs.clist import CList
 from tendermint_tpu.libs.envknob import env_number
+from tendermint_tpu.libs.txtrace import SAMPLE_BYTES
 
 CACHE_SIZE = 100_000
 
@@ -88,6 +90,10 @@ class SigBatcher:
         self.max_wait_s = max_wait_s
         self.max_backlog = max_backlog
         self.on_results = on_results
+        # on_dispatch(batch, rid, wall_s): a batch went to the verifier at
+        # wall_s as the daemon request rid ("" where the host answered);
+        # the mempool stamps its traced txs' gate_dispatch there
+        self.on_dispatch = None
         # pipelined pre-verify (round 6): up to max_inflight batches are
         # dispatched via verify_batch_async — batch k's verdicts resolve
         # while batch k+1's txs are already marshaling toward the device
@@ -180,27 +186,38 @@ class SigBatcher:
     def _run(self) -> None:
         from collections import deque
 
-        pending: deque = deque()  # (batch, resolver|None) FIFO
-        while True:
-            batch = self._take_batch(wait=not pending)
-            if batch is None and not pending:
-                return
-            if batch:
-                try:
-                    resolver = self.verifier.verify_batch_async(
-                        [b[0] for b in batch]
-                    )
-                except Exception:  # noqa: BLE001 — fail OPEN at delivery
-                    # (see _deliver); dispatch failures must not stall
-                    # the intake side of the pipeline
-                    logger.exception("sig gate dispatch failed")
-                    resolver = None
-                pending.append((batch, resolver))
-            if pending and (not batch or len(pending) >= self.max_inflight):
-                self._deliver(*pending.popleft())
+        from tendermint_tpu import devd
 
-    def _deliver(self, batch: list, resolver) -> None:
-        t0 = time.perf_counter()
+        # every daemon request this thread sends is a gate call
+        with devd.asking("gate"):
+            pending: deque = deque()  # (batch, resolver|None, t0) FIFO
+            while True:
+                batch = self._take_batch(wait=not pending)
+                if batch is None and not pending:
+                    return
+                if batch:
+                    devd.take_rid()
+                    wall, t0 = time.time(), time.perf_counter()
+                    try:
+                        resolver = self.verifier.verify_batch_async(
+                            [b[0] for b in batch]
+                        )
+                    except Exception:  # noqa: BLE001 — fail OPEN at
+                        # delivery (see _deliver); dispatch failures must
+                        # not stall the intake side of the pipeline
+                        logger.exception("sig gate dispatch failed")
+                        resolver = None
+                    if self.on_dispatch is not None:
+                        try:
+                            self.on_dispatch(batch, devd.take_rid(), wall)
+                        except Exception:  # noqa: BLE001 — a bad hook
+                            # must not stall the gate
+                            logger.exception("sig gate dispatch hook failed")
+                    pending.append((batch, resolver, t0))
+                if pending and (not batch or len(pending) >= self.max_inflight):
+                    self._deliver(*pending.popleft())
+
+    def _deliver(self, batch: list, resolver, t0: float) -> None:
         try:
             oks = resolver() if resolver is not None else None
         except Exception:  # noqa: BLE001 — fail OPEN (round-8 latch
@@ -300,6 +317,7 @@ class Mempool:
             # the mempool is the gate's result sink: whole batches admit
             # through one lock round trip (see SigBatcher docstring)
             sig_batcher.on_results = self._sig_gate_results
+            sig_batcher.on_dispatch = self._sig_gate_dispatched
         self.txs = CList()
         self.counter = 0
         self.height = 0
@@ -358,10 +376,9 @@ class Mempool:
         # gated burst hot path pays zero per-tx tracing there.
         self._txtrace = None
         self._admit_rec = None
-        # the recorder-bound sampling countdown (libs/txtrace.bind_tick):
-        # check_tx's fast path is a pure local-attribute decrement; with
-        # no recorder it counts down from 2^60 — never fires
-        self._trace_tick = 1 << 60
+        # the recorder's N (libs/txtrace.bind): check_tx's fast path runs
+        # the sample rule on its own attribute; 0 = nothing traced
+        self._trace_n = 0
         self._mtx = threading.RLock()  # the proxy mtx (mempool/mempool.go:58)
         proxy_app_conn.set_response_callback(self._res_cb)
 
@@ -374,7 +391,9 @@ class Mempool:
         self._txtrace = rec
         self._admit_rec = rec if self.sig_batcher is None else None
         if rec is not None:
-            rec.bind_tick(self)
+            rec.bind(self)
+        else:
+            self._trace_n = 0
 
     # -- wal ---------------------------------------------------------------
 
@@ -461,16 +480,12 @@ class Mempool:
                     f"mempool_source_limit: {src_key} holds "
                     f">={self.source_max_txs} txs")
             self._pending_source[tx] = src_key
-            # lifecycle ingress, inlined (the <2% discipline): an
-            # untraced tx pays ONE local-attribute countdown decrement;
-            # only the sampled tx enters the recorder (which re-arms
-            # this tick through the bind_tick mirror)
-            self._trace_tick -= 1
-            if self._trace_tick <= 0:
-                if self._txtrace is not None:
-                    self._txtrace.ingress(tx, source)
-                else:
-                    self._trace_tick = 1 << 60
+            # lifecycle ingress, inlined: an untraced tx pays one
+            # C-level checksum (libs/txtrace.in_sample, the same decision
+            # on every node); only a sampled tx enters the recorder
+            n = self._trace_n
+            if n and zlib.crc32(tx[:SAMPLE_BYTES]) % n == 0:
+                self._txtrace.ingress(tx, source)
             if self.wal is not None:
                 self.wal.write_line(tx.hex())
                 self.wal.flush()
@@ -518,10 +533,10 @@ class Mempool:
         (mempool/mempool.go:231)."""
         rec = self._txtrace
         ok_entries = [ctx for ctx, ok in results if ok]
-        if rec is not None and rec._active:
-            # batch-granular stamping (the <2% discipline): one set
-            # build for the whole verdict batch, zero per-tx calls
-            rec.stamp_gate_batch(ok_entries)
+        ok_txs = [tx for tx, _cb in ok_entries]
+        if rec is not None:
+            # batch-granular stamping: one instant for the whole batch
+            rec.stamp_present(ok_txs, "sig_gate")
         for tx, cb in (ctx for ctx, ok in results if not ok):
             if rec is not None:
                 rec.reject(tx, "bad_sig")
@@ -535,15 +550,25 @@ class Mempool:
         if not ok_entries:
             return
         with self._mtx:
-            rrs = self.proxy_app_conn.check_tx_many_async(
-                [tx for tx, _cb in ok_entries]
-            )
+            rrs = self.proxy_app_conn.check_tx_many_async(ok_txs)
+        if rec is not None:
+            # the app answered the grouped CheckTx (a local app has, by
+            # the call's return; its rejects already sealed their traces)
+            rec.stamp_present(ok_txs, "mempool_admit")
         for (_tx, cb), rr in zip(ok_entries, rrs):
             if cb is not None:
                 try:
                     rr.set_callback(cb)
                 except Exception:  # noqa: BLE001 — same isolation rule
                     logger.exception("check_tx callback failed")
+
+    def _sig_gate_dispatched(self, batch, rid: str, wall: float) -> None:
+        """A gate batch went to the verifier (batcher thread): its traced
+        txs' gate_dispatch, and the daemon request that carries them."""
+        rec = self._txtrace
+        if rec is not None:
+            rec.stamp_gate_dispatch((ctx[0] for _item, ctx in batch), rid,
+                                    at=wall)
 
     def _reject_bad_sig(self, tx: bytes, cb) -> None:
         """Signature failed the batch gate: reject without app dispatch —

@@ -59,8 +59,11 @@ snapshot (p2p gossip totals + consensus position via ``counters_fn``,
 wired by node/node.py) so picks-vs-sends is readable without a second
 artifact, ``consensus_traces``: the per-height traces' ring as the
 ``consensus_trace`` RPC serves it (``traces_fn``, wired the same way),
-and ``links``: one record a peer (``links_fn``: ping round trips, the
-relay hold, the delay line's counters where one is configured).
+``links``: one record a peer (``links_fn``: ping round trips, the
+relay hold, the delay line's counters where one is configured), and
+``tx_traces``: the sampled tx-lifecycle traces, the completed ring
+oldest first and then those in flight (``tx_traces_fn``,
+libs/txtrace.TxTraceRecorder.dump).
 
 ``record()`` is one enabled-check + one deque.append (GIL-atomic) — the
 TENDERMINT_FLIGHTREC_DISABLE kill switch makes it a single attribute
@@ -112,6 +115,9 @@ class FlightRecorder:
         # trips, its relay hold, and its delay line's counters where
         # `[p2p]` configures one (node/node.py)
         self.links_fn = None
+        # optional provider of the sampled tx-lifecycle traces, completed
+        # and in flight (node/node.py: the node's TxTraceRecorder.dump)
+        self.tx_traces_fn = None
         self._watch_stop: threading.Event | None = None
 
     @property
@@ -244,6 +250,7 @@ class FlightRecorder:
             "events": self.events(),
             "consensus_traces": self._snapshot(self.traces_fn, []),
             "links": self._snapshot(self.links_fn, []),
+            "tx_traces": self._snapshot(self.tx_traces_fn, []),
         }
         self.dumps += 1
         if self.dump_dir is None:

@@ -208,6 +208,11 @@ class Node(BaseService):
 
         self.txtrace = TxTraceRecorder()
         self.flightrec = FlightRecorder(home=config.base.root_dir)
+        # the daemon's records of this process's calls name it
+        # (`<moniker>-<why>-<n>`, tendermint_tpu/devd.py)
+        from tendermint_tpu import devd as _devd
+
+        _devd.set_client_name(config.base.moniker)
 
         sig_batcher = None
         local_app = getattr(client_creator, "app", None)
@@ -335,8 +340,9 @@ class Node(BaseService):
             self.block_store,
             fast_sync,
             event_cache=None,
-            batch_verifier=self.verifier.commit_batch_verifier(),
-            async_batch_verifier=self.verifier.verify_batch_async,
+            batch_verifier=self.verifier.commit_batch_verifier("sync"),
+            async_batch_verifier=self.verifier.asking(
+                "sync", self.verifier.verify_batch_async),
             part_hasher=self.hasher.part_leaf_hashes,
             part_tree_hasher=self.hasher.part_set_tree,
             post_apply_hook=post_apply_hook,
@@ -489,6 +495,7 @@ class Node(BaseService):
         self.flightrec.traces_fn = lambda: [
             t.to_json() for t in trace.last(trace.ring_size)]
         self.flightrec.links_fn = self.link_records
+        self.flightrec.tx_traces_fn = self.txtrace.dump
 
     def link_records(self) -> list[dict]:
         """One record a peer for the flight recorder's dumps: who it is,

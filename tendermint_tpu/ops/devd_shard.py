@@ -264,6 +264,7 @@ def _dispatch(items: list, run, floor: int, sigs: bool) -> list:
     cond = threading.Condition()
     last_exc: list[BaseException] = []
 
+    why = devd.current_why()  # the caller's, for the worker threads
     # slice records: [start, stop, home_worker_index]
     pending: list[list[int]] = []
     inflight = [0]
@@ -355,9 +356,13 @@ def _dispatch(items: list, run, floor: int, sigs: bool) -> list:
                     inflight[0] -= 1
                     cond.notify_all()
 
+        def asked(i: int, ep) -> None:
+            with devd.asking(why):  # the caller's purpose, on this thread
+                worker(i, ep)
+
         threads = [
             threading.Thread(
-                target=worker, args=(i, ep), daemon=True,
+                target=asked, args=(i, ep), daemon=True,
                 name=f"devd-shard-{i}",
             )
             for i, ep in enumerate(eps)
@@ -419,9 +424,12 @@ def verify_batch_async(items):
     box: dict = {}
     evt = threading.Event()
 
+    why = devd.current_why()
+
     def run() -> None:
         try:
-            box["res"] = verify_batch(items)
+            with devd.asking(why):
+                box["res"] = verify_batch(items)
         except BaseException as exc:  # noqa: BLE001 — re-raised at resolve
             box["exc"] = exc
         finally:

@@ -1050,10 +1050,15 @@ class Verifier:
         prepare-time screening in consensus/vote_batcher.py) overlaps
         marshal, IPC, and device compute instead of serializing behind
         them. `on_done(dt_s)` observes the dispatch→verdicts wall time
-        on successful resolution."""
+        on successful resolution. The daemon's record of the call names
+        it a `vote` call (docs/device-daemon.md)."""
         if not items:
             return
-        pending = _PendingBatch(items, self.verify_batch_async(items), on_done)
+        from tendermint_tpu import devd
+
+        with devd.asking("vote"):
+            resolve = self.verify_batch_async(items)
+        pending = _PendingBatch(items, resolve, on_done)
         with self._mtx:
             for it in items:
                 self._primed[it] = pending
@@ -1090,9 +1095,23 @@ class Verifier:
 
     # -- adapters for the call sites --------------------------------------
 
-    def commit_batch_verifier(self):
-        """For ValidatorSet.verify_commit(batch_verifier=...)."""
-        return self.verify_batch
+    def commit_batch_verifier(self, why: str = "commit"):
+        """For ValidatorSet.verify_commit(batch_verifier=...): verify_batch
+        with its calls named `why` on the daemon's records
+        (docs/device-daemon.md)."""
+        return self.asking(why, self.verify_batch)
+
+    @staticmethod
+    def asking(why: str, fn):
+        """`fn` with every daemon request it sends named `why` (the
+        async form names its dispatch: the request goes out in the call)."""
+        from tendermint_tpu import devd
+
+        def call(*args, **kw):
+            with devd.asking(why):
+                return fn(*args, **kw)
+
+        return call
 
     def vote_verifier(self):
         """For VoteSet.add_vote(verifier=...)."""

@@ -10,7 +10,8 @@ cross-node causal id — into per-tx timelines: the stage instants are
 absolute wall-clock seconds (the round-15 arrival-mark convention), so
 one tx's lifecycle reads ACROSS the fleet: submitted on A (rpc_ingress
 there), gossiped (p2p_broadcast on A, rpc_ingress source=peer on B),
-reaped into B's proposal, committed everywhere. A tx parked mid-flight
+reaped into B's proposal (`reap` on B alone), committed everywhere,
+answered on A (`rpc_reply`). A tx parked mid-flight
 (the netchaos partition scenario) shows with its last stamped stage and
 no commit — which is the wedge-triage read.
 
@@ -87,10 +88,13 @@ def join_tx_timelines(snapshot: dict) -> list[dict]:
             t["stages"].get("block_commit") is not None
             for t in nodes.values()
         )
+        # the proposer of the committed block stamped `reap`; a fleet
+        # scraped mid-flight may show only `proposal` receipts
         proposed_on = next(
             (url for url, t in nodes.items()
-             if t["stages"].get("proposal") is not None),
-            None,
+             if t["stages"].get("reap") is not None),
+            next((url for url, t in nodes.items()
+                  if t["stages"].get("proposal") is not None), None),
         )
         last_activity = max(
             (max(t["stages"].values()) for t in nodes.values()
@@ -142,8 +146,8 @@ def _ms(v) -> str:
 
 def render(rows: list[dict], out=sys.stdout, last: int = 10) -> None:
     if not rows:
-        print("no traced txs reported (sampling knobs: "
-              "TENDERMINT_TXTRACE_FIRST_K / _SAMPLE_N)", file=out)
+        print("no traced txs reported (1 in TENDERMINT_TXTRACE_SAMPLE_N "
+              "txs is traced, by a hash of its bytes)", file=out)
         return
     for r in rows[: max(1, int(last))]:
         state = (

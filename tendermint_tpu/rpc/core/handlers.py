@@ -275,14 +275,19 @@ def broadcast_tx_commit(ctx, tx, timeout: float = 60.0) -> dict:
     tx = _unhex(tx)
     committed = threading.Event()
     box = {}
+    h = tx_hash(tx)
+    hash_hex = _hex(h)
 
-    listener_id = f"rpc-tx-{_hex(tx_hash(tx))[:16]}-{time.monotonic_ns()}"
-    event = tev.event_string_tx(tx_hash(tx))
+    listener_id = f"rpc-tx-{hash_hex[:16]}-{time.monotonic_ns()}"
+    event = tev.event_string_tx(h)
 
     def on_tx(data):
         box["deliver"] = data
         committed.set()
 
+    # a traced write's trace seals at this handler's reply (rpc_reply)
+    rec = getattr(getattr(ctx, "node", None), "txtrace", None)
+    traced = rec is not None and rec.expect_reply(tx)
     ctx.event_switch.add_listener_for_event(listener_id, event, on_tx)
     try:
         check_done = threading.Event()
@@ -303,7 +308,7 @@ def broadcast_tx_commit(ctx, tx, timeout: float = 60.0) -> dict:
             return {
                 "check_tx": check_json,
                 "deliver_tx": None,
-                "hash": _hex(tx_hash(tx)),
+                "hash": hash_hex,
                 "height": 0,
             }
         _wait_or_deadline(ctx, committed, float(timeout),
@@ -312,11 +317,13 @@ def broadcast_tx_commit(ctx, tx, timeout: float = 60.0) -> dict:
         return {
             "check_tx": check_json,
             "deliver_tx": {"code": d.code, "data": _hex(d.data or b""), "log": d.log},
-            "hash": _hex(tx_hash(tx)),
+            "hash": hash_hex,
             "height": d.height,
         }
     finally:
         ctx.event_switch.remove_listener(listener_id)
+        if traced:
+            rec.reply(tx)
 
 
 def unconfirmed_txs(ctx) -> dict:

@@ -130,7 +130,8 @@ def _records(client, **kw) -> list[dict]:
 def test_instants_monotone_and_phases_sum_exactly(sim):
     _, client, _ = sim
     assert client.verify_batch(_items(3)) == [True] * 3
-    assert client.verify_stream(_items(20), chunk=8) == [True] * 20
+    with devd.asking("sync"):
+        assert client.verify_stream(_items(20), chunk=8) == [True] * 20
     recs = _records(client)
     assert [(r["op"], r["lanes"]) for r in recs] == [
         ("verify", 3), ("verify_stream", 8), ("verify_stream", 8),
@@ -143,8 +144,14 @@ def test_instants_monotone_and_phases_sum_exactly(sim):
         assert all(p >= 0 for p in phases)
         # the sim device's time is its wait: 0.5 ms a lane at this rate
         assert r["t_verdicts"] - r["t_dispatched"] >= r["lanes"] * 400_000
-    assert recs[0]["rid"].startswith(f"{os.getpid()}-")
-    assert recs[1]["rid"] == ""   # the binary stream frames carry none
+    # who asked and why: this process, named as its client names it;
+    # a call no site named is set-up traffic, and a stream's chunks
+    # carry the stream's rid
+    assert recs[0]["rid"].startswith(f"{devd._client_name}-warm-")
+    assert (recs[0]["node"], recs[0]["why"]) == (devd._client_name, "warm")
+    assert len({r["rid"] for r in recs[1:]}) == 1
+    assert {(r["node"], r["why"]) for r in recs[1:]} == {
+        (devd._client_name, "sync")}
     seqs = [r["seq"] for r in recs]
     assert seqs == sorted(set(seqs))
     assert len({r["conn"] for r in recs[1:]}) == 1
@@ -356,7 +363,7 @@ def test_old_daemon_reply_without_svc_ns_is_tolerated(short_dir):
     c.close()
     th.join(5)
     srv.close()
-    assert got[0]["rid"].startswith(f"{os.getpid()}-")
+    assert got[0]["rid"].startswith(f"{devd._client_name}-warm-")
     assert count("devd_single_shot_seconds") == single0 + 1
     assert count("devd_single_shot_ipc_seconds") == ipc0
     assert devd.thread_ipc_ns() == before
@@ -520,6 +527,37 @@ def test_mark_with_no_record_open_reads_no_clock(monkeypatch):
     assert clock.reads == 5
     devd_spans.mark("reply")
     assert clock.reads == 5   # finish() closed it: no record is open
+
+
+@pytest.mark.parametrize("rid, node, why", [
+    ("node3-gate-17", "node3", "gate"),
+    ("node-a-3-vote-2", "node-a-3", "vote"),   # a moniker with dashes
+    ("p4242-warm-1", "p4242", "warm"),
+    ("4242-17", "", ""),                       # an older client's rid
+    ("", "", ""),
+])
+def test_record_names_node_and_why_from_the_rid(rid, node, why):
+    ring = devd_spans.SpanRing(size=2)
+    rec = ring.begin(conn=1)
+    ring.decoded(rec, "verify", 1, rid)
+    ring.finish(rec)
+    row = dict(zip(devd_spans.FIELDS, ring.rows()[0]))
+    assert (row["rid"], row["node"], row["why"]) == (rid, node, why)
+
+
+def test_client_rid_names_the_process_and_the_callers_purpose(monkeypatch):
+    monkeypatch.setattr(devd, "_client_name", "node5")
+    devd.take_rid()
+    with devd.asking("commit"):
+        rid = devd._next_rid()
+        with devd.asking("block"):
+            inner = devd._next_rid()
+        assert devd.current_why() == "commit"
+    outside = devd._next_rid()
+    assert devd_spans.parse_rid(rid)[0] == "node5"
+    assert [devd_spans.parse_rid(r)[1] for r in (rid, inner, outside)] == [
+        "commit", "block", "warm"]
+    assert devd.take_rid() == outside and devd.take_rid() == ""
 
 
 # -- what the benchmark hangs on ----------------------------------------------------

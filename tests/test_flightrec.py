@@ -142,6 +142,26 @@ class TestAutoDump:
         assert payload["reason"] == "stop"
         assert [t["height"] for t in payload["consensus_traces"]] == [7, 6]
 
+    def test_stop_dump_carries_the_tx_traces(self, tmp_path):
+        """The stop dump holds the tx-lifecycle ring and the traces still
+        in flight (what the benchmark joins with its writes)."""
+        from tendermint_tpu.libs.txtrace import TxTraceRecorder
+
+        txr = TxTraceRecorder(sample_n=1)
+        for i, tx in enumerate((b"fr-a=1", b"fr-b=2")):
+            txr.maybe_trace(tx, at=float(i))
+        txr.commit([b"fr-a=1"], height=3, at=2.0)
+        txr.delivered([b"fr-a=1"], at=2.1)
+        rec = FlightRecorder(home=str(tmp_path), ring=8)
+        with open(rec.dump("bare")) as f:
+            assert json.load(f)["tx_traces"] == []
+        rec.tx_traces_fn = txr.dump
+        with open(rec.dump("stop")) as f:
+            got = json.load(f)["tx_traces"]
+        assert [(t["height"], t["outcome"]) for t in got] == [(3, "committed"),
+                                                               (0, None)]
+        assert all(len(t["hash"]) == 40 for t in got)
+
     def test_trace_provider_failure_costs_the_section_not_the_dump(
         self, tmp_path
     ):

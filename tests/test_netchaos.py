@@ -435,7 +435,9 @@ def test_partition_wedge_diagnosable_from_artifacts_alone(net4, monkeypatch):
     # -- partition, then submit a tx to the cut-off node ----------------
     net4.partition({3})
     time.sleep(0.5)
-    parked_tx = b"wedge-probe=never-commits"
+    # bytes in the 1-in-4 sample (libs/txtrace.in_sample): every node
+    # traces this tx
+    parked_tx = b"wedge-probe=never-commits-4"
     net4.broadcast_tx(parked_tx, via=3)
 
     # -- artifact 1: the auto-dumped flight record ----------------------
@@ -479,7 +481,7 @@ def test_partition_wedge_diagnosable_from_artifacts_alone(net4, monkeypatch):
     want = tx_hash(parked_tx).hex().upper()
     parked = [r for r in rows if r["hash"] == want]
     assert parked, (
-        f"partitioned tx not traced (first-K window consumed?): {rows}"
+        f"partitioned tx not traced (out of the sample?): {rows}"
     )
     [row] = parked
     assert not row["committed"], row
@@ -488,7 +490,8 @@ def test_partition_wedge_diagnosable_from_artifacts_alone(net4, monkeypatch):
     from tendermint_tpu.libs.txtrace import STAGES
 
     assert row["last_stage"] in (
-        "rpc_ingress", "sig_gate", "mempool_admit", "p2p_broadcast"
+        "rpc_ingress", "gate_dispatch", "sig_gate", "mempool_admit",
+        "p2p_broadcast"
     ), row
     assert STAGES.index(row["last_stage"]) < STAGES.index("proposal")
 

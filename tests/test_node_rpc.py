@@ -700,8 +700,8 @@ def test_tx_trace_rpc_spans_sum_to_commit_latency(node, client):
     assert t["height"] == res["height"]
     assert t["source"] == "rpc"
     # the lifecycle stages a sole-validator commit must cross
-    for stage in ("rpc_ingress", "mempool_admit", "proposal",
-                  "block_commit", "apply", "event_delivery"):
+    for stage in ("rpc_ingress", "mempool_admit", "reap", "proposal",
+                  "block_commit", "apply", "event_delivery", "rpc_reply"):
         assert stage in t["stages"], (stage, t["stages"])
     # stamped instants are causally ordered
     from tendermint_tpu.libs.txtrace import STAGES
@@ -734,6 +734,57 @@ def test_tx_trace_rpc_spans_sum_to_commit_latency(node, client):
     buf = io.StringIO()
     ops_txtrace.render(rows, out=buf)
     assert f"committed @h={res['height']}" in buf.getvalue()
+
+
+def test_signed_write_road_on_a_live_node(tmp_path):
+    """PR 37: a signed write through the signature gate is stamped at
+    the gate's dispatch, the proposer's reap and the reply; its spans
+    still telescope (through block_commit to the commit latency, all of
+    them to ingress -> reply); and the node's stop dump carries it."""
+    import glob as _glob
+
+    from tendermint_tpu.abci.apps.signedkv import make_sig_tx
+    from tendermint_tpu.libs.txtrace import in_sample
+
+    cfg = reset_test_root(str(tmp_path))
+    cfg.base.proxy_app = "signedkv"
+    cfg.rpc.laddr = "tcp://127.0.0.1:0"
+    cfg.p2p.laddr = "tcp://127.0.0.1:0"
+    n = default_new_node(cfg)
+    n.start()
+    try:
+        assert wait_until(lambda: n.block_store.height() >= 1, timeout=30)
+        cli = HTTPClient(f"127.0.0.1:{n.rpc_port()}")
+        # a write in the node's 1-in-4 sample (the default N)
+        tx = next(t for t in (make_sig_tx(bytes([9, k]) + b"\x09" * 30,
+                                          b"road-key=road-val")
+                              for k in range(64)) if in_sample(t, 4))
+        res = cli.broadcast_tx_commit(tx=tx.hex())
+        assert res["deliver_tx"]["code"] == 0
+        # sealed at the reply, before the answer left the handler
+        [t] = [x for x in cli.tx_trace(last=50)["traces"]
+               if x["hash"] == res["hash"]]
+    finally:
+        n.stop()
+    assert t["outcome"] == "committed" and t["source"] == "rpc"
+    for stage in ("rpc_ingress", "gate_dispatch", "sig_gate",
+                  "mempool_admit", "reap", "proposal", "block_commit",
+                  "apply", "event_delivery", "rpc_reply"):
+        assert stage in t["stages"], (stage, t["stages"])
+    st = t["stages"]
+    assert st["rpc_ingress"] <= st["gate_dispatch"] <= st["sig_gate"] \
+        <= st["mempool_admit"] <= st["reap"] <= st["block_commit"] \
+        <= st["rpc_reply"]
+    assert t.get("gate_rid", "") == ""   # answered on the host here
+    through = sum(v for k, v in t["spans"].items()
+                  if st[k] <= st["block_commit"])
+    assert through == pytest.approx(t["commit_latency_s"], abs=1e-5)
+    assert sum(t["spans"].values()) == pytest.approx(
+        st["rpc_reply"] - st["rpc_ingress"], abs=1e-5)
+    [dump] = _glob.glob(str(tmp_path / "flightrec" / "dump-*-stop*.json"))
+    with open(dump) as f:
+        traces = json.load(f)["tx_traces"]
+    assert res["hash"] in {x["hash"] for x in traces}
 
 
 def test_debug_flight_endpoint(node, client):

@@ -1,7 +1,8 @@
 """Tx-lifecycle tracing tests (round 17, libs/txtrace.py + the
 tx_trace RPC + ops/txtrace cross-node join).
 
-Contracts under test: the sampling knobs (first-K-per-height + 1-in-N),
+Contracts under test: the sample rule (crc32 of the first 96 bytes, 1
+in N, the same set on every node),
 keep-first stamp semantics, span TELESCOPING (stamped spans through
 block_commit sum exactly to the commit latency), the bounded
 active/ring tables (eviction seals, never drops silently), the kill
@@ -11,46 +12,70 @@ this round."""
 
 from __future__ import annotations
 
+import random
 import threading
 import time
+import zlib
 
 import pytest
 
 from tendermint_tpu.libs import telemetry
-from tendermint_tpu.libs.txtrace import STAGES, TxTraceRecorder, txtrace_hists
+from tendermint_tpu.libs.txtrace import (
+    STAGES,
+    TxTraceRecorder,
+    in_sample,
+    txtrace_hists,
+)
 
 
 def _tx(i: int) -> bytes:
     return b"txtrace-%04d=v" % i
 
 
+def _signed(n: int, seed: int) -> list[bytes]:
+    """n signed-tx shapes: 32 bytes of key, 64 of signature (random:
+    what the sample rule hashes), then the payload."""
+    rng = random.Random(seed)
+    return [rng.randbytes(96) + b"k%d=v" % i for i in range(n)]
+
+
+def _sampled(n: int, count: int) -> list[bytes]:
+    """The first `count` of _tx(0..) that fall in a 1-in-n sample."""
+    return [t for t in (_tx(i) for i in range(64 * count)) if in_sample(t, n)][:count]
+
+
 class TestSampling:
-    def test_first_k_per_height_plus_one_in_n(self):
-        rec = TxTraceRecorder(first_k=2, sample_n=10)
-        decisions = [rec.maybe_trace(_tx(i)) for i in range(25)]
-        # first 2 sampled (the K window), then the countdown samples
-        # every 10th submission after the burst
-        assert decisions[0] and decisions[1]
-        assert decisions[2:11] == [False] * 9
-        assert decisions[11] is True  # the 1-in-10 countdown fired
-        assert decisions[12:21] == [False] * 9
-        assert decisions[21] is True
-        assert rec.sampled == sum(decisions)
+    def test_hash_rule_samples_crc_zero_mod_n(self):
+        """The sample rule: exactly the txs whose crc32 over their first
+        96 bytes is 0 mod N — no countdown, no per-height window."""
+        rec = TxTraceRecorder(sample_n=10)
+        decisions = [rec.maybe_trace(_tx(i)) for i in range(200)]
+        assert decisions == [zlib.crc32(_tx(i)[:96]) % 10 == 0
+                             for i in range(200)]
+        assert 0 < rec.sampled == sum(decisions) < 200
 
-    def test_commit_resets_the_first_k_window(self):
-        rec = TxTraceRecorder(first_k=1, sample_n=0)
-        assert rec.maybe_trace(_tx(0))
-        assert not rec.maybe_trace(_tx(1))
-        rec.commit([_tx(0)], height=5)
-        assert rec.maybe_trace(_tx(2)), "commit must re-arm first-K"
+    def test_commit_does_not_change_the_sample(self):
+        """The retired first-K arm re-opened after each commit; the hash
+        rule takes no notice of commits: a tx in the sample stays in, one
+        out stays out."""
+        inside = _sampled(4, 1)[0]
+        outside = next(t for t in (_tx(i) for i in range(64))
+                       if not in_sample(t, 4))
+        rec = TxTraceRecorder(sample_n=4)
+        assert rec.maybe_trace(inside) and not rec.maybe_trace(outside)
+        rec.commit([inside], height=5)
+        rec.delivered([inside])
+        assert not rec.maybe_trace(outside), "a commit re-armed sampling"
+        assert rec.maybe_trace(inside)
+        assert rec.sampled == 2
 
-    def test_sample_n_zero_disables_the_modulo_arm(self):
-        rec = TxTraceRecorder(first_k=0, sample_n=0)
+    def test_sample_n_zero_traces_nothing(self):
+        rec = TxTraceRecorder(sample_n=0)
         assert not any(rec.maybe_trace(_tx(i)) for i in range(50))
         assert rec.stats()["active"] == 0
 
     def test_kill_switch(self):
-        rec = TxTraceRecorder(first_k=8, sample_n=1)
+        rec = TxTraceRecorder(sample_n=1)
         rec.set_enabled(False)
         assert not rec.maybe_trace(_tx(0))
         rec.stamp(_tx(0), "mempool_admit")
@@ -59,13 +84,37 @@ class TestSampling:
             "active": 0,
         }
 
+    @pytest.mark.parametrize("seed", [1, 2, 3])
+    def test_two_recorders_in_any_order_sample_the_same_set(self, seed):
+        """Every node traces the same writes: two recorders fed the same
+        txs in different orders (and interleaved with other txs) sample
+        the same set."""
+        txs = _signed(256, seed)
+        a, b = TxTraceRecorder(sample_n=4), TxTraceRecorder(sample_n=4)
+        got_a = {t for t in txs if a.maybe_trace(t, source="rpc")}
+        shuffled = list(txs)
+        random.Random(seed).shuffle(shuffled)
+        got_b = set()
+        for t in shuffled:
+            b.maybe_trace(_tx(seed), source="peer")  # traffic between
+            if b.maybe_trace(t, source="peer"):
+                got_b.add(t)
+        assert got_a == got_b and got_a
+
+    @pytest.mark.parametrize("n", [2, 4, 8])
+    def test_rate_is_one_in_n(self, n):
+        """1 in N within 20% over 4,096 random signed txs."""
+        txs = _signed(4096, n)
+        hits = sum(in_sample(t, n) for t in txs)
+        assert abs(hits - 4096 / n) <= 0.2 * 4096 / n, (hits, n)
+
 
 class TestSpans:
     def test_spans_telescope_to_the_end_to_end_latencies(self):
         """The acceptance-bar arithmetic: stamped spans through
         block_commit sum EXACTLY to the commit latency (a bench asserts
         within 10% against the live node to guard the stamp sites)."""
-        rec = TxTraceRecorder(first_k=1, sample_n=0)
+        rec = TxTraceRecorder(sample_n=1)
         t0 = 1000.0
         assert rec.maybe_trace(_tx(0), at=t0)
         rec.stamp(_tx(0), "sig_gate", at=t0 + 0.010)
@@ -95,7 +144,7 @@ class TestSpans:
         assert instants == sorted(instants)
 
     def test_stamps_are_keep_first(self):
-        rec = TxTraceRecorder(first_k=1, sample_n=0)
+        rec = TxTraceRecorder(sample_n=1)
         rec.maybe_trace(_tx(0), at=10.0)
         rec.stamp(_tx(0), "proposal", at=11.0)
         rec.stamp(_tx(0), "proposal", at=99.0)  # re-proposed round
@@ -104,7 +153,7 @@ class TestSpans:
         assert rec.last(1)[0]["stages"]["proposal"] == 11.0
 
     def test_untraced_stamps_are_no_ops(self):
-        rec = TxTraceRecorder(first_k=1, sample_n=0)
+        rec = TxTraceRecorder(sample_n=1)
         rec.stamp(_tx(5), "mempool_admit")      # nothing in flight
         rec.maybe_trace(_tx(0))
         rec.stamp(_tx(5), "mempool_admit")      # in flight, wrong tx
@@ -112,9 +161,89 @@ class TestSpans:
         assert rec.last(5) == []
 
 
+class TestRoad:
+    """PR 37's stages: the gate's dispatch and its daemon rid, the
+    proposer's reap of the committed block, the reply that seals a
+    waited-for write."""
+
+    def test_gate_dispatch_keeps_the_daemon_rid(self):
+        rec = TxTraceRecorder(sample_n=1)
+        rec.maybe_trace(_tx(0), at=1.0)
+        rec.stamp_gate_dispatch([_tx(0), _tx(9)], "node2-gate-41", at=1.002)
+        rec.stamp_gate_dispatch([_tx(0)], "node2-gate-42", at=1.5)  # keep-first
+        [t] = rec.active()
+        assert t["stages"]["gate_dispatch"] == 1.002
+        assert t["gate_rid"] == "node2-gate-41"
+
+    @pytest.mark.parametrize("committed, want", [
+        (b"B", 2.0),    # the re-proposal's block committed: the last reap
+        (b"C", None),   # another node's block committed: no reap here
+    ])
+    def test_reap_is_the_last_and_only_for_the_committed_block(
+            self, committed, want):
+        rec = TxTraceRecorder(sample_n=1)
+        rec.maybe_trace(_tx(0), at=0.0)
+        rec.stamp_reap([_tx(0)], b"A", at=1.0)
+        rec.stamp_reap([_tx(0)], b"B", at=2.0)
+        rec.commit([_tx(0)], height=3, block_hash=committed, at=3.0)
+        rec.delivered([_tx(0)], at=3.1)
+        [t] = rec.last(1)
+        assert t["stages"].get("reap") == want
+
+    def test_a_waited_write_seals_at_its_reply(self):
+        rec = TxTraceRecorder(sample_n=1)
+        assert rec.expect_reply(_tx(0))
+        rec.maybe_trace(_tx(0), at=10.0)
+        rec.stamp(_tx(0), "sig_gate", at=10.01)
+        rec.commit([_tx(0)], height=4, at=10.5)
+        rec.delivered([_tx(0)], at=10.6)
+        assert rec.stats()["active"] == 1, "sealed before its reply"
+        rec.reply(_tx(0), at=10.62)
+        assert rec.stats()["active"] == 0 and rec.completed == 1
+        [t] = rec.last(1)
+        assert t["outcome"] == "committed"
+        assert sum(t["spans"].values()) == pytest.approx(0.62, rel=1e-9)
+        assert rec._ring[0].tx is None and rec._ring[0].hash
+
+    def test_a_reply_before_any_commit_seals_unanswered(self):
+        rec = TxTraceRecorder(sample_n=1)
+        rec.expect_reply(_tx(0))
+        rec.maybe_trace(_tx(0), at=1.0)
+        rec.reply(_tx(0), at=61.0)
+        [t] = rec.last(1)
+        assert t["outcome"] == "unanswered" and rec.completed == 0
+        assert not rec._awaiting
+
+    def test_spans_telescope_whatever_the_stamps_order(self):
+        """A gossip send after the proposal (canonically before it) must
+        not break the sum: spans follow the instants in time order."""
+        rec = TxTraceRecorder(sample_n=1)
+        rec.maybe_trace(_tx(0), at=0.0)
+        rec.stamp(_tx(0), "mempool_admit", at=0.01)
+        rec.stamp_present([_tx(0)], "proposal", at=0.10)
+        rec.stamp(_tx(0), "p2p_broadcast", at=0.12)
+        rec.commit([_tx(0)], height=1, at=0.30)
+        rec.delivered([_tx(0)], at=0.31)
+        [t] = rec.last(1)
+        assert t["spans"]["p2p_broadcast"] == pytest.approx(0.02)
+        through = sum(v for k, v in t["spans"].items()
+                      if t["stages"][k] <= t["stages"]["block_commit"])
+        assert through == pytest.approx(t["commit_latency_s"], rel=1e-9)
+
+    def test_dump_is_the_ring_then_the_traces_in_flight(self):
+        rec = TxTraceRecorder(sample_n=1, ring=8)
+        for i in range(3):
+            rec.maybe_trace(_tx(i), at=float(i))
+        rec.commit([_tx(0)], height=1, at=5.0)
+        rec.delivered([_tx(0)], at=5.1)
+        got = rec.dump()
+        assert [t["outcome"] for t in got] == ["committed", None, None]
+        assert [t["stages"]["rpc_ingress"] for t in got] == [0.0, 1.0, 2.0]
+
+
 class TestBounds:
     def test_active_bound_evicts_oldest_as_sealed(self):
-        rec = TxTraceRecorder(first_k=100, sample_n=0, max_active=3)
+        rec = TxTraceRecorder(sample_n=1, max_active=3)
         for i in range(5):
             assert rec.maybe_trace(_tx(i))
         assert rec.stats()["active"] == 3
@@ -125,7 +254,7 @@ class TestBounds:
         }
 
     def test_ring_keeps_newest(self):
-        rec = TxTraceRecorder(first_k=100, sample_n=0, ring=4)
+        rec = TxTraceRecorder(sample_n=1, ring=4)
         for i in range(8):
             rec.maybe_trace(_tx(i), at=float(i))
             rec.commit([_tx(i)], height=i + 1, at=float(i) + 0.5)
@@ -135,7 +264,7 @@ class TestBounds:
         assert [t["height"] for t in got] == [8, 7, 6, 5]  # newest first
 
     def test_reject_seals_with_outcome(self):
-        rec = TxTraceRecorder(first_k=1, sample_n=0)
+        rec = TxTraceRecorder(sample_n=1)
         rec.maybe_trace(_tx(0))
         rec.reject(_tx(0), "bad_sig")
         assert rec.stats()["active"] == 0 and rec.rejected == 1
@@ -145,7 +274,7 @@ class TestBounds:
 class TestMetrics:
     def test_seal_feeds_the_histograms(self):
         reg = telemetry.Registry()
-        rec = TxTraceRecorder(first_k=1, sample_n=0)
+        rec = TxTraceRecorder(sample_n=1)
         rec.metrics_registry = reg
         rec.maybe_trace(_tx(0), at=0.0)
         rec.stamp(_tx(0), "mempool_admit", at=0.010)
@@ -160,7 +289,7 @@ class TestMetrics:
         assert hists["visible"].sum == pytest.approx(0.060)
 
     def test_concurrent_stamps_never_corrupt(self):
-        rec = TxTraceRecorder(first_k=1000, sample_n=0, max_active=1000)
+        rec = TxTraceRecorder(sample_n=1, max_active=1000)
         txs = [_tx(i) for i in range(64)]
         for t in txs:
             rec.maybe_trace(t)
@@ -199,7 +328,7 @@ class TestMempoolIntegration:
             test_config().mempool,
             AppConnMempool(LocalClient(KVStoreApp(), threading.RLock())),
         )
-        mp.txtrace = TxTraceRecorder(first_k=4, sample_n=0)
+        mp.txtrace = TxTraceRecorder(sample_n=1)
         return mp
 
     def test_check_tx_stamps_ingress_and_admit(self):
@@ -302,7 +431,7 @@ class TestRPCAndCLI:
     def test_tx_trace_rpc_handler_filters_by_hash(self):
         from tendermint_tpu.rpc.core.handlers import tx_trace
 
-        rec = TxTraceRecorder(first_k=4, sample_n=0)
+        rec = TxTraceRecorder(sample_n=1)
         rec.maybe_trace(_tx(0), at=1.0)
         rec.maybe_trace(_tx(1), at=2.0)
         rec.commit([_tx(0)], height=3, at=4.0)
@@ -386,7 +515,7 @@ class TestGatedMempoolEdges:
             AppConnMempool(LocalClient(KVStoreApp(), threading.RLock())),
             sig_batcher=batcher,
         )
-        mp.txtrace = TxTraceRecorder(first_k=8, sample_n=0)
+        mp.txtrace = TxTraceRecorder(sample_n=1)
         return mp
 
     def test_gate_saturation_seals_the_trace(self):
