@@ -20,6 +20,13 @@ hardware differently:
 
 - [h](-A): per-lane gather from a device-resident POOL of per-validator
   tables (bf16 rows; 8-bit limbs are exact in bf16). HBM-bandwidth work.
+  The pool is (C*64, 1536): a row is one window position of one key, its
+  16 entries x 96 limbs. A 1536-wide row is 12 whole 128-lane tiles, so
+  the device stores the pool row-major and unpadded and the program
+  gathers rows where they lie. A 96-wide row pads to 128, so the device
+  stores such a pool transposed, and a program that gathers its rows
+  copies it whole first, on every call (2.4 GB read, 3.2 GB written at
+  12,288 slots).
 - [s]B: one-hot(digit) x fixed-basis-table matmuls via dot_general with
   bf16 inputs and fp32 accumulation — the MXU path. Exact: one-hot is
   0/1, table limbs are <= 255 (both exact bf16), the MXU multiplies bf16
@@ -67,6 +74,7 @@ NL = base.NL
 W_POS = 64  # 4-bit windows over 256 bits
 W_ENT = 16  # entries per window (digit values 0..15)
 COORD_ROWS = 3 * NL  # niels coords per entry: (y-x, y+x, 2dxy), 32 limbs each
+POOL_ROW = W_ENT * COORD_ROWS  # one window position of a key's table: 1536
 
 
 # ---------------------------------------------------------------------------
@@ -153,27 +161,52 @@ def _niels_add(acc, my, py, t2):
     )
 
 
+def _pool_entries(pool, slots, dh):
+    """The entry of digit dh[p, b] at position p of lane b's table, for
+    all 64 positions: (64, 96, B) f32.
+
+    Gathers the 64 rows of each lane's slot whole (a row is tile-aligned;
+    a 96-wide entry at digit*96 is not), zeroes every entry but the
+    digit's, and folds the 16 entries of a row onto one with a 0/1 (1536,
+    96) matrix on the MXU: exact, as one term of each sum is not zero and
+    it is an integer <= 255. Nothing splits a row into (16, 96): that view
+    pads 96 to 128, so the compiler lays the data out anew: for the pool
+    itself a copy of the whole pool on every call (a (C*1024, 96) pool is
+    stored column-major and copied so), for the gathered rows 2-3x the
+    device time of this fold at 256 lanes (estimated cycles of the program
+    compiled for a v5e)."""
+    batch = slots.shape[0]
+    pos = jnp.arange(W_POS, dtype=jnp.int32)[:, None]  # (64,1)
+    rows = jnp.take(pool, (slots[None, :] * W_POS + pos).reshape(-1), axis=0)
+    rows = rows.reshape(W_POS, batch, POOL_ROW)  # (64, B, 1536)
+    col = jnp.arange(POOL_ROW, dtype=jnp.int32)
+    picked = jnp.where(col // COORD_ROWS == dh[:, :, None], rows,
+                       jnp.zeros((), rows.dtype))
+    fold = (jnp.arange(COORD_ROWS, dtype=jnp.int32)[:, None]
+            == col % COORD_ROWS).astype(jnp.bfloat16)  # (96, 1536)
+    # batched over positions, as [s]B's dot is: XLA:CPU runs no bf16 x
+    # bf16 -> f32 dot without a batch dimension
+    return jax.lax.dot_general(
+        jnp.broadcast_to(fold, (W_POS,) + fold.shape),  # (64, 96, 1536)
+        picked,  # (64, B, 1536)
+        dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _verify_comb_impl(pool, t_b, slots, r_y, r_sign, s8, h8):
-    """pool: (C*W_POS*W_ENT, 96) bf16 per-validator niels tables (of -A);
+    """pool: (C*W_POS, POOL_ROW) bf16 per-validator niels tables (of -A),
+    one row a (slot, window position) holding its 16 entries (CombPool);
     t_b: (W_POS, W_ENT, 96) f32 fixed-base table; slots: (B,) int32 pool
     slot per lane; r_y/r_sign/s8/h8 as in base._verify_impl. -> bool[B].
 
     Accumulates W = [s]B + [h](-A) as 128 niels lookups + 127 mixed adds
     (no doublings), then compares against R exactly like the ladder
     kernels."""
-    batch = slots.shape[0]
     dh = _digits4(h8)  # (64,B) digits of h -> per-validator pool
     ds = _digits4(s8)  # (64,B) digits of s -> fixed-base table
 
-    # [h](-A): gather 64 niels rows per lane from the pool
-    pos = jnp.arange(W_POS, dtype=jnp.int32)[:, None]  # (64,1)
-    flat = (slots[None, :] * W_POS + pos) * W_ENT + dh  # (64,B)
-    rows_a = jnp.take(pool, flat.reshape(-1), axis=0)  # (64*B, 96) bf16
-    rows_a = (
-        rows_a.reshape(W_POS, batch, COORD_ROWS)
-        .astype(jnp.float32)
-        .transpose(0, 2, 1)
-    )  # (64, 96, B)
+    rows_a = _pool_entries(pool, slots, dh)  # [h](-A): (64, 96, B)
 
     # [s]B: one-hot x basis-table batched matmul (MXU: bf16 inputs, fp32
     # accumulation; exact for 0/1 x <=255 integer operands)
@@ -406,7 +439,12 @@ _build_jit = jax.jit(_build_tables_impl)
 
 
 def _scatter_tables(pool, slots, tables):
-    return pool.at[slots].set(tables)
+    """The closed pool's install: `tables` (n, 1024, 96) f32 into the
+    slots `slots` of a new pool beside `pool` (C*64, 1536), through a
+    (C, 64, 1536) view, which tiles without padding."""
+    rows = tables.astype(jnp.bfloat16).reshape(-1, W_POS, POOL_ROW)
+    pool3 = pool.reshape(-1, W_POS, POOL_ROW)
+    return pool3.at[slots].set(rows).reshape(pool.shape)
 
 
 _scatter_jit = jax.jit(_scatter_tables)
@@ -414,18 +452,19 @@ _scatter_jit = jax.jit(_scatter_tables)
 
 def _update_pool_impl(pool, slots, tables):
     """The open population's pool update: write `tables` (n, 1024, 96)
-    f32 over the slots `slots` of the flat pool (C*1024, 96) bf16. The
-    pool is DONATED: the slots are written in place, no second pool
-    exists at any instant, and the runtime orders the write behind every
-    program that was handed the old buffer before this call. One
-    dynamic-update-slice a key, in a loop: the v5e's compiler turns a
-    scatter through a (C, 1024, 96) view into a copy of the whole pool
-    (tests/test_chip_compile.py holds this form to none)."""
-    rows = W_POS * W_ENT
-    tables = tables.astype(jnp.bfloat16)
+    f32 over the slots `slots` of the pool (C*64, 1536) bf16, a key's
+    table as its 64 rows of (16 entries x 96). The pool is DONATED: the
+    slots are written in place, no second pool exists at any instant, and
+    the runtime orders the write behind every program that was handed the
+    old buffer before this call. One dynamic-update-slice a key, in a
+    loop (tests/test_chip_compile.py holds it to no second pool). The
+    build's (n, 1024, 96) tables are laid out as rows here, in this
+    program, not in the build: the two forms' estimated cycles differ by
+    0.4% (the programs compiled for a v5e)."""
+    tables = tables.astype(jnp.bfloat16).reshape(-1, W_POS, POOL_ROW)
 
     def write(i, p):
-        return jax.lax.dynamic_update_slice(p, tables[i], (slots[i] * rows, 0))
+        return jax.lax.dynamic_update_slice(p, tables[i], (slots[i] * W_POS, 0))
 
     return jax.lax.fori_loop(0, slots.shape[0], write, pool)
 
@@ -487,6 +526,14 @@ class CombPool:
     Slots are leased to pubkeys on first sight; the table build runs on
     device, batched across all new keys in the request. Eviction is LRU.
 
+    The array (`_pool`) is (C*W_POS, POOL_ROW) bf16: slot s is rows
+    s*64 .. s*64+63, row s*64+p window position p of its key's table with
+    the 16 entries of 96 limbs side by side (a reshape of a slot's rows
+    gives its (1024, 96) table). That shape is the one the v5e stores
+    row-major and unpadded, so the comb program gathers from the pool in
+    place; a (C*1024, 96) array, the same bytes, is stored column-major
+    (96 would pad to 128) and costs a copy of the whole pool every call.
+
     Two populations, chosen where the pool is made (the daemon's
     configuration: TENDERMINT_TPU_COMB_OPEN):
 
@@ -520,9 +567,7 @@ class CombPool:
         c0 = int(capacity or (self.cap if self.open else min(self.cap, 256)))
         self._c = c0
         self.miss_bucket = min(MISS_BUCKET, c0)
-        self._pool = jnp.zeros(
-            (c0 * W_POS * W_ENT, COORD_ROWS), dtype=jnp.bfloat16
-        )
+        self._pool = jnp.zeros((c0 * W_POS, POOL_ROW), dtype=jnp.bfloat16)
         self._lru: OrderedDict[bytes, int] = OrderedDict()
         self._free: list[int] = list(range(c0 - 1, 0, -1))  # slot 0 reserved
         self._lock = threading.RLock()
@@ -553,9 +598,8 @@ class CombPool:
         new_c = min(self._c * 2, self.cap)
         if new_c == self._c:
             return
-        pad = jnp.zeros(
-            ((new_c - self._c) * W_POS * W_ENT, COORD_ROWS), dtype=jnp.bfloat16
-        )
+        pad = jnp.zeros(((new_c - self._c) * W_POS, POOL_ROW),
+                        dtype=jnp.bfloat16)
         self._pool = jnp.concatenate([self._pool, pad], axis=0)
         self._free.extend(range(new_c - 1, self._c - 1, -1))
         self._c = new_c
@@ -661,12 +705,7 @@ class CombPool:
         """The closed pool's install: one build program of the exact
         count, the pool array rebuilt beside the old one."""
         tables = _build_jit(jnp.asarray(qx), jnp.asarray(qy))
-        # scatter whole-slot row blocks: view pool as (C, 1024, 96)
-        pool3 = self._pool.reshape(self._c, W_POS * W_ENT, COORD_ROWS)
-        pool3 = _scatter_jit(
-            pool3, jnp.asarray(tslots), tables.astype(jnp.bfloat16)
-        )
-        self._pool = pool3.reshape(self._c * W_POS * W_ENT, COORD_ROWS)
+        self._pool = _scatter_jit(self._pool, jnp.asarray(tslots), tables)
         self.stats["builds"] += 1
 
     def _install_in_place(self, qx, qy, tslots, last: dict) -> None:

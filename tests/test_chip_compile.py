@@ -18,6 +18,7 @@ conftest.py.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -134,6 +135,29 @@ def test_f32p_pallas_ladder_compiles_for_v5e(one_chip, no_compile_cache, items):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _pool_shape(comb, slots: int, sharding) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct((slots * comb.W_POS, comb.POOL_ROW),
+                                jnp.bfloat16, sharding=sharding)
+
+
+def _entry_param(compiled, index: int) -> tuple[str, str]:
+    """(name, layout) the compiled program gives its entry parameter
+    `index`, as `compiled.as_text()` writes it."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    m = re.search(r"%(\S+) = \w+\[[\d,]*\](\{[^}]*\}) parameter\("
+                  + str(index) + r"\)", entry)
+    assert m, entry[:2000]
+    return m.group(1), m.group(2)
+
+
+def _relayouts_of(compiled, name: str) -> list[str]:
+    """The ops of `compiled` that lay the parameter `name` out anew: a
+    copy, a transpose or a reshape that is not a bitcast, of it whole."""
+    return re.findall(r"= \S+ (?:copy|transpose|reshape)\(%" + re.escape(name)
+                      + r"[,)]", compiled.as_text())
+
+
 def test_open_pool_update_is_in_place_on_v5e(one_chip, no_compile_cache):
     """ops/ed25519_comb._update_pool_impl at the shipped ceiling (12,288
     slots, 2.4 GB) and the widest build bucket: the donated pool is the
@@ -141,18 +165,45 @@ def test_open_pool_update_is_in_place_on_v5e(one_chip, no_compile_cache):
     from tendermint_tpu.ops import ed25519_comb as comb
 
     cap, bucket = 12288, comb.MISS_BUCKET
-    rows = comb.W_POS * comb.W_ENT
-    pool = jax.ShapeDtypeStruct((cap * rows, comb.COORD_ROWS), jnp.bfloat16,
-                                sharding=one_chip)
+    pool = _pool_shape(comb, cap, one_chip)
     slots = jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one_chip)
-    tables = jax.ShapeDtypeStruct((bucket, rows, comb.COORD_ROWS), jnp.float32,
-                                  sharding=one_chip)
+    tables = jax.ShapeDtypeStruct(
+        (bucket, comb.W_POS * comb.W_ENT, comb.COORD_ROWS), jnp.float32,
+        sharding=one_chip)
     compiled = jax.jit(comb._update_pool_impl, donate_argnums=(0,)).lower(
         pool, slots, tables).compile()
     ma = compiled.memory_analysis()
     pool_bytes = cap * comb.SLOT_BYTES
     assert ma.alias_size_in_bytes >= pool_bytes, ma
     assert ma.temp_size_in_bytes < pool_bytes // 8, ma
+    name, layout = _entry_param(compiled, 0)
+    assert layout.startswith("{1,0"), layout
+    assert _relayouts_of(compiled, name) == []
+
+
+@pytest.mark.parametrize("slots", [256, 12288])
+@pytest.mark.parametrize("width", [8, 256])
+def test_comb_program_reads_the_pool_where_it_lies_on_v5e(
+        one_chip, no_compile_cache, items, slots, width):
+    """_verify_comb_impl over a closed pool's 256 slots and the shipped
+    ceiling's 12,288, at the narrowest and the widest width the daemon
+    serves: the pool enters row-major, no program op copies it, and the
+    temporaries are the gathered rows' (a (C*1024, 96) pool enters
+    column-major and is copied whole on every call: 3.2 GB of
+    temporaries at 12,288 slots)."""
+    from tendermint_tpu.ops import ed25519_comb as comb
+    from tendermint_tpu.ops import ed25519_f32 as f32
+
+    _ax, _ay, ry, rs, s8, h8, _valid = f32.prepare_batch8(items, width)
+    tb = jnp.asarray(comb.b_table())
+    compiled = _compile(
+        comb._verify_comb_impl,
+        (_pool_shape(comb, slots, one_chip),)
+        + _on(one_chip, (tb, jnp.zeros((width,), jnp.int32), ry, rs, s8, h8)))
+    name, layout = _entry_param(compiled, 0)
+    assert layout.startswith("{1,0"), layout
+    assert _relayouts_of(compiled, name) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 def test_open_pool_verify_and_build_compile_for_v5e(one_chip, no_compile_cache,
@@ -162,9 +213,7 @@ def test_open_pool_verify_and_build_compile_for_v5e(one_chip, no_compile_cache,
     from tendermint_tpu.ops import ed25519_comb as comb
     from tendermint_tpu.ops import ed25519_f32 as f32
 
-    rows = comb.W_POS * comb.W_ENT
-    pool = jax.ShapeDtypeStruct((12288 * rows, comb.COORD_ROWS), jnp.bfloat16,
-                                sharding=one_chip)
+    pool = _pool_shape(comb, 12288, one_chip)
     _ax, _ay, ry, rs, s8, h8, _valid = f32.prepare_batch8(items, 8)
     tb = jnp.asarray(comb.b_table())
     slots = jnp.zeros((8,), jnp.int32)

@@ -122,7 +122,8 @@ def test_padded_builds_give_the_tables_unpadded_builds_gave(monkeypatch):
         # 3 keys: one program of 3 in the closed pool, padded to 16 in the
         # open one (to 128 in a pool of 128 slots or more)
         assert all(comb.verify_batch([lane(k, b"t") for k in (3, 4, 5)]))
-        arr = np.asarray(pool._pool).reshape(16, -1)
+        assert pool._pool.shape == (16 * comb.W_POS, comb.POOL_ROW)
+        arr = np.asarray(pool._pool).reshape(16, comb.W_POS, comb.POOL_ROW)
         tables[is_open] = {k: arr[pool._lru[PUBS[k]]].tobytes() for k in (3, 4, 5)}
         assert pool.stats["build_keys"] == 3
     comb.reset_default_pool()
@@ -171,6 +172,35 @@ def test_an_in_flight_batch_survives_an_eviction_storm(open_pool):
         assert all(comb.verify_batch(storm))
     assert not set(PUBS[:6]) & set(open_pool._lru)
     assert list(resolve()) == [True, True, False, True, True, True]
+
+
+@pytest.mark.parametrize("width", [1, 8, 128])
+def test_verdicts_at_a_width_through_ladder_builds_and_an_eviction_storm(
+        open_pool, tmp_path, width):
+    """Batches of `width` lanes over up to 7 keys: a first sight on the
+    ladder, a second built and dispatched but not read while eight storms
+    of new keys evict its every slot, some lanes forged in each batch.
+    Every verdict is plain Ed25519's, every route the plain model's."""
+    def batch(first_key: int, tag: bytes, salt: int) -> list:
+        return [lane((first_key + j % 7) % len(PUBS), b"%s-%d" % (tag, j),
+                     forged=(j + salt) % 3 == 0) for j in range(width)]
+
+    a = batch(0, b"a", 0)
+    served = [(a, comb.verify_batch(a))]
+    held = batch(0, b"held", 1)
+    resolve = comb.verify_batch_async(held)
+    for step in range(1, 9):
+        storm = batch(7 * step, b"storm%d" % step, step)
+        served += [(storm, comb.verify_batch(storm)) for _ in range(2)]
+    served.append((held, resolve()))
+    assert not {it[0] for it in held} & set(open_pool._lru)
+    for items, oks in served:
+        assert [bool(o) for o in oks] == [ed.verify(*it) for it in items]
+    assert any(not ed.verify(*it) for it in held + a)
+    out = replay(open_pool, tmp_path)
+    assert out["lanes_routed_unlike_reference"] == 0
+    s = open_pool.stats
+    assert min(s["ladders"], s["builds"], s["evictions"]) > 0
 
 
 def test_a_pool_that_leaves_slots_stale_fails_the_verdicts(monkeypatch):
@@ -227,7 +257,7 @@ def test_the_closed_pool_and_the_merger_are_the_parents(monkeypatch):
     # grown by doubling, built in one program of the exact count, and the
     # array rebuilt beside the old one (an in-flight verify may hold it)
     assert (pool.capacity, pool.stats["grows"], pool.stats["builds"]) == (8, 2, 1)
-    assert np.asarray(held).shape == (2 * 1024, 96)
+    assert np.asarray(held).shape == (2 * comb.W_POS, comb.POOL_ROW)
     assert os.environ.get("TENDERMINT_TPU_COMB_OPEN") is None
     comb.reset_default_pool()
 

@@ -241,14 +241,17 @@ def _reference_tables(points) -> np.ndarray:
     return out.reshape(len(points), comb.W_POS * comb.W_ENT, comb.COORD_ROWS)
 
 
+def _neg_point(pub: bytes):
+    """-A of the key `pub` as an extended point."""
+    x, y = comb.base._affine(ed.point_decompress(pub))
+    x = (-x) % comb.P
+    return (x, y, 1, x * y % comb.P)
+
+
 def _neg_points(rng, n):
     """n random keys' -A as extended points, and the build's (32, n) limb
     columns of them."""
-    pts = []
-    for _ in range(n):
-        x, y = comb.base._affine(ed.point_decompress(_keypair(rng)[1]))
-        x = (-x) % comb.P
-        pts.append((x, y, 1, x * y % comb.P))
+    pts = [_neg_point(_keypair(rng)[1]) for _ in range(n)]
     qx = np.stack([comb.base._int_to_limbs_const(p[0]) for p in pts], axis=1)
     qy = np.stack([comb.base._int_to_limbs_const(p[1]) for p in pts], axis=1)
     return pts, qx, qy
@@ -323,3 +326,50 @@ def test_build_is_a_few_hundred_sequential_steps():
     jaxpr = jax.make_jaxpr(comb._build_tables_impl)(q, q).jaxpr
     steps = _sequential_steps(jaxpr)
     assert steps <= 600, steps
+
+
+class TestPoolLayout:
+    """The pool is (C*64, 1536) bf16, a row a (slot, window position) with
+    its 16 entries: the bytes of the (C*1024, 96) form, in its order, but
+    a shape the v5e stores row-major and unpadded, so that the comb
+    program gathers rows from the pool where it lies
+    (tests/test_chip_compile.py holds the compiled program to that)."""
+
+    @pytest.mark.parametrize("is_open", [False, True])
+    def test_a_slots_rows_are_its_reference_table(self, is_open):
+        rng = np.random.default_rng(60 + is_open)
+        pairs = [_keypair(rng) for _ in range(3)]
+        pool = comb.CombPool(capacity=8, max_capacity=8, open_pop=is_open)
+        comb.set_default_pool(pool)
+        items = [it for sk, pk in pairs for it in _signed(rng, sk, pk)]
+        assert all(comb.verify_batch(items))
+        assert pool._pool.shape == (8 * comb.W_POS, comb.POOL_ROW)
+        arr = np.asarray(pool._pool)
+        want = _reference_tables([_neg_point(pk) for _sk, pk in pairs])
+        for (_sk, pk), table in zip(pairs, want):
+            slot = pool._lru[pk]
+            rows = arr[slot * comb.W_POS:(slot + 1) * comb.W_POS]
+            assert rows.reshape(comb.W_POS * comb.W_ENT, comb.COORD_ROWS
+                                ).tobytes() == table.astype(arr.dtype).tobytes()
+
+    @pytest.mark.parametrize("width", [1, 8, 128])
+    def test_the_entries_a_lane_reads_are_the_flat_pools(self, width):
+        """_pool_entries against a gather of single entries from the same
+        bytes viewed as (C*1024, 96): every lane, padding lanes on slot 0
+        among them, every digit."""
+        import jax
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(80 + width)
+        cap = 6
+        pool = jnp.asarray(rng.integers(0, 256, (cap * comb.W_POS,
+                                                 comb.POOL_ROW)),
+                           dtype=jnp.bfloat16)
+        slots = rng.integers(0, cap, width).astype(np.int32)
+        slots[-1] = 0
+        dh = rng.integers(0, comb.W_ENT, (comb.W_POS, width)).astype(np.int32)
+        got = jax.jit(comb._pool_entries)(pool, slots, dh)
+        flat = np.asarray(pool, dtype=np.float32).reshape(-1, comb.COORD_ROWS)
+        pos = np.arange(comb.W_POS)[:, None]
+        want = flat[(slots[None, :] * comb.W_POS + pos) * comb.W_ENT + dh]
+        assert np.array_equal(np.asarray(got), want.transpose(0, 2, 1))
