@@ -59,6 +59,7 @@ from tendermint_tpu.consensus.height_vote_set import HeightVoteSet
 from tendermint_tpu.consensus.round_state import RoundState, RoundStep
 from tendermint_tpu.consensus.ticker import TickerI, TimeoutInfo, TimeoutTicker
 from tendermint_tpu.consensus.wal import WAL, WALMessage
+from tendermint_tpu.libs import applyclock
 from tendermint_tpu.libs.events import EventCache, EventSwitch
 from tendermint_tpu.libs.service import BaseService
 from tendermint_tpu.ops import gateway
@@ -1334,6 +1335,8 @@ class ConsensusState(BaseService):
         self.trace.note("last_commit_precommits", sum(
             1 for pc in getattr(block.last_commit, "precommits", None) or ()
             if pc is not None))
+        self.trace.note("txs", len(block.data.txs))
+        self.trace.note("parts", block_parts.total)
         # trace: the commit-wait segment ends here; the finalize
         # sub-phases (save -> apply -> snapshot hook -> events, or
         # save -> submit when pipelined) partition the rest of the
@@ -1390,15 +1393,19 @@ class ConsensusState(BaseService):
         else:
             self.pipeline_serial_commits += 1
             self.trace.mark("apply")
-            sm.apply_block(
-                state_copy,
-                event_cache,
-                self.proxy_app_conn,
-                block,
-                block_parts.header(),
-                self.mempool,
-                batch_verifier=self._commit_batch_verifier(),
-            )
+            with applyclock.clock() as stamps:
+                sm.apply_block(
+                    state_copy,
+                    event_cache,
+                    self.proxy_app_conn,
+                    block,
+                    block_parts.header(),
+                    self.mempool,
+                    batch_verifier=self._commit_batch_verifier(),
+                )
+            if block.data.txs:
+                for k, v in ctrace.apply_notes(stamps).items():
+                    self.trace.note(k, v)
 
             fail_point()
 
@@ -1467,17 +1474,22 @@ class ConsensusState(BaseService):
 
             pipeline_point("pre_apply")
             t0 = time.monotonic()
-            sm.apply_block(
-                state_copy,
-                event_cache,
-                self.proxy_app_conn,
-                block,
-                parts_header,
-                self.mempool,
-                batch_verifier=batch_verifier,
-            )
+            with applyclock.clock() as stamps:
+                sm.apply_block(
+                    state_copy,
+                    event_cache,
+                    self.proxy_app_conn,
+                    block,
+                    parts_header,
+                    self.mempool,
+                    batch_verifier=batch_verifier,
+                )
             pipeline_point("post_apply")
             apply_s = time.monotonic() - t0
+            if block.data.txs:
+                # noted before the join resolves: H+1 cannot seal first
+                for k, v in ctrace.apply_notes(stamps).items():
+                    self.trace.note_overlap(height + 1, k, v)
             # resolve the join NOW: the consensus thread only needs the
             # applied state. The snapshot hook + event flush below run as
             # the executor's tail — off the critical path entirely (the
@@ -1724,6 +1736,7 @@ class ConsensusState(BaseService):
                 tev.EventDataBlockPart(height, rs.round_, part.index),
             )
         if added and rs.proposal_block_parts.is_complete():
+            self.trace.mark_arrival("parts_complete")
             block_bytes = rs.proposal_block_parts.get_data()
             rs.proposal_block = Block.from_bytes(block_bytes)
             if self.txtrace is not None:

@@ -35,6 +35,17 @@ the consensus thread's segments still partition its own wall clock, and
 the join wait it actually pays surfaces as the ``pipeline_join_wait_s``
 aux note inside whichever segment blocked (normally propose).
 
+A height's block: aux ``txs`` and ``parts`` (its tx count and part
+count), and the arrival ``parts_complete`` when the whole part set is
+held. Inside a block's apply two stamps split it (``libs/applyclock``,
+stamped by the code the apply calls, on the thread that runs it): the
+app's whole-block signature call returned (``apply_verify``), and the
+app's fold and Commit returned (``apply_app``). They are noted as aux
+``apply_verify_s`` (the apply's start to the first) and ``apply_app_s``
+(to the second) on the height whose trace the apply overlaps: H+1 when
+pipelined (beside ``overlap_apply_s``), H itself when serial; only for a
+block that holds txs.
+
 Completed traces land in a ring buffer (TENDERMINT_TRACE_RING, default
 128) served by the ``consensus_trace`` RPC and the operator CLI
 ``python -m tendermint_tpu.ops.trace``.
@@ -80,6 +91,20 @@ def step_segment(step: int) -> str:
     return _STEP_SEGMENTS.get(step, "new_height")
 
 
+def apply_notes(stamps: dict) -> dict:
+    """The apply's spans, seconds, from its `applyclock.clock` stamps:
+    its start to `apply_verify`, then to `apply_app` (from the start
+    where nothing verified)."""
+    out = {}
+    t = stamps["start"]
+    if "apply_verify" in stamps:
+        out["apply_verify_s"] = stamps["apply_verify"] - t
+        t = stamps["apply_verify"]
+    if "apply_app" in stamps:
+        out["apply_app_s"] = stamps["apply_app"] - t
+    return out
+
+
 # gossip arrival marks (round 15): wall-clock instants recorded once per
 # height, in canonical order. Absolute epoch seconds — the fleet
 # aggregator (ops/fleet.py) compares them ACROSS nodes to reconstruct
@@ -90,6 +115,7 @@ ARRIVALS = (
                          # a height's quorum-arrival floor counts from
     "proposal",          # proposal message accepted
     "first_block_part",  # first proposal part added (build or gossip)
+    "parts_complete",    # the whole part set held (the block is whole)
     "own_prevote",       # our prevote signed
     "prevote_quorum",    # +2/3 prevotes for a block observed
     "own_precommit",     # our precommit signed

@@ -17,7 +17,7 @@ import logging
 import threading
 import time
 import zlib
-from collections import OrderedDict
+from collections import OrderedDict, deque
 
 from tendermint_tpu.abci.types import (
     CODE_MEMPOOL_FULL,
@@ -101,6 +101,11 @@ class SigBatcher:
         # behind one synchronous verify round trip
         self.max_inflight = max(1, max_inflight)
         self.dropped = 0
+        # batches sent to the verifier and the lanes they carried (their
+        # ratio is the gate's mean batch width; node stop dumps and
+        # /debug/queues carry both beside `dropped`)
+        self.batches = 0
+        self.lanes = 0
         # exactly-once accounting (round 8 chaos coverage): every
         # submitted item is delivered to on_results exactly once — on
         # daemon death between the in-flight batches the verifier's
@@ -184,8 +189,6 @@ class SigBatcher:
             return batch
 
     def _run(self) -> None:
-        from collections import deque
-
         from tendermint_tpu import devd
 
         # every daemon request this thread sends is a gate call
@@ -196,6 +199,8 @@ class SigBatcher:
                 if batch is None and not pending:
                     return
                 if batch:
+                    self.batches += 1
+                    self.lanes += len(batch)
                     devd.take_rid()
                     wall, t0 = time.time(), time.perf_counter()
                     try:
@@ -357,6 +362,10 @@ class Mempool:
         self.source_counts: dict[str, int] = {}
         # tx -> source for in-flight CheckTx (popped at every terminal)
         self._pending_source: dict[bytes, str] = {}
+        # in-flight txs whose block committed before their CheckTx answer
+        # came (a gossiped copy in the signature gate): the answer stands,
+        # the pool does not keep them (`update`, `_res_cb_normal`)
+        self._committed_in_flight: set[bytes] = set()
         # load-shed ladder probe, wired by the node to
         # OverloadMonitor.level; None (bare harnesses) = never shed
         self.pressure_fn = None
@@ -573,8 +582,11 @@ class Mempool:
     def _reject_bad_sig(self, tx: bytes, cb) -> None:
         """Signature failed the batch gate: reject without app dispatch —
         same cache semantics as an app-rejected tx (allow resubmission,
-        mempool/mempool.go:231)."""
-        self.cache.remove(tx)
+        mempool/mempool.go:231). A committed tx stays in the cache."""
+        if tx in self._committed_in_flight:
+            self._committed_in_flight.discard(tx)
+        else:
+            self.cache.remove(tx)
         self._pending_source.pop(tx, None)
         if cb is not None:
             cb(ResponseCheckTx(code=CODE_UNAUTHORIZED,
@@ -592,6 +604,11 @@ class Mempool:
 
     def _res_cb_normal(self, tx: bytes, res: ResponseCheckTx) -> None:
         src = self._pending_source.pop(tx, "")
+        if tx in self._committed_in_flight:
+            # its block committed while it was in flight: the answer
+            # stands, the pool does not keep it and the cache does
+            self._committed_in_flight.discard(tx)
+            return
         if res.is_ok:
             # lane admission (round 23): the app's priority hint picks
             # the lane; a full lane or a shed-writes ladder level rejects
@@ -702,6 +719,14 @@ class Mempool:
         self.height = height
         self.notified_txs_available = False
         committed = set(txs)
+        # a committed tx is never admitted again, though this node may not
+        # have met it before its block did (a gossiped copy still queued for
+        # CheckTx): it stays in the cache, as the reference's later mempool
+        # keeps it
+        for tx in txs:
+            self.cache.push(tx)
+        self._committed_in_flight.update(
+            tx for tx in committed if tx in self._pending_source)
         good = self._filter_txs(committed)
         # Recheck && (RecheckEmpty || block had txs) — mempool/mempool.go:351
         if good and self.config.recheck and (self.config.recheck_empty or txs):

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import json
 import threading
+from collections import deque
 
 from tendermint_tpu.libs.service import BaseService
 from tendermint_tpu.p2p.conn import ChannelDescriptor
@@ -17,6 +18,14 @@ from tendermint_tpu.p2p.switch import Reactor
 
 MEMPOOL_CHANNEL = 0x30
 PEER_CATCHUP_SLEEP = 0.1
+# txs gossiped in from peers wait here for the mempool's CheckTx, which
+# ONE thread runs (`mempool.ingest`): a connection's receive routine hands
+# the tx over and goes back to its socket, so the consensus messages
+# behind it on the same connection (a proposal, its parts, the votes) are
+# not held up by the mempool's lock or its signature gate. Past the
+# backlog a gossiped tx is dropped and counted (`ingest_dropped`): it is
+# not lost, the node that sent it holds it and proposes it in its turn.
+INGEST_BACKLOG = 8192
 
 
 def _encode_tx(tx: bytes) -> bytes:
@@ -31,6 +40,17 @@ class MempoolReactor(Reactor, BaseService):
         self._peer_threads: dict[str, threading.Thread] = {}
         self._peer_stops: dict[str, threading.Event] = {}
         self._mtx = threading.Lock()
+        self._ingest: deque = deque()
+        self._ingest_cv = threading.Condition()
+        self.ingest_dropped = 0
+
+    def on_start(self) -> None:
+        threading.Thread(target=self._ingest_routine, daemon=True,
+                         name="mempool.ingest").start()
+
+    def on_stop(self) -> None:
+        with self._ingest_cv:
+            self._ingest_cv.notify_all()
 
     # -- Reactor interface -------------------------------------------------
 
@@ -89,13 +109,31 @@ class MempoolReactor(Reactor, BaseService):
         except (ValueError, KeyError, UnicodeDecodeError) as exc:
             self.switch.stop_peer_for_error(peer, exc)
             return
+        with self._ingest_cv:
+            if len(self._ingest) >= INGEST_BACKLOG:
+                self.ingest_dropped += 1
+                return
+            self._ingest.append((tx, str(peer.id())))
+            if len(self._ingest) == 1:
+                self._ingest_cv.notify()
+
+    def _check(self, tx: bytes, peer_id: str) -> None:
         try:
             # peer id keys the mempool's per-source admission accounting
             # (round 23): one flooding peer exhausts ITS budget, not the
             # lanes other sources share
-            self.mempool.check_tx(tx, source="peer", source_id=str(peer.id()))
+            self.mempool.check_tx(tx, source="peer", source_id=peer_id)
         except Exception:  # noqa: BLE001 — dup/full/source-limit/app reject: fine
             pass
+
+    def _ingest_routine(self) -> None:
+        while self.is_running():
+            with self._ingest_cv:
+                while not self._ingest and self.is_running():
+                    self._ingest_cv.wait(0.5)
+                batch, self._ingest = self._ingest, deque()
+            for tx, peer_id in batch:
+                self._check(tx, peer_id)
 
     # -- gossip ------------------------------------------------------------
 

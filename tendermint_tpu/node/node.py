@@ -485,6 +485,15 @@ class Node(BaseService):
 
             for k in GOSSIP_COUNTERS:
                 out[k] = getattr(self.consensus_reactor, k)
+            batcher = self.mempool.sig_batcher
+            if batcher is not None:
+                # the signature gate: batches, their lanes, and the writes
+                # its full backlog refused
+                out["sig_gate_batches"] = batcher.batches
+                out["sig_gate_lanes"] = batcher.lanes
+                out["sig_gate_dropped"] = batcher.dropped
+            # gossiped txs the mempool reactor's ingest queue refused
+            out["mempool_ingest_dropped"] = self.mempool_reactor.ingest_dropped
             out.update(p2p_telemetry.family_totals(self.telemetry))
             return out
 

@@ -118,6 +118,12 @@ def _queues(node) -> dict:
                 with batcher._cv:
                     row["sig_gate_backlog"] = len(batcher._buf)
                 row["sig_gate_dropped"] = batcher.dropped
+                row["sig_gate_batches"] = batcher.batches
+                row["sig_gate_lanes"] = batcher.lanes
+            reactor = getattr(node, "mempool_reactor", None)
+            if reactor is not None:
+                row["ingest_backlog"] = len(reactor._ingest)
+                row["ingest_dropped"] = reactor.ingest_dropped
             return row
 
         section("mempool", mempool_section)

@@ -43,7 +43,17 @@ def is_unix_laddr(laddr: str) -> bool:
     )
 
 
-class _UnixThreadingHTTPServer(ThreadingHTTPServer):
+class _HTTPServer(ThreadingHTTPServer):
+    """ThreadingHTTPServer with the kernel's listen backlog. socketserver's
+    default of 5 drops the SYNs of a client that opens its connections at
+    once (a batch client's pool of 64): each retries 1, 3, 7, 15 and 31 s
+    later, which a write's latency then carries. The reference's Go
+    listener takes the kernel's somaxconn."""
+
+    request_queue_size = socket.SOMAXCONN
+
+
+class _UnixThreadingHTTPServer(_HTTPServer):
     """ThreadingHTTPServer over AF_UNIX. HTTPServer.server_bind assumes a
     (host, port) address tuple and BaseHTTPRequestHandler.address_string
     indexes client_address — both break on unix sockets, so bind and
@@ -396,7 +406,7 @@ class RPCServer(BaseService):
             self.unix_path: str | None = path
         else:
             host, _, port = laddr.split("://", 1)[-1].rpartition(":")
-            self._httpd = ThreadingHTTPServer((host or "0.0.0.0", int(port)), Handler)
+            self._httpd = _HTTPServer((host or "0.0.0.0", int(port)), Handler)
             self.port = self._httpd.server_address[1]
             self.unix_path = None
         self._httpd.daemon_threads = True

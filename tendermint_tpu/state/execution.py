@@ -14,6 +14,7 @@ from __future__ import annotations
 import logging
 
 from tendermint_tpu.crypto.keys import PubKeyEd25519, pub_key_from_json
+from tendermint_tpu.libs import applyclock
 from tendermint_tpu.state.fail import fail_point
 from tendermint_tpu.state.state import ABCIResponses, State
 from tendermint_tpu.types import Validator, ValidatorSet
@@ -213,6 +214,8 @@ def commit_state_update_mempool(state: State, proxy_app_conn, block, mempool) ->
         res = proxy_app_conn.commit_sync()
         if not res.is_ok:
             raise ProxyAppConnError(f"commit failed: {res.log}")
+        # the app's fold and Commit are done: the rest is the mempool's
+        applyclock.stamp("apply_app")
         state.app_hash = res.data
         mempool.update(block.header.height, block.data.txs)
     finally:

@@ -67,10 +67,14 @@ class KVTxIndexer(TxIndexer):
         self.pruned_txs = 0
 
     def add_batch(self, batch: Batch) -> None:
+        """One write for the block (upstream's `Batch.Write`): the
+        store's batch path, never a commit a key."""
+        pairs = []
         for result in batch.ops:
             h = tx_hash(result.tx)
-            self.db.set(h, json.dumps(result.to_json()).encode())
-            self.db.set(_height_key(result.height, h), b"")
+            pairs.append((h, json.dumps(result.to_json()).encode()))
+            pairs.append((_height_key(result.height, h), b""))
+        self.db.set_many(pairs)
 
     def get(self, h: bytes) -> TxResult | None:
         from tendermint_tpu.abci.types import ResponseDeliverTx

@@ -133,6 +133,28 @@ def test_streamed_parity_with_single_shot_and_cpu(daemon):
     assert not all(want)  # the forged lanes actually exercised rejection
 
 
+def test_streamed_forged_lanes_at_and_across_a_chunk_boundary(daemon):
+    """A streamed batch whose forged lanes sit at a chunk's edges (the
+    last lane of one chunk and the first of the next, each between valid
+    lanes) and inside a chunk: every lane's verdict is plain Ed25519's
+    (perfbench/reference/ed25519_ref.py), chunk by chunk."""
+    sys.path.insert(0, os.path.join(REPO, "perfbench"))
+    from reference import ed25519_ref
+
+    _, client = daemon
+    items = _items(48, tag=b"edge")
+    for k in (15, 16, 31, 40):       # 16-lane chunks: 15|16 across, 31 at
+        pub, msg, sig = items[k]
+        if k % 2:
+            sig = bytes([sig[0] ^ 0x01]) + sig[1:]
+        else:
+            msg = msg + b"!"
+        items[k] = (pub, msg, sig)
+    want = [ed25519_ref.verify(pub, msg, sig) for pub, msg, sig in items]
+    assert [k for k, ok in enumerate(want) if not ok] == [15, 16, 31, 40]
+    assert client.verify_stream(items, chunk=16) == want
+
+
 def test_streamed_empty_and_single_item(daemon):
     _, client = daemon
     assert client.verify_stream([]) == []

@@ -177,8 +177,12 @@ def test_reactor_receive_paths_never_leak_exceptions():
     mp = Mempool(_cfg().mempool, AppConnMempool(LocalClient(CounterApp())))
     mr = MempoolReactor(_cfg().mempool, mp)
     mr.switch = _FuzzSwitch()
-    for data in payloads():
-        mr.receive(0x30, peer, data)
+    mr.start()
+    try:
+        for data in payloads():
+            mr.receive(0x30, peer, data)
+    finally:
+        mr.stop()
 
     # pex reactor
     from tendermint_tpu.p2p.addrbook import AddrBook
