@@ -125,7 +125,7 @@ class BlockchainReactor(Reactor, BaseService):
         # /metrics (fastsync_*_s) so the residual bottleneck is measured
         # in production, not guessed (VERDICT r3 weak #6). `decode` is the
         # one stage off that thread: json.loads + Block.from_json of every
-        # block_response, on the peers' recv routines (hence its lock)
+        # block_response, on the p2p I/O loop (hence its lock)
         self.stage_s = {
             "dispatch": 0.0, "part_hash": 0.0, "verify_wait": 0.0,
             "store_save": 0.0, "apply": 0.0, "decode": 0.0,
@@ -181,7 +181,7 @@ class BlockchainReactor(Reactor, BaseService):
         # EVERYTHING in the message is attacker input: any decode
         # violation (missing key, wrong type, out-of-range scalar) must
         # end as a peer error, never an exception escaping into the p2p
-        # recv routine (codec/jsonval contract)
+        # I/O loop (codec/jsonval contract)
         from tendermint_tpu.codec import jsonval as jv
 
         try:

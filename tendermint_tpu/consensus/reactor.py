@@ -31,6 +31,7 @@ from tendermint_tpu.consensus.round_state import RoundStep
 from tendermint_tpu.libs.bitarray import BitArray
 from tendermint_tpu.libs.service import BaseService
 from tendermint_tpu.p2p.conn import ChannelDescriptor, MConnConfig
+from tendermint_tpu.p2p.ioloop import hold_reads
 from tendermint_tpu.p2p.switch import Reactor
 from tendermint_tpu.types import events as tev
 from tendermint_tpu.types.agg_commit import AggregateLastCommit, commit_is_aggregate
@@ -809,6 +810,18 @@ class ConsensusReactor(Reactor, BaseService):
         self.con_s.trace.note("relay_hold_s", total / len(states))
         self.con_s.trace.note("relay_holds", 1)
 
+    def _to_state(self, msg, peer) -> None:
+        """Hand a peer's message to the state machine. A full queue holds
+        this peer's reads back (its later messages wait, in order; the
+        loop and every other peer go on) until the message fits, or
+        PEER_PUT_TIMEOUT passes and add_peer_message drops it and counts
+        the drop (consensus/state._enqueue_peer_msg)."""
+        cs, pid = self.con_s, peer.id()
+        if not cs.try_add_peer_message(msg, pid):
+            hold_reads(lambda: cs.try_add_peer_message(msg, pid),
+                       lambda: cs.add_peer_message(msg, pid),
+                       cs.PEER_PUT_TIMEOUT)
+
     def receive(self, ch_id: int, peer, msg_bytes: bytes) -> None:
         """reactor.go:159-302."""
         if not self.is_running():
@@ -875,16 +888,16 @@ class ConsensusReactor(Reactor, BaseService):
                 return
             if isinstance(msg, msgs.ProposalMessage):
                 ps.set_has_proposal(msg.proposal)
-                self.con_s.add_peer_message(msg, peer.id())
+                self._to_state(msg, peer)
             elif isinstance(msg, msgs.ProposalPOLMessage):
                 ps.apply_proposal_pol(msg)
                 ps.gossip.wake()
             elif isinstance(msg, msgs.BlockPartMessage):
                 ps.set_has_proposal_block_part(msg.height, msg.round_, msg.part.index)
-                self.con_s.add_peer_message(msg, peer.id())
+                self._to_state(msg, peer)
             elif isinstance(msg, msgs.AggregateCommitMessage):
                 if self._screen_agg_commit(peer, msg):
-                    self.con_s.add_peer_message(msg, peer.id())
+                    self._to_state(msg, peer)
             else:
                 self.switch.stop_peer_for_error(peer, f"bad data msg {type(msg)}")
         elif ch_id == VOTE_CHANNEL:
@@ -896,7 +909,7 @@ class ConsensusReactor(Reactor, BaseService):
                     msg.vote.height, msg.vote.round_, msg.vote.type_,
                     msg.vote.validator_index,
                 )
-                self.con_s.add_peer_message(msg, peer.id())
+                self._to_state(msg, peer)
             else:
                 self.switch.stop_peer_for_error(peer, f"bad vote msg {type(msg)}")
         elif ch_id == VOTE_SET_BITS_CHANNEL:

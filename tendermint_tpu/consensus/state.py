@@ -413,29 +413,40 @@ class ConsensusState(BaseService):
     def send_internal_message(self, mi: MsgInfo) -> None:
         self.internal_msg_queue.put(mi)
 
-    # every peer-originated enqueue goes through _enqueue_peer_msg so the
-    # bounded-wait invariant below cannot be bypassed by a sibling entry
-    # point
-    PEER_PUT_TIMEOUT = 0.5  # s
+    # every peer-originated enqueue goes through try_add_peer_message
+    # (the p2p path, which holds the peer back for room) or
+    # _enqueue_peer_msg, so neither can wait on the queue
+    PEER_PUT_TIMEOUT = 0.5  # s: how long a peer's reads wait for room
+
+    def try_add_peer_message(self, msg, peer_id: str) -> bool:
+        """Queue a peer's message if the queue has room; False (nothing
+        queued, nothing counted) when it is full."""
+        try:
+            self.peer_msg_queue.put_nowait(MsgInfo(msg, peer_id))
+            return True
+        except queue.Full:
+            return False
 
     def _enqueue_peer_msg(self, msg, peer_id: str) -> None:
-        """Called (indirectly) from the peer RECV routine — must never
-        wedge it. A bounded-timeout put gives a briefly-behind state
-        machine time to drain (no message loss under transient pressure —
-        important because gossip senders optimistically mark parts/votes
-        as delivered and won't re-offer them within the round); only when
-        the queue stays full past the timeout — a flooding peer or a
-        stopped state machine — is the message dropped. An UNbounded put
-        here wedges the recv routine, freezes the whole multiplexed
-        connection, and hands any flooding peer a denial-of-service lever
-        (found via the fast-sync stall flake: a stopped consensus state
-        filled the queue, the blocked put froze the peer, and both sides
-        eventually dropped 'stream closed'). Drops are counted and logged
-        at most once per 5s so the flood can't also spam the log."""
-        try:
-            self.peer_msg_queue.put(MsgInfo(msg, peer_id), timeout=self.PEER_PUT_TIMEOUT)
-            return
-        except queue.Full:
+        """Never waits: a full queue drops the message and counts the
+        drop. Drops are counted and logged at most once per 5s so a
+        flood can't also spam the log.
+
+        The p2p path comes here last. ConsensusReactor.receive runs on
+        the node's one I/O loop (p2p/ioloop.py), which serves every peer:
+        a wait there would stall them all, and an UNbounded one hands any
+        flooding peer a denial-of-service lever (found via the fast-sync
+        stall flake: a stopped consensus state filled the queue, the
+        blocked put froze the peer, and both sides eventually dropped
+        'stream closed'). On a full queue it holds that peer's reads
+        back and offers the message again (`try_add_peer_message`) until
+        it fits, which gives a briefly-behind state machine time to drain
+        (no message loss under transient pressure — important because
+        gossip senders optimistically mark parts/votes as delivered and
+        won't re-offer them within the round); only when the queue stays
+        full past PEER_PUT_TIMEOUT — a flooding peer or a stopped state
+        machine — does it come here."""
+        if not self.try_add_peer_message(msg, peer_id):
             self._note_peer_drop(MsgInfo(msg, peer_id))
 
     def _note_peer_drop(self, mi) -> None:

@@ -17,8 +17,7 @@ class Status:
 
 
 class Monitor:
-    """EWMA rate monitor with an optional limit() that sleeps to cap the
-    average transfer rate."""
+    """EWMA rate monitor; not_before() caps the average transfer rate."""
 
     def __init__(self, sample_period: float = 0.1):
         self._mtx = threading.Lock()
@@ -42,19 +41,15 @@ class Monitor:
                 self._window_start = now
                 self._window_bytes = 0
 
-    def limit(self, want: int, rate_limit: float) -> int:
-        """Sleep as needed so the *average* rate stays <= rate_limit, then
-        return how many bytes the caller may transfer (always `want` here;
-        pacing is purely time-based)."""
+    def not_before(self, rate_limit: float) -> float:
+        """The `time.monotonic()` instant before which the next transfer
+        would lift the *average* rate over rate_limit (the start, so any
+        instant, for 0 = unlimited). An event loop waits for it on its
+        clock instead of sleeping."""
         if rate_limit <= 0:
-            return want
+            return self._start
         with self._mtx:
-            elapsed = time.monotonic() - self._start
-            allowed = rate_limit * elapsed
-            excess = self._bytes - allowed
-        if excess > 0:
-            time.sleep(excess / rate_limit)
-        return want
+            return self._start + self._bytes / rate_limit
 
     def status(self) -> Status:
         with self._mtx:

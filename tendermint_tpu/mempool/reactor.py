@@ -19,8 +19,8 @@ from tendermint_tpu.p2p.switch import Reactor
 MEMPOOL_CHANNEL = 0x30
 PEER_CATCHUP_SLEEP = 0.1
 # txs gossiped in from peers wait here for the mempool's CheckTx, which
-# ONE thread runs (`mempool.ingest`): a connection's receive routine hands
-# the tx over and goes back to its socket, so the consensus messages
+# ONE thread runs (`mempool.ingest`): the p2p I/O loop hands the tx over
+# and goes back to the sockets, so the consensus messages
 # behind it on the same connection (a proposal, its parts, the votes) are
 # not held up by the mempool's lock or its signature gate. Past the
 # backlog a gossiped tx is dropped and counted (`ingest_dropped`): it is

@@ -494,6 +494,9 @@ class Node(BaseService):
                 out["sig_gate_dropped"] = batcher.dropped
             # gossiped txs the mempool reactor's ingest queue refused
             out["mempool_ingest_dropped"] = self.mempool_reactor.ingest_dropped
+            # the p2p I/O loop: its wake-ups, and the packets it read and
+            # wrote over them (p2p/ioloop.py)
+            out.update(self.sw.io.stats())
             out.update(p2p_telemetry.family_totals(self.telemetry))
             return out
 

@@ -37,8 +37,9 @@ no socket is refused.
 
 The timer thread writes with a blocking `send`: a peer that stops
 reading until its socket buffer fills holds back every link of this
-node, as it would hold back its own `MConnection` send routine. That is
-a test option's trade for one thread a node and not one a link.
+node (the I/O loop, which writes the undelayed links, keeps such a
+link's unsent tail and goes on; the line cannot). That is a test
+option's trade for one thread a node and not one a link.
 """
 
 from __future__ import annotations
@@ -166,6 +167,19 @@ class DelayedStream:
             self.stream.write(data)
         else:
             self._line.put(self._nid, self.delay_s, bytes(data))
+
+    def seal(self, chunks: list[bytes]) -> list[bytes]:
+        """The write path of a connection on the I/O loop: [] once the
+        link has a delay (the line writes each chunk at its due instant,
+        one line frame a chunk, as `write` puts them), else `chunks`,
+        for the socket."""
+        if self._closed:
+            raise ConnectionError("stream closed")
+        if self._nid is None:
+            return chunks
+        for data in chunks:
+            self._line.put(self._nid, self.delay_s, bytes(data))
+        return []
 
     def close(self) -> None:
         # what is queued is dropped, as a cut cable drops what is in it

@@ -52,9 +52,37 @@ class FuzzedStream:
         self.corrupted_writes = 0  # observable by harnesses/tests
         self._rng = random.Random(seed)
 
-    def _maybe_sleep(self) -> None:
+    def _draw_sleep(self) -> float:
         if self._rng.random() < self.prob_sleep:
-            time.sleep(self._rng.random() * self.max_delay)
+            return self._rng.random() * self.max_delay
+        return 0.0
+
+    def stall(self, n: int = 1) -> float:
+        """Seconds `prob_sleep` holds back `n` reads or writes (0.0 most
+        of the time), one draw each: `read` and `write` sleep it in the
+        caller; a connection on the I/O loop holds its next read or
+        write back by it instead, drawing once a frame it writes through
+        this stream and once a frame it opens above it."""
+        return sum(self._draw_sleep() for _ in range(n))
+
+    def _maybe_sleep(self) -> None:
+        delay = self._draw_sleep()
+        if delay:
+            time.sleep(delay)
+
+    def _corrupt(self, data: bytes) -> bytes:
+        if data and self._rng.random() < self.prob_corrupt:
+            buf = bytearray(data)
+            buf[self._rng.randrange(len(buf))] ^= 0xFF
+            data = bytes(buf)
+            self.corrupted_writes += 1
+        return data
+
+    def seal(self, chunks: list[bytes]) -> list[bytes]:
+        """The write path of a connection on the I/O loop: what `write`
+        would hand below for each of `chunks`, one corruption draw each
+        (a chunk is one frame when a secret connection sits above)."""
+        return [self._corrupt(data) for data in chunks]
 
     def read(self, n: int) -> bytes:
         # reads are only delayed: dropping them would desync framing
@@ -63,12 +91,7 @@ class FuzzedStream:
 
     def write(self, data: bytes) -> None:
         self._maybe_sleep()
-        if data and self._rng.random() < self.prob_corrupt:
-            buf = bytearray(data)
-            buf[self._rng.randrange(len(buf))] ^= 0xFF
-            data = bytes(buf)
-            self.corrupted_writes += 1
-        self.stream.write(data)
+        self.stream.write(self._corrupt(data))
 
     def close(self) -> None:
         self.stream.close()

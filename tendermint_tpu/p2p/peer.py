@@ -132,6 +132,7 @@ class Peer(BaseService):
         config: PeerConfig,
         node_priv_key,
         persistent: bool = False,
+        loop=None,
     ):
         super().__init__(name="peer")
         self.outbound = outbound
@@ -165,6 +166,7 @@ class Peer(BaseService):
             on_receive=lambda ch, msg: on_receive(self, ch, msg),
             on_error=lambda exc: on_error(self, exc),
             config=config.mconfig,
+            loop=loop,
         )
 
     # -- handshake (before start) -----------------------------------------
@@ -205,31 +207,6 @@ class Peer(BaseService):
     # -- lifecycle ---------------------------------------------------------
 
     def on_start(self) -> None:
-        # The switch arms an admission timeout on the RAW socket for the
-        # handshakes (Switch.add_peer_from_stream) and restores it to
-        # blocking only AFTER add_peer returns — but the mconn recv
-        # routine starts HERE, inside add_peer, and CPython fixes a
-        # recv's deadline at call entry, so its first blocking read
-        # inherited the armed timeout. A link quiet past the remaining
-        # budget (mconn pings only every 40 s; a loaded box delays the
-        # remote's first gossip sends arbitrarily) then tripped the
-        # timeout, which SocketStream.read reports as EOF — both sides
-        # dropped "stream closed" with nothing wrong on the wire: the
-        # round-16 full-suite fast-sync flake. Clearing the timeout
-        # BEFORE the recv routine launches closes the race; the
-        # handshakes this timeout actually bounds are all complete by
-        # the time start() runs.
-        obj, hops = self.stream, 0
-        while obj is not None and hops < 4:
-            sock = getattr(obj, "sock", None)
-            if sock is not None:
-                try:
-                    sock.settimeout(None)
-                except OSError:
-                    pass
-                break
-            obj = getattr(obj, "stream", None)
-            hops += 1
         self.mconn.start()
 
     def on_stop(self) -> None:

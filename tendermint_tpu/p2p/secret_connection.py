@@ -181,6 +181,7 @@ class SecretConnection:
         self._wmtx = threading.Lock()
         self._rmtx = threading.Lock()
         self._recv_buf = b""
+        self._inbuf = bytearray()  # feed's partial frame
 
         # 4. authenticate node keys over the encrypted channel
         challenge = hashlib.sha256(lo + hi).digest()
@@ -269,14 +270,23 @@ class SecretConnection:
     def _nonce12(self, counter: int) -> bytes:
         return counter.to_bytes(12, "big")
 
-    def _write_frame(self, chunk: bytes) -> None:
-        ct = self._send_aead.encrypt(self._nonce12(self._send_nonce), chunk, None)
-        self._send_nonce += 1
-        self.stream.write(_LEN.pack(len(ct)) + ct)
+    def _seal(self, data: bytes, frames: list[bytes]) -> None:
+        """Append the frames that carry `data` to `frames`, one per
+        DATA_MAX_SIZE bytes (one empty frame for b""). Caller holds
+        _wmtx: the nonces count."""
+        encrypt = self._send_aead.encrypt
+        for off in range(0, len(data), DATA_MAX_SIZE) if data else (0,):
+            ct = encrypt(self._nonce12(self._send_nonce),
+                         data[off:off + DATA_MAX_SIZE], None)
+            self._send_nonce += 1
+            frames.append(_LEN.pack(len(ct)) + ct)
 
-    def _read_msg(self) -> bytes:
-        """One frame's plaintext."""
-        (clen,) = _LEN.unpack(self._read_exact(_LEN.size))
+    def _poison(self, err: SecretConnectionError) -> SecretConnectionError:
+        self._poisoned = err
+        self.stream.close()
+        return err
+
+    def _check_len(self, clen: int) -> None:
         if clen > DATA_MAX_SIZE + 16:
             # oversized-frame adversary (round 18): our writer never
             # exceeds plaintext DATA_MAX_SIZE + the 16-byte tag, so a
@@ -285,43 +295,44 @@ class SecretConnection:
             # attacker bytes per frame just to fail the AEAD tag)
             _counters()["oversized_frames"].inc()
             _counters()["auth_failures"].inc()
-            err = SecretConnectionError(
+            raise self._poison(SecretConnectionError(
                 f"secret connection: oversized frame claim ({clen} B; "
                 f"legal max {DATA_MAX_SIZE + 16})"
-            )
-            self._poisoned = err
-            self.stream.close()
-            raise err
-        ct = self._read_exact(clen)
+            ))
+
+    def _open(self, ct: bytes) -> bytes:
         try:
             pt = self._recv_aead.decrypt(self._nonce12(self._recv_nonce), ct, None)
         except InvalidTag as exc:
             # tampering / desync is unrecoverable: poison the connection
             _counters()["auth_failures"].inc()
-            err = SecretConnectionError(
+            raise self._poison(SecretConnectionError(
                 "secret connection: frame authentication failed"
-            )
-            self._poisoned = err
-            self.stream.close()
-            raise err from exc
+            )) from exc
         self._recv_nonce += 1
         return pt
+
+    def _read_msg(self) -> bytes:
+        """One frame's plaintext."""
+        (clen,) = _LEN.unpack(self._read_exact(_LEN.size))
+        self._check_len(clen)
+        return self._open(self._read_exact(clen))
 
     # -- stream interface --------------------------------------------------
 
     def write(self, data: bytes) -> None:
         with self._wmtx:
-            for off in range(0, len(data), DATA_MAX_SIZE):
-                self._write_frame(data[off : off + DATA_MAX_SIZE])
-            if not data:
-                self._write_frame(b"")
+            frames: list[bytes] = []
+            self._seal(data, frames)
+            for frame in frames:
+                self.stream.write(frame)
 
     def read(self, n: int) -> bytes:
         """Up to n plaintext bytes; b"" on clean EOF (peer hangup).
         Tampering is NOT EOF: an authentication failure raises
         SecretConnectionError — here and on every subsequent read (the
-        connection is poisoned) — so the mconn recv routine drops the
-        peer for cause instead of reading a quiet close."""
+        connection is poisoned) — so the connection drops the peer for
+        cause instead of reading a quiet close."""
         with self._rmtx:
             if self._poisoned is not None:
                 raise self._poisoned
@@ -333,6 +344,46 @@ class SecretConnection:
                 except ConnectionError:
                     return b""
             out, self._recv_buf = self._recv_buf[:n], self._recv_buf[n:]
+            return out
+
+    # -- the I/O loop's side (p2p/ioloop.py) --------------------------------
+
+    def seal(self, chunks: list[bytes]) -> list[bytes]:
+        """The write path of a connection on the I/O loop: the frames
+        that carry each of `chunks`, one element a frame, for the layer
+        below, as `write` writes them."""
+        frames: list[bytes] = []
+        with self._wmtx:
+            for data in chunks:
+                self._seal(data, frames)
+        return frames
+
+    def feed(self, chunks: list[bytes]) -> list[bytes]:
+        """The read path of a connection on the I/O loop: ciphertext as
+        the socket gave it in, the plaintext of each whole frame it
+        completes out, one element a frame. A partial frame, its 2-byte
+        length included, waits for the next call. Tampering and an
+        oversized claim raise SecretConnectionError and poison the
+        connection, as in `read`."""
+        with self._rmtx:
+            if self._poisoned is not None:
+                raise self._poisoned
+            buf = self._inbuf
+            for data in chunks:
+                buf += data
+            # plaintext a blocking read took in and did not hand out
+            out = [self._recv_buf] if self._recv_buf else []
+            self._recv_buf = b""
+            n, off = len(buf), 0
+            while n - off >= 2:
+                clen = (buf[off] << 8) | buf[off + 1]
+                self._check_len(clen)
+                end = off + 2 + clen
+                if end > n:
+                    break
+                out.append(self._open(bytes(buf[off + 2:end])))
+                off = end
+            del buf[:off]
             return out
 
     def close(self) -> None:

@@ -16,6 +16,7 @@ import time
 from tendermint_tpu.crypto.keys import PrivKeyEd25519, gen_priv_key_ed25519
 from tendermint_tpu.libs.service import BaseService
 from tendermint_tpu.p2p.conn import ChannelDescriptor
+from tendermint_tpu.p2p.ioloop import IOLoop
 from tendermint_tpu.p2p.netaddress import NetAddress
 from tendermint_tpu.p2p.node_info import NodeInfo
 from tendermint_tpu.p2p.peer import Peer, PeerConfig
@@ -137,6 +138,9 @@ class Switch(BaseService):
             "schedule_refused": 0,
         }
         self._mtx = threading.Lock()
+        # the node's one I/O loop: every peer connection's reads and
+        # writes (p2p/ioloop.py); its thread starts with the first peer
+        self.io = IOLoop()
 
     def _note_adversary(self, kind: str) -> None:
         with self._mtx:
@@ -202,6 +206,7 @@ class Switch(BaseService):
             self._stop_and_remove(peer, "switch stopping")
         for reactor in self.reactors.values():
             reactor.stop()
+        self.io.stop()
         if self.peer_config.link_delays is not None:
             self.peer_config.link_delays.line.stop()
 
@@ -298,6 +303,7 @@ class Switch(BaseService):
                 config=self.peer_config,
                 node_priv_key=self.node_priv_key,
                 persistent=persistent,
+                loop=self.io,
             )
             peer.metrics_registry = self.metrics_registry
             peer.dialed_addr = dialed_addr

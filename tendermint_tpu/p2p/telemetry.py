@@ -222,8 +222,8 @@ def _ch_label(ch_id: int) -> str:
 
 class PeerConnMetrics:
     """Per-connection handle bundle: child series resolved ONCE at
-    handshake (labels never change for a live connection), so the
-    send/recv routines pay one attribute read + one child inc per event
+    handshake (labels never change for a live connection), so the I/O
+    loop pays one attribute read + one child inc per event
     — no registry lookups on the hot path."""
 
     __slots__ = ("peer_id", "_send_bytes", "_recv_bytes", "_send_msgs",

@@ -5,7 +5,7 @@ follows the later statesync reactor, JSON-framed like this codebase's
 blockchain reactor).
 
 Wire messages (every field is attacker input — any decode violation is a
-peer error, never an exception escaping the p2p recv routine):
+peer error, never an exception escaping into the p2p I/O loop):
 
     {"type": "snapshots_request"}
     {"type": "snapshots_response", "snapshots": [manifest-lite, ...]}

@@ -379,7 +379,7 @@ def test_a_peers_message_wakes_its_routines_only_if_it_can_add(
     What the peer asks for or steps into marks that peer, and the sweep
     looks at it alone (three peers here: three looks if it took all)."""
     net = net_factory(n_peers=3)
-    net.cs.add_peer_message = lambda msg, peer_id: None
+    net.cs.try_add_peer_message = lambda msg, peer_id: True
     net.cs.rs.votes.set_peer_maj23 = lambda *a: None
     net.settle()
     wakes, looks = net.r.gossip_wakes_event, net.r.gossip_peer_looks

@@ -78,14 +78,18 @@ class TestFlowrate:
         t0 = time.monotonic()
         sent = 0
         while sent < 3000:
-            n = m.limit(1000, rate_limit=10_000)  # 10 KB/s cap
-            m.update(n)
-            sent += n
+            # each transfer waits for its instant under a 10 KB/s cap
+            wait = m.not_before(rate_limit=10_000) - time.monotonic()
+            if wait > 0:
+                time.sleep(wait)
+            m.update(1000)
+            sent += 1000
         elapsed = time.monotonic() - t0
         # 3 KB at 10 KB/s floor: >= ~0.2s (pacing happened); uncapped this
         # loop finishes in microseconds
         assert elapsed >= 0.15, elapsed
-        assert m.limit(500, rate_limit=0) == 500  # 0 = unlimited, no sleep
+        # 0 = unlimited: no wait, whatever was sent
+        assert m.not_before(rate_limit=0) <= time.monotonic()
 
 
 class _P:
