@@ -11,7 +11,8 @@ process that may load libtpu) and adds, from the benchmark's side only:
 - spans around the daemon's verifier calls (dispatch -> verdicts read),
   on the wall clock, so idle gaps of the device can be attributed;
 - `jax.profiler` start/stop on request, with a marker annotation that
-  ties the trace's clock to the wall clock;
+  ties the trace's clock to the wall clock; a trace stops by itself at its
+  12th verifier call, and at the harness's stop only once it holds one;
 - the device's `memory_stats()` peak on request.
 
 Requests are files: the harness writes `<ctl>/req-<n>.json`
@@ -40,18 +41,54 @@ POLL_S = 0.02
 # 2.6 MB of trace and 3 s of writing it out (measured, PERF.md: 20 calls,
 # 58-75 s): a trace that has seen this many verifier calls stops by itself
 MAX_TRACED_CALLS = 12
+# at the harness's stop, a trace that holds no verifier call yet stays open
+# until the first one lands, this long at most: a committee spends a
+# second of each height in `timeout_commit`, with gate checks alone (some
+# 15 a second) reaching the daemon, and its nodes run on after the close
+FIRST_CALL_WAIT_S = 5.0
+
+
+class JaxProfiler:
+    """`jax.profiler`, with a marker annotation that ties the trace's
+    clock to the wall clock at each end."""
+
+    @staticmethod
+    def mark(wall_ns: int) -> None:
+        import jax
+
+        with jax.profiler.TraceAnnotation(f"bench_mark:{wall_ns}"):
+            time.sleep(0.001)
+
+    @staticmethod
+    def start(tdir: str) -> None:
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(tdir, profiler_options=opts)
+
+    @staticmethod
+    def stop() -> None:
+        import jax
+
+        jax.profiler.stop_trace()
 
 
 class Recorder:
-    """What the launcher observes, on the wall clock (ns)."""
+    """What the launcher observes, on the wall clock (ns). `clock` and
+    `sleep` pace the wait for a first traced call; tests give their own,
+    and a profiler that records nothing."""
 
-    def __init__(self) -> None:
+    def __init__(self, profiler=JaxProfiler, clock=time.monotonic,
+                 sleep=time.sleep) -> None:
         self.lock = threading.Lock()
         self.compiles: list[tuple[int, float]] = []   # (end_wall_ns, seconds)
         self.spans: list[tuple[int, int, int]] = []   # (start_ns, end_ns, lanes)
         self.tracing_since: int | None = None         # len(spans) at the start
         self.trace_result: dict | None = None
         self.trace_lock = threading.Lock()
+        self.profiler, self.clock, self.sleep = profiler, clock, sleep
 
     def on_duration(self, event: str, duration: float, **_kw) -> None:
         if event == COMPILE_EVENT:
@@ -70,25 +107,31 @@ class Recorder:
             threading.Thread(target=self.stop_trace, name="bench-stop-trace").start()
 
     def start_trace(self, tdir: str) -> dict:
-        import jax
-
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 1
-        jax.profiler.start_trace(tdir, profiler_options=opts)
+        self.profiler.start(tdir)
         wall = time.time_ns()
-        with jax.profiler.TraceAnnotation(f"bench_mark:{wall}"):
-            time.sleep(0.001)
+        self.profiler.mark(wall)
         with self.lock:
             self.tracing_since = len(self.spans)
             self.trace_result = None
         return {"ok": True, "start_wall_ns": wall}
 
-    def stop_trace(self) -> dict:
-        """Stop once; a second call (the harness's, after the trace
-        stopped by itself) gets the first one's answer."""
-        import jax
+    def traced_calls(self) -> int | None:
+        """Verifier calls since the trace started; None where none runs."""
+        with self.lock:
+            if self.tracing_since is None:
+                return None
+            return len(self.spans) - self.tracing_since
 
+    def stop_trace(self, wait_s: float = 0.0) -> dict:
+        """Stop once; a second call (the harness's, after the trace
+        stopped by itself) gets the first one's answer. A trace that holds
+        no verifier call yet stays open until the first one lands, `wait_s`
+        at most; one that still holds none stops all the same, and says so
+        (`traced_calls` 0)."""
+        t0 = self.clock()
+        while self.traced_calls() == 0 and self.clock() - t0 < wait_s:
+            self.sleep(POLL_S)
+        waited = self.clock() - t0
         with self.trace_lock:
             with self.lock:
                 if self.tracing_since is None:
@@ -97,12 +140,12 @@ class Recorder:
                 calls = len(self.spans) - self.tracing_since
                 self.tracing_since = None
             wall = time.time_ns()
-            with jax.profiler.TraceAnnotation(f"bench_mark:{wall}"):
-                time.sleep(0.001)
-            jax.profiler.stop_trace()
+            self.profiler.mark(wall)
+            self.profiler.stop()
             self.trace_result = {"ok": True, "stop_wall_ns": wall,
                                  "written_wall_ns": time.time_ns(),
-                                 "traced_calls": calls}
+                                 "traced_calls": calls,
+                                 "waited_for_call_s": waited}
             return self.trace_result
 
     def snapshot(self, since_ns: int = 0) -> dict:
@@ -212,7 +255,7 @@ def handle(req: dict, rec: Recorder) -> dict:
         if op == "start_trace":
             return rec.start_trace(req["dir"])
         if op == "stop_trace":
-            return rec.stop_trace()
+            return rec.stop_trace(FIRST_CALL_WAIT_S)
         return {"ok": False, "error": f"unknown op {op!r}"}
     except Exception as exc:  # noqa: BLE001 — reported to the harness
         return {"ok": False, "error": f"{type(exc).__name__}: {exc}"}

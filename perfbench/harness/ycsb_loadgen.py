@@ -40,7 +40,11 @@ async def one_op(i: int, op: dict, target, due_mono: float, timeout: float,
                   and (res.get("deliver_tx") or {}).get("code", 1) == 0)
             height = int(res.get("height") or 0)
             if not ok:
-                err = json.dumps(res)[:200]
+                # the two codes and logs whole, the judge reads them
+                err = json.dumps({k: {"code": (res.get(k) or {}).get("code"),
+                                      "log": str((res.get(k) or {}).get("log")
+                                                 or "")[:80]}
+                                  for k in ("check_tx", "deliver_tx")})
         else:
             res = out["result"]["response"]
             ok = res.get("code", 1) == 0

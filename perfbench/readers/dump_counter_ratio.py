@@ -1,7 +1,8 @@
-"""One counter of the stop dumps' `counters` summed over EVERY node, over
-another summed alike: the whole run's, as the counters run from a node's
-start. params: {"num": key, "den": key}. Nothing from a program that
-writes no such dump or counter, or where the denominator is 0."""
+"""Counters of the stop dumps' `counters` summed over EVERY node, over
+others summed alike: the whole run's, as the counters run from a node's
+start. params: {"num": key or [keys], "den": key or [keys]}. Nothing from
+a program that writes no such dump or counter, or where the denominator
+is 0."""
 
 import json
 import os
@@ -10,10 +11,15 @@ import re
 from harness import artifacts
 
 
+def names(keys) -> list:
+    return [keys] if isinstance(keys, str) else list(keys)
+
+
 def read(obs, params, device):
     if not artifacts.program_keeps_records():
         return None
     run = artifacts.run_dir(obs)
+    tops, bottoms = names(params["num"]), names(params["den"])
     num = den = 0.0
     for d in os.listdir(run):
         m = re.fullmatch(r"node(\d+)", d)
@@ -21,10 +27,10 @@ def read(obs, params, device):
             continue
         with open(artifacts.stop_dump(run, int(m.group(1)))) as f:
             counters = json.load(f).get("counters") or {}
-        if params["num"] not in counters or params["den"] not in counters:
+        if any(k not in counters for k in tops + bottoms):
             return None
-        num += float(counters[params["num"]])
-        den += float(counters[params["den"]])
+        num += sum(float(counters[k]) for k in tops)
+        den += sum(float(counters[k]) for k in bottoms)
     if den <= 0:
         return None
     return num / den

@@ -8,8 +8,10 @@ per-layer metric is a file of its own, found by the name BENCHMARK.json
 gives it: `configs/<config>.json` (as BENCHMARK.json's `file` says),
 `traffic/<traffic>.json`, `metrics/<metric>.json`. A configuration names
 its `deployment`, which is a module in `scenarios/`; a metric names its
-`reader`, a module in `readers/`. A later PR adds a cell or a metric by
-adding files and entries.
+`reader`, a module in `readers/`, and the cells that read it (one entry
+a metric, however many cells); where one cell reads it another way, the
+metric's file gives that cell's reader under `by_workload`. A later PR
+adds a cell or a metric by adding files and entries.
 
 This process launches and reads; it never imports JAX. The device daemon
 (started through `harness/devd_launcher.py`) is the one process that
@@ -59,7 +61,9 @@ class Context:
         execution: so the traced stretch is short, lies at the end, and
         `finish_trace` waits for the writing after the close. The stretch
         is counted from the launcher's answer (starting the profiler
-        takes tens of milliseconds)."""
+        takes tens of milliseconds). The launcher ends it at its 12th
+        verifier call; at the harness's stop, one that holds no call yet
+        stays open until the first one lands (`devd_launcher.Recorder`)."""
         start_at = close_wall - length_s - 0.3
         while time.time() < start_at:
             time.sleep(0.02)
@@ -75,6 +79,12 @@ class Context:
         trace["stop_wall_ns"] = b["stop_wall_ns"]
         trace["stop_took_s"] = (b["written_wall_ns"] - b["stop_wall_ns"]) / 1e9
         trace["traced_calls"] = b["traced_calls"]
+        if b["traced_calls"] < 1:
+            from harness import procs
+
+            raise procs.HarnessError(
+                "the traced stretch holds no verifier call: none came within "
+                f"{b['waited_for_call_s']:.1f} s of the harness's stop")
         return trace
 
 
@@ -103,14 +113,21 @@ def reduce_trace(trace: dict) -> None:
     trace["extracted"] = load_json(out)
 
 
+def metric_reader(spec: dict, cell: str) -> tuple[str, dict]:
+    """The reader and its parameters that a metric's file gives for one
+    cell: the cell's own under `by_workload`, else the metric's."""
+    own = spec.get("by_workload", {}).get(cell, spec)
+    return own["reader"], own.get("params", {})
+
+
 def per_layer_metrics(bench: dict, cell: str, obs, device: dict) -> dict:
     out = {}
     for m in bench["per_layer"]:
         if "workloads" in m and cell not in m["workloads"]:
             continue
         spec = load_json(os.path.join(BENCH, "metrics", m["name"] + ".json"))
-        reader = importlib.import_module("readers." + spec["reader"])
-        value = reader.read(obs, spec.get("params", {}), device)
+        name, params = metric_reader(spec, cell)
+        value = importlib.import_module("readers." + name).read(obs, params, device)
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out
@@ -173,6 +190,14 @@ def main(argv=None) -> int:
 
             red = trace_reduce.reduce(obs.trace["extracted"], spans=obs.spans,
                                       compiles=obs.compiles_in_window)
+            # (a CPU daemon's profile has no device plane: a rehearsal
+            # reads 0 for both)
+            if not ctx.rehearsal and (red["window_s"] <= 0 or red["busy_s"] <= 0):
+                raise procs.HarnessError(
+                    f"the traced stretch of {obs.trace['traced_calls']} verifier "
+                    f"calls holds no device operation (window_s "
+                    f"{red['window_s']}, busy_s {red['busy_s']})")
+            res["notes"]["traced_calls"] = obs.trace["traced_calls"]
             res["device"]["busy_s"] = red["busy_s"]
             res["device"]["window_s"] = red["window_s"]
             breakdown = {"device_ops": red.get("device_ops", []),

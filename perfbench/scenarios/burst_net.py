@@ -35,29 +35,6 @@ from reference import burst_ref
 from scenarios import validator_net as vn
 
 BOOT_LIMIT_S = 150.0
-# this cell's per-layer readings that BENCHMARK.json has no room for (its
-# `per_layer` holds 128 entries at most): the benchmark's own readers,
-# read in every run once the nodes and the daemon have stopped, into the
-# result line's notes under these names
-READINGS = {
-    "committed_writes_per_s": ("mean", {"series": "committed_writes_per_s"}),
-    "block_txs_max": ("percentile", {"series": "block_txs", "q": 100}),
-    "block_parts_ms_p50": ("dump_arrival_gap_percentile", {
-        "from": "proposal", "to": "parts_complete", "q": 50, "node": 0,
-        "min_aux": {"parts": 2}, "skip": "propose_as_proposer"}),
-    "sig_gate_lanes_per_batch_mean": ("ratio_of_deltas", {
-        "num": ["gate.sig_gate_lanes"], "den": ["gate.sig_gate_batches"]}),
-    "apply_verify_ms_p50": ("dump_aux_present_percentile",
-                            {"aux": "apply_verify_s", "q": 50, "node": 0}),
-    "apply_app_ms_p50": ("dump_aux_present_percentile",
-                         {"aux": "apply_app_s", "q": 50, "node": 0}),
-    "daemon_lanes_per_call_mean": ("span_program_lanes_mean", {}),
-    "height_interval_ms_mean": ("mean", {"series": "height_interval_ms"}),
-    "height_propose_ms_p50": ("dump_height_percentile",
-                              {"segments": ["new_round", "propose"], "q": 50}),
-    "rounds_over_zero": ("count", {"series": "height_rounds_over_zero",
-                                   "needs": "heights_in_window"}),
-}
 # a node sends a batch this wide or wider down the streamed protocol
 # (TENDERMINT_DEVD_STREAM_MIN's default): the harness warms those widths
 # the same way
@@ -300,7 +277,6 @@ def run(ctx) -> dict:
         except subprocess.TimeoutExpired:
             codes.append(None)
     daemon_code = daemon.shutdown()
-    readings = read_more(obs, ctx.run_dir, dev)
     refused = Counter((lg["err"][i] or "")[:60] for i in valid
                       if lg["code"][i] not in (0, None))
     return {
@@ -320,7 +296,6 @@ def run(ctx) -> dict:
                   "wide_programs_at_s": wide_at[:64],
                   "trace_began_at_s": round(trace["start_wall_ns"] / 1e9
                                             - open_wall, 3) if trace else None,
-                  "readings": readings,
                   **jnotes,
                   "heights_in_window": len(in_win), "top_height": top,
                   "node_exit_codes": codes, "daemon_exit_code": daemon_code,
@@ -368,23 +343,6 @@ def trace_the_drain(ctx, daemon, addr0, open_wall: float) -> dict:
     finally:
         trace["pending"] = daemon.post("stop_trace")
     return trace
-
-
-def read_more(obs, run_dir: str, dev: dict) -> dict:
-    """READINGS, each as its reader gives it (None where the program
-    keeps nothing to read; the reader's error where it failed)."""
-    import dataclasses
-    import importlib
-
-    shim = dataclasses.replace(obs, trace={"dir": os.path.join(run_dir, "trace")})
-    out = {}
-    for name, (reader, params) in READINGS.items():
-        try:
-            out[name] = importlib.import_module("readers." + reader).read(
-                shim, params, dev)
-        except Exception as exc:  # noqa: BLE001 — a note, never the run
-            out[name] = f"{type(exc).__name__}: {exc}"[:200]
-    return out
 
 
 def _tx_result(addr, tx_hash: str):

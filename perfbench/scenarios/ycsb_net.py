@@ -46,6 +46,7 @@ import subprocess
 import sys
 import threading
 import time
+from collections import Counter
 
 from harness import device, procs, rpc, ycsb, ycsb_load
 from harness.chain import derive
@@ -245,13 +246,16 @@ def run(ctx) -> dict:
     obs.trace = trace
 
     comparisons, jnotes = judge_live(ctx, cfg, mix, addrs, lg, top, unanswered,
-                                     status0, daemon, history, pool0, pool1)
+                                     status0, daemon, history, pool0, pool1,
+                                     made["blocks"] + 1)
     if trace:
         ctx.finish_trace(daemon, trace)
     metrics_e2e = {}
     if lat:
         metrics_e2e["commit_latency_p50_ms"] = quantile(lat, 0.50)
         metrics_e2e["commit_latency_p95_ms"] = quantile(lat, 0.95)
+    if read_lat:
+        metrics_e2e["read_latency_p50_ms"] = quantile(read_lat, 0.50)
     metrics_e2e["setup_s"] = setup_s
     codes = []
     for nd in nodes:
@@ -399,8 +403,56 @@ def _sha(value: bytes) -> str:
     return hashlib.sha256(value).hexdigest()
 
 
+# A node under load turns a write away for the moment and says so: its
+# mempool lane full or its overload ladder at shed-writes (ABCI code 6,
+# `mempool_lane_full:<lane>`, `mempool_shed_writes:<lane>`), or its
+# signature gate's backlog full (code 3, `signature gate saturated;
+# retry`). Such an answer says "later", not "invalid".
+SHED_ANSWERS = ((6, ("mempool_lane_full:", "mempool_shed_writes:")),
+                (3, ("signature gate saturated",)))
+DRAIN_LIMIT_S = 60.0
+
+
+def answer_kind(ok: bool, err: str | None) -> str:
+    """What an update's answer says: `acked` (both codes 0), `shed`
+    (turned away under load, see SHED_ANSWERS), `refused` (a CheckTx or
+    DeliverTx code that judges the write) or `open` (no verdict: an RPC
+    error such as a time-out, or no answer), whose fate the chain says."""
+    if ok:
+        return "acked"
+    if not (err or "").startswith("{"):
+        return "open"
+    check = json.loads(err)["check_tx"]
+    for code, logs in SHED_ANSWERS:
+        if check["code"] == code and check["log"].startswith(logs):
+            return "shed"
+    return "refused"
+
+
+def _chain_places(addrs, first_height: int) -> dict[str, tuple[int, int]]:
+    """Every tx of the chain from `first_height` on, at its (height,
+    place in the block), once every node's mempool has drained (a write
+    whose answer timed out may still be committed; a minute at most)."""
+    deadline = time.time() + DRAIN_LIMIT_S
+    while time.time() < deadline:
+        try:
+            if all(int(rpc.call(a, "num_unconfirmed_txs")["n_txs"]) == 0
+                   for a in addrs):
+                break
+        except (OSError, rpc.RPCFailure):
+            pass                                   # a shed read: ask again
+        time.sleep(0.2)
+    head = min(rpc.height(a) for a in addrs)
+    places: dict[str, tuple[int, int]] = {}
+    for h in range(first_height, head + 1):
+        blk = rpc.call(addrs[0], "block", {"height": h}, timeout=30)["block"]
+        for k, t in enumerate(blk["data"]["txs"] or []):
+            places.setdefault(t.upper(), (h, k))
+    return places
+
+
 def judge_live(ctx, cfg, mix, addrs, lg, top, unanswered, status0, daemon,
-               history, pool0, pool1) -> tuple[list, dict]:
+               history, pool0, pool1, first_height) -> tuple[list, dict]:
     """Every number compared while the nodes and the daemon still answer,
     beside its limit (all exact: every limit 0), and the notes."""
     seed, recordcount = ctx.seed, int(cfg["recordcount"])
@@ -423,24 +475,31 @@ def judge_live(ctx, cfg, mix, addrs, lg, top, unanswered, status0, daemon,
                 + ycsb_ref.value_of(seed, r, i + 1):
             off_draw += 1
 
-    # 2. the chain's order of the acknowledged updates, lead-in included:
-    #    the height each names and its place in that block
-    acked = [i for i in all_updates if lg["ok"][i]]
-    by_height: dict[int, list[int]] = {}
-    for i in acked:
-        by_height.setdefault(lg["height"][i], []).append(i)
-    place: dict[int, int] = {}
-    for h, members in sorted(by_height.items()):
-        blk = rpc.call(addrs[0], "block", {"height": h}, timeout=30)["block"]
-        at = {t.upper(): k for k, t in enumerate(blk["data"]["txs"] or [])}
-        for i in members:
-            place[i] = at.get(lg["tx"][i].upper(), -1)
-    order = sorted(acked, key=lambda i: (lg["height"][i], place[i]))
+    # 2. the chain's order of the updates it holds, lead-in included: the
+    #    acknowledged ones at the height each names and their place in
+    #    that block, and those whose answer gave no verdict that the chain
+    #    took all the same, where it took them (acknowledged by no node)
+    said = {i: answer_kind(lg["ok"][i], lg["err"][i]) for i in all_updates}
+    chain = _chain_places(addrs, first_height)
+    acked = [i for i in all_updates if said[i] == "acked"]
+    height = {i: lg["height"][i] for i in acked}
+    place = {i: chain[lg["tx"][i].upper()][1]
+             if chain.get(lg["tx"][i].upper(), (None,))[0] == height[i] else -1
+             for i in acked}
+    late = [i for i in all_updates
+            if said[i] == "open" and lg["tx"][i].upper() in chain]
+    for i in late:
+        height[i], place[i] = chain[lg["tx"][i].upper()]
+    # a write turned away under load is one the chain does not hold
+    shed_in_chain = sum(1 for i in all_updates
+                        if said[i] == "shed" and lg["tx"][i].upper() in chain)
+    order = sorted(acked + late, key=lambda i: (height[i], place[i]))
     store = ycsb_ref.Store(seed, recordcount)
     writes: dict[int, tuple] = {}
     for i in order:
-        store.acknowledge(lg["record"][i], i + 1, lg["height"][i], place[i])
-        writes[i + 1] = (lg["node"][i], lg["sent"][i], lg["done"][i])
+        store.acknowledge(lg["record"][i], i + 1, height[i], place[i])
+        writes[i + 1] = ((lg["node"][i], lg["sent"][i], lg["done"][i])
+                         if said[i] == "acked" else (-1, lg["sent"][i], float("inf")))
 
     # 3. every answered read of the window: a loaded record is there, the
     #    value is one the reference allows, and it is what the record
@@ -470,16 +529,16 @@ def judge_live(ctx, cfg, mix, addrs, lg, top, unanswered, status0, daemon,
             "value": [bytes.fromhex(lg["tx"][i])[kv_ref.SIG_TX_OVERHEAD:]
                       .split(b"=", 1)[1].hex() for i in order],
             "tx": [lg["tx"][i] for i in order],
-            "height": [lg["height"][i] for i in order]}
+            "height": [height[i] for i in order]}
     ten = vn.judge(ctx, cfg, {**mix, "forged_writes": 0}, addrs, view,
                    list(range(len(order))), top, unanswered, status0, daemon)
     ten = [c for c in ten if c[0] not in ("forged_writes_accepted",
                                           "reference_verdict_disagreements")]
 
-    # 5. valid updates refused; the sampled lanes' verdicts and owners by
-    #    the plain reference
-    refused = sum(1 for i in all_updates
-                  if not lg["ok"][i] and (lg["err"][i] or "").startswith("{"))
+    # 5. valid updates refused (one shed under load is a failed
+    #    operation, not a verdict); the sampled lanes' verdicts and owners
+    #    by the plain reference
+    refused = sum(1 for i in all_updates if said[i] == "refused")
     disagreements = 0
     window_acked = [i for i in acked if i >= k0]
     sample = rng.sample(window_acked, min(len(window_acked),
@@ -538,6 +597,7 @@ def judge_live(ctx, cfg, mix, addrs, lg, top, unanswered, status0, daemon,
         ("reads_differing_from_reference", reads_off, 0),
         ("reads_of_a_loaded_record_that_found_none", found_none, 0),
         ("valid_writes_refused", refused, 0),
+        ("shed_writes_in_chain", shed_in_chain, 0),
         ("forged_writes_accepted.resident", accepted["resident"], 0),
         ("forged_writes_accepted.evicted", accepted["evicted"], 0),
         ("forged_writes_accepted.never_seen", accepted["never_seen"], 0),
@@ -550,6 +610,11 @@ def judge_live(ctx, cfg, mix, addrs, lg, top, unanswered, status0, daemon,
         "reads_answered": len(window_reads),
         "forged_writes_accepted": sum(accepted.values()),
         "forged_pubkeys": forged_pubkeys,
+        "updates_by_answer": dict(Counter(said.values())),
+        "updates_committed_unacknowledged": len(late),
+        "updates_shed_by_log": dict(Counter(
+            json.loads(lg["err"][i])["check_tx"]["log"]
+            for i in all_updates if said[i] == "shed")),
     }
 
 

@@ -27,6 +27,13 @@ SMALL = {"config": {"burst_writes": 200,
                      "answer_after_close_s": 10.0, "readback_sample": 20}}
 
 
+BURST_METRICS = [
+    "daemon_compiles_in_window", "rounds_over_zero", "height_interval_ms_mean",
+    "height_propose_ms_p50", "daemon_lanes_per_call_mean", "committed_writes_per_s",
+    "block_txs_max", "sig_gate_lanes_per_batch_mean", "apply_verify_ms_p50",
+    "apply_app_ms_p50", "block_parts_ms_p50", "p2p_io_frames_per_wake"]
+
+
 def load(path):
     with open(path) as f:
         return json.load(f)
@@ -37,11 +44,10 @@ def test_the_cell_its_configuration_and_its_traffic():
     [cell] = [w for w in b["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "burst-signedkv", "writes-burst", 1)
-    assert b["workloads"][-1] == cell
-    assert b["configs"][-1]["name"] == "burst-signedkv"
-    cfg = load(os.path.join(ROOT, b["configs"][-1]["file"]))
+    [entry] = [c for c in b["configs"] if c["name"] == "burst-signedkv"]
+    cfg = load(os.path.join(ROOT, entry["file"]))
     assert cfg["deployment"] == "burst_net"
-    assert b["configs"][-1]["reduced"] == cfg["reduced"]
+    assert entry["reduced"] == cfg["reduced"]
     mix = load(os.path.join(BENCH, "traffic", "writes-burst.json"))
     steady = load(os.path.join(BENCH, "traffic", "writes-steady.json"))
     assert mix["lead_in_rate_per_s"] == steady["rate_per_s"]
@@ -51,15 +57,14 @@ def test_the_cell_its_configuration_and_its_traffic():
         <= cfg["upstream_limits"]["rpc_max_inflight"]
     for m in b["end_to_end"]:
         if m["name"].startswith("commit_latency"):
-            assert m["workloads"][-1] == CELL
-    # the one entry that fits under the 128 `per_layer` may hold; the
-    # cell's other readings are in its result line's notes
+            assert CELL in m["workloads"]
+    # every reading of the cell is an entry that lists it
     burst = [m for m in b["per_layer"] if CELL in m.get("workloads", [])]
-    assert [m["name"] for m in burst] == ["daemon_compiles_in_window.burst"]
-    assert b["per_layer"][-1:] == burst and len(b["per_layer"]) == 128
+    assert {m["name"] for m in burst} == set(BURST_METRICS)
+    assert 1 <= len(b["per_layer"]) <= 128
     for m in burst:
         spec = load(os.path.join(BENCH, "metrics", m["name"] + ".json"))
-        assert {k: spec[k] for k in m} == m
+        assert {k: spec[k] for k in m} == m and "by_workload" not in spec
 
 
 def run_cell(scale, seconds, control="", trace=0):
@@ -80,19 +85,23 @@ def run_cell(scale, seconds, control="", trace=0):
 
 
 def test_rehearsal_run_is_correct():
-    line, over = run_cell(SMALL, 12)
+    """Traced, so that the line carries the cell's per-layer metrics (a
+    burst of 200 makes no block of several parts: no `block_parts_ms_p50`)."""
+    line, over = run_cell(SMALL, 12, trace=1)
     assert line["correct"] is True and not over, over
     assert line["attempted"] == 188 and line["failed"] == 0
     notes = line["notes"]
     assert notes["forged_writes"] == 12 and notes["refused_valid_writes"] == {}
     assert notes["drain_s"] > 8 and sum(notes["block_txs"]) >= 188
-    assert len(notes["drain_s_by_part"]) == 3
-    r = notes["readings"]
-    assert r["sig_gate_lanes_per_batch_mean"] >= 1
+    assert len(notes["drain_s_by_part"]) == 3 and notes["traced_calls"] >= 1
+    r = {k: v["value"] for k, v in line["metrics"].items()}
+    assert set(BURST_METRICS) - set(r) == {"block_parts_ms_p50"}
+    assert r["sig_gate_lanes_per_batch_mean"] >= 1 and r["p2p_io_frames_per_wake"] > 0
     assert r["committed_writes_per_s"] > 0 and r["rounds_over_zero"] == 0
     assert r["apply_verify_ms_p50"] > 0 and r["apply_app_ms_p50"] > 0
+    assert r["block_txs_max"] == max(notes["block_txs"])
     assert {"commit_latency_p50_ms", "commit_latency_p95_ms", "setup_s"} <= set(
-        line["metrics"])
+        line["end_to_end_of_this_traced_run"])
     # the warm-up's wide batches rode the stream, one record a chunk
     from harness import artifacts
 

@@ -21,12 +21,12 @@ from harness import artifacts, trace_annotations
 from harness.observe import Observations
 
 DATA = os.path.join(BENCH, "tests", "data")
-NEW = [f"daemon_{p}_ms_p50.steady" for p in artifacts.PHASES] + [
-    "daemon_calls_in_flight_mean.steady", "daemon_verdict_lag_ms_p50.steady",
-    "node_verify_wait_ms_per_height_p50.steady",
-    "node_verify_ipc_ms_per_height_p50.steady",
-    "height_propose_ms_p50.steady", "height_votes_ms_p50.steady",
-    "height_commit_tail_ms_p50.steady"]
+NEW = [f"daemon_{p}_ms_p50" for p in artifacts.PHASES] + [
+    "daemon_calls_in_flight_mean", "daemon_verdict_lag_ms_p50",
+    "node_verify_wait_ms_per_height_p50",
+    "node_verify_ipc_ms_per_height_p50",
+    "height_propose_ms_p50", "height_votes_ms_p50",
+    "height_commit_tail_ms_p50"]
 
 
 def load(path):
@@ -71,7 +71,7 @@ def test_phase_percentiles_over_the_windows_eight_wide_calls(obs):
     total = 0.0
     for i, phase in enumerate(artifacts.PHASES):
         want = statistics.median((r[ends[i + 1]] - r[ends[i]]) / 1e6 for r in mine)
-        got = read(f"daemon_{phase}_ms_p50.steady", obs)
+        got = read(f"daemon_{phase}_ms_p50", obs)
         assert got == pytest.approx(want, rel=1e-9) and got > 0
         total += got
     whole = statistics.median((r["t_replied"] - r["t_recv0"]) / 1e6 for r in mine)
@@ -82,7 +82,7 @@ def test_in_flight_mean_over_the_windows_calls(obs):
     _head, recs = small_records()
     lo, hi = artifacts.window_ns(obs)
     mine = [r["in_flight_at_recv"] for r in recs if lo <= r["t_recv0"] < hi]
-    assert read("daemon_calls_in_flight_mean.steady", obs) == pytest.approx(
+    assert read("daemon_calls_in_flight_mean", obs) == pytest.approx(
         sum(mine) / len(mine))
 
 
@@ -92,9 +92,9 @@ def test_per_height_percentiles_from_the_stop_dump(obs):
     mine = [t for t in traces if lo <= t["started_at"] < lo + obs.window_s]
     assert 3 <= len(mine) < len(traces)
     want = statistics.median(1000 * t["aux"]["verify_wait_s"] for t in mine)
-    assert read("node_verify_wait_ms_per_height_p50.steady", obs) == pytest.approx(want)
-    assert read("node_verify_ipc_ms_per_height_p50.steady", obs) <= want
-    parts = [read(f"height_{k}_ms_p50.steady", obs)
+    assert read("node_verify_wait_ms_per_height_p50", obs) == pytest.approx(want)
+    assert read("node_verify_ipc_ms_per_height_p50", obs) <= want
+    parts = [read(f"height_{k}_ms_p50", obs)
              for k in ("propose", "votes", "commit_tail")]
     assert all(p >= 0 for p in parts) and parts[1] > 0
     # with new_height the three groups hold every segment: they partition
@@ -102,7 +102,7 @@ def test_per_height_percentiles_from_the_stop_dump(obs):
     names = set()
     for k in ("propose", "votes", "commit_tail"):
         names |= set(load(os.path.join(BENCH, "metrics",
-                                       f"height_{k}_ms_p50.steady.json"))["params"]["segments"])
+                                       f"height_{k}_ms_p50.json"))["params"]["segments"])
     from tendermint_tpu.consensus.trace import SEGMENTS
 
     assert names | {"new_height"} == set(SEGMENTS)
@@ -170,9 +170,9 @@ def test_verdict_lag_on_the_trace_recorded_on_the_chip():
 
 def test_verdict_lag_reader_reads_the_trace_once(obs):
     obs.trace["annotations"] = synthetic_trace()   # as read_annotations leaves it
-    assert read("daemon_verdict_lag_ms_p50.steady", obs) == pytest.approx(0.1)
+    assert read("daemon_verdict_lag_ms_p50", obs) == pytest.approx(0.1)
     obs.trace["annotations"] = {"annotations": [], "modules": [], "host_exec": []}
-    assert read("daemon_verdict_lag_ms_p50.steady", obs) is None
+    assert read("daemon_verdict_lag_ms_p50", obs) is None
 
 
 def test_a_cpu_trace_takes_the_executor_threads_for_the_device():
@@ -212,10 +212,10 @@ def test_a_missing_file_raises_with_the_path_it_looked_for(obs):
     run = artifacts.run_dir(obs)
     os.remove(os.path.join(run, "devd.spans.jsonl"))
     with pytest.raises(FileNotFoundError, match="devd.spans.jsonl"):
-        read("daemon_marshal_ms_p50.steady", obs)
+        read("daemon_marshal_ms_p50", obs)
     shutil.rmtree(os.path.join(run, "node0", "flightrec"))
     with pytest.raises(FileNotFoundError, match="flightrec"):
-        read("height_votes_ms_p50.steady", obs)
+        read("height_votes_ms_p50", obs)
 
 
 def test_the_sockets_fallback_directory_is_read_from_the_daemons_log(obs, tmp_path):
@@ -227,7 +227,7 @@ def test_the_sockets_fallback_directory_is_read_from_the_daemons_log(obs, tmp_pa
         f.write("2026-10-01 06:00:00,000 devd INFO devd listening on "
                 f"{far}/devd.sock (pid 7)\n")
     assert artifacts.spans_path(run) == str(far / "devd.spans.jsonl")
-    assert read("daemon_reply_ms_p50.steady", obs) > 0
+    assert read("daemon_reply_ms_p50", obs) > 0
 
 
 def test_a_wrapped_ring_is_refused(obs):
@@ -238,7 +238,7 @@ def test_a_wrapped_ring_is_refused(obs):
     with open(path, "w") as f:
         f.write("\n".join([json.dumps(head)] + lines[1:]) + "\n")
     with pytest.raises(RuntimeError, match="wrapped"):
-        read("daemon_decode_ms_p50.steady", obs)
+        read("daemon_decode_ms_p50", obs)
 
 
 def test_a_program_from_before_the_records_yields_nothing(obs, monkeypatch):
@@ -254,12 +254,12 @@ def test_a_program_from_before_the_records_yields_nothing(obs, monkeypatch):
 def test_every_new_metric_file_names_a_reader_and_an_entry():
     bench = load(os.path.join(ROOT, "BENCHMARK.json"))
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"]][-len(NEW):] == NEW
     for name in NEW:
         spec = load(os.path.join(BENCH, "metrics", name + ".json"))
         entry = entries[name]
         assert {k: spec[k] for k in entry} == entry
-        assert entry["workloads"] == ["net4.steady"] and entry["better"] == "lower"
+        assert "net4.steady" in entry["workloads"] and entry["better"] == "lower"
+        assert "by_workload" not in spec
         assert os.path.exists(os.path.join(BENCH, "readers", spec["reader"] + ".py"))
         assert hasattr(importlib.import_module("readers." + spec["reader"]), "read")
     readers = {load(os.path.join(BENCH, "metrics", n + ".json"))["reader"] for n in NEW}
@@ -291,8 +291,8 @@ def test_a_traced_rehearsal_prints_every_new_metric_with_a_value():
     got = {k: v["value"] for k, v in line["metrics"].items()}
     assert [m for m in NEW if m not in got] == []
     assert all(isinstance(got[m], float) and got[m] >= 0 for m in NEW)
-    phases = sum(got[f"daemon_{p}_ms_p50.steady"] for p in artifacts.PHASES)
-    assert phases > 0 and got["height_votes_ms_p50.steady"] > 0
+    phases = sum(got[f"daemon_{p}_ms_p50"] for p in artifacts.PHASES)
+    assert phases > 0 and got["height_votes_ms_p50"] > 0
     # the daemon's ring and node 0's stop dump are where the issue says
     run_dir = os.path.join(ROOT, ".perfbench_run", "net4.steady")
     assert os.path.exists(os.path.join(run_dir, "devd.spans.jsonl"))

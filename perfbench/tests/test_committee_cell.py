@@ -28,10 +28,9 @@ SMALL = {"config": {"validators": 7,
                                "warm_buckets": [8, 16, 32, 64], "warm_passes": 1}},
          "traffic": {"rate_per_s": 5, "signers": 6, "lead_in_s": 1.0,
                      "readback_sample": 12, "forged_writes": 4}}
-NEW_COUNTERS = ["daemon_lanes_per_call_mean.committee",
-                "commit_verify_ms_per_height_p50.committee",
-                "node_cpu_ms_per_height_p50.committee",
-                "fleet_cpu_share.committee", "votes_batched_share.committee"]
+NEW_COUNTERS = ["daemon_lanes_per_call_mean", "commit_verify_ms_per_height_p50",
+                "node_cpu_ms_per_height_p50", "fleet_cpu_share",
+                "votes_batched_share"]
 
 
 def load(path):
@@ -51,11 +50,11 @@ def read(metric, obs):
 def test_the_cell_its_configuration_and_its_traffic():
     b = load(os.path.join(ROOT, "BENCHMARK.json"))
     cell = [w for w in b["workloads"] if w["name"] == CELL]
-    assert cell == [b["workloads"][-1]] and cell[0]["chips"] == 1
+    assert len(cell) == 1 and cell[0]["chips"] == 1
     assert (cell[0]["config"], cell[0]["traffic"]) == ("committee-signedkv",
                                                        "writes-committee")
-    entry = b["configs"][-1]
-    assert entry["name"] == "committee-signedkv" and entry["reduced"] == ["validators"]
+    [entry] = [c for c in b["configs"] if c["name"] == "committee-signedkv"]
+    assert entry["reduced"] == ["validators"]
     cfg = load(os.path.join(ROOT, entry["file"]))
     net4 = load(os.path.join(BENCH, "configs", "net4-signedkv.json"))
     # nothing but the committee differs from the deployment in the benchmark:
@@ -75,30 +74,32 @@ def test_the_cell_its_configuration_and_its_traffic():
         assert mix[key] == steady[key], key
     for m in b["end_to_end"]:
         if m["name"].startswith("commit_latency"):
-            assert m["workloads"] == ["net4.steady", CELL]
+            assert {"net4.steady", CELL} <= set(m["workloads"])
 
 
 def test_every_committee_metric_has_its_entry_its_file_and_its_reader():
+    import run as bench_run
+
     b = load(os.path.join(ROOT, "BENCHMARK.json"))
-    mine = [m for m in b["per_layer"] if m["name"].endswith(".committee")]
-    assert len(mine) == 22 and b["per_layer"][-22:] == mine
-    layers = {m["layer"] for m in b["per_layer"] if not m["name"].endswith(".committee")}
+    mine = [m for m in b["per_layer"] if CELL in m["workloads"]]
+    # the cell's metrics are those it read before the fold, and the
+    # frames per wake of its nodes' I/O loops
+    fold = load(os.path.join(BENCH, "tests", "data", "per_layer_fold.json"))
+    before = {r["new"] for r in fold["entries"] if r["cell"] == CELL}
+    assert len(before) == 29
+    assert {m["name"] for m in mine} == before | {"p2p_io_frames_per_wake"}
     for m in mine:
-        assert m["workloads"] == [CELL] and m["layer"] in layers
         assert m["moves"] in ("commit_latency_p50_ms", "commit_latency_p95_ms")
         spec = load(os.path.join(BENCH, "metrics", m["name"] + ".json"))
         assert {k: spec[k] for k in m} == m
-        assert hasattr(importlib.import_module("readers." + spec["reader"]), "read")
-        twin = os.path.join(BENCH, "metrics",
-                            m["name"].replace(".committee", ".steady") + ".json")
-        if os.path.exists(twin):          # same reader, same way of reading
-            other = load(twin)
-            assert other["reader"] == spec["reader"]
-            assert {k: v for k, v in other["params"].items() if k != "width"} == \
-                {k: v for k, v in spec["params"].items() if k != "width"}
+        reader, _params = bench_run.metric_reader(spec, CELL)
+        assert hasattr(importlib.import_module("readers." + reader), "read")
+        if "net4.steady" in m["workloads"]:   # same reader, same way of reading
+            assert bench_run.metric_reader(spec, "net4.steady") == \
+                bench_run.metric_reader(spec, CELL)
     assert {m["name"] for m in mine} >= set(NEW_COUNTERS)
     # the three daemon phases are read at one width, which the files state
-    widths = {load(os.path.join(BENCH, "metrics", f"daemon_{p}_ms_p50.committee.json"))
+    widths = {load(os.path.join(BENCH, "metrics", f"daemon_{p}_ms_p50.json"))
               ["params"]["width"] for p in ("marshal", "dispatch", "device_wait")}
     assert len(widths) == 1 and widths <= {8, 16, 32, 64, 128, 256}
 
@@ -155,13 +156,13 @@ def test_new_readers_on_files_whose_answers_are_known(tmp_path):
                record(4, 16, 2, 3, 36), record(5, 7, 5, 1, 7),
                record(6, 200, 6, 1, 200, at=20.0)]               # past the window
     obs = make_run(tmp_path, nodes, records)
-    assert read("node_cpu_ms_per_height_p50.committee", obs) == pytest.approx(300.0)
-    assert read("commit_verify_ms_per_height_p50.committee", obs) == pytest.approx(40.0)
+    assert read("node_cpu_ms_per_height_p50", obs) == pytest.approx(300.0)
+    assert read("commit_verify_ms_per_height_p50", obs) == pytest.approx(40.0)
     # (0.2 + 0.4 + 0.3 + 1.0) core-seconds of 13 cores x 10 s
-    assert read("fleet_cpu_share.committee", obs) == pytest.approx(100 * 1.9 / 130)
-    assert read("votes_batched_share.committee", obs) == pytest.approx(100 * 120 / 200)
+    assert read("fleet_cpu_share", obs) == pytest.approx(100 * 1.9 / 130)
+    assert read("votes_batched_share", obs) == pytest.approx(100 * 120 / 200)
     # three programs in the window: 1, 36 and 7 lanes
-    assert read("daemon_lanes_per_call_mean.committee", obs) == pytest.approx(44 / 3)
+    assert read("daemon_lanes_per_call_mean", obs) == pytest.approx(44 / 3)
 
 
 def test_new_readers_read_nothing_from_a_program_without_the_counters(tmp_path):
@@ -221,9 +222,9 @@ def test_traced_rehearsal_prints_the_metrics_of_the_new_counters():
     line, _over = run_cell(SMALL, 8, trace=1)
     for metric in NEW_COUNTERS:
         assert metric in line["metrics"], metric
-    assert line["metrics"]["daemon_lanes_per_call_mean.committee"]["value"] > 1.0
-    assert 0 < line["metrics"]["fleet_cpu_share.committee"]["value"]
-    assert 0 <= line["metrics"]["votes_batched_share.committee"]["value"] <= 100
+    assert line["metrics"]["daemon_lanes_per_call_mean"]["value"] > 1.0
+    assert 0 < line["metrics"]["fleet_cpu_share"]["value"]
+    assert 0 <= line["metrics"]["votes_batched_share"]["value"] <= 100
 
 
 def test_commit_with_votes_removed_under_quorum_is_caught(monkeypatch):

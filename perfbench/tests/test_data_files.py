@@ -22,6 +22,12 @@ def bench():
     return load(os.path.join(ROOT, "BENCHMARK.json"))
 
 
+def bench_run():
+    import run
+
+    return run
+
+
 def test_benchmark_json_shape():
     b = bench()
     assert set(b) == {"command", "paths", "run_seconds", "configs", "workloads",
@@ -116,3 +122,92 @@ def test_net4_config_carries_the_upstream_timeouts():
     shipped = ConsensusConfig()
     for k, v in cfg["consensus"].items():
         assert getattr(shipped, k) == v, k
+
+
+def test_one_entry_a_metric_over_the_cells_that_read_it():
+    b = bench()
+    cells = {w["name"] for w in b["workloads"]}
+    assert 1 <= len(b["per_layer"]) <= 60
+    seen = set()
+    for m in b["per_layer"]:
+        # no cell's suffix: the cells are the entry's `workloads`
+        assert "." not in m["name"] and m["workloads"]
+        assert set(m["workloads"]) <= cells and \
+            len(set(m["workloads"])) == len(m["workloads"])
+        spec = load(os.path.join(BENCH, "metrics", m["name"] + ".json"))
+        assert set(spec.get("by_workload", {})) <= set(m["workloads"])
+        for cell in m["workloads"]:
+            reader, params = bench_run().metric_reader(spec, cell)
+            assert hasattr(importlib.import_module("readers." + reader), "read")
+            key = (m["name"], reader, json.dumps(params, sort_keys=True))
+            seen.add(key)
+    # no two entries share a name, a reader and its parameters
+    assert len({k[0] for k in seen}) == len(b["per_layer"])
+
+
+def test_the_fold_leaves_every_cell_reading_what_it_read():
+    """perfbench/tests/data/per_layer_fold.json: the 128 entries of one
+    cell each that the benchmark had, and the name each is read under
+    now. Every cell reads exactly the reader and parameters it read
+    before."""
+    b = bench()
+    fold = load(os.path.join(BENCH, "tests", "data", "per_layer_fold.json"))["entries"]
+    assert len(fold) == 128 == len({r["old"] for r in fold})
+    entries = {m["name"]: m for m in b["per_layer"]}
+    e2e = {m["name"]: m for m in b["end_to_end"]}
+    for r in fold:
+        assert r["old"].startswith(r["new"] + ".") or r["as"] == "end_to_end"
+        if r["as"] == "end_to_end":
+            # the p50 of the window's reads, now computed by the scenario
+            assert r["cell"] in e2e[r["new"]]["workloads"]
+            assert (r["reader"], r["params"]) == (
+                "percentile", {"q": 50, "series": "read_latency_ms"})
+            continue
+        m = entries[r["new"]]
+        assert r["cell"] in m["workloads"], r["old"]
+        spec = load(os.path.join(BENCH, "metrics", r["new"] + ".json"))
+        assert bench_run().metric_reader(spec, r["cell"]) == (r["reader"], r["params"])
+    # and nothing else changed name: every (cell, metric) pair of the fold
+    # is one of the benchmark's, the new entries apart
+    old_pairs = {(r["new"], r["cell"]) for r in fold if r["as"] == "per_layer"}
+    new_pairs = {(n, c) for n, m in entries.items() for c in m["workloads"]}
+    assert old_pairs <= new_pairs
+
+
+def test_per_layer_metrics_takes_a_cells_own_reader(monkeypatch):
+    """`device_idle_share` and the two `verify_kernel_*` metrics read
+    `ycsb-a.steady` by a reader of its own (`by_workload`), every other
+    cell by the metric's."""
+    run = bench_run()
+    b = bench()
+    picked = []
+
+    def fake(name):
+        def read(obs, params, device):
+            picked.append((name, params))
+            return 1.0
+        return read
+
+    names = ("window_idle_share", "pool_window_idle_share", "trace_kernel_rate",
+             "trace_kernel_roofline", "trace_comb_lanes")
+    for name in names:
+        monkeypatch.setattr(importlib.import_module("readers." + name), "read",
+                            fake(name))
+    mine = {"device_idle_share", "verify_kernel_sigs_per_s",
+            "verify_kernel_roofline_share"}
+    only = {**b, "per_layer": [m for m in b["per_layer"] if m["name"] in mine]}
+    assert len(only["per_layer"]) == 3
+    out = run.per_layer_metrics(only, "ycsb-a.steady", None, {})
+    assert set(out) == mine
+    assert sorted(picked, key=str) == sorted([
+        ("pool_window_idle_share", {"comb": "_verify_comb_impl",
+                                    "build": "_build_tables_impl",
+                                    "update": "_update_pool_impl",
+                                    "ladder": "jit__verify_impl"}),
+        ("trace_comb_lanes", {"as": "rate", "kernel": "_verify_comb_impl"}),
+        ("trace_comb_lanes", {"as": "roofline_share", "kernel": "_verify_comb_impl"}),
+    ], key=str)
+    picked.clear()
+    run.per_layer_metrics(only, "net4.steady", None, {})
+    assert sorted(n for n, _p in picked) == [
+        "trace_kernel_rate", "trace_kernel_roofline", "window_idle_share"]

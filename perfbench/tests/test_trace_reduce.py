@@ -51,15 +51,26 @@ def test_extract_reduces_a_real_xplane_file(tmp_path):
     """Stage 1 on the small .xplane.pb recorded on the chip (one traced
     stretch of the daemon under the benchmark's launcher: two 8-lane
     batches, 44,310 device events), and stage 2 on what it gives: the same
-    numbers as the recorded extraction beside it."""
+    numbers as the recorded extraction beside it. Stage 1 runs as the
+    harness runs it, a script in a process of its own: this one stays off
+    JAX, as a harness process must (`procs.no_jax_here`)."""
     import gzip
     import shutil
+    import subprocess
+    import sys
 
-    path = str(tmp_path / "trace_small.xplane.pb")
+    tdir = tmp_path / "trace" / "plugins" / "profile" / "1"
+    tdir.mkdir(parents=True)
     with gzip.open(os.path.join(HERE, "data", "trace_small.xplane.pb.gz"), "rb") as f, \
-            open(path, "wb") as g:
+            open(tdir / "trace_small.xplane.pb", "wb") as g:
         shutil.copyfileobj(f, g)
-    ex = trace_reduce.extract(path)
+    out = tmp_path / "extracted.json"
+    r = subprocess.run([sys.executable, trace_reduce.__file__, str(tmp_path / "trace"),
+                        str(out)], env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True, timeout=200)
+    assert r.returncode == 0, r.stderr[-2000:]
+    with open(out) as f:
+        ex = json.load(f)
     with open(os.path.join(HERE, "data", "trace_small.json")) as f:
         rec = json.load(f)
     assert ex["window"] == rec["extracted"]["window"]

@@ -111,12 +111,12 @@ def test_nothing_from_a_program_without_tx_traces(tmp_path):
 
 
 def test_every_piece_has_a_metric_in_every_cell():
-    names = {m["name"] for m in json.load(
+    entries = {m["name"]: m for m in json.load(
         open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")))["per_layer"]}
     for p in road.PIECES:
-        for cell in ("steady", "committee", "wan", "ycsb"):
-            name = f"tx_{p}_ms_p50.{cell}"
-            assert name in names
-            spec = json.load(open(os.path.join(BENCH, "metrics", name + ".json")))
-            assert spec["reader"] == "tx_road_percentile"
-            assert spec["params"] == {"piece": p, "q": 50}
+        name = f"tx_{p}_ms_p50"
+        assert entries[name]["workloads"] == ["net4.steady", "committee.steady",
+                                              "committee-wan.steady", "ycsb-a.steady"]
+        spec = json.load(open(os.path.join(BENCH, "metrics", name + ".json")))
+        assert spec["reader"] == "tx_road_percentile" and "by_workload" not in spec
+        assert spec["params"] == {"piece": p, "q": 50}

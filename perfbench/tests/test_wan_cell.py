@@ -1,6 +1,6 @@
 """`committee-wan.steady` (PR 32): its entries and data files held
 against `committee.steady`'s (everything but the links is equal), the
-table of round trips, the `.wan` twins of the `.committee` metrics, the
+table of round trips, the metrics it shares with `committee.steady`, the
 three new readers on hand-made files whose answers are known, the three
 new comparisons on a faulted input (a link with the delay off must read
 over its limit), and the whole cell in rehearsal at 7 validators in 7
@@ -31,8 +31,8 @@ WAN_KEYS = {"name", "deployment", "source", "why", "validators_published",
             "regions_published", "regions", "region_of_validator", "rtt_ms",
             "transport", "injected_message_delay_ms", "guarantees", "chip_mapping",
             "assumed", "reduced_note"}
-NEW = ["height_over_floor_ms_p50.wan", "link_rtt_over_configured_ms_p50.wan",
-       "link_delay_late_ms_p95.wan", "vote_duplicates_per_accepted.wan"]
+NEW = ["height_over_floor_ms_p50", "link_rtt_over_configured_ms_p50",
+       "link_delay_late_ms_p95", "vote_duplicates_per_accepted"]
 SMALL = {"config": {"validators": 7,
                     "daemon": {"env": {"TENDERMINT_DEVD_KERNEL": "comb",
                                        "TENDERMINT_DEVD_WARM": "",
@@ -68,8 +68,7 @@ def test_the_cell_its_configuration_and_its_traffic():
         assert word in entry["source"], word
     for m in b["end_to_end"]:
         if m["name"].startswith("commit_latency"):
-            assert m["workloads"][-1] == CELL
-            assert m["workloads"][:2] == ["net4.steady", "committee.steady"]
+            assert m["workloads"][:3] == ["net4.steady", "committee.steady", CELL]
 
 
 def test_the_configuration_is_the_committees_but_for_the_links():
@@ -130,37 +129,32 @@ def test_the_table_is_symmetric_complete_and_nearly_metric():
 
 
 def test_every_wan_metric_has_its_entry_its_file_and_its_twin():
+    import run as bench_run
+
     b = load(os.path.join(ROOT, "BENCHMARK.json"))
-    mine = [m for m in b["per_layer"] if m["name"].endswith(".wan")]
-    names = [m["name"] for m in b["per_layer"]]
-    first = names.index(mine[0]["name"])
-    # one contiguous block, after the `.committee` block (a later PR's
-    # entries go after it: nothing here asserts that this block is the last)
-    assert len(mine) == 26 and b["per_layer"][first:first + 26] == mine
-    assert first > max(i for i, n in enumerate(names) if n.endswith(".committee"))
-    committee = [m["name"] for m in b["per_layer"] if m["name"].endswith(".committee")]
-    assert [m["name"] for m in mine[:22]] == \
-        [n.replace(".committee", ".wan") for n in committee]
-    assert [m["name"] for m in mine[22:]] == NEW
-    layers = {m["layer"] for m in b["per_layer"] if not m["name"].endswith(".wan")}
+    mine = [m for m in b["per_layer"] if CELL in m["workloads"]]
+    committee = [m for m in b["per_layer"] if "committee.steady" in m["workloads"]]
+    # every metric of `committee.steady` is read here too, by the same
+    # entry (its twin, with its reader and parameters), and four of its own
+    assert [m for m in mine if m["name"] not in NEW] == committee
+    assert {m["name"] for m in mine} - {m["name"] for m in committee} == set(NEW)
+    fold = load(os.path.join(BENCH, "tests", "data", "per_layer_fold.json"))
+    before = {r["new"] for r in fold["entries"] if r["cell"] == CELL}
+    assert len(before) == 33 and before <= {m["name"] for m in mine}
     for m in mine:
-        assert m["workloads"] == [CELL]
         assert m["moves"] in ("commit_latency_p50_ms", "commit_latency_p95_ms")
         spec = load(os.path.join(BENCH, "metrics", m["name"] + ".json"))
         assert {k: spec[k] for k in m} == m
-        assert hasattr(importlib.import_module("readers." + spec["reader"]), "read")
-        twin = os.path.join(BENCH, "metrics",
-                            m["name"].replace(".wan", ".committee") + ".json")
+        reader, _params = bench_run.metric_reader(spec, CELL)
+        assert hasattr(importlib.import_module("readers." + reader), "read")
         if m["name"] in NEW:
-            assert not os.path.exists(twin)
+            assert m["workloads"] == [CELL]
             continue
-        other = load(twin)                     # same reader, same parameters
-        assert m["layer"] in layers
-        for key in ("unit", "better", "source", "layer", "moves", "reader"):
-            assert spec[key] == other[key], (m["name"], key)
-        assert spec.get("params") == other.get("params"), m["name"]
-    assert {m["layer"] for m in mine[22:]} == {"consensus", "p2p links", "vote plane"}
-    for name in ("verify_kernel_roofline_share.wan", "device_idle_share.wan"):
+        assert bench_run.metric_reader(spec, "committee.steady") == \
+            bench_run.metric_reader(spec, CELL)
+    assert {m["layer"] for m in mine if m["name"] in NEW} == \
+        {"consensus", "p2p links", "vote plane"}
+    for name in ("verify_kernel_roofline_share", "device_idle_share"):
         assert name in {m["name"] for m in mine}
 
 
@@ -201,14 +195,14 @@ def test_new_readers_on_files_whose_answers_are_known(tmp_path):
     counters = [{"vote_duplicates": 10, "vote_accepted": 100},
                 {"vote_duplicates": 30, "vote_accepted": 100}]
     obs = make_run(tmp_path, links, heights, counters)
-    assert read("height_over_floor_ms_p50.wan", obs) == pytest.approx(200.0)
-    assert read("link_rtt_over_configured_ms_p50.wan", obs) == pytest.approx(3.0)
+    assert read("height_over_floor_ms_p50", obs) == pytest.approx(200.0)
+    assert read("link_rtt_over_configured_ms_p50", obs) == pytest.approx(3.0)
     # 20 frames: ten under 0.1 ms, eight in 0.1-1 ms, one in 1-10 ms, one above
     # 10 ms. The 19th of them (rank 19.0) closes the 1-10 ms bucket
-    assert read("link_delay_late_ms_p95.wan", obs) == pytest.approx(10.0)
-    assert read("vote_duplicates_per_accepted.wan", obs) == pytest.approx(0.2)
+    assert read("link_delay_late_ms_p95", obs) == pytest.approx(10.0)
+    assert read("vote_duplicates_per_accepted", obs) == pytest.approx(0.2)
     # the open bucket is bounded by the largest value any link saw
-    spec = load(os.path.join(BENCH, "metrics", "link_delay_late_ms_p95.wan.json"))
+    spec = load(os.path.join(BENCH, "metrics", "link_delay_late_ms_p95.json"))
     reader = importlib.import_module("readers." + spec["reader"])
     assert reader.read(obs, {**spec["params"], "q": 100}, {}) == pytest.approx(50.0)
     assert reader.read(obs, {**spec["params"], "q": 25}, {}) == pytest.approx(0.05)
@@ -222,6 +216,18 @@ def test_new_readers_read_nothing_from_a_run_the_judge_left_nothing_of(tmp_path)
     obs = make_run(tmp_path / "empty", links=[], heights=[])
     for metric in NEW[:3]:
         assert read(metric, obs) is None, metric
+
+
+def test_frames_per_wake_sums_its_two_numerators_over_the_fleet(tmp_path):
+    """`p2p_io_frames_per_wake` (every cell): the fleet's frames read and
+    written over its I/O loops' wake-ups."""
+    counters = [{"p2p_io_frames_in": 30, "p2p_io_frames_out": 20, "p2p_io_wakes": 10},
+                {"p2p_io_frames_in": 10, "p2p_io_frames_out": 0, "p2p_io_wakes": 10}]
+    obs = make_run(tmp_path, counters=counters)
+    assert read("p2p_io_frames_per_wake", obs) == pytest.approx(60 / 20)
+    # a program from before the loop's counters
+    obs = make_run(tmp_path / "old", counters=[{"p2p_io_wakes": 3}])
+    assert read("p2p_io_frames_per_wake", obs) is None
 
 
 # -- the new comparisons on a faulted input ---------------------------------------
@@ -330,14 +336,14 @@ def test_seven_validators_in_seven_regions_are_correct_by_all_fifteen_comparison
     assert notes["links_judged"] == 42 and notes["heights_judged_against_floor"] >= 3
     assert min(notes["height_over_floor_ms"]) >= -wan_judge.STAMP_TOLERANCE_MS
     assert notes["waited_for_rtt_samples_s"] < 5.0
-    for metric in NEW + ["height_votes_ms_p50.wan", "fleet_cpu_share.wan",
-                         "rounds_over_zero.wan"]:
+    for metric in NEW + ["height_votes_ms_p50", "fleet_cpu_share",
+                         "rounds_over_zero"]:
         assert metric in line["metrics"], metric
-    assert line["metrics"]["rounds_over_zero.wan"]["value"] == 0
-    assert line["metrics"]["link_rtt_over_configured_ms_p50.wan"]["value"] > 0
-    assert line["metrics"]["height_over_floor_ms_p50.wan"]["value"] > 0
+    assert line["metrics"]["rounds_over_zero"]["value"] == 0
+    assert line["metrics"]["link_rtt_over_configured_ms_p50"]["value"] > 0
+    assert line["metrics"]["height_over_floor_ms_p50"]["value"] > 0
     # a vote round waits for the net: the links' legs are in the height
-    assert line["metrics"]["height_interval_ms_mean.wan"]["value"] > 1150.0
+    assert line["metrics"]["height_interval_ms_mean"]["value"] > 1150.0
 
 
 def test_a_real_node_whose_line_delays_too_little_reads_over_its_limit():

@@ -31,8 +31,13 @@ SMALL = {"config": {"recordcount": 1024, "load": {"txs_per_block": 64},
 POOL_METRICS = [
     "pool_miss_lane_share", "table_builds_per_s", "pool_evictions_per_s",
     "pool_resident_share", "table_build_ms_p50", "pool_update_ms_p50",
-    "read_latency_ms_p50", "read_latency_ms_p95", "build_kernel_roofline_share",
+    "read_latency_ms_p95", "build_kernel_roofline_share",
     "update_kernel_roofline_share", "ladder_kernel_roofline_share"]
+# read by this cell and by `burst.drain` alone
+APPLY_METRICS = ["apply_verify_ms_p50", "apply_app_ms_p50", "block_parts_ms_p50"]
+# read by a reader of this cell's own
+OWN_READER = {"device_idle_share", "verify_kernel_sigs_per_s",
+              "verify_kernel_roofline_share"}
 
 
 def load(path):
@@ -91,31 +96,39 @@ def test_the_cell_its_configuration_and_its_traffic():
     assert mix["rate_per_s"] == 0.8 * mix["sweep"]["knee_ops_per_s"]
     for m in b["end_to_end"]:
         if m["name"].startswith("commit_latency"):
-            assert m["workloads"][-1] == CELL
+            assert CELL in m["workloads"]
+    [read] = [m for m in b["end_to_end"] if m["name"] == "read_latency_p50_ms"]
+    assert read["workloads"] == [CELL] and read["unit"] == "ms"
+    assert (read["better"], read["source"]) == ("lower", "host_clock")
 
 
 def test_every_ycsb_metric_has_its_entry_its_file_and_its_reader():
     import importlib
 
+    import run as bench_run
+
     b = load(os.path.join(ROOT, "BENCHMARK.json"))
-    names = [m["name"] for m in b["per_layer"]]
-    block = [n for n in names if n.endswith(".ycsb")]
-    # one contiguous block after the last .wan entry
-    first = names.index(block[0])
-    assert names[first:first + len(block)] == block
-    assert first == 1 + max(i for i, n in enumerate(names) if n.endswith(".wan"))
-    assert block[:len(POOL_METRICS)] == [n + ".ycsb" for n in POOL_METRICS]
-    twins = {n[:-len(".steady")] for n in names if n.endswith(".steady")}
-    assert {n[:-len(".ycsb")] for n in block[len(POOL_METRICS):]} == twins
-    for m in b["per_layer"]:
-        if not m["name"].endswith(".ycsb"):
-            continue
-        assert m["workloads"] == [CELL]
-        spec = load(os.path.join(BENCH, "metrics", m["name"] + ".json"))
+    mine = {m["name"]: m for m in b["per_layer"] if CELL in m["workloads"]}
+    net4 = {m["name"] for m in b["per_layer"] if "net4.steady" in m["workloads"]}
+    # the pool's metrics, every metric of `net4.steady` (its twin), and the
+    # apply's stamps and the block's parts; the reads' median is end to end
+    assert set(mine) == set(POOL_METRICS) | net4 | set(APPLY_METRICS)
+    fold = load(os.path.join(BENCH, "tests", "data", "per_layer_fold.json"))
+    before = {r["new"]: r["as"] for r in fold["entries"] if r["cell"] == CELL}
+    assert len(before) == 38 and before.pop("read_latency_p50_ms") == "end_to_end"
+    assert set(before) <= set(mine)
+    for name, m in mine.items():
+        spec = load(os.path.join(BENCH, "metrics", name + ".json"))
         assert {k: spec[k] for k in m} == m
-        assert hasattr(importlib.import_module("readers." + spec["reader"]), "read")
-        if "roofline" in m["name"]:
+        reader, _params = bench_run.metric_reader(spec, CELL)
+        assert hasattr(importlib.import_module("readers." + reader), "read")
+        if name in net4:
+            own = bench_run.metric_reader(spec, "net4.steady") != (reader, _params)
+            assert own == (name in OWN_READER), name
+        if "roofline" in name:
             assert m["unit"] == "%" and m["source"] == "device_trace"
+    for name in APPLY_METRICS:
+        assert mine[name]["workloads"] == [CELL, "burst.drain"]
 
 
 # -- the generator, the references, the costs ---------------------------------------
@@ -165,6 +178,31 @@ def test_the_values_a_read_may_return():
     # node 2 acknowledged nothing: it may still hold the loaded value
     assert may(hist, writes, 2, 2.5, 3.5) == {0, 4, 11, 20}
     assert may(hist, writes, 0, 4.5, 4.6) == {20}
+
+
+def test_what_an_updates_answer_says():
+    from scenarios.ycsb_net import answer_kind
+
+    def answer(code, log, deliver=None):
+        return json.dumps({"check_tx": {"code": code, "log": log},
+                           "deliver_tx": {"code": deliver, "log": ""}})
+
+    assert answer_kind(True, "") == "acked"
+    # turned away under load: a failed operation, not a verdict
+    assert answer_kind(False, answer(6, "mempool_shed_writes:default")) == "shed"
+    assert answer_kind(False, answer(6, "mempool_lane_full:default")) == "shed"
+    assert answer_kind(False, answer(3, "signature gate saturated; retry")) == "shed"
+    # a verdict on the write itself
+    assert answer_kind(False, answer(3, "invalid signature (batch pre-verify)")) \
+        == "refused"
+    assert answer_kind(False, answer(3, "invalid signature")) == "refused"
+    assert answer_kind(False, answer(6, "invalid signature")) == "refused"
+    assert answer_kind(False, answer(0, "", deliver=3)) == "refused"
+    # no verdict at all: the chain says what became of the write
+    assert answer_kind(False, "timed out waiting for CheckTx") == "open"
+    assert answer_kind(False, "shed:inflight_cap") == "open"
+    assert answer_kind(False, "TimeoutError: ") == "open"
+    assert answer_kind(False, None) == "open"
 
 
 def test_the_pool_model_on_a_hand_made_log():
@@ -306,8 +344,10 @@ def test_rehearsal_run_is_correct():
         "lanes_first_sight", "builds", "evictions", "ladders")) > 0
     # one bucket; a pool of under 128 slots builds at its own size
     assert set(notes["miss_programs_s"]) == {"build_32", "update_32", "ladder_32"}
-    assert {"commit_latency_p50_ms", "commit_latency_p95_ms", "setup_s"} <= set(
-        line["metrics"])
+    assert {"commit_latency_p50_ms", "commit_latency_p95_ms", "read_latency_p50_ms",
+            "setup_s"} <= set(line["metrics"])
+    assert line["metrics"]["read_latency_p50_ms"]["value"] == \
+        line["notes"]["read_latency_ms"]["p50"] > 0
 
 
 def test_control_verifier_that_skips_verification():
