@@ -38,6 +38,7 @@ from tendermint_tpu.abci.types import (
     ResponseQuery,
 )
 from tendermint_tpu.libs.envknob import env_number
+from tendermint_tpu.libs.txtrace import stamp_read
 from tendermint_tpu.statetree import VersionedTree
 from tendermint_tpu.statetree.tree import TreeError
 
@@ -253,6 +254,7 @@ class KVStoreApp(Application):
             )
         try:
             proof = self.tree.prove(data, version)
+            stamp_read("proved")
         except TreeError as exc:
             return ResponseQuery(
                 code=CODE_UNAUTHORIZED, key=data, height=version,

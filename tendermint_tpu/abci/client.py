@@ -28,6 +28,7 @@ from tendermint_tpu.abci.types import (
     ResponseQuery,
 )
 from tendermint_tpu.libs.service import BaseService
+from tendermint_tpu.libs.txtrace import stamp_read
 
 
 class ReqRes:
@@ -181,6 +182,7 @@ class LocalClient(ABCIClient):
 
     def query_sync(self, data: bytes, path: str = "", height: int = 0, prove: bool = False) -> ResponseQuery:
         with self._app_mtx:
+            stamp_read("app_lock")
             return self.app.query(data, path, height, prove)
 
     def flush_sync(self) -> None:

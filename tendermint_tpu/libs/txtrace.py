@@ -58,10 +58,27 @@ the spans through block_commit sum EXACTLY to its commit latency.
 Served by the ``tx_trace`` RPC (completed ring + in-flight actives), the
 ``python -m tendermint_tpu.ops.txtrace`` cross-node CLI, and the flight
 recorder's stop dump (``tx_traces``), which the benchmark reads.
+
+A read's road: an ``abci_query`` from the RPC port to its reply,
+keyed by its JSON-RPC ``id``, stamped on the thread that serves it —
+
+    rpc_ingress     the handler has the request (its id parsed)
+    app_lock        the proxy's app mutex is held (abci/client.py)
+    proved          the value and its TreeProof are in hand: the tree's
+                    lock and the walk are done (abci/apps/kvstore.py)
+    rpc_reply       the reply is written
+
+A read is sampled by the node's own count of reads, 1 in the same N (a
+record's key would trace a hot record always or never), under the same
+kill switch. Sealed traces go to a ring of 4x TENDERMINT_TXTRACE_RING
+(on a read-mostly chain a node answers many reads for each write), the
+``query_stage_seconds{stage}`` histogram, and the stop dump's
+``query_traces``.
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 import zlib
@@ -77,6 +94,8 @@ STAGES = (
     "event_delivery", "rpc_reply",
 )
 _RANK = {s: i for i, s in enumerate(STAGES)}
+READ_STAGES = ("rpc_ingress", "app_lock", "proved", "rpc_reply")
+_READ_RANK = {s: i for i, s in enumerate(READ_STAGES)}
 # the bytes the sample rule hashes: a signed tx's pubkey and signature
 SAMPLE_BYTES = 96
 
@@ -87,6 +106,20 @@ def in_sample(tx: bytes, n: int) -> bool:
     """The sample rule, the same on every node: 1 in n txs by the crc32
     of their first SAMPLE_BYTES bytes (n <= 0: none)."""
     return n > 0 and zlib.crc32(tx[:SAMPLE_BYTES]) % n == 0
+
+
+def _spans(stamps: dict, rank: dict) -> dict[str, float]:
+    """Seconds since the PREVIOUS stamped instant, for each stamped stage
+    but the first, in time order (ties in `rank` order): they telescope
+    to the last stamp less the first."""
+    out: dict[str, float] = {}
+    prev = None
+    for t, _rank, stage in sorted(
+            (t, rank.get(s, len(rank)), s) for s, t in stamps.items()):
+        if prev is not None:
+            out[stage] = t - prev
+        prev = t
+    return out
 
 
 def txtrace_hists(reg=None) -> dict:
@@ -116,6 +149,12 @@ def txtrace_hists(reg=None) -> dict:
             "tx_visible_latency_seconds",
             "sampled per-tx end-to-end latency: check_tx ingress to "
             "DeliverTx event delivery",
+        ),
+        "query": reg.histogram(
+            "query_stage_seconds",
+            "per-read span of an abci_query from the previous stamped "
+            "stage to this one (sampled reads only)",
+            labelnames=("stage",),
         ),
     }
     setattr(reg, _hist_attr, fams)
@@ -156,16 +195,7 @@ class TxTrace:
         PREVIOUS stamped instant, in time order (ties in canonical
         order). Telescoping by construction — summing the spans through
         block_commit reproduces the commit latency exactly."""
-        if stamps is None:
-            stamps = self.stamps
-        out: dict[str, float] = {}
-        prev = None
-        for t, _rank, stage in sorted(
-                (t, _RANK.get(s, len(STAGES)), s) for s, t in stamps.items()):
-            if prev is not None:
-                out[stage] = t - prev
-            prev = t
-        return out
+        return _spans(self.stamps if stamps is None else stamps, _RANK)
 
     def to_json(self) -> dict:
         # snapshot FIRST: an RPC reader serializes in-flight traces
@@ -199,6 +229,35 @@ class TxTrace:
         return out
 
 
+# the sampled read this thread is serving (RPC handler threads only): the
+# app's client and the app stamp it without a handle on the recorder
+_serving = threading.local()
+
+
+def stamp_read(stage: str) -> None:
+    """Stamp `stage` (keep-first) on the sampled read this thread
+    serves; a thread serving no sampled read pays one getattr."""
+    tr = getattr(_serving, "read", None)
+    if tr is not None and stage not in tr.stamps:
+        tr.stamps[stage] = time.time()
+
+
+class ReadTrace:
+    """One sampled read's stamps, keyed by its JSON-RPC id."""
+
+    __slots__ = ("id", "stamps")
+
+    def __init__(self, rid, at: float):
+        self.id = rid
+        self.stamps: dict[str, float] = {"rpc_ingress": at}
+
+    def to_json(self) -> dict:
+        stamps = dict(self.stamps)
+        return {"id": self.id, "stages": stamps,
+                "spans": {k: round(v, 6)
+                          for k, v in _spans(stamps, _READ_RANK).items()}}
+
+
 class TxTraceRecorder:
     """Sampled per-tx lifecycle spans keyed by tx bytes in flight and
     by tx hash at rest (the ring). One recorder per node — the mempool,
@@ -225,6 +284,8 @@ class TxTraceRecorder:
             ring = max(1, int(_env_number("TENDERMINT_TXTRACE_RING", 2048,
                                           cast=int)))
         self._ring: deque[TxTrace] = deque(maxlen=ring)
+        self._read_ring: deque[ReadTrace] = deque(maxlen=4 * ring)
+        self._reads = itertools.count()
         self._mtx = threading.Lock()
         # insertion-ordered (py3.7 dict): the oldest active is the
         # eviction victim when the bound is hit
@@ -431,6 +492,38 @@ class TxTraceRecorder:
         self._seal(tr, "committed" if committed else "unanswered")
         if committed:
             self.completed += 1
+
+    # -- reads (the RPC handler's thread) ----------------------------------
+
+    def read_ingress(self, rid, at: float | None = None) -> ReadTrace | None:
+        """An abci_query arrived with JSON-RPC id `rid`: open its trace if
+        the node's count of reads samples it, as the calling thread's."""
+        n = self._n()
+        if n <= 0 or next(self._reads) % n:
+            return None
+        tr = _serving.read = ReadTrace(
+            rid, at if at is not None else time.time())
+        return tr
+
+    def read_reply(self, tr: ReadTrace, at: float | None = None) -> None:
+        """The read's reply is written: stamp rpc_reply, seal."""
+        _serving.read = None
+        tr.stamps.setdefault("rpc_reply", at if at is not None else time.time())
+        try:
+            hist = txtrace_hists(self.metrics_registry)["query"]
+            for stage, span in _spans(tr.stamps, _READ_RANK).items():
+                hist.labels(stage=stage).observe(span)
+        except Exception:  # noqa: BLE001 — never break the reply path
+            pass
+        with self._mtx:
+            self._read_ring.append(tr)
+
+    def read_dump(self) -> list[dict]:
+        """The sealed read traces, oldest first (the stop dump's
+        `query_traces`)."""
+        with self._mtx:
+            items = list(self._read_ring)
+        return [tr.to_json() for tr in items]
 
     # -- sealing + metrics -------------------------------------------------
 

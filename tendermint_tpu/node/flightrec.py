@@ -63,7 +63,8 @@ artifact, ``consensus_traces``: the per-height traces' ring as the
 relay hold, the delay line's counters where one is configured), and
 ``tx_traces``: the sampled tx-lifecycle traces, the completed ring
 oldest first and then those in flight (``tx_traces_fn``,
-libs/txtrace.TxTraceRecorder.dump).
+libs/txtrace.TxTraceRecorder.dump), and ``query_traces``: the sampled
+reads' traces, oldest first (``query_traces_fn``, ``read_dump``).
 
 ``record()`` is one enabled-check + one deque.append (GIL-atomic) — the
 TENDERMINT_FLIGHTREC_DISABLE kill switch makes it a single attribute
@@ -118,6 +119,8 @@ class FlightRecorder:
         # optional provider of the sampled tx-lifecycle traces, completed
         # and in flight (node/node.py: the node's TxTraceRecorder.dump)
         self.tx_traces_fn = None
+        # ... and of the sampled reads' traces (TxTraceRecorder.read_dump)
+        self.query_traces_fn = None
         self._watch_stop: threading.Event | None = None
 
     @property
@@ -251,6 +254,7 @@ class FlightRecorder:
             "consensus_traces": self._snapshot(self.traces_fn, []),
             "links": self._snapshot(self.links_fn, []),
             "tx_traces": self._snapshot(self.tx_traces_fn, []),
+            "query_traces": self._snapshot(self.query_traces_fn, []),
         }
         self.dumps += 1
         if self.dump_dir is None:

@@ -508,6 +508,7 @@ class Node(BaseService):
             t.to_json() for t in trace.last(trace.ring_size)]
         self.flightrec.links_fn = self.link_records
         self.flightrec.tx_traces_fn = self.txtrace.dump
+        self.flightrec.query_traces_fn = self.txtrace.read_dump
 
     def link_records(self) -> list[dict]:
         """One record a peer for the flight recorder's dumps: who it is,

@@ -101,7 +101,8 @@ def build_registry(node) -> telemetry.Registry:
     cs.trace.metrics_registry = reg
 
     # round 17: the tx-lifecycle histograms (tx_stage_seconds{stage} +
-    # the two end-to-end latencies) live on the NODE registry like the
+    # the two end-to-end latencies; a read's road,
+    # query_stage_seconds{stage}) live on the NODE registry like the
     # per-peer families, materialized now for a stable family set
     from tendermint_tpu.libs import txtrace as _txtrace
 

@@ -202,9 +202,16 @@ class RPCServer(BaseService):
                 raw = self.rfile.read(length)
                 id_ = None
                 admitted = False
+                reads = read = None
                 try:
                     req = json.loads(raw.decode())
                     id_ = req.get("id")
+                    if req.get("method") == "abci_query":
+                        # a read's road (libs/txtrace.py): sampled by the
+                        # node's count of reads, stamped on this thread
+                        reads = getattr(getattr(server.ctx, "node", None),
+                                        "txtrace", None)
+                        read = reads and reads.read_ingress(id_)
                     admit = server.admission.admit_request(
                         self.client_address[0],
                         self._request_kind(req.get("method", "")),
@@ -228,6 +235,8 @@ class RPCServer(BaseService):
                 finally:
                     if admitted:
                         server.admission.request_done()
+                    if read:
+                        reads.read_reply(read)
 
             # -- GET URI + websocket (handlers.go:229-300, 351+) -----------
 

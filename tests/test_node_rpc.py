@@ -460,9 +460,10 @@ def test_prometheus_exposition_endpoint(node):
                 # round 15: gossip-arrival distributions + per-peer RTT
                 "consensus_quorum_seconds", "consensus_first_part_seconds",
                 "p2p_peer_ping_rtt_seconds",
-                # round 17: the tx-lifecycle distributions
+                # round 17: the tx-lifecycle distributions; a read's
+                # road
                 "tx_stage_seconds", "tx_commit_latency_seconds",
-                "tx_visible_latency_seconds"):
+                "tx_visible_latency_seconds", "query_stage_seconds"):
         assert families.get(fam) == "histogram", fam
     # a live node has fsynced (group commit): the histogram has samples
     count = next(
